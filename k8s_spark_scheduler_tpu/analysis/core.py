@@ -86,7 +86,6 @@ DEFAULT_ALLOWLIST: Dict[str, List[dict]] = {
         {"path": "resilience/gate.py", "why": "shed-recently window is an operator-facing wall-clock signal"},
         {"path": "kube/restclient.py", "why": "idle-connection reconnect tracks real socket age"},
         {"path": "kube/ratelimit.py", "why": "token-bucket refill meters real API-server wall time"},
-        {"path": "utils/tpuprobe.py", "why": "subprocess probe timeout bounds real wall time"},
         {"path": "ha/crashmatrix.py", "why": "matrix cells run live servers with wall-clock lease TTLs; waits must bound real time"},
         {"path": "tracing/", "why": "latency measurement wants real durations even in sims"},
     ],

@@ -66,7 +66,12 @@ class GangPacker:
                     avail_after=node_mat,
                 ),
             )
-        elif config.backend == "pallas" and jax.default_backend() == "tpu":
+        elif config.backend == "pallas":
+            if jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    "GangPackerConfig(backend='pallas') needs a TPU; the default "
+                    f"backend is {jax.default_backend()!r} — ask for backend='xla'"
+                )
             from ..ops.pallas_queue import pallas_solve_queue
 
             evenly = config.assignment_policy == "distribute-evenly"

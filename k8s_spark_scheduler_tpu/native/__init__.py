@@ -1,16 +1,20 @@
 """ctypes binding for the native snapshot maintainer (native/snapshot.cpp).
 
-Builds the shared library on first import with g++ (cached beside the
-source); degrades gracefully to a pure-numpy implementation when no
-compiler is available, so the framework never hard-depends on the
-toolchain.
+Builds the shared library on first use with g++ (cached under
+``native/_build`` by content hash, see :func:`build_native_lib`);
+degrades gracefully to a pure-numpy implementation when no compiler is
+available, so the framework never hard-depends on the toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import glob
+import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Tuple
@@ -21,24 +25,52 @@ logger = logging.getLogger(__name__)
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "snapshot.cpp")
-_LIB = os.path.join(_REPO_ROOT, "native", "_build", "libsnapshot.so")
+_BUILD_DIR = os.path.join(_REPO_ROOT, "native", "_build")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
 
 
-def build_native_lib(src: str, lib_path: str, flags: list[str]) -> ctypes.CDLL:
-    """Shared compile-on-first-use machinery for the native libraries:
-    rebuild when the source is newer (a present prebuilt .so with no
-    source alongside is used as-is), always via an atomic tmp+rename so
-    concurrent processes never CDLL-load a partially written file.
-    Raises on failure — callers wrap with their own degrade policy."""
-    stale = not os.path.exists(lib_path) or (
-        os.path.exists(src) and os.path.getmtime(lib_path) < os.path.getmtime(src)
-    )
-    if stale:
-        os.makedirs(os.path.dirname(lib_path), exist_ok=True)
+@functools.cache
+def _toolchain_identity() -> bytes:
+    """Compiler version + host CPU identity: ``-march=native`` output is
+    only valid on the CPU it was built for, and a different g++ may lay
+    out the same source differently."""
+    version = subprocess.run(
+        ["g++", "--version"], check=True, capture_output=True
+    ).stdout
+    cpu = [platform.machine().encode()]
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"model name", b"flags", b"Features")):
+                    cpu.append(line.strip())
+                    if len(cpu) >= 3:
+                        break
+    except OSError:
+        pass
+    return version + b"\0" + b"\0".join(cpu)
+
+
+def build_native_lib(src: str, name: str, flags: list[str]) -> ctypes.CDLL:
+    """Shared compile-on-first-use machinery for the native libraries.
+
+    The built file is named by a hash of the source bytes, the flags,
+    the compiler version and the host CPU identity, so the library that
+    loads was compiled on this host from this source: a ``.so`` carried
+    over from another machine, another source revision or other flags
+    has a different name and is never picked up.  Built via an atomic
+    tmp+rename so concurrent processes never CDLL-load a partially
+    written file.  Raises on failure — callers wrap with their own
+    degrade policy."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read())
+    digest.update("\0".join(flags).encode())
+    digest.update(_toolchain_identity())
+    lib_path = os.path.join(_BUILD_DIR, f"lib{name}-{digest.hexdigest()[:16]}.so")
+    if not os.path.exists(lib_path):
+        os.makedirs(_BUILD_DIR, exist_ok=True)
         tmp = lib_path + f".tmp.{os.getpid()}"
         subprocess.run(
             ["g++", *flags, "-shared", "-fPIC", "-std=c++17", src, "-o", tmp],
@@ -46,6 +78,13 @@ def build_native_lib(src: str, lib_path: str, flags: list[str]) -> ctypes.CDLL:
             capture_output=True,
         )
         os.replace(tmp, lib_path)
+        # builds of older sources / other hosts are dead weight now
+        for stale in glob.glob(os.path.join(_BUILD_DIR, f"lib{name}-*.so")):
+            if stale != lib_path:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass
     return ctypes.CDLL(lib_path)
 
 
@@ -55,7 +94,7 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            lib = build_native_lib(_SRC, _LIB, ["-O2"])
+            lib = build_native_lib(_SRC, "snapshot", ["-O2"])
             lib.snap_create.restype = ctypes.c_void_p
             lib.snap_create.argtypes = [ctypes.c_int64]
             lib.snap_destroy.argtypes = [ctypes.c_void_p]
@@ -91,28 +130,19 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
                 ctypes.c_void_p,
                 ctypes.c_void_p,
             ]
-            try:
-                # optional (older prebuilt .so may lack it; rows_equal
-                # then uses the numpy fallback)
-                lib.snap_rows_diff.restype = ctypes.c_int64
-                lib.snap_rows_diff.argtypes = [
-                    ctypes.c_void_p,
-                    ctypes.c_void_p,
-                    ctypes.c_int64,
-                ]
-            except AttributeError:
-                pass
-            try:
-                # optional: equivalence-class grouping (ROADMAP 2)
-                lib.snap_group_rows.restype = ctypes.c_int64
-                lib.snap_group_rows.argtypes = [
-                    ctypes.c_void_p,
-                    ctypes.c_void_p,
-                    ctypes.c_int64,
-                    ctypes.c_void_p,
-                ]
-            except AttributeError:
-                pass
+            lib.snap_rows_diff.restype = ctypes.c_int64
+            lib.snap_rows_diff.argtypes = [
+                ctypes.c_void_p,
+                ctypes.c_void_p,
+                ctypes.c_int64,
+            ]
+            lib.snap_group_rows.restype = ctypes.c_int64
+            lib.snap_group_rows.argtypes = [
+                ctypes.c_void_p,
+                ctypes.c_void_p,
+                ctypes.c_int64,
+                ctypes.c_void_p,
+            ]
             _lib = lib
         except Exception:
             logger.warning("native snapshot library unavailable; using numpy fallback",
@@ -220,7 +250,7 @@ def rows_equal(a: np.ndarray, b: np.ndarray) -> bool:
     if n == 0:
         return True
     lib = _build_and_load()
-    if lib is not None and hasattr(lib, "snap_rows_diff"):
+    if lib is not None:
         diff = lib.snap_rows_diff(
             a.ctypes.data_as(ctypes.c_void_p),
             b.ctypes.data_as(ctypes.c_void_p),
@@ -247,7 +277,7 @@ def group_rows(rows: np.ndarray, flags: Optional[np.ndarray] = None
     if flags is not None:
         flags = np.ascontiguousarray(flags, dtype=np.uint8)
     lib = _build_and_load()
-    if lib is not None and hasattr(lib, "snap_group_rows"):
+    if lib is not None:
         n_classes = lib.snap_group_rows(
             rows.ctypes.data_as(ctypes.c_void_p),
             flags.ctypes.data_as(ctypes.c_void_p) if flags is not None else None,

@@ -22,7 +22,6 @@ _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 _SRC = os.path.join(_REPO_ROOT, "native", "fifo_solver.cpp")
-_LIB = os.path.join(_REPO_ROOT, "native", "_build", "libfifosolver.so")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -41,7 +40,7 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
 
             lib = build_native_lib(
                 _SRC,
-                _LIB,
+                "fifosolver",
                 [
                     "-O3", "-march=native", "-funroll-loops",
                     # IEEE semantics preserved; only errno/trap
@@ -67,80 +66,54 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
                 ctypes.c_int64, ctypes.c_int64, _P, _P, _P, _P, _P, _P, _P,
                 _P, _P,
             ]
-            try:
-                # optional helper: a prebuilt library from an older
-                # source may lack it — that must not disable the lane
-                lib.seq_sum_f64.restype = ctypes.c_double
-                lib.seq_sum_f64.argtypes = [_P, ctypes.c_int64]
-            except AttributeError:
-                pass
-            try:
-                lib.seq_sum_f64_plain.restype = ctypes.c_double
-                lib.seq_sum_f64_plain.argtypes = [_P, ctypes.c_int64]
-            except AttributeError:
-                pass
+            lib.seq_sum_f64.restype = ctypes.c_double
+            lib.seq_sum_f64.argtypes = [_P, ctypes.c_int64]
             lib.fifo_solve_queue_single_az.restype = ctypes.c_int
             lib.fifo_solve_queue_single_az.argtypes = [
                 ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, _P, _P, _P,
                 _P, _P, _P, _P, _P, _P, _P, ctypes.c_int, ctypes.c_int,
                 ctypes.c_int, _P, _P, _P,
             ]
-            try:
-                # delta-solve session API (PR 5) — optional for the same
-                # prebuilt-library reason as seq_sum_f64
-                lib.fifo_sess_create.restype = _P
-                lib.fifo_sess_create.argtypes = []
-                lib.fifo_sess_destroy.restype = None
-                lib.fifo_sess_destroy.argtypes = [_P]
-                lib.fifo_sess_load.restype = ctypes.c_int
-                lib.fifo_sess_load.argtypes = [
-                    _P, ctypes.c_int64, _P, _P, _P, ctypes.c_int,
-                    ctypes.c_int64, ctypes.c_int, ctypes.c_int64,
-                ]
-                lib.fifo_sess_solve.restype = ctypes.c_int64
-                lib.fifo_sess_solve.argtypes = [
-                    _P, ctypes.c_int64, _P, _P, _P, _P,
-                ]
-                lib.fifo_sess_mem_bytes.restype = ctypes.c_int64
-                lib.fifo_sess_mem_bytes.argtypes = [_P]
-            except AttributeError:
-                pass
-            try:
-                # equivalence-class compressed lanes (ROADMAP 2) —
-                # optional for the same prebuilt-library reason
-                lib.fifo_solve_queue_classes.restype = ctypes.c_int
-                lib.fifo_solve_queue_classes.argtypes = [
-                    ctypes.c_int64, ctypes.c_int64, _P, _P, _P, _P,
-                    ctypes.c_int, _P, _P, _P,
-                ]
-                lib.fifo_sess_set_classes.restype = None
-                lib.fifo_sess_set_classes.argtypes = [_P, ctypes.c_int]
-                lib.fifo_sess_class_stats.restype = None
-                lib.fifo_sess_class_stats.argtypes = [_P, _P]
-            except AttributeError:
-                pass
-            try:
-                # decision-provenance explainer (PR 6) — optional for the
-                # same prebuilt-library reason as the session API
-                lib.fifo_explain_queue.restype = ctypes.c_int
-                lib.fifo_explain_queue.argtypes = [
-                    ctypes.c_int64, ctypes.c_int64, _P, _P, _P, _P,
-                    ctypes.c_int, ctypes.c_int64, _P, _P,
-                ]
-            except AttributeError:
-                pass
-            try:
-                # capacity-observatory probes (PR 7) — optional for the
-                # same prebuilt-library reason
-                lib.fifo_probe_headroom.restype = ctypes.c_int
-                lib.fifo_probe_headroom.argtypes = [
-                    ctypes.c_int64, _P, _P, _P, ctypes.c_int64, _P,
-                    ctypes.c_int32, _P, _P, _P,
-                ]
-                lib.fifo_frag_report.restype = ctypes.c_int
-                lib.fifo_frag_report.argtypes = [ctypes.c_int64, _P, _P, _P]
-            except AttributeError:
-                pass
+            # delta-solve session API
+            lib.fifo_sess_create.restype = _P
+            lib.fifo_sess_create.argtypes = []
+            lib.fifo_sess_destroy.restype = None
+            lib.fifo_sess_destroy.argtypes = [_P]
+            lib.fifo_sess_load.restype = ctypes.c_int
+            lib.fifo_sess_load.argtypes = [
+                _P, ctypes.c_int64, _P, _P, _P, ctypes.c_int,
+                ctypes.c_int64, ctypes.c_int, ctypes.c_int64,
+            ]
+            lib.fifo_sess_solve.restype = ctypes.c_int64
+            lib.fifo_sess_solve.argtypes = [
+                _P, ctypes.c_int64, _P, _P, _P, _P,
+            ]
+            lib.fifo_sess_mem_bytes.restype = ctypes.c_int64
+            lib.fifo_sess_mem_bytes.argtypes = [_P]
+            # equivalence-class compressed lanes
+            lib.fifo_solve_queue_classes.restype = ctypes.c_int
+            lib.fifo_solve_queue_classes.argtypes = [
+                ctypes.c_int64, ctypes.c_int64, _P, _P, _P, _P,
+                ctypes.c_int, _P, _P, _P,
+            ]
+            lib.fifo_sess_set_classes.restype = None
+            lib.fifo_sess_set_classes.argtypes = [_P, ctypes.c_int]
+            lib.fifo_sess_class_stats.restype = None
+            lib.fifo_sess_class_stats.argtypes = [_P, _P]
+            # decision-provenance explainer
+            lib.fifo_explain_queue.restype = ctypes.c_int
+            lib.fifo_explain_queue.argtypes = [
+                ctypes.c_int64, ctypes.c_int64, _P, _P, _P, _P,
+                ctypes.c_int, ctypes.c_int64, _P, _P,
+            ]
+            # capacity-observatory probes
+            lib.fifo_probe_headroom.restype = ctypes.c_int
+            lib.fifo_probe_headroom.argtypes = [
+                ctypes.c_int64, _P, _P, _P, ctypes.c_int64, _P,
+                ctypes.c_int32, _P, _P, _P,
+            ]
+            lib.fifo_frag_report.restype = ctypes.c_int
+            lib.fifo_frag_report.argtypes = [ctypes.c_int64, _P, _P, _P]
             _lib = lib
         except Exception:
             logger.warning(
@@ -276,24 +249,11 @@ def solve_queue_single_az_native(
 
 def seq_sum_f64_native(values: np.ndarray) -> Optional[float]:
     """CPython-sum-compatible float64 reduction — bit-identical to
-    builtin sum() of the list on THIS interpreter (Neumaier-compensated
-    since 3.12, plain left-to-right before), or None when the lib (or
-    the needed symbol, in an older prebuilt) is unavailable.
-
-    The gauge path now uses :func:`neumaier_sum_f64_native` instead
-    (its contract is cross-lane order-robustness, not builtin parity);
-    this wrapper remains the drop-in for any host loop of the form
-    ``sum(list)`` a lane wants to move to C without changing a bit."""
-    import sys
-
-    lib = _build_and_load()
-    if lib is None:
-        return None
-    symbol = "seq_sum_f64" if sys.version_info >= (3, 12) else "seq_sum_f64_plain"
-    if not hasattr(lib, symbol):
-        return None
-    v = np.ascontiguousarray(values, dtype=np.float64)
-    return float(getattr(lib, symbol)(_c(v), v.shape[0]))
+    builtin sum() of the list (Neumaier-compensated in Python 3.12),
+    or None when the lib is unavailable.  The drop-in for any host loop
+    of the form ``sum(list)`` a lane wants to move to C without changing
+    a bit; the same symbol as :func:`neumaier_sum_f64_native`."""
+    return neumaier_sum_f64_native(values)
 
 
 def neumaier_sum_f64_native(values: np.ndarray) -> Optional[float]:
@@ -305,7 +265,7 @@ def neumaier_sum_f64_native(values: np.ndarray) -> Optional[float]:
     recovers the same rounded value where plain sequential addition
     diverges by an ulp.  None when unavailable."""
     lib = _build_and_load()
-    if lib is None or not hasattr(lib, "seq_sum_f64"):
+    if lib is None:
         return None
     v = np.ascontiguousarray(values, dtype=np.float64)
     return float(lib.seq_sum_f64(_c(v), v.shape[0]))
@@ -345,7 +305,7 @@ def solve_packed_cold(
 
 def native_classes_available() -> bool:
     lib = _build_and_load()
-    return lib is not None and hasattr(lib, "fifo_solve_queue_classes")
+    return lib is not None
 
 
 def solve_packed_classes(
@@ -362,7 +322,7 @@ def solve_packed_classes(
     instead of O(nodes).  The fourth element is the compression evidence:
     ``{"classes_initial", "rebuilds", "overlay_peak", "classes_last"}``."""
     lib = _build_and_load()
-    if lib is None or not hasattr(lib, "fifo_solve_queue_classes"):
+    if lib is None:
         raise RuntimeError("native class-compressed solver not available")
     avail_io = np.ascontiguousarray(avail, dtype=np.int32).copy()
     rank = np.ascontiguousarray(driver_rank, dtype=np.int32)
@@ -387,7 +347,7 @@ def solve_packed_classes(
 
 def native_session_available() -> bool:
     lib = _build_and_load()
-    return lib is not None and hasattr(lib, "fifo_sess_create")
+    return lib is not None
 
 
 class NativeFifoSession:
@@ -403,7 +363,7 @@ class NativeFifoSession:
 
     def __init__(self, threads: int = 0, min_pool_nodes: int = 8192):
         lib = _build_and_load()
-        if lib is None or not hasattr(lib, "fifo_sess_create"):
+        if lib is None:
             raise RuntimeError("native fifo session not available")
         self._lib = lib
         self._handle = ctypes.c_void_p(lib.fifo_sess_create())
@@ -469,21 +429,16 @@ class NativeFifoSession:
     def set_classes(self, enable: bool) -> bool:
         """Toggle equivalence-class compressed stepping (ROADMAP 2).
         Verdicts and planes stay byte-identical either way; returns
-        whether the loaded extension supports the mode (older prebuilt
-        libraries silently stay row-level)."""
-        if not hasattr(self._lib, "fifo_sess_set_classes"):
-            return False
+        True (the mode is always available once the library loads)."""
         self._lib.fifo_sess_set_classes(self._handle, int(bool(enable)))
         return True
 
     def class_stats(self) -> dict:
         """Compression evidence of the session's class partition:
         ``{"classes_last", "rebuilds", "overlay_peak", "overlay_now"}``
-        (zeros until class mode has stepped, or when unsupported)."""
+        (zeros until class mode has stepped)."""
         out = np.zeros(4, dtype=np.int64)
-        if getattr(self, "_handle", None) and hasattr(
-            self._lib, "fifo_sess_class_stats"
-        ):
+        if getattr(self, "_handle", None):
             self._lib.fifo_sess_class_stats(self._handle, _c(out))
         return {
             "classes_last": int(out[0]),
@@ -495,7 +450,7 @@ class NativeFifoSession:
 
 def native_explain_available() -> bool:
     lib = _build_and_load()
-    return lib is not None and hasattr(lib, "fifo_explain_queue")
+    return lib is not None
 
 
 class ExplainResult:
@@ -540,11 +495,10 @@ def explain_queue_native(
 ) -> Optional[ExplainResult]:
     """Shortfall vector + blocker set for the app at queue position
     ``target`` (see fifo_solver.cpp fifo_explain_queue), or None when
-    the library (or the symbol, in an older prebuilt) is unavailable or
-    the inputs are degenerate.  Diagnostic only — never a decision
+    the library is unavailable or the inputs are degenerate.  Diagnostic only — never a decision
     input."""
     lib = _build_and_load()
-    if lib is None or not hasattr(lib, "fifo_explain_queue"):
+    if lib is None:
         return None
     av = np.ascontiguousarray(avail, dtype=np.int32)
     rank = np.ascontiguousarray(driver_rank, dtype=np.int32)
@@ -566,7 +520,7 @@ def explain_queue_native(
 
 def native_probe_available() -> bool:
     lib = _build_and_load()
-    return lib is not None and hasattr(lib, "fifo_probe_headroom")
+    return lib is not None
 
 
 def probe_headroom_native(
@@ -579,10 +533,10 @@ def probe_headroom_native(
     """(headroom[S] int64, usable[S,3] int64, probes[S] int64) — per
     shape, the largest gang size the solver would admit at queue
     position 0 against this basis (fifo_probe_headroom), or None when
-    the library (or symbol) is unavailable.  Read-only diagnostic —
+    the library is unavailable.  Read-only diagnostic —
     never a decision input."""
     lib = _build_and_load()
-    if lib is None or not hasattr(lib, "fifo_probe_headroom"):
+    if lib is None:
         return None
     av = np.ascontiguousarray(avail, dtype=np.int32)
     rank = np.ascontiguousarray(driver_rank, dtype=np.int32)
@@ -609,9 +563,9 @@ def frag_report_native(
 ) -> Optional[np.ndarray]:
     """[3, 4] int64 per-dimension (total free, largest chunk, free
     nodes, overdrawn nodes) over the eligible rows, or None when the
-    library (or symbol) is unavailable."""
+    library is unavailable."""
     lib = _build_and_load()
-    if lib is None or not hasattr(lib, "fifo_frag_report"):
+    if lib is None:
         return None
     av = np.ascontiguousarray(avail, dtype=np.int32)
     eok = np.ascontiguousarray(exec_ok, dtype=np.uint8)

@@ -5,7 +5,7 @@ The paper's core guarantee — a driver schedules only if the whole gang
 fits and every earlier driver fits first — was re-proved from scratch on
 every Filter request: a full snapshot marshal, the AZ-aware sorts, GCD
 scaling, and an O(queue × nodes) native queue solve (~17-21 ms at
-10k × 1k per NOTES_ROUND5).  Between consecutive decisions almost
+10k × 1k on one CPU core).  Between consecutive decisions almost
 nothing changes (the Firmament observation), so the warm path here costs
 O(what changed):
 

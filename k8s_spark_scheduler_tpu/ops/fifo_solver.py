@@ -411,7 +411,6 @@ class TpuFifoSolver:
         # differential-tested bit-identical to the device scans
         use_native = self._use_native()
 
-        shape_key = (problem.avail.shape, problem.driver.shape)
         didx_all = None  # native lanes keep per-position driver indices
         if n_earlier > 0:
             # whole-queue pass over the earlier drivers only.  The
@@ -465,7 +464,7 @@ class TpuFifoSolver:
                         self.last_queue_lane = "pallas-minfrag"
                         with default_profiler.profile(
                             "fifo_queue", lane="pallas-minfrag",
-                            shape_key=shape_key,
+                            fn=pallas_solve_queue_min_frag,
                         ) as rec:
                             feasible_dev, _, avail_after = pallas_solve_queue_min_frag(
                                 *queue_args
@@ -487,7 +486,7 @@ class TpuFifoSolver:
 
                         self.last_queue_lane = "pallas"
                         with default_profiler.profile(
-                            "fifo_queue", lane="pallas", shape_key=shape_key
+                            "fifo_queue", lane="pallas", fn=pallas_solve_queue
                         ) as rec:
                             feasible_dev, _, avail_after = pallas_solve_queue(
                                 *queue_args, evenly=evenly
@@ -822,6 +821,11 @@ class TpuSingleAzFifoSolver:
         # solver-side pallas wiring is testable on CPU
         self.interpret = interpret
         self.last_path: Optional[str] = None
+        # which program ran the last queue pass — "pallas" / "xla" (the
+        # two fused one-dispatch lanes), "native" or "host"; None = no
+        # queue pass ran.  last_path says how the answer was reached,
+        # this says on what.
+        self.last_queue_lane: Optional[str] = None
 
     def _use_pallas(self) -> bool:
         return _pallas_selected(self.backend)
@@ -844,6 +848,7 @@ class TpuSingleAzFifoSolver:
         all_apps = list(earlier_apps) + [current_app]
         apps = tensorize_apps(all_apps)
         problem = scale_problem(cluster, apps)
+        self.last_queue_lane = None
         if not problem.ok:
             self.last_path = None
             return FifoOutcome(supported=False)
@@ -985,7 +990,7 @@ class TpuSingleAzFifoSolver:
                         n_zones=len(candidate_zones), az_aware=self.az_aware,
                         minfrag=minfrag_inner, strict=self.strict_reference_parity,
                     )
-                self.last_path = "native"
+                self.last_path = self.last_queue_lane = "native"
                 for i in range(n_earlier):
                     if not feas_n[i] and not earlier_skip_allowed[i]:
                         gate_span.tag("earlierOk", False)
@@ -1005,7 +1010,7 @@ class TpuSingleAzFifoSolver:
 
                     with default_profiler.profile(
                         "fifo_queue_single_az", lane="pallas",
-                        shape_key=(avail.shape, problem.driver.shape),
+                        fn=pallas_solve_queue_single_az,
                     ) as rec:
                         feas_d, zone_d, didx_d, uncertain_d, avail_after_d = (
                             pallas_solve_queue_single_az(
@@ -1068,9 +1073,15 @@ class TpuSingleAzFifoSolver:
                     # the lane that served this request, whatever the
                     # FIFO verdict
                     self.last_path = "fused"
+                    self.last_queue_lane = "pallas" if self._use_pallas() else "xla"
                     feasible = np.asarray(out.feasible)[:n_earlier]
                     with tracing.child_span(
-                        "fifo_gate", {"lane": "fused", "earlierApps": n_earlier}
+                        "fifo_gate",
+                        {
+                            "lane": "fused",
+                            "kernel": self.last_queue_lane,
+                            "earlierApps": n_earlier,
+                        },
                     ) as gate_span:
                         for i in range(n_earlier):
                             if not feasible[i] and not earlier_skip_allowed[i]:
@@ -1085,7 +1096,7 @@ class TpuSingleAzFifoSolver:
         if not fused_done and n_earlier > 0:
             # host lane: per-driver vmapped zone solves with the exact
             # float64 zone choice (the uncertainty/guard fallback)
-            self.last_path = "host"
+            self.last_path = self.last_queue_lane = "host"
             with tracing.child_span(
                 "fifo_gate", {"lane": "host", "earlierApps": n_earlier}
             ) as gate_span:

@@ -258,7 +258,9 @@ def _solve_min_frag(cpu, mem, gpu, rank, exec_ok, dr, ex, k, node_ids):
     # exact (k + max)//2 without int32 overflow (batch_solver quirk:
     # an unbounded node's host threshold admits every bounded capacity)
     target = (k // 2) + (max_cap // 2) + (((k & 1) + (max_cap & 1)) // 2)
-    subset = elig & jnp.where(has_sent, d < MF_SENT, d < target)
+    # select the scalar threshold, not the masks: mosaic cannot legalize
+    # a select whose operands are i1 vectors
+    subset = elig & (d < jnp.where(has_sent, MF_SENT, target))
     attempt = has_sent | (k < max_cap)
 
     sub_ok, sub_drained, sub_partial, sub_kstar = _mf_run(
@@ -268,12 +270,13 @@ def _solve_min_frag(cpu, mem, gpu, rank, exec_ok, dr, ex, k, node_ids):
         d, elig, k, node_ids
     )
     use_sub = attempt & sub_ok
-    drained = jnp.where(use_sub, sub_drained, full_drained)
     partial = jnp.where(use_sub, sub_partial, full_partial)
     kstar = jnp.where(use_sub, sub_kstar, full_kstar)
-    counts = jnp.where(drained, d, 0) + jnp.where(
-        node_ids == partial, kstar, 0
+    # int32 planes for the same reason as the threshold above
+    drained_counts = jnp.where(
+        use_sub, jnp.where(sub_drained, d, 0), jnp.where(full_drained, d, 0)
     )
+    counts = drained_counts + jnp.where(node_ids == partial, kstar, 0)
     counts = jnp.where(full_ok & feasible, counts, 0)
     return feasible, flat_idx, is_driver, counts
 

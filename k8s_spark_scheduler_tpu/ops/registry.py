@@ -7,7 +7,6 @@ Name → algorithm map with the reference's names plus the TPU-native
 
 from __future__ import annotations
 
-import logging
 from dataclasses import dataclass
 
 from .. import compat
@@ -85,52 +84,30 @@ def select_binpacker(
         TPU_BATCH_EVENLY,
         TPU_BATCH_SINGLE_AZ_MIN_FRAG,
     ):
-        try:
-            # imported lazily: pulls in jax
-            from .batch_adapter import (
-                tpu_batch_az_aware_binpacker,
-                tpu_batch_binpacker,
-                tpu_batch_evenly_binpacker,
-                tpu_batch_min_frag_binpacker,
-                tpu_batch_single_az_binpacker,
-                tpu_batch_single_az_min_frag_binpacker,
-            )
+        # imported lazily: pulls in jax.  A tpu-batch name with no
+        # importable solver is a broken install and raises — answering
+        # from the host policy under the device policy's name would hide
+        # that the device never served.
+        from .batch_adapter import (
+            tpu_batch_az_aware_binpacker,
+            tpu_batch_binpacker,
+            tpu_batch_evenly_binpacker,
+            tpu_batch_min_frag_binpacker,
+            tpu_batch_single_az_binpacker,
+            tpu_batch_single_az_min_frag_binpacker,
+        )
 
-            if name == TPU_BATCH_MIN_FRAG:
-                return tpu_batch_min_frag_binpacker(strict_reference_parity)
-            if name == TPU_BATCH_SINGLE_AZ:
-                return tpu_batch_single_az_binpacker()
-            if name == TPU_BATCH_AZ_AWARE:
-                return tpu_batch_az_aware_binpacker()
-            if name == TPU_BATCH_EVENLY:
-                return tpu_batch_evenly_binpacker()
-            if name == TPU_BATCH_SINGLE_AZ_MIN_FRAG:
-                return tpu_batch_single_az_min_frag_binpacker(strict_reference_parity)
-            return tpu_batch_binpacker()
-        except ImportError:
-            # fall back to the host policy with the SAME placement and
-            # single-AZ semantics, not the default
-            fallback = {
-                TPU_BATCH: TIGHTLY_PACK,
-                TPU_BATCH_SINGLE_AZ: SINGLE_AZ_TIGHTLY_PACK,
-                TPU_BATCH_AZ_AWARE: AZ_AWARE_TIGHTLY_PACK,
-                TPU_BATCH_MIN_FRAG: MINIMAL_FRAGMENTATION,
-                TPU_BATCH_EVENLY: DISTRIBUTE_EVENLY,
-                TPU_BATCH_SINGLE_AZ_MIN_FRAG: SINGLE_AZ_MINIMAL_FRAGMENTATION,
-            }[name]
-            logging.getLogger(__name__).error(
-                "binpack %r configured but the JAX batch solver could not be "
-                "imported; falling back to %s",
-                name,
-                fallback,
-                exc_info=True,
-            )
-            if not strict_reference_parity and fallback in (
-                MINIMAL_FRAGMENTATION,
-                SINGLE_AZ_MINIMAL_FRAGMENTATION,
-            ):
-                return _minfrag_binpacker(fallback, strict_reference_parity)
-            return _REGISTRY[fallback]
+        if name == TPU_BATCH_MIN_FRAG:
+            return tpu_batch_min_frag_binpacker(strict_reference_parity)
+        if name == TPU_BATCH_SINGLE_AZ:
+            return tpu_batch_single_az_binpacker()
+        if name == TPU_BATCH_AZ_AWARE:
+            return tpu_batch_az_aware_binpacker()
+        if name == TPU_BATCH_EVENLY:
+            return tpu_batch_evenly_binpacker()
+        if name == TPU_BATCH_SINGLE_AZ_MIN_FRAG:
+            return tpu_batch_single_az_min_frag_binpacker(strict_reference_parity)
+        return tpu_batch_binpacker()
     return _REGISTRY.get(name, _REGISTRY[DEFAULT])
 
 

@@ -1,0 +1,106 @@
+"""Compile the queue-solver kernels a policy dispatches, before traffic.
+
+The warm-up drives a private instance of the configured policy's queue
+solver over synthetic problems of each wanted shape, through the same
+``solve`` entry the extender calls.  Whatever that policy dispatches on
+this platform — Pallas queue kernel on a TPU, XLA zone solves on a CPU
+host, the native C++ lane — is therefore what gets compiled; no second
+list of "the kernels policy X uses" exists to drift from the solver.
+
+Anything the compile raises propagates: a kernel the platform refuses is
+a start-up failure (server/wiring.py keeps readiness false), not a
+request-time fallback.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Iterable, Tuple
+
+from ..types.resources import NodeSchedulingMetadata, Resources
+from .registry import select_binpacker
+from .sparkapp import AppDemand
+from .tensorize import APP_BUCKETS, NODE_BUCKETS, bucket_size
+
+WARM_ZONES = 3  # zone count is a compile shape; 3 AZs is typical
+
+# the shapes real clusters hit first; a server that starts against an
+# already-populated cluster adds its own (warm_shapes)
+_BASE_SHAPES = tuple((nb, APP_BUCKETS[0]) for nb in NODE_BUCKETS[:3])
+
+
+def warm_shapes(n_nodes: int, n_pending_drivers: int) -> Tuple[Tuple[int, int], ...]:
+    """(node bucket, app bucket) pairs to compile: the small buckets plus
+    the bucket of the cluster as observed at start-up, if any."""
+    shapes = list(_BASE_SHAPES)
+    if n_nodes > 0:
+        observed = (
+            bucket_size(n_nodes),
+            bucket_size(n_pending_drivers + 1, buckets=APP_BUCKETS),
+        )
+        if observed not in shapes:
+            shapes.append(observed)
+    return tuple(shapes)
+
+
+def warm_queue_solver(
+    binpack_algo: str,
+    strict_reference_parity: bool,
+    shapes: Iterable[Tuple[int, int]],
+    should_stop: Callable[[], bool] = lambda: False,
+) -> None:
+    """Compile every kernel ``binpack_algo`` dispatches on this platform
+    at each (nodes, apps) shape.  No-op for policies without a device
+    queue solver, and for the plain policies on hosts where the native
+    C++ lane serves them (nothing to compile; loading the library is the
+    whole warm-up).  Raises on any build or compile failure."""
+    binpacker = select_binpacker(
+        binpack_algo, strict_reference_parity=strict_reference_parity
+    )
+    solver = binpacker.queue_solver
+    if solver is None:
+        return
+    from .fifo_solver import _native_selected, _pallas_selected
+
+    native_lane = not _pallas_selected(solver.backend) and _native_selected(
+        solver.backend
+    )
+    if native_lane and not binpacker.is_single_az:
+        return
+    one = Resources.of("1", "1Gi")
+    node = Resources.of("8", "8Gi")
+    for n_nodes, n_apps in shapes:
+        if should_stop():
+            return
+        metadata = {
+            f"warm-{i:06d}": NodeSchedulingMetadata(
+                available=node,
+                schedulable=node,
+                zone_label=f"warm-z{i % WARM_ZONES}",
+            )
+            for i in range(n_nodes)
+        }
+        order = list(metadata)
+        earlier = [AppDemand(one, one, 1) for _ in range(n_apps - 1)]
+        outcome = solver.solve(
+            metadata, order, order, earlier, [True] * len(earlier),
+            AppDemand(one, one, 1),
+        )
+        if not outcome.supported or solver.last_queue_lane is None:
+            raise RuntimeError(
+                f"solver warm-up for {binpack_algo} at {n_nodes}x{n_apps} did "
+                "not reach the queue solve (synthetic problem refused)"
+            )
+        if getattr(solver, "az_aware", False):
+            # the cross-zone fallback only runs when no single zone fits,
+            # which the all-feasible problem above never reaches
+            import jax.numpy as jnp
+
+            from .batch_solver import solve_single
+
+            row = jnp.zeros((3,), jnp.int32)
+            solve_single(
+                jnp.zeros((n_nodes, 3), jnp.int32),
+                jnp.zeros((n_nodes,), jnp.int32),
+                jnp.zeros((n_nodes,), bool),
+                row, row, jnp.int32(0),
+            ).feasible.block_until_ready()

@@ -37,11 +37,14 @@ _STATE_VALUE = {HEALTHY: 0.0, DEMOTED: 1.0}
 
 
 class _Lane:
-    __slots__ = ("state", "consecutive_failures", "demoted_at", "probe_in_flight")
+    __slots__ = (
+        "state", "consecutive_failures", "failures", "demoted_at", "probe_in_flight",
+    )
 
     def __init__(self):
         self.state = HEALTHY
         self.consecutive_failures = 0
+        self.failures = 0  # lifetime total, never reset
         self.demoted_at = 0.0
         self.probe_in_flight = False
 
@@ -114,6 +117,7 @@ class LaneHealth:
         with self._lock:
             lane = self._lane(name)
             lane.consecutive_failures += 1
+            lane.failures += 1
             if lane.state == DEMOTED:
                 # failed probe: restart the cooloff
                 lane.demoted_at = timesource.now()
@@ -144,6 +148,13 @@ class LaneHealth:
     def demoted_lanes(self) -> List[str]:
         with self._lock:
             return sorted(n for n, l in self._lanes.items() if l.state == DEMOTED)
+
+    def failure_totals(self) -> Dict[str, int]:
+        """Lifetime failures per lane (errors and over-budget successes),
+        lanes with none omitted.  chip_smoke.py asserts it is empty: a lane
+        that failed even once did not serve every answer."""
+        with self._lock:
+            return {n: l.failures for n, l in self._lanes.items() if l.failures}
 
     def state_of(self, name: str) -> str:
         with self._lock:

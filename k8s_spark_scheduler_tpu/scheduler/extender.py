@@ -24,6 +24,7 @@ from ..capacity import enter_predicate_lock, exit_predicate_lock
 from ..config import FifoConfig
 from ..contention.locktime import TimedLock
 from ..tracing import spans as tracing
+from ..tracing.profiling import default_profiler
 from ..demands.manager import DemandManager
 from ..events import events as ev
 from ..kube.informer import Informer
@@ -55,6 +56,14 @@ from .sparkpods import (
 )
 
 logger = logging.getLogger(__name__)
+
+# lane-health lane → the ``path`` tag its host fallbacks are counted
+# under in tpu.fastpath{lane=fallback}
+_FALLBACK_PATH = {
+    "tensor_driver": "driver",
+    "device_fifo": "driver-fifo",
+    "tensor_reschedule": "executor",
+}
 
 # outcome constants (resource.go:46-60)
 FAILURE_UNBOUND = "failure-unbound"
@@ -248,6 +257,40 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         if self._lane_health is not None:
             self._lane_health.release_probe(lane)
         return None
+
+    def _lane_elapsed(self, t0: float, compile0: float) -> float:
+        """Seconds a device lane took since ``t0``, without the jit
+        compile time the kernel profiler booked meanwhile: the first
+        request of a new shape bucket compiles for seconds, once, and
+        must not read as a slow lane to the latency budget."""
+        return (time.perf_counter() - t0) - (
+            default_profiler.compile_seconds() - compile0
+        )
+
+    def _lane_fault(self, lane: str) -> None:
+        """A device lane raised and the request is about to be answered
+        from the host path.  Counted where operators, bench.py and
+        chip_smoke.py can read it (``tpu.fastpath`` with
+        ``lane=fallback``): an answer that did not come from the device
+        must never look like one that did."""
+        if self._lane_health is not None:
+            self._lane_health.record_failure(lane)
+        self._metrics.counter(
+            mnames.TPU_FASTPATH, {"path": _FALLBACK_PATH[lane], "lane": "fallback"}
+        )
+
+    def host_fallbacks(self) -> int:
+        """Requests so far whose device lane raised and that the host
+        path answered instead (the sum of the ``lane=fallback``
+        counters).  Zero on a healthy deployment."""
+        return int(
+            sum(
+                self._metrics.get_counter(
+                    mnames.TPU_FASTPATH, {"path": path, "lane": "fallback"}
+                )
+                for path in _FALLBACK_PATH.values()
+            )
+        )
 
     def _check_deadline(self, phase: str) -> None:
         """Phase-boundary deadline check (resilience/deadline.py): one
@@ -676,6 +719,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         ):
             return None  # demoted: host path serves until the re-probe
         t0 = time.perf_counter()
+        compile0 = default_profiler.compile_seconds()
         try:
             check_kernel_fault("tensor_driver")
             from ..ops.fast_path import build_cluster_tensor
@@ -734,7 +778,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                     tracing.add_tag("speculation", "hit")
                     if self._lane_health is not None:
                         self._lane_health.record_success(
-                            "tensor_driver", time.perf_counter() - t0
+                            "tensor_driver", self._lane_elapsed(t0, compile0)
                         )
                     return outcome, zones
 
@@ -751,7 +795,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                     outcome, zones = served
                     if self._lane_health is not None:
                         self._lane_health.record_success(
-                            "tensor_driver", time.perf_counter() - t0
+                            "tensor_driver", self._lane_elapsed(t0, compile0)
                         )
                     return outcome, zones
 
@@ -780,12 +824,11 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                 return self._lane_neutral("tensor_driver")
             if self._lane_health is not None:
                 self._lane_health.record_success(
-                    "tensor_driver", time.perf_counter() - t0
+                    "tensor_driver", self._lane_elapsed(t0, compile0)
                 )
             return outcome, zones
         except Exception:
-            if self._lane_health is not None:
-                self._lane_health.record_failure("tensor_driver")
+            self._lane_fault("tensor_driver")
             logger.exception("tensor-snapshot fast path failed; using Quantity path")
             return None
 
@@ -838,6 +881,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         if prov is not None:
             prov.note_context(queue_names=queue_names)
         t0 = time.perf_counter()
+        compile0 = default_profiler.compile_seconds()
         try:
             check_kernel_fault("device_fifo")
             outcome = solver.solve(
@@ -862,12 +906,11 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                 )
             if self._lane_health is not None:
                 self._lane_health.record_success(
-                    "device_fifo", time.perf_counter() - t0
+                    "device_fifo", self._lane_elapsed(t0, compile0)
                 )
             return outcome
         except Exception:
-            if self._lane_health is not None:
-                self._lane_health.record_failure("device_fifo")
+            self._lane_fault("device_fifo")
             logger.exception("device FIFO solve failed; falling back to host loop")
             return None
 
@@ -1177,8 +1220,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                     self._lane_health.release_probe("tensor_reschedule")
             return result
         except Exception:
-            if self._lane_health is not None:
-                self._lane_health.record_failure("tensor_reschedule")
+            self._lane_fault("tensor_reschedule")
             logger.exception("fast reschedule lane failed; using Quantity path")
             return None
 

@@ -22,6 +22,7 @@ import sys
 from .. import __version__
 from ..config import Install
 from ..kube.apiserver import APIServer
+from ..utils.compilecache import configure_compile_cache
 from .http import ExtenderHTTPServer
 from .wiring import init_server_with_clients
 
@@ -160,6 +161,7 @@ def main(argv=None) -> int:
     else:
         api = APIServer()
         backend_desc = "embedded"
+    cache_dir = configure_compile_cache()
     scheduler = init_server_with_clients(api, install)
     http = ExtenderHTTPServer(
         scheduler,
@@ -172,17 +174,30 @@ def main(argv=None) -> int:
     print(
         f"extender serving on :{http.port} "
         f"(binpack={install.binpack_algo}, backend={backend_desc}, "
-        f"tls={'on' if http.tls else 'off'})",
+        f"tls={'on' if http.tls else 'off'}, compile-cache={cache_dir})",
         flush=True,
     )
+    rc = 0
     try:
-        stop_event.wait()
+        # a policy whose kernels this platform cannot compile must not
+        # linger unready behind a log line: the process fails
+        while not scheduler.warmup_complete() and not stop_event.wait(0.2):
+            if scheduler.warmup_error is not None:
+                print(
+                    f"solver warmup failed: {scheduler.warmup_error!r}",
+                    file=sys.stderr,
+                    flush=True,
+                )
+                rc = 1
+                break
+        if rc == 0:
+            stop_event.wait()
     finally:
         http.stop()
         scheduler.stop()
         if hasattr(api, "close"):
             api.close()
-    return 0
+    return rc
 
 
 if __name__ == "__main__":

@@ -39,6 +39,10 @@ from ..tracing import profiling as kernel_profiling
 from ..types.objects import Node, Pod, ResourceReservation
 
 
+class SolverWarmupError(RuntimeError):
+    """The configured policy's kernels did not compile on this platform."""
+
+
 @dataclass
 class Server:
     """Everything InitServerWithClients wires up."""
@@ -96,201 +100,91 @@ class Server:
         started).  Readiness gates on this: traffic admitted before the
         kernels are compiled pays jit latency on the request path, and —
         worse on a small host — the warmup's compiler threads compete
-        with live Filter requests for cores."""
+        with live Filter requests for cores.  A warmup that FAILED never
+        completes: a policy whose kernels this platform cannot compile
+        must not serve (its answers would all come from the host
+        fallback)."""
         ev = getattr(self, "_warm_done", None)
         return ev is None or ev.is_set()
+
+    @property
+    def warmup_error(self) -> "BaseException | None":
+        """What the solver warmup raised, if it failed (fatal to
+        start-up: server/__main__.py exits non-zero on it)."""
+        return getattr(self, "_warm_error", None)
 
     def wait_ready(self, timeout: float = 120.0) -> bool:
         """Block until caches are synced AND the solver warmup finished
         (the readiness condition) — what a deployment's readiness probe
-        polls for before kube-scheduler sends the first Filter."""
+        polls for before kube-scheduler sends the first Filter.  Raises
+        SolverWarmupError as soon as the warmup has failed."""
         import time as _time
 
         deadline = _time.monotonic() + timeout  # schedlint: disable=TS002 -- readiness-probe wait bounds real wall time for a live kubelet
         if not self.informer_factory.wait_for_cache_sync():
             return False
         ev = getattr(self, "_warm_done", None)
-        if ev is not None and not ev.wait(max(0.0, deadline - _time.monotonic())):  # schedlint: disable=TS002 -- remaining budget of the same real-time probe deadline
-            return False
+        while ev is not None and not ev.wait(0.05):
+            if self.warmup_error is not None:
+                raise SolverWarmupError(
+                    f"solver warmup failed for {self.install.binpack_algo}"
+                ) from self.warmup_error
+            if _time.monotonic() >= deadline:  # schedlint: disable=TS002 -- remaining budget of the same real-time probe deadline
+                return False
         return True
 
     def _warm_solver_async(self) -> None:
-        """Pre-compile the device solver kernels for the common shape
-        buckets in the background so the first Filter request doesn't
-        pay jit latency (first compile is seconds on TPU).
+        """Pre-compile, in the background, the kernels the configured
+        policy dispatches on this platform (ops/warmup.py) so the first
+        Filter request doesn't pay jit latency (first compile is seconds
+        on TPU).  A compile error is recorded in ``warmup_error`` and
+        ``_warm_done`` stays unset: readiness never turns true.
 
         The thread is joined (bounded) in stop(): a daemon thread killed
         mid-XLA-compile at interpreter shutdown aborts the whole process
         ("FATAL: exception not rethrown" from pthread teardown inside
-        the compiler).  It stays a daemon thread so a compile wedged on
+        the compiler).  It stays a daemon thread so a compile stuck on
         a dead device can never block process exit outright."""
         import threading
 
         self._warm_done = threading.Event()
-        if not self.extender.binpacker.name.startswith("tpu-batch"):
+        self._warm_error = None
+        if self.extender.binpacker.queue_solver is None:
             self._warm_done.set()
             return
 
         def warm():
+            import logging
+
+            from ..ops.warmup import warm_queue_solver, warm_shapes
+            from ..scheduler import labels as L
+
             try:
-                import numpy as _np
-
-                import jax.numpy as jnp
-
-                from ..ops.batch_solver import (
-                    solve_queue,
-                    solve_queue_min_frag,
-                    solve_queue_single_az,
-                    solve_single,
-                    solve_zones_jit,
-                )
-                from ..ops.fifo_solver import _pallas_selected
-                from ..ops.tensorize import APP_BUCKETS, NODE_BUCKETS
-
-                # warm the kernels the configured policy's PRODUCTION
-                # path actually dispatches — on TPU the plain FIFO pass
-                # runs the pallas queue kernel, the single-AZ policies
-                # dispatch solve_zones / the fused single-AZ scan, and
-                # min-frag its own queue scan; evenly and with_placements
-                # are static jit argnames, so warming the wrong variant
-                # leaves the production one uncompiled
-                name = self.extender.binpacker.name
-                minfrag = name == "tpu-batch-minimal-fragmentation"
-                evenly = name.endswith("distribute-evenly")
-                single_az = "single-az" in name or name.endswith("az-aware")
-                saz_minfrag = name == "tpu-batch-single-az-minimal-fragmentation"
-                use_pallas = _pallas_selected("auto")
-
-                # on accelerator-less hosts the native C++ lane serves the
-                # queue pass, so compiling the device kernels here would
-                # burn the serving core for minutes (the compiler threads
-                # ran concurrently with live Filters before this guard)
-                # for code the deployment never dispatches.  Build/load
-                # the native library instead; the plain policies then
-                # need no XLA at all (fallbacks compile on demand), and
-                # the single-AZ policies keep only the kernels their
-                # host-math path actually calls (solve_single +
-                # solve_zones_jit for the current-app pack).
-                native_lane = False
-                if not use_pallas:
-                    try:
-                        from ..ops.fifo_solver import _native_selected
-
-                        solver_backend = getattr(
-                            self.extender.binpacker.queue_solver,
-                            "backend", "auto",
-                        )
-                        native_lane = _native_selected(solver_backend)
-                    except Exception:
-                        native_lane = False
-                if native_lane and not single_az:
-                    return
-                warm_zones = 3  # zone count is a compile shape; 3 AZs is typical
-                for nb in NODE_BUCKETS[:3]:  # the shapes real clusters hit first
-                    if self._warm_stop.is_set():
-                        return
-                    avail = jnp.zeros((nb, 3), jnp.int32)
-                    rank = jnp.full((nb,), 2**31 - 1, jnp.int32)
-                    eok = jnp.zeros((nb,), bool)
-                    row = jnp.zeros((3,), jnp.int32)
-                    solve_single(avail, rank, eok, row, row, jnp.int32(0))
-                    ab = APP_BUCKETS[0]
-                    apps = (
-                        jnp.zeros((ab, 3), jnp.int32),
-                        jnp.zeros((ab, 3), jnp.int32),
-                        jnp.zeros((ab,), jnp.int32),
-                        jnp.zeros((ab,), bool),
+                # a server that starts against a populated cluster warms
+                # that cluster's own shape bucket too
+                pending = sum(
+                    1
+                    for pod in self.pod_informer.list(
+                        label_selector={L.SPARK_ROLE_LABEL: L.DRIVER}
                     )
-                    if single_az:
-                        # per-driver vmapped zone solves (host zone-choice
-                        # lane; the only queue lane for single-az min-frag)
-                        solve_zones_jit(
-                            avail, rank, eok,
-                            jnp.zeros((warm_zones, nb), bool),
-                            row, row, jnp.int32(0),
-                        )
-                    if native_lane:
-                        # single-AZ native: the C++ lane runs the queue
-                        # scan; only the host-math kernels above are hit
-                        continue
-                    if single_az and saz_minfrag:
-                        # the fused min-frag single-AZ scan (XLA only);
-                        # strict is a static jit argname, so warm the
-                        # configured compat mode
-                        strict = getattr(
-                            self.extender.binpacker.queue_solver,
-                            "strict_reference_parity",
-                            True,
-                        )
-                        solve_queue_single_az(
-                            avail, rank, eok,
-                            jnp.zeros((warm_zones, nb), bool),
-                            *apps,
-                            jnp.zeros((nb,), jnp.int32),
-                            jnp.zeros((nb,), jnp.int32),
-                            jnp.zeros((nb,), jnp.float32),
-                            jnp.zeros((nb,), jnp.int32),
-                            jnp.int32(1),
-                            jnp.int32(1),
-                            az_aware=False,
-                            minfrag=True,
-                            strict=strict,
-                        )
-                    elif single_az:
-                        az_aware = name.endswith("az-aware")
-                        if use_pallas:
-                            from ..ops.pallas_queue import (
-                                pallas_solve_queue_single_az,
-                            )
-
-                            pallas_solve_queue_single_az(
-                                avail, rank, eok,
-                                jnp.full((nb,), -1, jnp.int32),
-                                *apps,
-                                jnp.zeros((nb,), jnp.int32),
-                                jnp.zeros((nb,), jnp.int32),
-                                jnp.zeros((nb,), jnp.float32),
-                                jnp.zeros((nb,), jnp.int32),
-                                jnp.asarray(_np.array([1], _np.int32)),
-                                jnp.asarray(_np.array([1], _np.int32)),
-                                n_zones=warm_zones,
-                                az_aware=az_aware,
-                            )
-                        else:
-                            solve_queue_single_az(
-                                avail, rank, eok,
-                                jnp.zeros((warm_zones, nb), bool),
-                                *apps,
-                                jnp.zeros((nb,), jnp.int32),
-                                jnp.zeros((nb,), jnp.int32),
-                                jnp.zeros((nb,), jnp.float32),
-                                jnp.zeros((nb,), jnp.int32),
-                                jnp.int32(1),
-                                jnp.int32(1),
-                                az_aware=az_aware,
-                            )
-                    elif minfrag:
-                        solve_queue_min_frag(
-                            avail, rank, eok, *apps, with_placements=False
-                        )
-                    elif use_pallas:
-                        from ..ops.pallas_queue import pallas_solve_queue
-
-                        pallas_solve_queue(avail, rank, eok, *apps, evenly=evenly)
-                    else:
-                        solve_queue(
-                            avail, rank, eok, *apps,
-                            evenly=evenly, with_placements=False,
-                        )
-            except Exception:
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "solver warmup failed; first request will compile",
+                    if not pod.node_name
+                )
+                warm_queue_solver(
+                    self.install.binpack_algo,
+                    self.install.strict_reference_parity,
+                    warm_shapes(len(self.node_informer.list()), pending),
+                    should_stop=self._warm_stop.is_set,
+                )
+            except Exception as err:
+                self._warm_error = err
+                logging.getLogger(__name__).error(
+                    "solver warmup failed for %s; this instance will not "
+                    "become ready",
+                    self.install.binpack_algo,
                     exc_info=True,
                 )
-            finally:
-                self._warm_done.set()
+                return
+            self._warm_done.set()
 
         self._warm_stop = threading.Event()
         self._warm_thread = threading.Thread(

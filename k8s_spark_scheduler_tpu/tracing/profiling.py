@@ -123,6 +123,7 @@ class _Profile:
             execute = t_end - t_dispatch
             metrics.counter(mnames.KERNEL_CACHE_MISSES, tags)
             metrics.histogram(mnames.KERNEL_COMPILE_TIME, compile_s, tags)
+            prof._add_compile_seconds(compile_s)
             self._span.tag("compileMs", round(compile_s * 1000.0, 4))
         else:
             # steady state: dispatch is µs-level, fold it into execute
@@ -133,7 +134,7 @@ class _Profile:
         self._span.tag("cacheHit", not miss)
 
 
-@guarded_by("_seen_lock", "_seen")
+@guarded_by("_seen_lock", "_seen", "_compile_s")
 class KernelProfiler:
     """Profiling sink: records into a metrics registry and the active
     trace.  One module-level instance (``default_profiler``) is rebound
@@ -145,6 +146,7 @@ class KernelProfiler:
         self.metrics = metrics if metrics is not None else default_registry
         self.tracer = tracer if tracer is not None else default_tracer
         self._seen: Set[Tuple[str, Any]] = set()
+        self._compile_s = 0.0
         self._seen_lock = threading.Lock()
 
     def configure(self, metrics=None, tracer: Optional[Tracer] = None) -> None:
@@ -165,6 +167,18 @@ class KernelProfiler:
         value is a record whose ``sync(*outputs)`` the caller invokes
         immediately after the dispatch returns."""
         return _Profile(self, kernel, lane, fn, shape_key, jit)
+
+    def compile_seconds(self) -> float:
+        """Total first-call (trace + lower + compile) seconds recorded
+        so far.  Callers difference two readings around a region: the
+        extender keeps a request's compile time out of the lane-latency
+        score, chip_smoke.py reports it per shape as set-up."""
+        with self._seen_lock:
+            return self._compile_s
+
+    def _add_compile_seconds(self, seconds: float) -> None:
+        with self._seen_lock:
+            self._compile_s += seconds
 
     def _classify_miss(self, kernel, fn, shape_key, cache_before) -> bool:
         if fn is not None and cache_before is not None:

@@ -2493,14 +2493,10 @@ int fifo_frag_report(int64_t nb, const int32_t* avail_rows,
 }
 
 // CPython-compatible float64 sum: the packing-efficiency gauge
-// contract is bit-equality with the host lane's builtin sum().  Which
-// algorithm that is depends on the interpreter: since Python 3.12 the
-// float fast path is NEUMAIER-compensated summation; before that it is
-// naive left-to-right addition.  Both are provided and the ctypes
-// wrapper (native/fifo.py seq_sum_f64_native) picks by interpreter
-// version, so the bit-equality contract holds on either.  The optimize
-// attribute pins scalar in-order codegen (vectorizing would
-// reassociate).
+// contract is bit-equality with the host lane's builtin sum(), whose
+// float fast path is NEUMAIER-compensated summation (Python 3.12, the
+// interpreter this is written for).  The optimize attribute pins scalar
+// in-order codegen (vectorizing would reassociate).
 __attribute__((optimize("no-tree-vectorize", "no-unroll-loops")))
 double seq_sum_f64(const double* v, int64_t n) {
   double s = 0.0, c = 0.0;
@@ -2515,14 +2511,6 @@ double seq_sum_f64(const double* v, int64_t n) {
     s = t;
   }
   return s + c;
-}
-
-// pre-3.12 builtin sum(): plain sequential IEEE addition
-__attribute__((optimize("no-tree-vectorize", "no-unroll-loops")))
-double seq_sum_f64_plain(const double* v, int64_t n) {
-  double s = 0.0;
-  for (int64_t i = 0; i < n; ++i) s += v[i];
-  return s;
 }
 
 }  // extern "C"

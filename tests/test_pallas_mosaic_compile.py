@@ -1,0 +1,83 @@
+"""Every Pallas kernel variant the registry can dispatch on a TPU must
+get through Mosaic for the chip the system serves from.
+
+The parity tests (test_pallas_queue.py) run the kernels in interpret
+mode, which accepts programs Mosaic refuses — the min-frag kernels once
+carried a select over i1 vectors that the interpreter ran and the
+compiler could not legalize.  This test AOT-compiles each variant
+against a v5e topology description from the CPU sandbox: no chip is
+needed, libtpu is.  A compile here is a necessary condition, not a run;
+chip_smoke.py is the run.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from k8s_spark_scheduler_tpu.ops import pallas_queue as pq
+
+SHAPES = [(1024, 64), (10240, 1024)]
+
+
+@pytest.fixture(scope="module")
+def v5e_sharding():
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _queue_args(n, a):
+    """(avail, driver_rank, exec_ok, drivers, executors, counts, app_valid)
+    with the dtypes TpuFifoSolver.solve_tensor passes."""
+    return (
+        _sds((n, 3), jnp.int32), _sds((n,), jnp.int32), _sds((n,), jnp.bool_),
+        _sds((a, 3), jnp.int32), _sds((a, 3), jnp.int32), _sds((a,), jnp.int32),
+        _sds((a,), jnp.bool_),
+    )
+
+
+def _single_az_args(n, a):
+    q = _queue_args(n, a)
+    return (
+        *q[:3], _sds((n,), jnp.int32), *q[3:],
+        _sds((n,), jnp.int32), _sds((n,), jnp.int32), _sds((n,), jnp.float32),
+        _sds((n,), jnp.int32), _sds((1,), jnp.int32), _sds((1,), jnp.int32),
+    )
+
+
+# (registry policy, jitted entry point, arg builder, static kwargs)
+VARIANTS = [
+    ("tpu-batch", pq.pallas_solve_queue, _queue_args, dict(evenly=False)),
+    ("tpu-batch-distribute-evenly", pq.pallas_solve_queue, _queue_args, dict(evenly=True)),
+    ("tpu-batch-minimal-fragmentation", pq.pallas_solve_queue_min_frag, _queue_args, {}),
+    ("tpu-batch-single-az", pq.pallas_solve_queue_single_az, _single_az_args,
+     dict(n_zones=3, az_aware=False)),
+    ("tpu-batch-az-aware", pq.pallas_solve_queue_single_az, _single_az_args,
+     dict(n_zones=3, az_aware=True)),
+    ("tpu-batch-single-az-minimal-fragmentation", pq.pallas_solve_queue_single_az,
+     _single_az_args, dict(n_zones=3, minfrag=True, strict=True)),
+    ("tpu-batch-single-az-minimal-fragmentation (strict off)",
+     pq.pallas_solve_queue_single_az, _single_az_args,
+     dict(n_zones=3, minfrag=True, strict=False)),
+]
+
+
+@pytest.mark.parametrize("n,a", SHAPES, ids=lambda v: str(v))
+@pytest.mark.parametrize("policy,fn,args_of,static", VARIANTS, ids=[v[0] for v in VARIANTS])
+def test_kernel_compiles_for_v5e(v5e_sharding, policy, fn, args_of, static, n, a):
+    # the module-level entry points are already jitted for the default
+    # backend; re-jit the underlying function for the topology's device
+    target = jax.jit(
+        functools.partial(fn.__wrapped__, **static),
+        in_shardings=v5e_sharding,
+        out_shardings=v5e_sharding,
+    )
+    compiled = target.lower(*args_of(n, a)).compile()
+    assert compiled is not None
