@@ -247,9 +247,14 @@ def bind(stack: Stack, pod: Pod, node: str) -> None:
     stack.api.update(bound)
 
 
-def gate_lane_of(stack: Stack, trace_id: str) -> Optional[str]:
-    """The ``lane`` tag of the request's fifo_gate span (``kernel`` where
-    the single-AZ solver reports one: its lane tag says fused/host)."""
+# what a driver request served by a device lane of the tensor-snapshot
+# queue solver carries in its trace (docs/observability.md): the
+# benchmark's upload_ms, device_wait_ms and readback_ms read these
+DEVICE_SPANS = ("device.upload", "device.wait", "device.readback")
+
+
+def trace_of(stack: Stack, trace_id: str) -> dict:
+    """The request's completed trace."""
     deadline = time.monotonic() + 5.0
     trace = None
     while trace is None and time.monotonic() < deadline:
@@ -257,18 +262,23 @@ def gate_lane_of(stack: Stack, trace_id: str) -> Optional[str]:
         if trace is None:
             time.sleep(0.01)  # the root span closes just after the reply
     check(trace is not None, f"{stack.name}: no trace {trace_id!r} recorded")
+    return trace
 
-    def walk(span: dict) -> Optional[str]:
+
+def spans_of(span: dict):
+    yield span
+    for child in span.get("children", ()):
+        yield from spans_of(child)
+
+
+def gate_lane_of(trace: dict) -> Optional[str]:
+    """The ``lane`` tag of the request's fifo_gate span (``kernel`` where
+    the single-AZ solver reports one: its lane tag says fused/host)."""
+    for span in spans_of(trace["root"]):
         if span["name"] == "fifo_gate":
             tags = span.get("tags", {})
             return tags.get("kernel") or tags.get("lane")
-        for child in span.get("children", ()):
-            found = walk(child)
-            if found is not None:
-                return found
-        return None
-
-    return walk(trace["root"])
+    return None
 
 
 def assert_device_served(stack: Stack, trace_id: str, expect_lane: str) -> None:
@@ -287,11 +297,17 @@ def assert_device_served(stack: Stack, trace_id: str, expect_lane: str) -> None:
             solver.last_path in ("fused", "native"),
             f"{stack.name}: single-AZ queue pass took the {solver.last_path!r} lane",
         )
-    gate = gate_lane_of(stack, trace_id)
+    trace = trace_of(stack, trace_id)
+    gate = gate_lane_of(trace)
     check(
         gate is not None and gate.split("-")[0] == expect_lane,
         f"{stack.name}: fifo_gate span says lane {gate!r}, expected {expect_lane!r}",
     )
+    if expect_lane != "native" and not hasattr(solver, "last_path"):
+        # a device lane that stops being traced fails bring-up here, not
+        # a benchmark metric's None later
+        missing = set(DEVICE_SPANS) - {span["name"] for span in spans_of(trace["root"])}
+        check(not missing, f"{stack.name}: a driver request on lane {gate!r} carries no {sorted(missing)} span")
     lanes = stack.scheduler.resilience.lanes
     check(not lanes.failure_totals(), f"{stack.name}: lane failures {lanes.failure_totals()}")
     check(not lanes.demoted_lanes(), f"{stack.name}: demoted lanes {lanes.demoted_lanes()}")

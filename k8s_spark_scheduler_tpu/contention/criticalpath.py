@@ -8,9 +8,21 @@ latency into named gating segments:
 - ``lock-wait``   — extender predicate-lock wait (``lockWaitMs`` root
   tag, stamped by the lock's ``TimedLock`` wrapper while the request's
   root span is active)
-- ``serde``       — request read/decode + response encode spans
-- ``solve``       — the predicate span tree: snapshot build, FIFO gate,
-  binpack/kernel time
+- ``serde``       — request read/decode + response encode spans, and
+  the answer's head (``http.write``)
+- ``solve``       — the predicate span tree: tensor build, app
+  tensorize + scaling, FIFO gate, binpack/kernel dispatch, placement
+  decode, efficiencies
+- ``assemble``    — the driver fast path's inputs: tensor-mirror
+  snapshot and the earlier-drivers queue (``fast_path.snapshot``,
+  ``fast_path.queue_assemble``)
+- ``upload`` / ``device-wait`` / ``readback`` — the host's side of a
+  device dispatch: ``jnp.asarray`` uploads, blocking until the outputs
+  are ready, device-to-host copies (``device.*`` spans)
+- ``finish``      — the granted driver's tail (``driver.finish``:
+  efficiency gauge, placement metrics, demand delete) less its
+  write-back, and the decision record's sealing
+  (``provenance.finish``)
 - ``write-back``  — reservation/state write-back spans
 - ``other``       — the unattributed remainder (kept explicit so the
   decomposition always sums to the request, and so a growing "other"
@@ -48,7 +60,23 @@ SPAN_SEGMENTS: Dict[str, str] = {
     "fifo_gate": "solve",
     "binpack": "solve",
     "fast_path.build_tensor": "solve",
+    "fast_path.tensorize_apps": "solve",
+    "fast_path.scale_problem": "solve",
+    "provenance.capture": "solve",
+    "fast_path.decode": "solve",
+    "fast_path.efficiency": "solve",
     "executor.fast_reschedule": "solve",
+    # the same parts the benchmark's per-layer metrics read
+    # (queue_assemble_ms, upload_ms, device_wait_ms, readback_ms,
+    # driver_finish_ms): one decomposition, two readers
+    "fast_path.snapshot": "assemble",
+    "fast_path.queue_assemble": "assemble",
+    "device.upload": "upload",
+    "device.wait": "device-wait",
+    "device.readback": "readback",
+    "driver.finish": "finish",
+    "provenance.finish": "finish",
+    "http.write": "serde",
     # the concurrent engine's speculative solve runs pre-lock on the
     # request's own thread; classifying it apart from "solve" keeps the
     # lock-tenure segment honest when speculation is on (a consumed
@@ -59,8 +87,8 @@ SPAN_SEGMENTS: Dict[str, str] = {
 }
 
 SEGMENT_NAMES = (
-    "gate-queue", "lock-wait", "serde", "solve", "speculate", "write-back",
-    "other",
+    "gate-queue", "lock-wait", "serde", "solve", "assemble", "upload",
+    "device-wait", "readback", "finish", "speculate", "write-back", "other",
 )
 
 

@@ -38,6 +38,7 @@ from .. import timesource
 from ..analysis import racecheck
 from ..analysis.guarded import guarded_by
 from ..capacity import in_predicate_lock
+from ..tracing.spans import REQUEST_ROOTS
 
 logger = logging.getLogger("k8s_spark_scheduler_tpu.lifecycle")
 
@@ -561,6 +562,8 @@ class LifecycleLedger:
             self._trace_cursor
         )
         for trace in fresh:
+            if trace.get("root", {}).get("name") not in REQUEST_ROOTS:
+                continue  # background work (the marker's scan): no Filter's latency
             duration_s = trace.get("durationMs", 0.0) / 1000.0
             if self._slo is not None:
                 self._slo.observe(

@@ -57,12 +57,21 @@ ASYNC_CLIENT_DROPPED = "foundry.spark.scheduler.async.request.dropped.count"
 # kernel profiling (tracing/profiling.py): per-dispatch jit compile vs
 # execute split for the solver kernels, tagged kernel= and lane=
 KERNEL_COMPILE_TIME = "foundry.spark.scheduler.tpu.kernel.compile.time"
+# host-observed dispatch-to-ready (a host clock from the call until
+# block_until_ready returns: launch + device + sync), NOT device time;
+# the device.wait span splits it per request, a JAX profile has the
+# device's own time
 KERNEL_EXECUTE_TIME = "foundry.spark.scheduler.tpu.kernel.execute.time"
 KERNEL_CACHE_HITS = "foundry.spark.scheduler.tpu.kernel.cache.hit.count"
 KERNEL_CACHE_MISSES = "foundry.spark.scheduler.tpu.kernel.cache.miss.count"
 KERNEL_JIT_CACHE_SIZE = "foundry.spark.scheduler.tpu.kernel.jit.cache.size"
 # per-span duration distributions (tracing/spans.py), tagged span=
 TRACE_SPAN_TIME = "foundry.spark.scheduler.trace.span.time"
+# unschedulable-pod marker (scheduler/unschedulable.py): seconds per
+# scan of the aged pending backlog, and empty-cluster feasibility
+# solves run (verdict-cache misses), tagged lane=tensor|host
+UNSCHEDULABLE_SCAN_TIME = "foundry.spark.scheduler.unschedulable.scan.time"
+UNSCHEDULABLE_SOLVE_COUNT = "foundry.spark.scheduler.unschedulable.solve.count"
 
 # resilience layer (resilience/): overload protection + degraded mode
 RESILIENCE_SHED_COUNT = "foundry.spark.scheduler.resilience.shed.count"

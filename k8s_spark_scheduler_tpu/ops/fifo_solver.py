@@ -43,6 +43,32 @@ def _ceil_div(v: int, d: int) -> int:
     return -((-v) // d)
 
 
+def _upload(*arrays):
+    """Host arrays handed to the device under a ``device.upload`` span,
+    which times what the host pays for the ``jnp.asarray`` calls; where
+    the transfers land is the profiler's to show (nothing blocks here)."""
+    import jax.numpy as jnp
+
+    with tracing.child_span(
+        "device.upload",
+        {"arrays": len(arrays), "bytes": sum(a.nbytes for a in arrays)},
+    ):
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
+def _readback(value, to=np.asarray):
+    """A device value brought to the host under a ``device.readback``
+    span (``to`` converts: ``np.asarray``, ``bool``, ``int``)."""
+    with tracing.child_span("device.readback"):
+        return to(value)
+
+
+def _on_host(value, to=np.asarray):
+    """``_readback`` for the native lanes, whose values are host arrays
+    already: no transfer, no span."""
+    return to(value)
+
+
 def _pallas_selected(backend: str) -> bool:
     """Shared backend choice: 'pallas' forces the kernel, 'auto' uses it
     exactly when the default backend is a TPU."""
@@ -387,25 +413,24 @@ class TpuFifoSolver:
         """Solve from a prebuilt ClusterTensor (the tensor-snapshot fast
         path passes one directly; `metadata` is only used for the
         Quantity-based efficiency computation when provided)."""
-        import jax.numpy as jnp
-
         from .batch_solver import solve_queue, solve_queue_min_frag
 
-        apps = self._tensorize_with_cache(list(earlier_apps), current_app)
+        with tracing.child_span("fast_path.tensorize_apps"):
+            apps = self._tensorize_with_cache(list(earlier_apps), current_app)
         self.last_queue_lane = None
-        problem = scale_problem(cluster, apps)
-        if not problem.ok:
-            return FifoOutcome(supported=False)
-
         evenly = self.assignment_policy == "distribute-evenly"
         minfrag = self.assignment_policy == "minimal-fragmentation"
-        if minfrag:
-            from .batch_solver import mf_sentinel_safe
-
-            if not mf_sentinel_safe(problem.avail):
-                # a real capacity could collide with the device kernel's
-                # unbounded-capacity sentinel (batch_solver.MF_SENT)
+        with tracing.child_span("fast_path.scale_problem"):
+            problem = scale_problem(cluster, apps)
+            if not problem.ok:
                 return FifoOutcome(supported=False)
+            if minfrag:
+                from .batch_solver import mf_sentinel_safe
+
+                if not mf_sentinel_safe(problem.avail):
+                    # a real capacity could collide with the device kernel's
+                    # unbounded-capacity sentinel (batch_solver.MF_SENT)
+                    return FifoOutcome(supported=False)
         n_earlier = len(earlier_apps)
         # the native C++ lane serves every policy; decisions are
         # differential-tested bit-identical to the device scans
@@ -449,14 +474,14 @@ class TpuFifoSolver:
                         )
                     feasible = feasible_all[:n_earlier]
                 else:
-                    queue_args = (
-                        jnp.asarray(problem.avail),
-                        jnp.asarray(problem.driver_rank),
-                        jnp.asarray(problem.exec_ok),
-                        jnp.asarray(problem.driver),
-                        jnp.asarray(problem.executor),
-                        jnp.asarray(problem.count),
-                        jnp.asarray(queue_valid),
+                    queue_args = _upload(
+                        problem.avail,
+                        problem.driver_rank,
+                        problem.exec_ok,
+                        problem.driver,
+                        problem.executor,
+                        problem.count,
+                        queue_valid,
                     )
                     if minfrag and self._use_pallas():
                         from .pallas_queue import pallas_solve_queue_min_frag
@@ -470,7 +495,7 @@ class TpuFifoSolver:
                                 *queue_args
                             )
                             rec.sync(avail_after)
-                        feasible = np.asarray(feasible_dev)[:n_earlier]
+                        feasible = _readback(feasible_dev)[:n_earlier]
                     elif minfrag:
                         self.last_queue_lane = "minfrag-xla"
                         with default_profiler.profile(
@@ -479,7 +504,7 @@ class TpuFifoSolver:
                         ) as rec:
                             out = solve_queue_min_frag(*queue_args, with_placements=False)
                             rec.sync(out.avail_after)
-                        feasible = np.asarray(out.feasible)[:n_earlier]
+                        feasible = _readback(out.feasible)[:n_earlier]
                         avail_after = out.avail_after
                     elif self._use_pallas():
                         from .pallas_queue import pallas_solve_queue
@@ -492,7 +517,7 @@ class TpuFifoSolver:
                                 *queue_args, evenly=evenly
                             )
                             rec.sync(avail_after)
-                        feasible = np.asarray(feasible_dev)[:n_earlier]
+                        feasible = _readback(feasible_dev)[:n_earlier]
                     else:
                         self.last_queue_lane = "xla"
                         with default_profiler.profile(
@@ -500,7 +525,7 @@ class TpuFifoSolver:
                         ) as rec:
                             out = solve_queue(*queue_args, evenly=evenly, with_placements=False)
                             rec.sync(out.avail_after)
-                        feasible = np.asarray(out.feasible)[:n_earlier]
+                        feasible = _readback(out.feasible)[:n_earlier]
                         avail_after = out.avail_after
                 gate_span.tag("lane", self.last_queue_lane)
                 # capture BEFORE the blocked-earlier verdict below: a
@@ -520,7 +545,7 @@ class TpuFifoSolver:
                 gate_span.tag("earlierOk", True)
         else:
             with tracing.child_span("fifo_gate", {"earlierApps": 0, "earlierOk": True}):
-                avail_after = problem.avail if use_native else jnp.asarray(problem.avail)
+                avail_after = problem.avail if use_native else _upload(problem.avail)[0]
             feasible = np.zeros(0, dtype=bool)
             if self.capture_sink is not None:
                 self._capture_solve(
@@ -538,7 +563,8 @@ class TpuFifoSolver:
         feasible, didx_all, avail_after,
     ) -> None:
         """Hand the queue solve's inputs + verdicts to the provenance
-        sink (provenance/tracker.py).  Array references, no copies; only
+        sink (provenance/tracker.py).  Array references, no copies but
+        the post-queue availability read back from a device lane; only
         runs when wiring installed a sink."""
         try:
             from .batch_solver import queue_policy_code
@@ -547,34 +573,40 @@ class TpuFifoSolver:
             policy_code = queue_policy_code(self.assignment_policy)
             if policy_code is None:
                 return
-            na = n_earlier + 1
-            packed = np.empty((na, 8), dtype=np.int32)
-            packed[:, 0:3] = problem.driver[:na]
-            packed[:, 3:6] = problem.executor[:na]
-            packed[:, 6] = problem.count[:na]
-            packed[:, 7] = problem.app_valid[:na]
-            self.capture_sink(SolveArtifacts(
-                policy_code=int(policy_code),
-                lane=self.last_queue_lane or "none",
-                basis=problem.avail,
-                driver_rank=problem.driver_rank,
-                exec_ok=problem.exec_ok,
-                packed=packed,
-                n_earlier=n_earlier,
-                feasible=np.asarray(feasible, dtype=bool),
-                didx=(
-                    np.asarray(didx_all, dtype=np.int32)
-                    if didx_all is not None
-                    else None
-                ),
-                resume=0,
-                avail_after=np.asarray(avail_after, dtype=np.int32),
-                scale=problem.scale,
-                node_names=cluster.node_names,
-                zone_names=cluster.zone_names,
-                zone_id=cluster.zone_id,
-                skip_allowed=list(earlier_skip_allowed),
-            ))
+            with tracing.child_span("provenance.capture"):
+                avail_host = (
+                    avail_after
+                    if isinstance(avail_after, np.ndarray)
+                    else _readback(avail_after)
+                )
+                na = n_earlier + 1
+                packed = np.empty((na, 8), dtype=np.int32)
+                packed[:, 0:3] = problem.driver[:na]
+                packed[:, 3:6] = problem.executor[:na]
+                packed[:, 6] = problem.count[:na]
+                packed[:, 7] = problem.app_valid[:na]
+                self.capture_sink(SolveArtifacts(
+                    policy_code=int(policy_code),
+                    lane=self.last_queue_lane or "none",
+                    basis=problem.avail,
+                    driver_rank=problem.driver_rank,
+                    exec_ok=problem.exec_ok,
+                    packed=packed,
+                    n_earlier=n_earlier,
+                    feasible=np.asarray(feasible, dtype=bool),
+                    didx=(
+                        np.asarray(didx_all, dtype=np.int32)
+                        if didx_all is not None
+                        else None
+                    ),
+                    resume=0,
+                    avail_after=np.asarray(avail_host, dtype=np.int32),
+                    scale=problem.scale,
+                    node_names=cluster.node_names,
+                    zone_names=cluster.zone_names,
+                    zone_id=cluster.zone_id,
+                    skip_allowed=list(earlier_skip_allowed),
+                ))
         except Exception:
             logger.exception("provenance capture failed (diagnostic only)")
 
@@ -593,12 +625,11 @@ class TpuFifoSolver:
         Shared tail of solve_tensor and the delta-solve engine
         (ops/deltasolve.py), which substitutes its session's warm carry
         for the cold queue pass and hands the identical arguments here."""
-        import jax.numpy as jnp
-
         from .batch_solver import solve_single
 
         evenly = self.assignment_policy == "distribute-evenly"
         minfrag = self.assignment_policy == "minimal-fragmentation"
+        to_host = _on_host if use_native else _readback
         with tracing.child_span(
             "binpack", {"policy": self.assignment_policy}
         ) as binpack_span:
@@ -624,53 +655,56 @@ class TpuFifoSolver:
                 )
             else:
                 binpack_span.tag("lane", "xla")
+                single_args = _upload(
+                    problem.driver_rank,
+                    problem.exec_ok,
+                    problem.driver[n_earlier],
+                    problem.executor[n_earlier],
+                    problem.count[n_earlier],
+                )
                 with default_profiler.profile(
                     "solve_single", lane="xla", fn=solve_single
                 ) as rec:
-                    solve = solve_single(
-                        avail_after,
-                        jnp.asarray(problem.driver_rank),
-                        jnp.asarray(problem.exec_ok),
-                        jnp.asarray(problem.driver[n_earlier]),
-                        jnp.asarray(problem.executor[n_earlier]),
-                        jnp.asarray(problem.count[n_earlier]),
-                    )
+                    solve = solve_single(avail_after, *single_args)
                     rec.sync(solve.exec_counts)
-            binpack_span.tag("feasible", bool(solve.feasible))
-        if not bool(solve.feasible):
+            feasible = to_host(solve.feasible, bool)
+            binpack_span.tag("feasible", feasible)
+        if not feasible:
             return FifoOutcome(supported=True, earlier_ok=True, result=empty_packing_result())
 
         names = cluster.node_names
-        driver_node = names[int(solve.driver_idx)]
         k = current_app.min_executor_count
-        if evenly:
-            cap = np.asarray(solve.exec_capacity)[: len(names)]
-            counts = evenly_counts(cap, k)
-            executor_nodes = counts_to_evenly_list(names, counts)
-        elif minfrag:
-            cap = min_frag_unclamped_caps(
-                np.asarray(avail_after)[: len(names)],
-                problem.executor[n_earlier],
-                np.asarray(problem.exec_ok[: len(names)]),
-                int(solve.driver_idx),
-                problem.driver[n_earlier],
-            )
-            executor_nodes = minimal_fragmentation_assignment(names, cap, k)
-            if executor_nodes is None:  # unreachable: feasibility proven above
-                return FifoOutcome(
-                    supported=True, earlier_ok=True, result=empty_packing_result()
+        with tracing.child_span("fast_path.decode"):
+            driver_idx = to_host(solve.driver_idx, int)
+            driver_node = names[driver_idx]
+            if evenly:
+                cap = to_host(solve.exec_capacity)[: len(names)]
+                counts = evenly_counts(cap, k)
+                executor_nodes = counts_to_evenly_list(names, counts)
+            elif minfrag:
+                cap = min_frag_unclamped_caps(
+                    to_host(avail_after)[: len(names)],
+                    problem.executor[n_earlier],
+                    np.asarray(problem.exec_ok[: len(names)]),
+                    driver_idx,
+                    problem.driver[n_earlier],
                 )
-            # reference quirk: min-frag reports only the driver in
-            # reserved/efficiencies under strict parity (packers.
-            # make_minimal_fragmentation QUIRK, switchable)
-            counts = np.zeros(len(names), dtype=np.int64)
-            if not self.strict_reference_parity:
-                pos = {name: i for i, name in enumerate(names)}
-                for node in executor_nodes:
-                    counts[pos[node]] += 1
-        else:
-            counts = np.asarray(solve.exec_counts)[: len(names)]
-            executor_nodes = counts_to_tightly_list(names, counts)
+                executor_nodes = minimal_fragmentation_assignment(names, cap, k)
+                if executor_nodes is None:  # unreachable: feasibility proven above
+                    return FifoOutcome(
+                        supported=True, earlier_ok=True, result=empty_packing_result()
+                    )
+                # reference quirk: min-frag reports only the driver in
+                # reserved/efficiencies under strict parity (packers.
+                # make_minimal_fragmentation QUIRK, switchable)
+                counts = np.zeros(len(names), dtype=np.int64)
+                if not self.strict_reference_parity:
+                    pos = {name: i for i, name in enumerate(names)}
+                    for node in executor_nodes:
+                        counts[pos[node]] += 1
+            else:
+                counts = to_host(solve.exec_counts)[: len(names)]
+                executor_nodes = counts_to_tightly_list(names, counts)
 
         # efficiencies feed metrics only on this path (non-single-AZ
         # policies); the host lane computes them against the metadata
@@ -687,42 +721,43 @@ class TpuFifoSolver:
                 return cluster.avail[: len(names)]
             scale = problem.scale.astype(np.int64)
             return (
-                np.asarray(avail_after)[: len(names)].astype(np.int64)
+                to_host(avail_after)[: len(names)].astype(np.int64)
                 * scale[None, :]
             )
 
-        if metadata is not None:
-            reserved = build_reserved(
-                names, counts, driver_node, current_app.driver_resources,
-                current_app.executor_resources,
+        with tracing.child_span("fast_path.efficiency"):
+            if metadata is not None:
+                reserved = build_reserved(
+                    names, counts, driver_node, current_app.driver_resources,
+                    current_app.executor_resources,
+                )
+                eff_meta = metadata
+                if n_earlier > 0:
+                    eff_meta = _patch_available(metadata, names, post_queue_avail_rows())
+                efficiencies = compute_packing_efficiencies(eff_meta, reserved)
+            else:
+                # per-node reserved = count × executor (+ driver on its node)
+                reserved_rows = np.zeros_like(cluster.avail)
+                drv_row, _ = _res_rows(current_app.driver_resources)
+                exec_row, _ = _res_rows(current_app.executor_resources)
+                reserved_rows[driver_idx] += np.array(drv_row, np.int64)
+                reserved_rows[: len(names)] += (
+                    counts.astype(np.int64)[:, None] * np.array(exec_row, np.int64)[None, :]
+                )
+                efficiencies = efficiencies_from_rows(
+                    names, cluster.sched, post_queue_avail_rows(), reserved_rows
+                )
+            result = PackingResult(
+                driver_node=driver_node,
+                executor_nodes=executor_nodes,
+                has_capacity=True,
+                packing_efficiencies=efficiencies,
+                max_avg_efficiency=(
+                    efficiencies.seq_max_avg()
+                    if isinstance(efficiencies, LazyEfficiencies)
+                    else None
+                ),
             )
-            eff_meta = metadata
-            if n_earlier > 0:
-                eff_meta = _patch_available(metadata, names, post_queue_avail_rows())
-            efficiencies = compute_packing_efficiencies(eff_meta, reserved)
-        else:
-            # per-node reserved = count × executor (+ driver on its node)
-            reserved_rows = np.zeros_like(cluster.avail)
-            drv_row, _ = _res_rows(current_app.driver_resources)
-            exec_row, _ = _res_rows(current_app.executor_resources)
-            reserved_rows[int(solve.driver_idx)] += np.array(drv_row, np.int64)
-            reserved_rows[: len(names)] += (
-                counts.astype(np.int64)[:, None] * np.array(exec_row, np.int64)[None, :]
-            )
-            efficiencies = efficiencies_from_rows(
-                names, cluster.sched, post_queue_avail_rows(), reserved_rows
-            )
-        result = PackingResult(
-            driver_node=driver_node,
-            executor_nodes=executor_nodes,
-            has_capacity=True,
-            packing_efficiencies=efficiencies,
-            max_avg_efficiency=(
-                efficiencies.seq_max_avg()
-                if isinstance(efficiencies, LazyEfficiencies)
-                else None
-            ),
-        )
         return FifoOutcome(supported=True, earlier_ok=True, result=result)
 
 

@@ -437,14 +437,15 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         # for THIS decision; passing the solver's last_queue_lane here
         # would stamp artifact-less decisions (executor replays, early
         # failures) with a stale lane from a previous driver solve
-        prov.finish_decision(
-            outcome,
-            node=node,
-            lane="",
-            policy=self.binpacker.name,
-            instance_group=instance_group,
-            message=message,
-        )
+        with self._tracer.span("provenance.finish"):
+            prov.finish_decision(
+                outcome,
+                node=node,
+                lane="",
+                policy=self.binpacker.name,
+                instance_group=instance_group,
+                message=message,
+            )
         if outcome == FAILURE_DEADLINE:
             prov.on_trigger("deadline-exceeded", message)
 
@@ -670,34 +671,36 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                 "fit",
             )
 
-        if efficiency is None:
-            if packing_result.max_avg_efficiency is not None:
-                # precomputed by the tensor lanes (same float64 value as
-                # the iteration below, without materializing every node)
-                max_avg = packing_result.max_avg_efficiency
+        # the granted driver's tail; reservation.writeback stays its child
+        with self._tracer.span("driver.finish"):
+            if efficiency is None:
+                if packing_result.max_avg_efficiency is not None:
+                    # precomputed by the tensor lanes (same float64 value as
+                    # the iteration below, without materializing every node)
+                    max_avg = packing_result.max_avg_efficiency
+                else:
+                    # fast path: average the per-node efficiencies directly
+                    # (the device adapters compute them with exact value()
+                    # semantics)
+                    effs = list(packing_result.packing_efficiencies.values())
+                    max_sum = sum(max(e.gpu, e.cpu, e.memory) for e in effs)
+                    max_avg = max_sum / max(len(effs), 1)
             else:
-                # fast path: average the per-node efficiencies directly
-                # (the device adapters compute them with exact value()
-                # semantics)
-                effs = list(packing_result.packing_efficiencies.values())
-                max_sum = sum(max(e.gpu, e.cpu, e.memory) for e in effs)
-                max_avg = max_sum / max(len(effs), 1)
-        else:
-            max_avg = efficiency.max
-        self._metrics.gauge(
-            mnames.PACKING_EFFICIENCY_MAX,
-            max_avg,
-            {"instanceGroup": instance_group, "binpacker": self.binpacker.name},
-        )
-        self._report_placement_metrics(instance_group, packing_result, zones)
+                max_avg = efficiency.max
+            self._metrics.gauge(
+                mnames.PACKING_EFFICIENCY_MAX,
+                max_avg,
+                {"instanceGroup": instance_group, "binpacker": self.binpacker.name},
+            )
+            self._report_placement_metrics(instance_group, packing_result, zones)
 
-        self._demands.delete_demand_if_exists(driver, "SparkSchedulerExtender")
-        self._rrm.create_reservations(
-            driver,
-            app_resources,
-            packing_result.driver_node,
-            packing_result.executor_nodes,
-        )
+            self._demands.delete_demand_if_exists(driver, "SparkSchedulerExtender")
+            self._rrm.create_reservations(
+                driver,
+                app_resources,
+                packing_result.driver_node,
+                packing_result.executor_nodes,
+            )
         return packing_result.driver_node, SUCCESS
 
     def _try_fast_driver_path(self, instance_group, driver, node_names, app_resources):
@@ -725,7 +728,8 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             from ..ops.fast_path import build_cluster_tensor
             from ..ops.sparkapp import AppDemand
 
-            snap = self._tensor_snapshot.snapshot()
+            with self._tracer.span("fast_path.snapshot"):
+                snap = self._tensor_snapshot.snapshot()
 
             prov = self._provenance
             if prov is not None and not prov.enabled:
@@ -734,22 +738,24 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             skip_allowed = []
             queue_names: Optional[List[str]] = [] if prov is not None else None
             if self._is_fifo:
-                skip_cutoff = self._fifo_skip_cutoff(instance_group)
-                for queued in self._earlier_drivers(driver):
-                    try:
-                        # stable AppDemand per pod version: tensor rows
-                        # are computed once per app, not per request
-                        _, demand = spark_app_demand_cached(queued)
-                    except AnnotationError:
-                        logger.warning(
-                            "failed to get driver resources, skipping driver %s",
-                            queued.name,
-                        )
-                        continue
-                    earlier_apps.append(demand)
-                    skip_allowed.append(self._skip_verdict(queued, driver, skip_cutoff))
-                    if queue_names is not None:
-                        queue_names.append(queued.name)
+                with self._tracer.span("fast_path.queue_assemble") as sp:
+                    skip_cutoff = self._fifo_skip_cutoff(instance_group)
+                    for queued in self._earlier_drivers(driver):
+                        try:
+                            # stable AppDemand per pod version: tensor rows
+                            # are computed once per app, not per request
+                            _, demand = spark_app_demand_cached(queued)
+                        except AnnotationError:
+                            logger.warning(
+                                "failed to get driver resources, skipping driver %s",
+                                queued.name,
+                            )
+                            continue
+                        earlier_apps.append(demand)
+                        skip_allowed.append(self._skip_verdict(queued, driver, skip_cutoff))
+                        if queue_names is not None:
+                            queue_names.append(queued.name)
+                    sp.tag("earlierApps", len(earlier_apps))
             if prov is not None:
                 prov.note_context(
                     queue_names=queue_names,

@@ -16,8 +16,10 @@ import time
 from typing import Optional
 
 from .. import timesource
+from .. import tracing
 from ..kube.apiserver import APIServer
 from ..kube.informer import Informer
+from ..metrics import names as mnames
 from ..ops.registry import Binpacker
 from ..types.objects import Pod, PodCondition
 from ..types.resources import Resources, node_scheduling_metadata_for_nodes
@@ -42,6 +44,8 @@ class UnschedulablePodMarker:
         binpacker: Binpacker,
         timeout_seconds: float = DEFAULT_TIMEOUT_SECONDS,
         polling_interval_seconds: float = UNSCHEDULABLE_POLLING_INTERVAL_SECONDS,
+        tracer: Optional[tracing.Tracer] = None,
+        metrics=None,
     ):
         if timeout_seconds <= 0:
             timeout_seconds = DEFAULT_TIMEOUT_SECONDS
@@ -52,6 +56,10 @@ class UnschedulablePodMarker:
         self._binpacker = binpacker
         self._timeout = timeout_seconds
         self._interval = polling_interval_seconds
+        # the server's tracer and registry (wiring); without them the
+        # scan runs untraced and uncounted
+        self._tracer = tracer
+        self._metrics = metrics
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -80,30 +88,57 @@ class UnschedulablePodMarker:
         sweep.  Without this, a 1k-deep backlog rebuilt 10k-node
         Quantity metadata and ran a full pack PER POD every interval
         (tens of seconds of CPU that, on a small host, came straight
-        out of live Filter latency)."""
+        out of live Filter latency).
+
+        One root span ``unschedulable.scan`` per scan; its solves,
+        metadata builds and condition writes are three aggregate
+        children (``scan.solve``, ``scan.metadata``, ``scan.mark``), the
+        yields between pods stay in the root's self time."""
+        span = (
+            self._tracer.span("unschedulable.scan")
+            if self._tracer is not None
+            else tracing.NOOP_SPAN
+        )
+        t0 = time.perf_counter()
+        with span:
+            self._scan(span)
+        if self._metrics is not None:
+            self._metrics.histogram(
+                mnames.UNSCHEDULABLE_SCAN_TIME, time.perf_counter() - t0
+            )
+
+    def _scan(self, span) -> None:
         now = timesource.now()
         meta_cache: dict = {}
         verdict_cache: dict = {}
-        for pod in self._pod_informer.list():
-            if (
-                pod.scheduler_name == L.SPARK_SCHEDULER_NAME
-                and pod.node_name == ""
-                and pod.meta.deletion_timestamp is None
-                and pod.labels.get(L.SPARK_ROLE_LABEL) == L.DRIVER
-                and pod.creation_timestamp + self._timeout < now
-            ):
-                try:
-                    exceeds = self._pod_exceeds_cached(pod, meta_cache, verdict_cache)
-                except AnnotationError:
-                    logger.exception("failed to check if pod was unschedulable")
-                    return
-                if exceeds:
-                    logger.info("marking pod %s as exceeds capacity", pod.name)
-                self._mark_pod_cluster_capacity_status(pod, exceeds)
-                # yield between pods: the scan is a background janitor —
-                # a deep backlog must not monopolize a small host's core
-                # against live Filter requests for seconds at a stretch
-                time.sleep(0.0005)
+        pods = writes = 0
+        try:
+            for pod in self._pod_informer.list():
+                if (
+                    pod.scheduler_name == L.SPARK_SCHEDULER_NAME
+                    and pod.node_name == ""
+                    and pod.meta.deletion_timestamp is None
+                    and pod.labels.get(L.SPARK_ROLE_LABEL) == L.DRIVER
+                    and pod.creation_timestamp + self._timeout < now
+                ):
+                    pods += 1
+                    try:
+                        exceeds = self._pod_exceeds_cached(pod, meta_cache, verdict_cache)
+                    except AnnotationError:
+                        logger.exception("failed to check if pod was unschedulable")
+                        return
+                    if exceeds:
+                        logger.info("marking pod %s as exceeds capacity", pod.name)
+                    writes += self._mark_pod_cluster_capacity_status(pod, exceeds)
+                    # yield between pods: the scan is a background janitor —
+                    # a deep backlog must not monopolize a small host's core
+                    # against live Filter requests for seconds at a stretch
+                    time.sleep(0.0005)
+        finally:
+            span.tag("pods", pods)
+            span.tag("verdictMisses", len(verdict_cache))
+            span.tag("signatures", len(meta_cache))
+            span.tag("conditionWrites", writes)
 
     @staticmethod
     def _affinity_sig(pod: Pod):
@@ -139,60 +174,66 @@ class UnschedulablePodMarker:
             return hit
         cached = meta_cache.get(sig)
         if cached is None:
-            nodes = self._node_informer.list_with_predicate(
-                lambda n: driver.matches_node(n)
-            )
-            node_names = [n.name for n in nodes]
-            zero_usage = {n.name: Resources.zero() for n in nodes}
-            overhead = self._overhead.get_non_schedulable_overhead(nodes)
-            # chunked: one unbroken 10k-node Quantity build holds the
-            # GIL for ~0.5-1s and was the single biggest tail spike
-            # live Filters saw from this janitor
-            metadata = {}
-            for i in range(0, len(nodes), 512):
-                chunk = nodes[i : i + 512]
-                metadata.update(
-                    node_scheduling_metadata_for_nodes(chunk, zero_usage, overhead)
+            with tracing.aggregate_span("scan.metadata"):
+                nodes = self._node_informer.list_with_predicate(
+                    lambda n: driver.matches_node(n)
                 )
-                time.sleep(0.0005)
-            cluster = None
-            solver = getattr(self._binpacker, "queue_solver", None)
-            if solver is not None and hasattr(solver, "feasible_tensor"):
-                # the tensor is pod-independent within the signature:
-                # build once, then each verdict is one feasibility-only
-                # solve on the device/native lane (identical to
-                # binpack_func's has_capacity, per the differential
-                # suites)
-                from ..ops.tensorize import tensorize_cluster
+                node_names = [n.name for n in nodes]
+                zero_usage = {n.name: Resources.zero() for n in nodes}
+                overhead = self._overhead.get_non_schedulable_overhead(nodes)
+                # chunked: one unbroken 10k-node Quantity build holds the
+                # GIL for ~0.5-1s and was the single biggest tail spike
+                # live Filters saw from this janitor
+                metadata = {}
+                for i in range(0, len(nodes), 512):
+                    chunk = nodes[i : i + 512]
+                    metadata.update(
+                        node_scheduling_metadata_for_nodes(chunk, zero_usage, overhead)
+                    )
+                    time.sleep(0.0005)
+                cluster = None
+                solver = getattr(self._binpacker, "queue_solver", None)
+                if solver is not None and hasattr(solver, "feasible_tensor"):
+                    # the tensor is pod-independent within the signature:
+                    # build once, then each verdict is one feasibility-only
+                    # solve on the device/native lane (identical to
+                    # binpack_func's has_capacity, per the differential
+                    # suites)
+                    from ..ops.tensorize import tensorize_cluster
 
-                cluster = tensorize_cluster(metadata, node_names, node_names)
-            cached = (node_names, metadata, cluster, solver)
-            meta_cache[sig] = cached
+                    cluster = tensorize_cluster(metadata, node_names, node_names)
+                cached = (node_names, metadata, cluster, solver)
+                meta_cache[sig] = cached
         node_names, metadata, cluster, solver = cached
         exceeds = None
-        if cluster is not None:
-            from ..ops.sparkapp import AppDemand
+        lane = "tensor"
+        with tracing.aggregate_span("scan.solve"):
+            if cluster is not None:
+                from ..ops.sparkapp import AppDemand
 
-            feasible = solver.feasible_tensor(
-                cluster,
-                AppDemand(
+                feasible = solver.feasible_tensor(
+                    cluster,
+                    AppDemand(
+                        app_resources.driver_resources,
+                        app_resources.executor_resources,
+                        app_resources.min_executor_count,
+                    ),
+                )
+                if feasible is not None:
+                    exceeds = not feasible
+            if exceeds is None:
+                lane = "host"
+                result = self._binpacker.binpack_func(
                     app_resources.driver_resources,
                     app_resources.executor_resources,
                     app_resources.min_executor_count,
-                ),
-            )
-            if feasible is not None:
-                exceeds = not feasible
-        if exceeds is None:
-            result = self._binpacker.binpack_func(
-                app_resources.driver_resources,
-                app_resources.executor_resources,
-                app_resources.min_executor_count,
-                node_names,
-                node_names,
-                metadata,
-            )
-            exceeds = not result.has_capacity
+                    node_names,
+                    node_names,
+                    metadata,
+                )
+                exceeds = not result.has_capacity
+        if self._metrics is not None:
+            self._metrics.counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": lane})
         verdict_cache[key] = exceeds
         return exceeds
 
@@ -201,13 +242,13 @@ class UnschedulablePodMarker:
         non-schedulable overhead."""
         return self._pod_exceeds_cached(driver, {}, {})
 
-    def _mark_pod_cluster_capacity_status(self, driver: Pod, exceeds: bool) -> None:
+    def _mark_pod_cluster_capacity_status(self, driver: Pod, exceeds: bool) -> bool:
         """unschedulablepods.go:168-180 (condition update only when
-        changed)."""
+        changed).  True when a write was attempted."""
         status = "True" if exceeds else "False"
         current = driver.conditions.get(POD_EXCEEDS_CLUSTER_CAPACITY)
         if current is not None and current.status == status:
-            return
+            return False
         from ..kube.conflict import run_with_conflict_retry
 
         state = {"fresh": None}
@@ -225,13 +266,15 @@ class UnschedulablePodMarker:
             )
             return self._api.update(fresh)
 
-        try:
-            # the kubelet and other controllers write pod status too, so
-            # 409s here are routine — resolve them through the shared
-            # conflict-retry discipline instead of swallowing the write
-            refresh()
-            run_with_conflict_retry(attempt, refresh, kind=Pod.KIND)
-        except Exception:
-            # per-pod failure (e.g. pod deleted concurrently) must not
-            # abort the scan of the remaining drivers
-            logger.exception("failed to mark pod cluster capacity status")
+        with tracing.aggregate_span("scan.mark"):
+            try:
+                # the kubelet and other controllers write pod status too, so
+                # 409s here are routine — resolve them through the shared
+                # conflict-retry discipline instead of swallowing the write
+                refresh()
+                run_with_conflict_retry(attempt, refresh, kind=Pod.KIND)
+            except Exception:
+                # per-pod failure (e.g. pod deleted concurrently) must not
+                # abort the scan of the remaining drivers
+                logger.exception("failed to mark pod cluster capacity status")
+        return True

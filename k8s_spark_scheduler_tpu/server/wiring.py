@@ -512,6 +512,8 @@ def init_server_with_clients(
         binpacker,
         timeout_seconds=install.unschedulable_pod_timeout_seconds,
         polling_interval_seconds=unschedulable_polling_interval,
+        tracer=tracer,
+        metrics=metrics,
     )
 
     server = Server(

@@ -21,9 +21,13 @@ Design constraints (the hot path is ~1ms end to end):
 
 from .spans import (  # noqa: F401
     NOOP_SPAN,
+    REQUEST_ROOTS,
+    AggregateSpan,
     Span,
     Tracer,
     add_tag,
+    aggregate_span,
+    annotations_built,
     child_span,
     current_span,
     current_trace_id,

@@ -8,13 +8,18 @@ profiler splits every profiled dispatch into:
 
 - **compile time** — wall time of the traced Python call when the jit
   cache grew (trace + lower + compile; ``KERNEL_COMPILE_TIME``),
-- **execute time** — ``block_until_ready``-bounded device time
-  (``KERNEL_EXECUTE_TIME``),
+- **execute time** — host-observed dispatch-to-ready: the host's clock
+  from the call until ``block_until_ready`` returns
+  (``KERNEL_EXECUTE_TIME``).  It holds the launch and the sync besides
+  the device's own time; device time comes from a JAX profile only,
 - **cache hits/misses** — ``KERNEL_CACHE_HITS`` / ``KERNEL_CACHE_MISSES``,
 
 all tagged with the kernel name and the lane ("xla", "pallas",
 "native", …), and mirrored onto the active trace span so a span tree
-shows exactly which kernel compiled mid-request.
+shows exactly which kernel compiled mid-request.  Under a request's
+trace a jitted dispatch's ``kernel:<name>`` span has two children:
+``device.dispatch`` (the jitted call returning) and ``device.wait``
+(``sync()`` blocking until the outputs are ready).
 
 Cache-miss detection prefers the jitted function's own cache
 (``fn._cache_size()``); lanes that can't expose one (pallas wrappers)
@@ -30,7 +35,7 @@ import time
 from typing import Any, Optional, Set, Tuple
 
 from ..metrics import names as mnames
-from .spans import NOOP_SPAN, Tracer, current_span, default_tracer
+from .spans import NOOP_SPAN, Tracer, child_span, current_span, default_tracer
 from ..analysis.guarded import guarded_by
 
 
@@ -48,19 +53,29 @@ class _KernelRecord:
     right after the traced call returns, with the outputs — it stamps
     the dispatch end, then blocks until the arrays are device-ready."""
 
-    __slots__ = ("t0", "t_dispatch", "t_end")
+    __slots__ = ("t0", "t_dispatch", "t_end", "_phase")
 
-    def __init__(self) -> None:
+    def __init__(self, phase=NOOP_SPAN) -> None:
+        # the open ``device.dispatch`` span of a traced jitted dispatch
+        self._phase = phase
+        self._phase.__enter__()
         self.t0 = time.perf_counter()
         self.t_dispatch: Optional[float] = None
         self.t_end: Optional[float] = None
 
+    def _end_phase(self) -> None:
+        self._phase.__exit__(None, None, None)
+        self._phase = NOOP_SPAN
+
     def sync(self, *arrays: Any) -> None:
         self.t_dispatch = time.perf_counter()
-        for a in arrays:
-            block = getattr(a, "block_until_ready", None)
-            if block is not None:
-                block()
+        traced = self._phase is not NOOP_SPAN
+        self._end_phase()
+        with child_span("device.wait") if traced else NOOP_SPAN:
+            for a in arrays:
+                block = getattr(a, "block_until_ready", None)
+                if block is not None:
+                    block()
         self.t_end = time.perf_counter()
 
 
@@ -90,11 +105,13 @@ class _Profile:
         self._span.__enter__()
         if self._jit and self._fn is not None:
             self._cache_before = jit_cache_size(self._fn)
-        self._rec = _KernelRecord()
+        traced = self._jit and self._span is not NOOP_SPAN
+        self._rec = _KernelRecord(child_span("device.dispatch") if traced else NOOP_SPAN)
         return self._rec
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         rec = self._rec
+        rec._end_phase()  # a dispatch that raised, or whose caller never synced
         now = time.perf_counter()
         t_end = rec.t_end if rec.t_end is not None else now
         t_dispatch = rec.t_dispatch if rec.t_dispatch is not None else t_end

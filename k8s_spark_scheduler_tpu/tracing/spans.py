@@ -9,11 +9,27 @@ The active span is a module-level ``ContextVar`` — per-thread in the
 threaded HTTP server (each request handler thread has its own context),
 and shared across tracer instances so ``events.events`` and log lines
 can stamp the current ``trace_id`` without any plumbing.
+
+Two additions serve the traces that are no request's and the profiler:
+
+- an *aggregate child* (``Span.aggregate(name)``): one child per name
+  that stands for many sequential phases of its parent, its duration
+  their sum and its ``count`` tag their number — the unschedulable-pod
+  marker's scan runs a thousand solves and must not put a thousand
+  spans into ``/traces``;
+- the *profiler bridge*: while a JAX profiler session is active in the
+  process, every span of a trace also opens a
+  ``jax.profiler.TraceAnnotation("sched.<name>")`` for its lifetime, so
+  the program's phases lie on the profiler's timeline beside the
+  device's operations.  The tracer asks once per root span
+  (``TraceMe.is_enabled()``, ~135 ns); the trace's spans inherit the
+  answer, and with no session nothing is built.
 """
 
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 import uuid
@@ -30,6 +46,31 @@ _CURRENT: ContextVar[Optional["Span"]] = ContextVar(
 )
 
 _SPAN_SEQ = itertools.count(1)
+
+# roots that are one scheduling request; any other root (the marker's
+# ``unschedulable.scan``) is background work that request-shaped
+# consumers (critical path, lifecycle ledger, SLO latency) must skip
+REQUEST_ROOTS = ("http.request", "predicate")
+
+PROFILER_PREFIX = "sched."
+_annotations_built = 0
+
+
+def annotations_built() -> int:
+    """Profiler annotations built so far by the bridge (0 for a process
+    that never had a profiler session while it traced)."""
+    return _annotations_built
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    active in this process, else None.  Never imports jax: a process
+    that has not imported it cannot hold a session."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation
+    return annotation if annotation.is_enabled() else None
 
 
 def current_span() -> Optional["Span"]:
@@ -66,6 +107,15 @@ def child_span(name: str, tags: Optional[Dict[str, Any]] = None):
     return span
 
 
+def aggregate_span(name: str):
+    """One phase of the aggregate child ``name`` of the active span (see
+    ``Span.aggregate``), or the shared no-op when none is active."""
+    parent = _CURRENT.get()
+    if parent is None:
+        return NOOP_SPAN
+    return parent.aggregate(name)
+
+
 class Span:
     """One timed phase.  Children attach at creation; duration lands at
     context-manager exit.  Not a dataclass: __slots__ + plain attribute
@@ -83,6 +133,8 @@ class Span:
         "_t0",
         "_token",
         "_tracer",
+        "_bridge",
+        "_annotation",
     )
 
     def __init__(self, name: str, trace_id: str, parent: Optional["Span"]):
@@ -97,6 +149,21 @@ class Span:
         self._t0 = 0.0
         self._token = None
         self._tracer: Optional["Tracer"] = None
+        # the profiler bridge: the annotation class while this trace is
+        # bridged (decided once, at the root), else None
+        self._bridge = parent._bridge if parent is not None else None
+        self._annotation = None
+
+    def aggregate(self, name: str) -> "AggregateSpan":
+        """The aggregate child ``name`` of this span, made on first use:
+        ``with span.aggregate("scan.solve"): ...`` once per phase.  The
+        phases must be sequential in the span's own thread."""
+        for child in self.children:
+            if child.name == name and type(child) is AggregateSpan:
+                return child
+        child = AggregateSpan(name, self.trace_id, self)
+        self.children.append(child)
+        return child
 
     def tag(self, key: str, value: Any) -> "Span":
         self.tags[key] = value
@@ -121,6 +188,11 @@ class Span:
         # semantic instant, not latency: sim traces carry virtual time
         self.start_time = timesource.now()
         self._token = _CURRENT.set(self)
+        if self._bridge is not None:
+            global _annotations_built
+            _annotations_built += 1
+            self._annotation = self._bridge(PROFILER_PREFIX + self.name)
+            self._annotation.__enter__()
         # duration through the same pluggable source family: a sim
         # trace must not mix virtual timestamps with wall durations
         self._t0 = timesource.perf()
@@ -128,12 +200,41 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.duration = timesource.perf() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if exc is not None and "error" not in self.tags:
             self.tags["error"] = f"{type(exc).__name__}: {exc}"
         if self._token is not None:
             _CURRENT.reset(self._token)
         if self.parent is None and self._tracer is not None:
             self._tracer._finish_trace(self)
+        return False
+
+
+class AggregateSpan(Span):
+    """One child that stands for every phase of its name under one
+    parent: each ``with`` adds the phase's time to ``duration`` and one
+    to the ``count`` tag, so the parent's self time stays right and the
+    tree stays small.  While a phase runs no span is active — whatever
+    the phase calls opens no-op children instead of a thousand real
+    ones — and nothing goes to the profiler."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "AggregateSpan":
+        if self.duration is None:
+            self.start_time = timesource.now()
+            self.duration = 0.0
+            self.tags["count"] = 0
+        self._token = _CURRENT.set(None)
+        self._t0 = timesource.perf()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.duration += timesource.perf() - self._t0
+        self.tags["count"] += 1
+        _CURRENT.reset(self._token)
         return False
 
 
@@ -209,6 +310,7 @@ class Tracer:
         else:
             span = Span(name, trace_id or new_trace_id(), None)
             span._tracer = self
+            span._bridge = _profiler_annotation()
         if tags:
             span.tags.update(tags)
         return span

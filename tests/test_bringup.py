@@ -321,6 +321,29 @@ def test_chip_smoke_fails_when_another_lane_served(chip_smoke):
         )
 
 
+def test_chip_smoke_fails_when_a_device_lane_is_not_traced(chip_smoke, monkeypatch):
+    """On the chip a served driver request has to carry device.upload,
+    device.wait and device.readback; rehearsed here on the jnp lane the
+    CPU can serve, then with one of the spans taken away."""
+    from k8s_spark_scheduler_tpu.ops import fifo_solver
+
+    start_stack = chip_smoke.start_stack
+
+    def on_the_xla_lane(policy, name, **kwargs):
+        stack = start_stack(policy, name, **kwargs)
+        if name == "device":
+            stack.scheduler.extender.delta_engine = None
+            stack.solver.backend = "xla"
+        return stack
+
+    monkeypatch.setattr(chip_smoke, "start_stack", on_the_xla_lane)
+    report = chip_smoke.run_phase("tpu-batch", "tightly-pack", 48, 6, seed=7, expect_lane="xla")
+    assert report.granted_drivers >= 1
+    monkeypatch.setattr(fifo_solver, "_readback", fifo_solver._on_host)
+    with pytest.raises(chip_smoke.SmokeFailure, match="device.readback"):
+        chip_smoke.run_phase("tpu-batch", "tightly-pack", 48, 6, seed=7, expect_lane="xla")
+
+
 def test_chip_smoke_catches_a_counted_fallback(chip_smoke):
     from k8s_spark_scheduler_tpu.ops import registry
 

@@ -1,0 +1,407 @@
+"""The span-name contract of the served path (ROADMAP "span names are a
+contract"): which children a granted driver Filter leaves under
+``predicate`` on each lane the CPU can serve, aggregate children, the
+marker's one trace per scan, the profiler bridge, and the critical-path
+decomposition over the new tree.  No wall-clock budgets (ROADMAP D10)."""
+
+import glob
+import json
+import os
+import time
+import urllib.request
+
+import pytest
+
+from k8s_spark_scheduler_tpu import tracing
+from k8s_spark_scheduler_tpu.contention import criticalpath
+from k8s_spark_scheduler_tpu.metrics import names as mnames
+from k8s_spark_scheduler_tpu.server.http import ExtenderHTTPServer
+from k8s_spark_scheduler_tpu.testing.harness import Harness
+from k8s_spark_scheduler_tpu.tracing import Tracer
+from k8s_spark_scheduler_tpu.types import serde
+
+NODES = [f"n{i}" for i in range(6)]
+
+# the driver's tree under ``predicate`` on the tensor-snapshot fast path,
+# in order; a name maps to its children
+HOST_LEAVES = (
+    "fast_path.snapshot",
+    "fast_path.queue_assemble",
+    "fast_path.build_tensor",
+    "fast_path.tensorize_apps",
+    "fast_path.scale_problem",
+)
+FINISH = ("driver.finish", [("reservation.writeback", [("state.writeback.enqueue", [])])])
+KERNEL_PHASES = [("device.dispatch", []), ("device.wait", [])]
+EXPECTED = {
+    "native": [
+        *((name, []) for name in HOST_LEAVES),
+        ("fifo_gate", [("kernel:fifo_queue", []), ("provenance.capture", [])]),
+        ("binpack", [("kernel:solve_app", [])]),
+        ("fast_path.decode", []),
+        ("fast_path.efficiency", []),
+        FINISH,
+        ("provenance.finish", []),
+    ],
+    "xla": [
+        *((name, []) for name in HOST_LEAVES),
+        ("fifo_gate", [
+            ("device.upload", []),
+            ("kernel:fifo_queue", KERNEL_PHASES),
+            ("device.readback", []),
+            ("provenance.capture", [("device.readback", [])]),  # the post-queue availability
+        ]),
+        ("binpack", [
+            ("device.upload", []),
+            ("kernel:solve_single", KERNEL_PHASES),
+            ("device.readback", []),
+        ]),
+        # tightly-pack decodes the driver index and the executor counts
+        ("fast_path.decode", [("device.readback", []), ("device.readback", [])]),
+        ("fast_path.efficiency", [("device.readback", [])]),
+        FINISH,
+        ("provenance.finish", []),
+    ],
+}
+
+
+def shape(span):
+    return [(c.name, shape(c)) for c in span.children]
+
+
+def names(span):
+    out = [span.name]
+    for child in span.children:
+        out.extend(names(child))
+    return out
+
+
+def find(span, name):
+    if span.name == name:
+        return span
+    for child in span.children:
+        hit = find(child, name)
+        if hit is not None:
+            return hit
+    return None
+
+
+def served_harness(lane):
+    """The full wiring at a small size with a short pending queue, the
+    warm delta-solve lane off so that ``solve_tensor`` serves, on the
+    queue lane asked for."""
+    h = Harness(binpack_algo="tpu-batch")
+    for name in NODES:
+        h.new_node(name)
+    h.extender.delta_engine = None
+    h.extender.binpacker.queue_solver.backend = lane
+    for i in range(3):
+        queued = h.static_allocation_spark_pods(f"app-queued-{i}", 1)[0]
+        queued.meta.creation_timestamp = time.time() - 100 + i
+        h.create_pod(queued)
+    return h
+
+
+def roots_of(h):
+    roots = []
+    h.server.tracer.add_observer(roots.append)
+    return roots
+
+
+# -- (a) the documented children, once per lane --------------------------------
+
+
+@pytest.mark.parametrize("lane", ["native", "xla"])
+def test_granted_driver_filter_has_exactly_the_documented_children(lane):
+    h = served_harness(lane)
+    try:
+        # the first request of an idle server reconciles (and compiles)
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
+        roots = roots_of(h)
+        driver = h.static_allocation_spark_pods("app-new", 2)[0]
+        h.assert_success(h.schedule(driver, NODES))
+        (root,) = [r for r in roots if r.name == "predicate"]
+        assert shape(root) == EXPECTED[lane]
+        gate = find(root, "fifo_gate")
+        assert gate.tags["lane"] == lane and gate.tags["earlierApps"] == 3
+        assert find(root, "fast_path.queue_assemble").tags["earlierApps"] == 3
+        device = [n for n in names(root) if n.startswith("device.")]
+        if lane == "native":
+            assert device == []
+        else:
+            upload = find(gate, "device.upload")
+            assert upload.tags["arrays"] == 7 and upload.tags["bytes"] > 0
+            assert find(find(root, "binpack"), "device.upload").tags["arrays"] == 5
+            assert {"device.upload", "device.dispatch", "device.wait", "device.readback"} == set(device)
+    finally:
+        h.close()
+
+
+def test_spans_a_self_metric_reads_keep_no_child():
+    """``serde_ms``, ``executor_serde_ms`` and ``write_back_ms`` read the
+    self time of these spans: a child under one would move an accepted
+    metric."""
+    h = served_harness("xla")
+    http = ExtenderHTTPServer(h.server, port=0)
+    http.start()
+    try:
+        post_driver(h, http.port, "app-first")
+        roots = roots_of(h)
+        post_driver(h, http.port, "app-http")
+        (root,) = [r for r in roots if r.name == "http.request"]
+        assert [c.name for c in root.children] == [
+            "http.read", "serde.decode", "predicate", "serde.encode", "http.write",
+        ]
+        for name in ("http.read", "serde.decode", "serde.encode", "state.writeback.enqueue"):
+            assert find(root, name).children == [], name
+        assert [c.name for c in find(root, "reservation.writeback").children] == [
+            "state.writeback.enqueue"
+        ]
+        assert root.tags["status"] == 200 and "status" not in find(root, "http.write").tags
+    finally:
+        http.stop()
+        h.close()
+
+
+# -- (b) aggregate children ----------------------------------------------------
+
+
+def test_aggregate_child_sums_its_phases_and_keeps_the_parents_self_time(monkeypatch):
+    from k8s_spark_scheduler_tpu import timesource
+
+    clock = [0.0]
+    monkeypatch.setattr(timesource, "perf", lambda: clock[0])
+    tracer = Tracer(capacity=4)
+    with tracer.span("unschedulable.scan") as root:
+        for _ in range(3):
+            clock[0] += 1.0  # the parent's own time
+            with root.aggregate("scan.solve"):
+                assert tracing.current_span() is None  # what a phase calls opens no children
+                assert tracing.child_span("kernel:x") is tracing.NOOP_SPAN
+                clock[0] += 0.25
+            assert tracing.current_span() is root
+        with tracing.aggregate_span("scan.mark"):
+            clock[0] += 0.5
+    assert [c.name for c in root.children] == ["scan.solve", "scan.mark"]
+    solve, mark = root.children
+    assert solve.duration == pytest.approx(0.75) and solve.tags["count"] == 3
+    assert mark.duration == pytest.approx(0.5) and mark.tags["count"] == 1
+    assert root.duration == pytest.approx(4.25)
+    assert root.duration - sum(c.duration for c in root.children) == pytest.approx(3.0)
+    as_dict = tracer.traces()[0]["root"]
+    assert [(c["name"], c["durationMs"], c["tags"]) for c in as_dict["children"]] == [
+        ("scan.solve", 750.0, {"count": 3}),
+        ("scan.mark", 500.0, {"count": 1}),
+    ]
+    assert all(c["parentId"] == as_dict["spanId"] for c in as_dict["children"])
+
+
+def test_aggregate_phase_outside_any_trace_is_the_noop():
+    assert tracing.aggregate_span("scan.solve") is tracing.NOOP_SPAN
+
+
+# -- (c) the marker's scan -----------------------------------------------------
+
+
+def aged_backlog(h, fitting=3, oversized=1):
+    for i in range(fitting):
+        pod = h.static_allocation_spark_pods(f"app-aged-{i}", 1 + i)[0]
+        pod.meta.creation_timestamp = time.time() - 3600
+        h.create_pod(pod)
+    for i in range(oversized):
+        pod = h.static_allocation_spark_pods(f"app-huge-{i}", 100)[0]
+        pod.meta.creation_timestamp = time.time() - 3600
+        h.create_pod(pod)
+
+
+def test_one_scan_is_one_trace_with_three_aggregate_children_and_two_metrics():
+    h = Harness(binpack_algo="tpu-batch")
+    try:
+        for name in NODES:
+            h.new_node(name)
+        aged_backlog(h)
+        roots = roots_of(h)
+        h.unschedulable_marker.scan_for_unschedulable_pods()
+        (root,) = roots
+        assert root.name == "unschedulable.scan" and root.parent is None
+        assert root.tags == {"pods": 4, "verdictMisses": 4, "signatures": 1, "conditionWrites": 4}
+        assert {c.name: c.tags["count"] for c in root.children} == {
+            "scan.metadata": 1, "scan.solve": 4, "scan.mark": 4,
+        }
+        assert all(type(c) is tracing.AggregateSpan and not c.children for c in root.children)
+        assert sum(c.duration for c in root.children) <= root.duration
+        metrics = h.server.metrics
+        assert metrics.get_counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": "tensor"}) == 4
+        snapshot = json.dumps(metrics.snapshot())
+        assert mnames.UNSCHEDULABLE_SCAN_TIME in snapshot
+        # a second scan finds the conditions set: verdicts again, no writes
+        h.unschedulable_marker.scan_for_unschedulable_pods()
+        assert roots[1].tags["conditionWrites"] == 0
+        assert "scan.mark" not in [c.name for c in roots[1].children]
+    finally:
+        h.close()
+
+
+def test_a_root_that_is_no_request_is_ignored_by_the_request_shaped_consumers():
+    h = Harness(binpack_algo="tpu-batch")
+    try:
+        for name in NODES:
+            h.new_node(name)
+        aged_backlog(h, fitting=1, oversized=0)
+        roots = roots_of(h)
+        h.unschedulable_marker.scan_for_unschedulable_pods()
+        (root,) = roots
+        assert root.name not in tracing.REQUEST_ROOTS
+        assert criticalpath.decompose(root) is None
+        assert h.server.tracer.find_by_tag("pod", "app-aged-0-driver") is None
+        ledger = h.server.lifecycle
+        latencies = []
+        observe = ledger._slo.observe
+
+        def recording(name, value, **kwargs):
+            if name == "filter_latency":
+                latencies.append(value)
+            return observe(name, value, **kwargs)
+
+        ledger._slo.observe = recording
+        ledger._drain_traces()
+        assert latencies == []  # the scan is no Filter's latency
+        driver = h.static_allocation_spark_pods("app-after-scan", 1)[0]
+        h.assert_success(h.schedule(driver, NODES))
+        ledger._drain_traces()
+        assert len(latencies) == 1
+    finally:
+        h.close()
+
+
+# -- (d) the profiler bridge ---------------------------------------------------
+
+
+def post_driver(h, port, app_id):
+    """One driver Filter over the wire; the pod exists in the cluster
+    before kube-scheduler asks, as it does there."""
+    created = h.create_pod(h.static_allocation_spark_pods(app_id, 1)[0])
+    assert h.wait_for_api(lambda: h.server.pod_informer.get(created.namespace, created.name) is not None)
+    body = {"Pod": serde.pod_to_dict(created), "NodeNames": NODES}
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/predicates", data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"}, method="POST",
+    )
+    with urllib.request.urlopen(req, timeout=30) as resp:
+        answer = json.loads(resp.read())
+    assert answer.get("NodeNames"), answer
+    return answer
+
+
+def test_spans_lie_on_the_profilers_clock_only_while_a_session_is_active(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    h = served_harness("xla")
+    http = ExtenderHTTPServer(h.server, port=0)
+    http.start()
+    try:
+        post_driver(h, http.port, "app-warm")  # compiles outside the session
+        before = tracing.annotations_built()
+        post_driver(h, http.port, "app-unprofiled")
+        assert tracing.annotations_built() == before  # no session: nothing is built
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with jax.profiler.TraceAnnotation("client.filter_driver"):
+                post_driver(h, http.port, "app-profiled")
+        finally:
+            jax.profiler.stop_trace()
+        assert tracing.annotations_built() > before
+        built = tracing.annotations_built()
+        post_driver(h, http.port, "app-after")
+        assert tracing.annotations_built() == built
+    finally:
+        http.stop()
+        h.close()
+
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    by_line = {}
+    client = client_line = None
+    for plane in ProfileData.from_file(path).planes:
+        for index, line in enumerate(plane.lines):  # one line per thread; names repeat
+            for ev in line.events:
+                if ev.name.startswith(tracing.spans.PROFILER_PREFIX):
+                    by_line.setdefault((plane.name, index), {}).setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns)
+                    )
+                elif ev.name == "client.filter_driver":
+                    client, client_line = (ev.start_ns, ev.start_ns + ev.duration_ns), (plane.name, index)
+    assert len(by_line) == 1, sorted(by_line)  # the handler's thread, and no other
+    ((plane_name, handler_line), sched), = by_line.items()
+    assert plane_name.startswith("/host:CPU") and (plane_name, handler_line) != client_line
+
+    def inside(inners, outer):
+        return any(outer[0] <= a and b <= outer[1] for a, b in inners)
+
+    chain = ["sched.http.request", "sched.predicate", "sched.fifo_gate", "sched.device.upload"]
+    for outer, inner in zip(chain, chain[1:]):
+        (interval,) = sched[outer]
+        assert inside(sched[inner], interval), (outer, inner)
+    assert len(sched["sched.device.upload"]) == 2  # the queue's and solve_single's
+    assert inside(sched["sched.http.request"], client)  # the same clock as the client's annotation
+    assert {"sched.device.wait", "sched.device.readback", "sched.driver.finish"} <= set(sched)
+
+
+def test_the_accepted_trace_reducer_does_not_see_the_bridge(tmp_path):
+    """``benchmarks/trace_reduce.py:load_events`` keeps device planes and
+    ``client.*`` names only."""
+    import sys
+
+    import jax
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        import trace_reduce
+    finally:
+        sys.path.remove(bench)
+    tracer = Tracer(capacity=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("client.filter_driver"):
+            with tracer.span("http.request"):
+                with tracer.span("predicate"):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [e["name"] for e in trace_reduce.load_events(str(tmp_path))] == ["client.filter_driver"]
+
+
+# -- (e) one decomposition, two readers ----------------------------------------
+
+
+def test_decompose_on_the_new_tree_sums_to_the_root_and_leaves_nothing_new_in_other():
+    h = served_harness("xla")
+    http = ExtenderHTTPServer(h.server, port=0)
+    http.start()
+    try:
+        post_driver(h, http.port, "app-first")
+        roots = roots_of(h)
+        post_driver(h, http.port, "app-decomposed")
+    finally:
+        http.stop()
+        h.close()
+    (root,) = [r for r in roots if r.name == "http.request"]
+    record = criticalpath.decompose(root)
+    assert sum(record["segments"].values()) == pytest.approx(record["totalMs"], abs=0.01)
+    for segment in ("assemble", "upload", "device-wait", "readback", "finish", "solve", "serde", "write-back"):
+        assert record["segments"][segment] > 0.0, segment
+    # ``other`` is the root's own time alone (less the measured waits): every
+    # span of the new tree is classified, or inherits a classified ancestor's segment
+    root_self = (root.duration - sum(c.duration for c in root.children)) * 1e3
+    waits = float(root.tags.get("gateWaitMs") or 0.0) + float(root.tags.get("lockWaitMs") or 0.0)
+    assert record["segments"]["other"] == pytest.approx(max(root_self - waits, 0.0), abs=0.01)
+    assert set(record["segments"]) == set(criticalpath.SEGMENT_NAMES)
+    assert all(c.name in criticalpath.SPAN_SEGMENTS for c in root.children)
+    assert all(
+        name in criticalpath.SPAN_SEGMENTS or name.startswith("kernel:") or name == "device.dispatch"
+        for name in names(find(root, "predicate"))
+    )
