@@ -51,11 +51,7 @@ from ..metrics import names as mnames
 from ..metrics.registry import MetricsRegistry, default_registry
 from ..resilience import deadline as req_deadline
 from ..scheduler import labels as L
-from ..scheduler.sparkpods import (
-    AnnotationError,
-    spark_app_demand_cached,
-    spark_resources,
-)
+from ..scheduler.sparkpods import AnnotationError, spark_resources
 
 
 class SpeculativeVerdict:
@@ -271,14 +267,7 @@ class Speculator:
             earlier_apps: List[Any] = []
             skip_allowed: List[bool] = []
             if ext._is_fifo:
-                skip_cutoff = ext._fifo_skip_cutoff(instance_group)
-                for queued in ext._earlier_drivers(pod):
-                    try:
-                        _, demand = spark_app_demand_cached(queued)
-                    except AnnotationError:
-                        continue
-                    earlier_apps.append(demand)
-                    skip_allowed.append(ext._skip_verdict(queued, pod, skip_cutoff))
+                earlier_apps, skip_allowed, _ = ext._queue_ahead(instance_group, pod)
             current = AppDemand(
                 app_resources.driver_resources,
                 app_resources.executor_resources,

@@ -27,7 +27,7 @@ Handler = Callable[[APIObject], None]
 UpdateHandler = Callable[[APIObject, APIObject], None]
 
 
-@guarded_by("_lock", "_store", "_indexes", "_last_rv", "_selector_revs")
+@guarded_by("_lock", "_store", "_indexes", "_last_rv", "_selector_revs", "_views")
 class Informer:
     """A shared informer for one kind."""
 
@@ -49,6 +49,10 @@ class Informer:
         # (client-go listers re-filter on every call) into O(result)
         self._index_labels = tuple(index_labels)
         self._indexes: Dict[str, Dict[str, set]] = {k: {} for k in self._index_labels}
+        # derived views kept beside the indexes (attach_view): each is
+        # handed every applied event under this lock, so a reader that
+        # holds the lock sees a view exactly as fresh as the store
+        self._views: List = []
         # key → highest resourceVersion ever delivered; events are globally
         # ordered by rv at the server, so delivery races are filtered here
         self._last_rv: Dict[Tuple[str, str], int] = {}
@@ -133,6 +137,8 @@ class Informer:
                     # above every stamp a consumer could have cached
                     self._selector_revs.clear()
                     self._selector_floor = self.revision
+            for view in self._views:
+                view.apply(key, None if event == DELETED else obj)
             add_handlers = list(self._add_handlers)
             update_handlers = list(self._update_handlers)
             delete_handlers = list(self._delete_handlers)
@@ -184,6 +190,24 @@ class Informer:
         # pre-existing objects
         for obj in snapshot:
             wrap_add(obj)
+
+    def attach_view(self, view) -> None:
+        """Keep ``view`` beside the label indexes: from now on
+        ``view.apply(key, obj)`` runs for every applied event (``obj``
+        is None for a delete) inside ``_on_event``, under the lock and
+        before it is released — unlike an event handler, which runs
+        after the release and can lag the store by the event in flight.
+        ``apply`` must not raise and must be cheap for objects the view
+        does not hold.  The view reads itself, and the store when it
+        has to rebuild, under :attr:`store_lock`."""
+        with self._lock:
+            self._views.append(view)
+
+    @property
+    def store_lock(self):
+        """The (re-entrant) lock every event is applied under; a view's
+        reader holds it so that no event lands mid-read."""
+        return self._lock
 
     # -- lister interface ----------------------------------------------------
 

@@ -136,6 +136,9 @@ PROVENANCE_PARITY_CHECKS = (
 # check in tests/test_metric_names.py covers them)
 TPU_FASTPATH = "foundry.spark.scheduler.tpu.fastpath"
 SINGLEAZ_LANE = "foundry.spark.scheduler.tpu.singleaz.lane"
+# earlier-drivers queue assemblies by how the kept pending-driver view
+# answered: result=hit|rebuild|stale|per-pod (scheduler/sparkpods.py)
+QUEUE_VIEW_READS = "foundry.spark.scheduler.fifo.queue.view.reads"
 PACKING_EFFICIENCY_MAX = "foundry.spark.scheduler.packing.efficiency.max"
 DRIVER_EXECUTOR_COLLOCATION = "foundry.spark.scheduler.driver.executor.collocation"
 EXECUTOR_NODE_COUNT = "foundry.spark.scheduler.executor.node.count"

@@ -47,6 +47,7 @@ from . import labels as L
 from .overhead import OverheadComputer
 from .reservations_manager import DRIVER_RESERVATION_NAME, ResourceReservationManager
 from .sparkpods import (
+    VIEW_PER_POD,
     AnnotationError,
     SparkPodLister,
     spark_resource_usage,
@@ -480,6 +481,42 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             return self._policy.skip_allowed(queued, driver, base)
         return base
 
+    def _queue_ahead(self, instance_group: str, driver: Pod):
+        """``(earlier_apps, skip_allowed, queue_names)`` of the FIFO
+        gate: the demands of the queue-ahead set, whether each may be
+        skipped where it does not fit, and the pods' names.  With the
+        plain creation-time order they are slices of the pod lister's
+        kept view and nothing is built per pod; a policy engine
+        (re-ordered queue, skips widened per pair) or a queued pod
+        whose annotations do not parse takes the per-pod walk.  Which
+        it was is counted and tagged ``queueView`` on the active span;
+        one clock sample per request either way."""
+        skip_cutoff = self._fifo_skip_cutoff(instance_group)
+        queue = None
+        if self._policy is None:
+            queue, how = self._pod_lister.pending_view.queue_ahead(driver, skip_cutoff)
+        if queue is None:
+            how = VIEW_PER_POD
+            earlier_apps, skip_allowed, queue_names = [], [], []
+            queue = (earlier_apps, skip_allowed, queue_names)
+            for queued in self._earlier_drivers(driver):
+                try:
+                    # stable AppDemand per pod version: tensor rows
+                    # are computed once per app, not per request
+                    _, demand = spark_app_demand_cached(queued)
+                except AnnotationError:
+                    logger.warning(
+                        "failed to get driver resources, skipping driver %s",
+                        queued.name,
+                    )
+                    continue
+                earlier_apps.append(demand)
+                skip_allowed.append(self._skip_verdict(queued, driver, skip_cutoff))
+                queue_names.append(queued.name)
+        self._metrics.counter(mnames.QUEUE_VIEW_READS, {"result": how})
+        tracing.add_tag("queueView", how)
+        return queue
+
     def _raise_driver_refusal(
         self, driver: Pod, app_resources, outcome: str, base_message: str, kind: str
     ):
@@ -596,18 +633,16 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         packing_result = None
         self._check_deadline("fifo-gate")
         if self._is_fifo:
-            queued_drivers = self._earlier_drivers(driver)
             # tpu-batch: the whole earlier-drivers pass plus this driver's
             # pack is ONE device solve (ops/fifo_solver); other policies
             # run the host loop
             outcome = self._try_device_fifo(
                 instance_group,
-                queued_drivers,
+                driver,
                 driver_node_names,
                 executor_node_names,
                 metadata,
                 app_resources,
-                current_driver=driver,
             )
             if outcome is not None and outcome.supported:
                 earlier_ok = outcome.earlier_ok
@@ -615,7 +650,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             else:
                 earlier_ok = self._fit_earlier_drivers(
                     instance_group,
-                    queued_drivers,
+                    self._earlier_drivers(driver),
                     driver_node_names,
                     executor_node_names,
                     metadata,
@@ -734,27 +769,12 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             prov = self._provenance
             if prov is not None and not prov.enabled:
                 prov = None
-            earlier_apps = []
-            skip_allowed = []
-            queue_names: Optional[List[str]] = [] if prov is not None else None
+            earlier_apps, skip_allowed, queue_names = [], [], []
             if self._is_fifo:
                 with self._tracer.span("fast_path.queue_assemble") as sp:
-                    skip_cutoff = self._fifo_skip_cutoff(instance_group)
-                    for queued in self._earlier_drivers(driver):
-                        try:
-                            # stable AppDemand per pod version: tensor rows
-                            # are computed once per app, not per request
-                            _, demand = spark_app_demand_cached(queued)
-                        except AnnotationError:
-                            logger.warning(
-                                "failed to get driver resources, skipping driver %s",
-                                queued.name,
-                            )
-                            continue
-                        earlier_apps.append(demand)
-                        skip_allowed.append(self._skip_verdict(queued, driver, skip_cutoff))
-                        if queue_names is not None:
-                            queue_names.append(queued.name)
+                    earlier_apps, skip_allowed, queue_names = self._queue_ahead(
+                        instance_group, driver
+                    )
                     sp.tag("earlierApps", len(earlier_apps))
             if prov is not None:
                 prov.note_context(
@@ -841,12 +861,11 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
     def _try_device_fifo(
         self,
         instance_group: str,
-        queued_drivers: List[Pod],
+        driver: Pod,
         driver_node_names: List[str],
         executor_node_names: List[str],
         metadata,
         app_resources,
-        current_driver: Optional[Pod] = None,
     ):
         """Run the FIFO pass + current pack on device when the configured
         binpacker provides a queue solver; returns None when unavailable
@@ -860,31 +879,11 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             return None  # demoted: the host earlier-drivers loop serves
         from ..ops.sparkapp import AppDemand
 
+        earlier_apps, skip_allowed, queue_names = self._queue_ahead(
+            instance_group, driver
+        )
         prov = self._provenance
-        if prov is not None and not prov.enabled:
-            prov = None
-        earlier_apps = []
-        skip_allowed = []
-        queue_names: Optional[List[str]] = [] if prov is not None else None
-        skip_cutoff = self._fifo_skip_cutoff(instance_group)
-        for queued in queued_drivers:
-            try:
-                _, demand = spark_app_demand_cached(queued)
-            except AnnotationError:
-                logger.warning(
-                    "failed to get driver resources, skipping driver %s", queued.name
-                )
-                continue
-            earlier_apps.append(demand)
-            if current_driver is not None:
-                skip_allowed.append(
-                    self._skip_verdict(queued, current_driver, skip_cutoff)
-                )
-            else:
-                skip_allowed.append(queued.creation_timestamp > skip_cutoff)
-            if queue_names is not None:
-                queue_names.append(queued.name)
-        if prov is not None:
+        if prov is not None and prov.enabled:
             prov.note_context(queue_names=queue_names)
         t0 = time.perf_counter()
         compile0 = default_profiler.compile_seconds()
