@@ -371,11 +371,14 @@ def _single_az_diag(problem) -> None:
     import jax
     import jax.numpy as jnp
 
+    from k8s_spark_scheduler_tpu.ops.batch_solver import snapshot_slots
     from k8s_spark_scheduler_tpu.ops.pallas_queue import (
         pallas_solve_queue_single_az,
     )
 
     nb = problem.avail.shape[0]
+    # without slots the pass halts at the first app it cannot certify
+    slots = snapshot_slots(nb)
     zone_vec = (np.arange(nb) % 3).astype(np.int32)
     sched = np.full(nb, 96000, np.int32)  # uniform synthetic schedulables
     no_gpu = np.zeros(nb, np.int32)
@@ -404,7 +407,7 @@ def _single_az_diag(problem) -> None:
         tot = jnp.int32(0)
         for _ in range(chain):
             feas, _z, _d, unc, a2 = pallas_solve_queue_single_az(
-                a, *rest, n_zones=3, az_aware=True
+                a, *rest, n_zones=3, az_aware=True, n_slots=slots
             )
             tot = tot + jnp.sum(feas) + jnp.sum(unc)
             a = a2
@@ -431,7 +434,8 @@ def _single_az_diag(problem) -> None:
         tot = jnp.int32(0)
         for _ in range(chain):
             feas, _z, _d, unc, a = pallas_solve_queue_single_az(
-                a, *rest, n_zones=3, az_aware=False, minfrag=True, strict=True
+                a, *rest, n_zones=3, az_aware=False, minfrag=True, strict=True,
+                n_slots=slots,
             )
             tot = tot + jnp.sum(feas) + jnp.sum(unc)
         return tot
@@ -470,7 +474,7 @@ def _single_az_diag(problem) -> None:
         tot = jnp.int32(0)
         for _ in range(chain):
             out = solve_queue_single_az(
-                a, *mf_rest, az_aware=False, minfrag=True, strict=True
+                a, *mf_rest, az_aware=False, minfrag=True, strict=True, n_slots=slots
             )
             tot = tot + jnp.sum(out.feasible)
             a = out.avail_after

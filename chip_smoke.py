@@ -13,8 +13,12 @@ requests over HTTP:
 1. **Full size** — 10,000 nodes and a 1,000-deep pending driver backlog
    (BASELINE config 5; shapes as ``bench.py:_config5_e2e``: 3 zones,
    4-96 cpu / 8-256 Gi nodes, gangs of 1-32 executors) under
-   ``tpu-batch``: three new drivers behind the backlog, every executor of
-   the first granted gang, and one driver too large to fit.
+   ``tpu-batch`` and under ``tpu-batch-single-az``: three new drivers
+   behind the backlog, every executor of the first granted gang, and
+   one driver too large to fit.  The single-AZ drive has to meet queue
+   apps whose zone the device's score cannot certify (``zoneResolved``,
+   printed with the lane): the exact decision for them is part of what
+   is compared.
 2. **Every device policy** — the same drive at the 1,024 × 64 shape
    bucket under each of the six ``tpu-batch*`` names, so every Pallas
    kernel variant the registry can dispatch is compiled by Mosaic, run,
@@ -271,21 +275,19 @@ def spans_of(span: dict):
         yield from spans_of(child)
 
 
-def gate_lane_of(trace: dict) -> Optional[str]:
-    """The ``lane`` tag of the request's fifo_gate span (``kernel`` where
-    the single-AZ solver reports one: its lane tag says fused/host)."""
+def gate_tags_of(trace: dict) -> dict:
+    """The tags of the request's fifo_gate span."""
     for span in spans_of(trace["root"]):
         if span["name"] == "fifo_gate":
-            tags = span.get("tags", {})
-            return tags.get("kernel") or tags.get("lane")
-    return None
+            return span.get("tags", {})
+    return {}
 
 
-def assert_device_served(stack: Stack, trace_id: str, expect_lane: str) -> None:
+def assert_device_served(stack: Stack, trace_id: str, expect_lane: str) -> dict:
     """After one driver Filter on the device stack: the queue pass ran on
     the expected lane, nothing failed, nothing fell back.  Lane names
     carry a policy suffix ("pallas-minfrag", "native-session"): the part
-    before the dash is the lane."""
+    before the dash is the lane.  Returns the fifo_gate span's tags."""
     solver = stack.solver
     lane = solver.last_queue_lane
     check(
@@ -298,12 +300,12 @@ def assert_device_served(stack: Stack, trace_id: str, expect_lane: str) -> None:
             f"{stack.name}: single-AZ queue pass took the {solver.last_path!r} lane",
         )
     trace = trace_of(stack, trace_id)
-    gate = gate_lane_of(trace)
+    gate = gate_tags_of(trace).get("lane")
     check(
         gate is not None and gate.split("-")[0] == expect_lane,
         f"{stack.name}: fifo_gate span says lane {gate!r}, expected {expect_lane!r}",
     )
-    if expect_lane != "native" and not hasattr(solver, "last_path"):
+    if expect_lane != "native":
         # a device lane that stops being traced fails bring-up here, not
         # a benchmark metric's None later
         missing = set(DEVICE_SPANS) - {span["name"] for span in spans_of(trace["root"])}
@@ -313,6 +315,7 @@ def assert_device_served(stack: Stack, trace_id: str, expect_lane: str) -> None:
     check(not lanes.demoted_lanes(), f"{stack.name}: demoted lanes {lanes.demoted_lanes()}")
     fallbacks = stack.scheduler.extender.host_fallbacks()
     check(fallbacks == 0, f"{stack.name}: {fallbacks} host fallbacks counted")
+    return gate_tags_of(trace)
 
 
 @dataclass
@@ -322,6 +325,10 @@ class DriveReport:
     executors: int = 0
     refused: int = 0
     first_request_compile_s: float = 0.0
+    # single-AZ: queue apps decided exactly on the host, and kernel
+    # launches, summed over the drive's driver requests
+    zone_resolved: int = 0
+    launches: int = 0
     device_s: List[float] = field(default_factory=list)
     twin_s: List[float] = field(default_factory=list)
 
@@ -352,7 +359,9 @@ def drive_and_compare(
             f"{what}: device stack answered {_brief(body)}, twin {_brief(twin_body)}",
         )
         if is_driver:
-            assert_device_served(device, trace_id, expect_lane)
+            gate = assert_device_served(device, trace_id, expect_lane)
+            report.zone_resolved += int(gate.get("zoneResolved", 0))
+            report.launches += int(gate.get("launches", 0))
         for stack, pod in zip((device, twin), created):
             if body.get("NodeNames"):
                 bind(stack, pod, body["NodeNames"][0])
@@ -393,9 +402,11 @@ def _brief(body: dict) -> str:
 
 def run_phase(
     device_policy: str, twin_policy: str, n_nodes: int, n_backlog: int,
-    seed: int, expect_lane: str, twin_native: bool = False,
+    seed: int, expect_lane: str, twin_native: bool = False, expect_resolved: bool = False,
 ) -> DriveReport:
-    """One policy at one size: start both stacks, load, drive, stop."""
+    """One policy at one size: start both stacks, load, drive, stop.
+    ``expect_resolved``: the drive has to meet queue apps that the
+    single-AZ valve decides on the host."""
     t0 = time.perf_counter()
     # twin first: the kernel profiler binds to the last server wired, and
     # the device stack's compile seconds are the ones to report
@@ -411,12 +422,19 @@ def run_phase(
             device.stop()
     finally:
         twin.stop()
+    valve = ""
+    if report.launches:
+        valve = f" zoneResolved={report.zone_resolved} launches={report.launches};"
+    check(
+        report.zone_resolved > 0 or not expect_resolved,
+        f"{device_policy}: no queue app needed the exact zone decision; take another --seed",
+    )
     print(
         f"  {device_policy} vs {twin_policy}"
         f"{' (native C++ queue lane)' if twin_native else ' (host oracle)'} "
         f"at {n_nodes} nodes x {n_backlog} backlog: {report.requests} requests "
         f"({report.granted_drivers} drivers granted, {report.executors} executors, "
-        f"{report.refused} refused) all equal; lane={expect_lane}; "
+        f"{report.refused} refused) all equal; lane={expect_lane};{valve} "
         f"first-request compile {report.first_request_compile_s:.2f}s; "
         f"device stack median {np.median(report.device_s) * 1e3:.1f}ms/request "
         f"(sum {sum(report.device_s):.1f}s), twin median "
@@ -482,6 +500,12 @@ def main(argv=None) -> int:
     run_phase(
         "tpu-batch", "tpu-batch", FULL_NODES, FULL_BACKLOG, args.seed,
         expect_lane="pallas", twin_native=True,
+    )
+    # the native lane chooses every zone in float64, so the twin is exact
+    # where the device's score is not: the apps the valve resolves
+    run_phase(
+        "tpu-batch-single-az", "tpu-batch-single-az", FULL_NODES, FULL_BACKLOG, args.seed,
+        expect_lane="pallas", twin_native=True, expect_resolved=True,
     )
 
     print(

@@ -139,6 +139,9 @@ SINGLEAZ_LANE = "foundry.spark.scheduler.tpu.singleaz.lane"
 # earlier-drivers queue assemblies by how the kept pending-driver view
 # answered: result=hit|rebuild|stale|per-pod (scheduler/sparkpods.py)
 QUEUE_VIEW_READS = "foundry.spark.scheduler.fifo.queue.view.reads"
+# queue apps of single-AZ driver Filters by who chose their zone
+# (result=certified|resolved|host-queue), ops/fifo_solver.py
+FIFO_ZONE_CHOICE = "foundry.spark.scheduler.fifo.zone.choice"
 PACKING_EFFICIENCY_MAX = "foundry.spark.scheduler.packing.efficiency.max"
 DRIVER_EXECUTOR_COLLOCATION = "foundry.spark.scheduler.driver.executor.collocation"
 EXECUTOR_NODE_COUNT = "foundry.spark.scheduler.executor.node.count"

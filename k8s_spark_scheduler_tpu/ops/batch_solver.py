@@ -431,8 +431,10 @@ class ZoneQueueSolve(NamedTuple):
     feasible: jnp.ndarray    # [A] bool
     zone_idx: jnp.ndarray    # [A] int32 — chosen zone; Z = cross-zone fallback, -1 = none
     driver_idx: jnp.ndarray  # [A] int32
-    uncertain: jnp.ndarray   # [A] bool — zone choice within the fixed-point margin
+    uncertain: jnp.ndarray   # [A] bool — flagged: zone choice within the fixed-point margin, or a probe
     avail_after: jnp.ndarray  # [N, 3] int32
+    slot: jnp.ndarray | None = None       # [A] int32 — the flagged app's snapshot, -1 = none
+    snapshots: jnp.ndarray | None = None  # [max(n_slots, 1), 4, N] int32
 
 
 # Fixed-point bits for the on-device zone-efficiency score.  The zone
@@ -443,11 +445,29 @@ class ZoneQueueSolve(NamedTuple):
 # places exactly k executors + 1 driver, comparing averages equals
 # comparing these sums.  Per-term quantization error is < 0.6 fixed-point
 # ulps, so |Q_a − Q_b| > 2(k+1)+2 certifies that the float64 oracle
-# orders the true sums the same way; equal Q keeps the earlier zone
-# (identical to Go for mathematically equal scores), and distinct-but-
-# closer scores raise `uncertain` and the caller re-solves on the exact
-# host path.  See docs/design.md § "Single-AZ zone choice on device".
+# orders the true sums the same way.  Anything closer — equal Q
+# included: the same Q from different inputs proves nothing about the
+# true sums, and equal sums added up in a different node order need not
+# compare equal in float64 — flags the app: the caller decides it, that
+# one app, in the oracle's float64 arithmetic, from a snapshot the pass
+# leaves of the carry and of every zone's packing, and launches the
+# pass again from that app only where it decides otherwise.  See
+# docs/design.md § "Single-AZ zone choice on device".
 EFF_SHIFT = 18
+# per-app ``forced`` zone of the single-AZ queue passes: the pass chooses
+FORCE_NONE = -2
+# ``forced`` = HINT_BASE + z is a guess, not a decision: zone z is taken
+# only where the score cannot certify the app, which stays flagged
+HINT_BASE = 64
+# a snapshot's packing plane: executor count | the driver's node << 30
+DRIVER_BIT = 30
+
+
+def snapshot_slots(n_nodes: int) -> int:
+    """Snapshot slots of a single-AZ pass over ``n_nodes``: as many as 6
+    MiB hold (four int32 rows of the node axis each; the pallas kernel
+    keeps them in its fast memory), 32 at the most."""
+    return max(1, min(32, (6 << 20) // (16 * max(n_nodes, 1))))
 
 
 def _zone_score(
@@ -507,7 +527,7 @@ def _zone_score(
     return score, nonzero
 
 
-@functools.partial(jax.jit, static_argnames=("az_aware", "minfrag", "strict"))
+@functools.partial(jax.jit, static_argnames=("az_aware", "minfrag", "strict", "n_slots"))
 def solve_queue_single_az(
     avail: jnp.ndarray,        # [N, 3] int32
     driver_rank: jnp.ndarray,  # [N] int32
@@ -516,16 +536,19 @@ def solve_queue_single_az(
     drivers: jnp.ndarray,      # [A, 3] int32
     executors: jnp.ndarray,    # [A, 3] int32
     counts: jnp.ndarray,       # [A] int32
-    app_valid: jnp.ndarray,    # [A] bool
+    app_valid: jnp.ndarray,    # [A] bool, or int32 with 2 = a probe
     s_cpu_milli: jnp.ndarray,  # [N] int32
     s_gpu_milli: jnp.ndarray,  # [N] int32
     inv_mem: jnp.ndarray,      # [N] f32
     th_mem: jnp.ndarray,       # [N] int32
     scale_cpu: jnp.ndarray,    # [] int32
     scale_gpu: jnp.ndarray,    # [] int32
+    forced: jnp.ndarray | None = None,  # [A] int32 — FORCE_NONE, -1 or a zone
+    start: jnp.ndarray | None = None,   # [] int32 — first app this call solves
     az_aware: bool = False,
     minfrag: bool = False,
     strict: bool = True,
+    n_slots: int = 0,
 ) -> ZoneQueueSolve:
     """Whole-FIFO-queue single-AZ gang solve in ONE dispatch
     (single_az.go:23-97 × resource.go:224-262): scan apps in order; each
@@ -536,13 +559,39 @@ def solve_queue_single_az(
     EFF_SHIFT), applies the strict-improvement choice in zone order,
     optionally falls back to a cross-zone pack
     (az_aware_pack_tightly.go:27-38; no min-frag variant), and carries
-    availability with the reference's subtraction quirk."""
+    availability with the reference's subtraction quirk.
+
+    An app whose zone the score cannot certify is *flagged*.  While a
+    snapshot slot is free (``n_slots``) the pass goes on with the
+    score's own choice and leaves in the slot what the exact decision
+    needs: the carry as it stood before the app and every zone's
+    packing (one row, the zones are disjoint: executor count, bit
+    DRIVER_BIT = the driver's node).  The caller checks each flagged app
+    in float64; where it decides otherwise it comes back with the
+    slot's carry, ``start`` at the app (earlier apps do nothing) and
+    the zone in ``forced`` (FORCE_NONE = the pass's own choice, -1 = no
+    zone, z = take zone z, HINT_BASE + z = a guess: zone z if the app is
+    flagged, and it stays flagged).  Out of slots, the pass halts at the flagged
+    app: the carry stays as it stood, every later app does nothing, and
+    ``avail_after`` is that carry.  ``app_valid`` 2 marks a probe (the
+    request's own app): every zone packed into a slot, nothing placed."""
     assert not (az_aware and minfrag)
     n = avail.shape[0]
+    a = drivers.shape[0]
     z_count = zone_masks.shape[0]
+    if forced is None:  # schedlint: disable=JX001 -- None is the argument's absence, static under jit
+        forced = jnp.full((a,), FORCE_NONE, jnp.int32)
+    if start is None:  # schedlint: disable=JX001 -- None is the argument's absence, static under jit
+        start = jnp.int32(0)
+    iota = jnp.arange(n, dtype=jnp.int32)
 
-    def step(carry_avail, app):
-        driver, executor, k, valid = app
+    def step(carry, app):
+        carry_avail, halted, taken, snapshots = carry
+        driver, executor, k, valid, force, index = app
+        active = (valid != 0) & ~halted & (index >= start)
+        probe = valid == 2
+        hinted = force >= HINT_BASE
+        is_forced = (force != FORCE_NONE) & ~hinted
         band = 2 * (k + 1) + 2
 
         def zone_solve(mask):
@@ -592,13 +641,34 @@ def solve_queue_single_az(
             f, score, nz = zf[z], zscore[z], znz[z]
             first = best_zone < 0
             better = f & jnp.where(first, nz, score > best_q)
-            uncertain = uncertain | (
-                f & ~first & (score != best_q) & (jnp.abs(score - best_q) <= band)
-            )
+            uncertain = uncertain | (f & ~first & (jnp.abs(score - best_q) <= band))
             best_q = jnp.where(better, score, best_q)
-            best_zone = jnp.where(better, jnp.int32(z), best_zone)
-            chosen_counts = jnp.where(better, zcounts[z], chosen_counts)
-            chosen_didx = jnp.where(better, zdidx[z], chosen_didx)
+            take = jnp.where(is_forced, f & (force == z), better)
+            best_zone = jnp.where(take, jnp.int32(z), best_zone)
+            chosen_counts = jnp.where(take, zcounts[z], chosen_counts)
+            chosen_didx = jnp.where(take, zdidx[z], chosen_didx)
+
+        flagged = ((uncertain & ~is_forced) | probe) & active
+        # where the score cannot tell, the caller's guess stands in for its choice
+        guess = jnp.clip(force - HINT_BASE, 0, z_count - 1)
+        use_hint = hinted & uncertain & zf[guess] & (force - HINT_BASE < z_count)
+        best_zone = jnp.where(use_hint, guess, best_zone)
+        chosen_counts = jnp.where(use_hint, zcounts[guess], chosen_counts)
+        chosen_didx = jnp.where(use_hint, zdidx[guess], chosen_didx)
+        keep = flagged & (taken < n_slots)
+        halt = flagged & ~keep
+        packings = jnp.sum(
+            zcounts + ((iota[None, :] == zdidx[:, None]).astype(jnp.int32) << DRIVER_BIT),
+            axis=0,
+        )
+        snapshots = lax.cond(
+            keep,
+            lambda s: s.at[taken].set(
+                jnp.concatenate([carry_avail.T, packings[None, :]], axis=0)
+            ),
+            lambda s: s,
+            snapshots,
+        )
 
         if az_aware:
             cross = solve_app(carry_avail, driver_rank, exec_ok, driver, executor, k)
@@ -607,7 +677,7 @@ def solve_queue_single_az(
             chosen_counts = jnp.where(use_cross, cross.exec_counts, chosen_counts)
             chosen_didx = jnp.where(use_cross, cross.driver_idx, chosen_didx)
 
-        placed = (best_zone >= 0) & valid
+        placed = (best_zone >= 0) & active & ~halt & ~probe
         chosen_counts = jnp.where(placed, chosen_counts, jnp.zeros_like(chosen_counts))
         chosen_didx = jnp.where(placed, chosen_didx, jnp.int32(n))
 
@@ -621,17 +691,32 @@ def solve_queue_single_az(
             jnp.where(is_driver[:, None], driver[None, :], jnp.zeros_like(driver)[None, :]),
         )
         delta = jnp.where(placed, delta, jnp.zeros_like(delta))
-        out = (placed, jnp.where(placed, best_zone, jnp.int32(-1)), chosen_didx, uncertain)
-        return carry_avail - delta, out
+        out = (
+            placed, jnp.where(placed, best_zone, jnp.int32(-1)), chosen_didx, flagged,
+            jnp.where(keep, taken, jnp.int32(-1)),
+        )
+        return (carry_avail - delta, halted | halt, taken + keep, snapshots), out
 
-    avail_after, outs = lax.scan(step, avail, (drivers, executors, counts, app_valid))
-    placed, zone_idx, chosen_didx, uncertain = outs
+    (avail_after, _, _, snapshots), outs = lax.scan(
+        step,
+        (
+            avail, jnp.zeros((), bool), jnp.int32(0),
+            jnp.zeros((max(n_slots, 1), 4, n), jnp.int32),
+        ),
+        (
+            drivers, executors, counts, app_valid.astype(jnp.int32), forced,
+            jnp.arange(a, dtype=jnp.int32),
+        ),
+    )
+    placed, zone_idx, chosen_didx, uncertain, slot = outs
     return ZoneQueueSolve(
         feasible=placed,
         zone_idx=zone_idx,
         driver_idx=chosen_didx,
         uncertain=uncertain,
         avail_after=avail_after,
+        slot=slot,
+        snapshots=snapshots,
     )
 
 

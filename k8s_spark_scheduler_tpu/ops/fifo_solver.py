@@ -22,7 +22,6 @@ from ..tracing.profiling import default_profiler
 from ..types.resources import NodeGroupSchedulingMetadata
 from .batch_adapter import (
     build_reserved,
-    candidate_zone_masks,
     counts_to_evenly_list,
     counts_to_tightly_list,
     evenly_counts,
@@ -34,7 +33,7 @@ from .efficiency import compute_packing_efficiencies
 from .packers import PackingResult, empty_packing_result
 from .sparkapp import AppDemand
 from .tensorize import _resources_to_base as _res_rows
-from .tensorize import scale_problem, tensorize_apps, tensorize_cluster
+from .tensorize import INT32_SAFE, scale_problem, tensorize_apps, tensorize_cluster
 
 logger = logging.getLogger(__name__)
 
@@ -225,15 +224,7 @@ def efficiencies_from_rows(names, sched_rows, avail_rows, reserved_rows):
         - np.asarray(avail_rows)[:n].astype(np.int64)
         + np.asarray(reserved_rows)[:n].astype(np.int64)
     )
-    s_cpu = _ceil_div(s[:, 0], 1000)
-    s_gpu = _ceil_div(s[:, 2], 1000)
-    r_cpu = _ceil_div(r[:, 0], 1000)
-    r_gpu = _ceil_div(r[:, 2], 1000)
-    # Go divides by normalize(schedulable)=1 when schedulable is 0
-    cpu = r_cpu / np.maximum(s_cpu, 1)
-    mem = r[:, 1] / np.maximum(s[:, 1], 1)
-    gpu = np.where(s_gpu != 0, r_gpu / np.maximum(s_gpu, 1), 0.0)
-    return LazyEfficiencies(names, cpu, mem, gpu)
+    return LazyEfficiencies(names, *_efficiency_columns(s, r))
 
 
 def _patch_available(metadata, names, avail_rows):
@@ -259,6 +250,39 @@ def _patch_available(metadata, names, avail_rows):
             ),
         )
     return patched
+
+
+def _tensorize_with_cache(solver, earlier, current_app):
+    """AppTensor for earlier + [current]: the earlier block is
+    cached by object identity (see TpuFifoSolver._earlier_tensor_cache) and the
+    current app's rows are appended."""
+    from .tensorize import AppTensor, _app_base_rows
+
+    key = tuple(map(id, earlier))
+    cached = solver._earlier_tensor_cache
+    if cached is not None and cached[0] == key:
+        base = cached[2]
+    else:
+        base = tensorize_apps(earlier)
+        solver._earlier_tensor_cache = (key, earlier, base)
+    drow, erow, exact = _app_base_rows(current_app)
+    a = base.driver.shape[0]
+    driver = np.empty((a + 1, 3), dtype=np.int64)
+    driver[:a] = base.driver
+    driver[a] = drow
+    executor = np.empty((a + 1, 3), dtype=np.int64)
+    executor[:a] = base.executor
+    executor[a] = erow
+    count = np.empty(a + 1, dtype=np.int64)
+    count[:a] = base.count
+    count[a] = current_app.min_executor_count
+    return AppTensor(
+        driver=driver,
+        executor=executor,
+        count=count,
+        valid=np.ones(a + 1, dtype=bool),
+        exact=base.exact and exact,
+    )
 
 
 @dataclass
@@ -335,36 +359,7 @@ class TpuFifoSolver:
         )
 
     def _tensorize_with_cache(self, earlier, current_app):
-        """AppTensor for earlier + [current]: the earlier block is
-        cached by object identity (see _earlier_tensor_cache) and the
-        current app's rows are appended."""
-        from .tensorize import AppTensor, _app_base_rows
-
-        key = tuple(map(id, earlier))
-        cached = self._earlier_tensor_cache
-        if cached is not None and cached[0] == key:
-            base = cached[2]
-        else:
-            base = tensorize_apps(earlier)
-            self._earlier_tensor_cache = (key, earlier, base)
-        drow, erow, exact = _app_base_rows(current_app)
-        a = base.driver.shape[0]
-        driver = np.empty((a + 1, 3), dtype=np.int64)
-        driver[:a] = base.driver
-        driver[a] = drow
-        executor = np.empty((a + 1, 3), dtype=np.int64)
-        executor[:a] = base.executor
-        executor[a] = erow
-        count = np.empty(a + 1, dtype=np.int64)
-        count[:a] = base.count
-        count[a] = current_app.min_executor_count
-        return AppTensor(
-            driver=driver,
-            executor=executor,
-            count=count,
-            valid=np.ones(a + 1, dtype=bool),
-            exact=base.exact and exact,
-        )
+        return _tensorize_with_cache(self, earlier, current_app)
 
     def feasible_tensor(self, cluster, app: AppDemand) -> Optional[bool]:
         """Feasibility of one app against a prebuilt ClusterTensor with
@@ -806,29 +801,299 @@ def _fused_efficiency_inputs(cluster, problem):
     return s_cpu, s_gpu, inv_m, th, int(scale[0]), int(scale[2])
 
 
-class TpuSingleAzFifoSolver:
-    """FIFO pass for the single-AZ policies.
+def _efficiency_columns(s: np.ndarray, r: np.ndarray):
+    """(cpu, memory, gpu) float64 efficiency columns from int64
+    base-unit schedulable rows ``s`` and reserved rows ``r``
+    (efficiency.go:80-105 with Quantity.value() semantics: cpu and gpu
+    rounded up to whole units, memory in bytes; Go divides by
+    normalize(schedulable) = 1 when schedulable is 0)."""
+    s_cpu = _ceil_div(s[:, 0], 1000)
+    s_gpu = _ceil_div(s[:, 2], 1000)
+    cpu = _ceil_div(r[:, 0], 1000) / np.maximum(s_cpu, 1)
+    mem = r[:, 1] / np.maximum(s[:, 1], 1)
+    gpu = np.where(s_gpu != 0, _ceil_div(r[:, 2], 1000) / np.maximum(s_gpu, 1), 0.0)
+    return cpu, mem, gpu
 
-    Fast lane (one dispatch): batch_solver.solve_queue_single_az scans
-    the whole earlier-driver queue on device — per-zone tightly-pack
-    solves, the zone-efficiency choice in certified fixed point
-    (batch_solver.EFF_SHIFT), the az-aware cross-zone fallback, and the
-    carried usage subtraction all fused into a single XLA program.  On
+
+def _host_gang_solve(avail, rank, exec_ok, driver, executor, k):
+    """batch_solver.solve_app in numpy over the given rows (one zone's,
+    in array order): (driver position, executor counts) or None.  The
+    same integers as the device computes: the capacity-total identity
+    for the driver, the tightly-pack greedy fill for the executors."""
+    avail = avail.astype(np.int64)
+    driver = np.asarray(driver, np.int64)
+    executor = np.asarray(executor, np.int64)
+
+    def caps(a):
+        per_dim = np.where(
+            executor[None, :] == 0,
+            np.where(a >= 0, INT32_SAFE, 0),
+            a // np.maximum(executor, 1)[None, :],
+        )
+        return np.where(exec_ok, np.clip(per_dim.min(axis=1), 0, k), 0)
+
+    base = caps(avail)
+    with_driver = caps(avail - driver[None, :])
+    fits = (avail >= driver[None, :]).all(axis=1) & (rank < INT32_SAFE)
+    feasible = fits & (int(base.sum()) - base + with_driver >= k)
+    if not feasible.any():
+        return None
+    d = int(np.argmin(np.where(feasible, rank, INT32_SAFE)))
+    cap = base
+    cap[d] = with_driver[d]
+    counts = np.clip(k - (np.cumsum(cap) - cap), 0, cap)
+    return d, counts
+
+
+def _same_evidence(a, b) -> bool:
+    return a is not None and b is not None and a.shape == b.shape and bool((a == b).all())
+
+
+@dataclass
+class _ZonePick:
+    """One app's exact single-AZ decision."""
+
+    zone: int                 # index into the candidate zones; their number = cross-zone
+    driver_idx: int
+    counts: np.ndarray        # [n] executors per node (the usage carry reads > 0)
+    executor_nodes: List[str]  # in the order the inner policy emits them
+
+
+class _ZoneProblem:
+    """What the exact zone choice reads of one request: the scaled
+    problem, the candidate zones in the reference's order and each
+    zone's rows."""
+
+    def __init__(self, cluster, problem, az_aware, inner_policy, strict):
+        self.cluster = cluster
+        self.problem = problem
+        self.az_aware = az_aware
+        self.minfrag = inner_policy == "minimal-fragmentation"
+        self.strict = strict
+        self.names = cluster.node_names
+        n = self.n = len(self.names)
+        self.scale = problem.scale.astype(np.int64)
+        self.rank = problem.driver_rank[:n]
+        self.exec_ok = np.asarray(problem.exec_ok[:n])
+        # candidate zones: first appearance in the driver's priority
+        # order, kept where the zone has an executor candidate
+        # (single_az.go:30-45)
+        zone_id = cluster.zone_id[:n]
+        members = [zone_id == z for z in range(len(cluster.zone_names))]
+        first_rank = [
+            int(self.rank[m].min()) if m.any() else INT32_SAFE for m in members
+        ]
+        order = [
+            z for z in np.argsort(first_rank, kind="stable").tolist()
+            if first_rank[z] < INT32_SAFE and (self.exec_ok & members[z]).any()
+        ]
+        self.n_zones = len(order)
+        self.zone_vec = np.full(problem.avail.shape[0], -1, np.int32)
+        for zi, z in enumerate(order):
+            self.zone_vec[:n][members[z]] = zi
+        self._zone_rows = None
+        self._index_of = None
+
+    @property
+    def zone_rows(self) -> List[np.ndarray]:
+        """Each candidate zone's rows, in array order (the host's own
+        packing reads them; a device pass's snapshots do not)."""
+        if self._zone_rows is None:
+            self._zone_rows = [
+                np.flatnonzero(self.zone_vec[: self.n] == zi) for zi in range(self.n_zones)
+            ]
+        return self._zone_rows
+
+    def _index(self, name: str) -> int:
+        if self._index_of is None:
+            self._index_of = {nm: i for i, nm in enumerate(self.names)}
+        return self._index_of[name]
+
+    def _average(self, avail, app_idx, d_idx, hosts, on_each, reserved_each, order=None) -> float:
+        """The packing's average efficiency as single_az.go:75-97 takes
+        it: per-node max efficiency with the gang reserved, summed in
+        float64 over the driver's node and then each executor's, one
+        term per pod, over their number.  ``hosts`` are the executors'
+        nodes in array order with ``on_each`` executors each (the order
+        tightly-pack emits them in; ``order`` where the inner policy
+        emits another) and ``reserved_each`` as the efficiencies see them."""
+        problem = self.problem
+        at = int(np.searchsorted(hosts, d_idx))
+        if at == len(hosts) or hosts[at] != d_idx:  # the driver's node hosts no executor
+            hosts = np.concatenate([hosts[:at], [d_idx], hosts[at:]])
+            on_each = np.concatenate([on_each[:at], [0], on_each[at:]])
+            reserved_each = np.concatenate([reserved_each[:at], [0], reserved_each[at:]])
+        reserved = reserved_each[:, None].astype(np.int64) * problem.executor[app_idx].astype(np.int64)
+        reserved[at] += problem.driver[app_idx]
+        s = self.cluster.sched[hosts]
+        r = s - (avail[hosts].astype(np.int64) - reserved) * self.scale
+        cpu, mem, gpu = _efficiency_columns(s, r)
+        node_max = np.maximum(np.maximum(cpu, mem), gpu).tolist()
+        total = node_max[at]
+        if order is None:
+            for term, times in zip(node_max, on_each.tolist()):
+                for _ in range(times):
+                    total += term
+        else:
+            where = {node: i for i, node in enumerate(hosts.tolist())}
+            for node in order:
+                total += node_max[where[node]]
+        return total / float(int(on_each.sum()) + 1)
+
+    def pick(self, avail: np.ndarray, app_idx: int) -> Optional[_ZonePick]:
+        """The app's packing in the zone the reference chooses, every
+        zone packed here on the host from the carry ``avail``."""
+        problem = self.problem
+        driver, executor = problem.driver[app_idx], problem.executor[app_idx]
+        k = int(problem.count[app_idx])
+        packings = []
+        for rows in self.zone_rows:
+            solved = _host_gang_solve(
+                avail[rows], self.rank[rows], self.exec_ok[rows], driver, executor, k
+            )
+            if solved is None:
+                packings.append(None)
+            else:
+                hosts = np.flatnonzero(solved[1])
+                packings.append((int(rows[solved[0]]), rows[hosts], solved[1][hosts]))
+        return self._choose(avail, app_idx, packings)
+
+    def pick_from_snapshot(self, snapshot: np.ndarray, app_idx: int):
+        """The same decision from a snapshot of the device pass ([4, n]:
+        the carry's three planes and every zone's packing in one row),
+        with no solve on the host: (the pick or None, the carry [n, 3])."""
+        from .batch_solver import DRIVER_BIT
+
+        avail = snapshot[:3].T
+        packed = snapshot[3]
+        occupied = np.flatnonzero(packed)
+        zone = self.zone_vec[occupied]
+        counts = (packed[occupied] & ((1 << DRIVER_BIT) - 1)).astype(np.int64)
+        is_driver = (packed[occupied] >> DRIVER_BIT) != 0
+        packings = []
+        for zi in range(self.n_zones):
+            inside = zone == zi
+            drivers = occupied[inside & is_driver]
+            if drivers.size == 0:
+                packings.append(None)
+                continue
+            hosts = inside & (counts > 0)
+            packings.append((int(drivers[0]), occupied[hosts], counts[hosts]))
+        return self._choose(avail, app_idx, packings), avail
+
+    def evidence(self, snapshot: np.ndarray, app_idx: int):
+        """Everything ``pick_from_snapshot`` reads to choose the app's
+        zone: the packings, and the carry, schedulable totals and zones
+        of the nodes they occupy, the app's demand and the scale.  None
+        where the choice reads more (the min-frag decode reads every
+        node's capacity)."""
+        if self.minfrag:
+            return None
+        problem = self.problem
+        occupied = np.flatnonzero(snapshot[3])
+        return np.concatenate([
+            occupied, snapshot[:, occupied].ravel(), self.cluster.sched[occupied].ravel(),
+            self.zone_vec[occupied], self.scale, problem.driver[app_idx],
+            problem.executor[app_idx], problem.count[app_idx : app_idx + 1],
+        ], dtype=np.int64)
+
+    def _choose(self, avail, app_idx, packings) -> Optional[_ZonePick]:
+        """_choose_best_result over the zones' tightly-pack packings
+        ((driver node, hosting nodes in array order, executors on each)
+        or None, in zone order): strict improvement from 0.0, all in the
+        oracle's float64; the cross-zone pack where az-aware finds no
+        zone."""
+        problem, n = self.problem, self.n
+        driver, executor = problem.driver[app_idx], problem.executor[app_idx]
+        k = int(problem.count[app_idx])
+        best, best_avg = None, 0.0
+        for zi, packing in enumerate(packings):
+            if packing is None:
+                continue
+            d_idx, hosts, on_each = packing
+            nodes = None
+            if self.minfrag:
+                # placements and their order are the drain's, from the
+                # exact host bisect on the same capacities
+                decoded = min_frag_zone_decode(
+                    self.names, avail[:n].astype(np.int64), executor,
+                    self.exec_ok & (self.zone_vec[:n] == zi), d_idx, driver, k, self.strict,
+                )
+                if decoded is None:  # unreachable: the zone is feasible
+                    continue
+                nodes, counts, reserved_counts = decoded
+                hosts = np.flatnonzero(counts)
+                on_each = counts[hosts]
+                avg = self._average(
+                    avail, app_idx, d_idx, hosts, on_each, reserved_counts[hosts],
+                    order=[self._index(nm) for nm in nodes],
+                )
+            else:
+                avg = self._average(avail, app_idx, d_idx, hosts, on_each, on_each)
+            if best_avg < avg:
+                best, best_avg = (zi, d_idx, hosts, on_each, nodes), avg
+        if best is not None:
+            zi, d_idx, hosts, on_each, nodes = best
+        elif self.az_aware:
+            # az_aware_pack_tightly.go:34-37: plain tightly-pack across zones
+            solved = _host_gang_solve(avail[:n], self.rank, self.exec_ok, driver, executor, k)
+            if solved is None:
+                return None
+            zi, d_idx, nodes = self.n_zones, int(solved[0]), None
+            hosts = np.flatnonzero(solved[1])
+            on_each = solved[1][hosts]
+        else:
+            return None
+        counts = np.zeros(n, np.int64)
+        counts[hosts] = on_each
+        if nodes is None:
+            nodes = [self.names[i] for i in np.repeat(hosts, on_each).tolist()]
+        return _ZonePick(zi, d_idx, counts, nodes)
+
+    def candidate(self, choice) -> int:
+        """The candidate zone of an exact pick (or None) or of a zone
+        index as a pass reports it; -1 for none and for the az-aware
+        cross-zone pack, which is the pass's own fallback once no zone
+        is forced on it."""
+        zone = choice.zone if isinstance(choice, _ZonePick) else -1 if choice is None else choice
+        return zone if 0 <= zone < self.n_zones else -1
+
+    def subtract(self, avail: np.ndarray, pick: _ZonePick, app_idx: int) -> None:
+        """The reference's usage-overwrite quirk in scaled int space."""
+        hosts = pick.counts > 0
+        avail[: self.n][hosts] -= self.problem.executor[app_idx]
+        if not hosts[pick.driver_idx]:
+            avail[pick.driver_idx] -= self.problem.driver[app_idx]
+
+
+class TpuSingleAzFifoSolver:
+    """FIFO pass for the single-AZ policies, from a cluster tensor.
+
+    The queue pass runs on the device: the pallas kernel on a TPU
+    (ops/pallas_queue.pallas_solve_queue_single_az), its XLA twin
+    otherwise (batch_solver.solve_queue_single_az) — per-zone
+    tightly-pack solves, the zone-efficiency choice in certified fixed
+    point (batch_solver.EFF_SHIFT), the az-aware cross-zone fallback and
+    the carried usage subtraction, all in one program.  On
     accelerator-less hosts (backend "auto" on CPU, or "native") the C++
     lane (native/fifo_solver.cpp::fifo_solve_queue_single_az) runs the
-    same per-zone solves with the zone chosen by EXACT float64
-    efficiency math — host-lane decisions with no uncertainty valve, at
-    native speed.
+    same per-zone solves with the zone chosen by exact float64 math.
 
-    Exactness valve: any app whose zone scores land inside the
-    fixed-point margin is flagged `uncertain`, and the whole queue is
-    re-solved on the host lane — per-driver vmapped zone solves
-    (solve_zones) with the zone choice in the oracle's float64
-    efficiency math — restoring bit-exact reference parity.  Snapshots
-    outside the fused lane's numeric bounds (_fused_efficiency_inputs)
-    go straight to the host lane.  The current app's packing is always
-    chosen with the exact host math.  `last_path` records which lane ran
-    ("fused" / "native" / "host") for tests and diagnostics."""
+    Exactness valve, one app at a time: where the device cannot certify
+    an app's zone the pass halts with the carry untouched; that app
+    alone is decided on the host in the oracle's float64 arithmetic over
+    its packings' own nodes (``_ZoneProblem.pick``), and the pass is
+    launched again from that app with its zone forced.  The current
+    app's packing is always chosen with the same exact host math.
+    Snapshots outside the device score's numeric bounds
+    (_fused_efficiency_inputs) run the whole queue through that host
+    decision, app by app.
+
+    ``last_path`` records how the queue was answered: "fused" (the
+    device pass, resolved apps included), "native" or "host";
+    ``last_queue_lane`` on what: "pallas" / "xla" / "native" / "host";
+    None = no queue pass ran.  ``last_zone_choices`` counts the last
+    request's queue apps by who chose their zone."""
 
     def __init__(
         self,
@@ -843,24 +1108,31 @@ class TpuSingleAzFifoSolver:
         # driver choice are shared with tightly (work-conserving drain),
         # placements come from the min-frag kernel / host bisect, and the
         # zone choice sees driver-only reserved under strict parity (the
-        # reference's no-write-back quirk).  Both fused one-dispatch
-        # lanes serve it (XLA scan with minfrag=True; pallas kernel with
-        # the min-frag drain per zone); az_aware has no min-frag variant
-        # in the reference.
+        # reference's no-write-back quirk).  az_aware has no min-frag
+        # variant in the reference.
         assert not (az_aware and inner_policy == "minimal-fragmentation")
         self.az_aware = az_aware
         self.backend = backend
         self.inner_policy = inner_policy
         self.strict_reference_parity = strict_reference_parity
+        # the reference policy's name; no whole-queue session lane has a
+        # code for it (batch_solver.queue_policy_code), so the delta-solve
+        # engine stands aside
+        self.assignment_policy = (
+            "az-aware-tightly-pack" if az_aware else "single-az-" + inner_policy
+        )
         # interpret=True runs the pallas kernel in interpreter mode so the
         # solver-side pallas wiring is testable on CPU
         self.interpret = interpret
         self.last_path: Optional[str] = None
-        # which program ran the last queue pass — "pallas" / "xla" (the
-        # two fused one-dispatch lanes), "native" or "host"; None = no
-        # queue pass ran.  last_path says how the answer was reached,
-        # this says on what.
         self.last_queue_lane: Optional[str] = None
+        self.last_zone_choices: dict = {}
+        self.last_launches = 0  # device launches of the last queue pass
+        self._earlier_tensor_cache = None  # as TpuFifoSolver's
+        # id(app) -> (the zone decided exactly for it in the last request,
+        # the evidence it was decided on); ids are stable while the
+        # tensor cache holds the apps
+        self._zone_memo: dict = {}
 
     def _use_pallas(self) -> bool:
         return _pallas_selected(self.backend)
@@ -874,336 +1146,318 @@ class TpuSingleAzFifoSolver:
         earlier_skip_allowed: List[bool],
         current_app: AppDemand,
     ) -> FifoOutcome:
-        import jax.numpy as jnp
-
-        from . import packers
-        from .batch_solver import solve_queue_single_az, solve_zones_jit
-
+        """The metadata entry (policy engines, snapshots the tensor
+        mirror cannot hold): tensorize, then the same core."""
         cluster = tensorize_cluster(metadata, driver_order, executor_order)
-        all_apps = list(earlier_apps) + [current_app]
-        apps = tensorize_apps(all_apps)
-        problem = scale_problem(cluster, apps)
-        self.last_queue_lane = None
+        return self.solve_tensor(cluster, earlier_apps, earlier_skip_allowed, current_app)
+
+    def feasible_tensor(self, cluster, app: AppDemand) -> Optional[bool]:
+        """Whether some zone takes the gang on ``cluster`` — the
+        unschedulable-marker's empty-cluster verdict, equal to
+        binpack_func's has_capacity: zone feasibility is the inner
+        policies' shared tightly-pack feasibility, and a feasible packing
+        reserves something (the driver asks for more than nothing), so
+        the all-zero-efficiency quirk cannot turn the verdict.  None =
+        not exactly tensorizable (caller uses the host path)."""
+        problem = scale_problem(cluster, tensorize_apps([app]))
         if not problem.ok:
-            self.last_path = None
-            return FifoOutcome(supported=False)
-
-        names = cluster.node_names
-        n = len(names)
-        nb = problem.avail.shape[0]
-        scale = problem.scale.astype(np.int64)
-
-        candidate_zones, zone_masks = candidate_zone_masks(
-            driver_order, executor_order, metadata, names, nb
+            return None
+        zones = _ZoneProblem(
+            cluster, problem, self.az_aware, "tightly-pack", self.strict_reference_parity
         )
-        zone_masks_dev = jnp.asarray(zone_masks)
-        rank_dev = jnp.asarray(problem.driver_rank)
-        exec_dev = jnp.asarray(problem.exec_ok)
+        n = zones.n
+        driver, executor, k = problem.driver[0], problem.executor[0], int(problem.count[0])
+        groups = [slice(0, n)] if self.az_aware else zones.zone_rows
+        return any(
+            _host_gang_solve(
+                problem.avail[rows], zones.rank[rows], zones.exec_ok[rows], driver, executor, k
+            ) is not None
+            for rows in groups
+        )
 
-        avail = problem.avail.astype(np.int32).copy()  # scaled, mutated per driver
-
-        minfrag_inner = self.inner_policy == "minimal-fragmentation"
-        exec_ok_arr = np.asarray(problem.exec_ok[:n])
-
-        def pack_one(app_idx: int):
-            """Device zone solves + host zone choice for one app.
-            Returns (driver_idx, counts) or None when infeasible."""
-            if not candidate_zones:
-                return None  # no zone has both driver and executor candidates
-            solves = solve_zones_jit(
-                jnp.asarray(avail),
-                rank_dev,
-                exec_dev,
-                zone_masks_dev,
-                jnp.asarray(problem.driver[app_idx]),
-                jnp.asarray(problem.executor[app_idx]),
-                jnp.asarray(problem.count[app_idx]),
+    def solve_tensor(
+        self,
+        cluster,
+        earlier_apps: List[AppDemand],
+        earlier_skip_allowed: List[bool],
+        current_app: AppDemand,
+    ) -> FifoOutcome:
+        """Solve from a prebuilt ClusterTensor, as the extender calls
+        TpuFifoSolver.solve_tensor: zones, schedulable totals and
+        availability all come from the tensor."""
+        with tracing.child_span("fast_path.tensorize_apps"):
+            apps = _tensorize_with_cache(self, list(earlier_apps), current_app)
+        self.last_path = self.last_queue_lane = None
+        self.last_zone_choices, self.last_launches = {}, 0
+        with tracing.child_span("fast_path.scale_problem"):
+            problem = scale_problem(cluster, apps)
+            if not problem.ok:
+                return FifoOutcome(supported=False)
+            zones = _ZoneProblem(
+                cluster, problem, self.az_aware, self.inner_policy,
+                self.strict_reference_parity,
             )
-            feasible = np.asarray(solves.feasible)
-            driver_idx = np.asarray(solves.driver_idx)
-            counts_all = np.asarray(solves.exec_counts)
-
-            results = []
-            per_zone = []
-            for zi, zone in enumerate(candidate_zones):
-                if not feasible[zi]:
-                    continue
-                d_idx = int(driver_idx[zi])
-                if minfrag_inner:
-                    # exact host bisect on the carried scaled availability
-                    # (capacities are scale-invariant); placement order is
-                    # the drain order, not priority order
-                    decoded = min_frag_zone_decode(
-                        names,
-                        avail.astype(np.int64)[:n],
-                        problem.executor[app_idx],
-                        exec_ok_arr & zone_masks[zi][:n],
-                        d_idx,
-                        problem.driver[app_idx],
-                        int(problem.count[app_idx]),
-                        self.strict_reference_parity,
-                    )
-                    if decoded is None:  # unreachable: zone feasible
-                        continue
-                    executor_nodes, zone_counts, eff_counts = decoded
-                    eff_rows = _reserved_rows(n, d_idx, eff_counts, problem, app_idx)
-                else:
-                    zone_counts = counts_all[zi][:n]
-                    executor_nodes = counts_to_tightly_list(names, zone_counts)
-                    eff_rows = _reserved_rows(n, d_idx, zone_counts, problem, app_idx)
-                results.append(
-                    PackingResult(
-                        driver_node=names[d_idx],
-                        executor_nodes=executor_nodes,
-                        has_capacity=True,
-                        packing_efficiencies=efficiencies_from_rows(
-                            names,
-                            cluster.sched,
-                            avail.astype(np.int64) * scale[None, :],
-                            eff_rows * scale[None, :],
-                        ),
-                    )
-                )
-                per_zone.append((d_idx, zone_counts))
-            if not results:
-                return None
-            best = packers._choose_best_result(metadata, results)
-            if not best.has_capacity:
-                # the all-zero-efficiency quirk: single-az yields nothing;
-                # the caller's az_aware fallback handles the cross-zone pack
-                return None
-            choice = results.index(best)
-            d_idx, counts = per_zone[choice]
-            return d_idx, counts, best
-
-        def plain_fallback(app_idx):
-            return self._plain_pack(app_idx, avail, problem, n)
-
         n_earlier = len(earlier_apps)
-        fused_done = False
-        # None = no queue pass ran (empty queue); "fused"/"native"/"host"
-        # report which lane actually processed earlier drivers
-        self.last_path = None
-        # min-frag inner: all fast lanes (native, XLA scan, pallas
-        # kernel) run the min-frag drain with the int32 MF_SENT
-        # sentinel, so the sentinel-collision guard gates every one of
-        # them; pathological snapshots take the exact host lane (its
-        # decode uses a 2^62 sentinel no int32 capacity can reach).
-        from .batch_solver import mf_sentinel_safe
-
-        mf_fused_ok = not minfrag_inner or mf_sentinel_safe(problem.avail)
-        # shared by the native and pallas lanes: disjoint zone masks →
-        # one zone index per node (-1 = in no candidate zone), and the
-        # queue-only validity mask
-        zone_vec = np.full(avail.shape[0], -1, np.int32)
-        for zi in range(len(candidate_zones)):
-            zone_vec[zone_masks[zi]] = zi
-        queue_valid = problem.app_valid.copy()
-        queue_valid[n_earlier:] = False
-
-        if (
-            n_earlier > 0
-            and mf_fused_ok
-            and not self._use_pallas()
-            and _native_selected(self.backend)
-        ):
-            # native C++ lane: per-zone solves with the zone chosen by
-            # EXACT float64 efficiency math — same decisions as the host
-            # lane with no uncertainty valve, at native speed
-            from ..native.fifo import solve_queue_single_az_native
-
-            with tracing.child_span(
-                "fifo_gate", {"lane": "native", "earlierApps": n_earlier}
-            ) as gate_span:
-                with default_profiler.profile(
-                    "fifo_queue_single_az", lane="native", jit=False
-                ):
-                    feas_n, _zone_n, _didx_n, avail_after_n = solve_queue_single_az_native(
-                        avail, problem.driver_rank, np.asarray(problem.exec_ok),
-                        zone_vec, problem.driver, problem.executor, problem.count,
-                        queue_valid, cluster.sched, scale,
-                        n_zones=len(candidate_zones), az_aware=self.az_aware,
-                        minfrag=minfrag_inner, strict=self.strict_reference_parity,
-                    )
-                self.last_path = self.last_queue_lane = "native"
-                for i in range(n_earlier):
-                    if not feas_n[i] and not earlier_skip_allowed[i]:
-                        gate_span.tag("earlierOk", False)
-                        return FifoOutcome(supported=True, earlier_ok=False)
-                gate_span.tag("earlierOk", True)
-                avail[:] = avail_after_n
-                fused_done = True
-
-        if not fused_done and n_earlier > 0 and mf_fused_ok:
-            eff_inputs = _fused_efficiency_inputs(cluster, problem)
-            if eff_inputs is not None:
-                s_cpu, s_gpu, inv_m, th_m, scale_c, scale_g = eff_inputs
-                if self._use_pallas():
-                    from .pallas_queue import pallas_solve_queue_single_az
-
-                    from .batch_solver import ZoneQueueSolve
-
-                    with default_profiler.profile(
-                        "fifo_queue_single_az", lane="pallas",
-                        fn=pallas_solve_queue_single_az,
-                    ) as rec:
-                        feas_d, zone_d, didx_d, uncertain_d, avail_after_d = (
-                            pallas_solve_queue_single_az(
-                                jnp.asarray(avail),
-                                rank_dev,
-                                exec_dev,
-                                jnp.asarray(zone_vec),
-                                jnp.asarray(problem.driver),
-                                jnp.asarray(problem.executor),
-                                jnp.asarray(problem.count),
-                                jnp.asarray(queue_valid),
-                                jnp.asarray(s_cpu),
-                                jnp.asarray(s_gpu),
-                                jnp.asarray(inv_m),
-                                jnp.asarray(th_m),
-                                jnp.asarray(np.array([scale_c], np.int32)),
-                                jnp.asarray(np.array([scale_g], np.int32)),
-                                n_zones=len(candidate_zones),
-                                az_aware=self.az_aware,
-                                interpret=self.interpret,
-                                minfrag=minfrag_inner,
-                                strict=self.strict_reference_parity,
-                            )
-                        )
-                        rec.sync(avail_after_d)
-                    out = ZoneQueueSolve(
-                        feasible=feas_d,
-                        zone_idx=zone_d,
-                        driver_idx=didx_d,
-                        uncertain=uncertain_d,
-                        avail_after=avail_after_d,
-                    )
-                else:
-                    with default_profiler.profile(
-                        "fifo_queue_single_az", lane="xla",
-                        fn=solve_queue_single_az,
-                    ) as rec:
-                        out = solve_queue_single_az(
-                            jnp.asarray(avail),
-                            rank_dev,
-                            exec_dev,
-                            zone_masks_dev,
-                            jnp.asarray(problem.driver),
-                            jnp.asarray(problem.executor),
-                            jnp.asarray(problem.count),
-                            jnp.asarray(queue_valid),
-                            jnp.asarray(s_cpu),
-                            jnp.asarray(s_gpu),
-                            jnp.asarray(inv_m),
-                            jnp.asarray(th_m),
-                            jnp.int32(scale_c),
-                            jnp.int32(scale_g),
-                            az_aware=self.az_aware,
-                            minfrag=minfrag_inner,
-                            strict=self.strict_reference_parity,
-                        )
-                        rec.sync(out.avail_after)
-                if not bool(np.asarray(out.uncertain)[:n_earlier].any()):
-                    # the one-dispatch lane's answer is certain — it is
-                    # the lane that served this request, whatever the
-                    # FIFO verdict
-                    self.last_path = "fused"
-                    self.last_queue_lane = "pallas" if self._use_pallas() else "xla"
-                    feasible = np.asarray(out.feasible)[:n_earlier]
-                    with tracing.child_span(
-                        "fifo_gate",
-                        {
-                            "lane": "fused",
-                            "kernel": self.last_queue_lane,
-                            "earlierApps": n_earlier,
-                        },
-                    ) as gate_span:
-                        for i in range(n_earlier):
-                            if not feasible[i] and not earlier_skip_allowed[i]:
-                                gate_span.tag("earlierOk", False)
-                                return FifoOutcome(supported=True, earlier_ok=False)
-                        gate_span.tag("earlierOk", True)
-                    # keep the closure binding: copy the carried result
-                    # into the same array pack_one reads
-                    avail[:] = np.asarray(out.avail_after)
-                    fused_done = True
-
-        if not fused_done and n_earlier > 0:
-            # host lane: per-driver vmapped zone solves with the exact
-            # float64 zone choice (the uncertainty/guard fallback)
-            self.last_path = self.last_queue_lane = "host"
-            with tracing.child_span(
-                "fifo_gate", {"lane": "host", "earlierApps": n_earlier}
-            ) as gate_span:
-                for i, app in enumerate(earlier_apps):
-                    packed = pack_one(i)
-                    if packed is None and self.az_aware:
-                        fallback = plain_fallback(i)
-                        packed = fallback if fallback is None else (*fallback, None)
-                    if packed is None:
-                        if earlier_skip_allowed[i]:
-                            continue
-                        gate_span.tag("earlierOk", False)
-                        return FifoOutcome(supported=True, earlier_ok=False)
-                    d_idx, counts = packed[0], packed[1]
-                    self._subtract(avail, d_idx, counts, problem, i, n)
-                gate_span.tag("earlierOk", True)
+        # the carry after the queue, scaled, on the host; from a device
+        # pass also the request's own app as the pass packed it (a snapshot)
+        avail, probe = problem.avail, None
+        if n_earlier > 0:
+            with tracing.child_span("fifo_gate", {"earlierApps": n_earlier}) as gate_span:
+                feasible, avail, probe = self._queue_pass(zones, n_earlier, gate_span)
+                gate_span.tag("lane", self.last_queue_lane)
+                blocked = ~feasible & ~np.asarray(earlier_skip_allowed, bool)
+                gate_span.tag("earlierOk", not blocked.any())
+                if blocked.any():
+                    # an enforced earlier driver that doesn't fit fails
+                    # the whole request (resource.go:244-253)
+                    return FifoOutcome(supported=True, earlier_ok=False)
+        else:
+            with tracing.child_span("fifo_gate", {"earlierApps": 0, "earlierOk": True}):
+                pass
 
         with tracing.child_span(
-            "binpack", {"policy": self.inner_policy, "azAware": self.az_aware}
+            "binpack", {"policy": self.inner_policy, "azAware": self.az_aware, "lane": "host"}
         ) as bp_span:
-            packed = pack_one(len(earlier_apps))
-            if packed is None and self.az_aware:
-                fallback = plain_fallback(len(earlier_apps))
-                packed = fallback if fallback is None else (*fallback, None)
-            bp_span.tag("feasible", packed is not None)
-        if packed is None:
+            with tracing.child_span("fast_path.zone_choice"):
+                if probe is None:
+                    pick = zones.pick(avail, n_earlier)
+                else:
+                    pick, _ = zones.pick_from_snapshot(probe, n_earlier)
+            bp_span.tag("feasible", pick is not None)
+        if pick is None:
             return FifoOutcome(supported=True, earlier_ok=True, result=empty_packing_result())
-        d_idx, counts, chosen = packed
-        if chosen is None:
-            # cross-zone fallback path: build the result from counts
-            chosen = PackingResult(
-                driver_node=names[d_idx],
-                executor_nodes=counts_to_tightly_list(names, counts),
-                has_capacity=True,
-                packing_efficiencies=efficiencies_from_rows(
-                    names,
-                    cluster.sched,
-                    avail.astype(np.int64) * scale[None, :],
-                    _reserved_rows(n, d_idx, counts, problem, len(earlier_apps))
-                    * scale[None, :],
-                ),
+        with tracing.child_span("fast_path.decode"):
+            driver_node = zones.names[pick.driver_idx]
+        with tracing.child_span("fast_path.efficiency"):
+            n = zones.n
+            # min-frag under strict parity reports the driver only
+            # (packers.make_minimal_fragmentation QUIRK)
+            reported = (
+                np.zeros(n, np.int64)
+                if zones.minfrag and self.strict_reference_parity
+                else pick.counts[:n].astype(np.int64)
             )
-        return FifoOutcome(supported=True, earlier_ok=True, result=chosen)
+            efficiencies = efficiencies_from_rows(
+                zones.names,
+                cluster.sched,
+                avail[:n].astype(np.int64) * zones.scale[None, :],
+                _reserved_rows(n, pick.driver_idx, reported, problem, n_earlier)
+                * zones.scale[None, :],
+            )
+            result = PackingResult(
+                driver_node=driver_node,
+                executor_nodes=pick.executor_nodes,
+                has_capacity=True,
+                packing_efficiencies=efficiencies,
+                max_avg_efficiency=efficiencies.seq_max_avg(),
+            )
+        return FifoOutcome(supported=True, earlier_ok=True, result=result)
 
-    @staticmethod
-    def _plain_pack(app_idx, avail, problem, n):
-        """Cross-zone tightly-pack (the az-aware fallback)."""
-        import jax.numpy as jnp
+    # -- the queue pass ---------------------------------------------------
 
-        from .batch_solver import solve_single
+    def _queue_pass(self, zones: _ZoneProblem, n_earlier: int, gate_span):
+        """(feasible[n_earlier] bool, the carried availability on the
+        host, the request's own app as a device pass packed it or None)
+        for the earlier drivers, on the lane this host serves from."""
+        problem = zones.problem
+        queue_valid = problem.app_valid.copy()
+        queue_valid[n_earlier:] = False
+        from .batch_solver import mf_sentinel_safe
 
-        solve = solve_single(
-            jnp.asarray(avail),
-            jnp.asarray(problem.driver_rank),
-            jnp.asarray(problem.exec_ok),
-            jnp.asarray(problem.driver[app_idx]),
-            jnp.asarray(problem.executor[app_idx]),
-            jnp.asarray(problem.count[app_idx]),
+        # min-frag inner: every fast lane runs the drain with the int32
+        # MF_SENT sentinel, so the collision guard gates them all
+        fast_ok = not zones.minfrag or mf_sentinel_safe(problem.avail)
+        if fast_ok and not self._use_pallas() and _native_selected(self.backend):
+            from ..native.fifo import solve_queue_single_az_native
+
+            self.last_path = self.last_queue_lane = "native"
+            with default_profiler.profile("fifo_queue_single_az", lane="native", jit=False):
+                feasible, _zone, _didx, avail_after = solve_queue_single_az_native(
+                    problem.avail, problem.driver_rank, np.asarray(problem.exec_ok),
+                    zones.zone_vec, problem.driver, problem.executor, problem.count,
+                    queue_valid, zones.cluster.sched, zones.scale,
+                    n_zones=zones.n_zones, az_aware=self.az_aware,
+                    minfrag=zones.minfrag, strict=self.strict_reference_parity,
+                )
+            return np.asarray(feasible[:n_earlier], bool), avail_after, None
+        score_inputs = _fused_efficiency_inputs(zones.cluster, problem) if fast_ok else None
+        if score_inputs is None or zones.n_zones == 0:
+            return self._host_queue_pass(zones, n_earlier)
+        return self._device_queue_pass(zones, n_earlier, queue_valid, score_inputs, gate_span)
+
+    def _host_queue_pass(self, zones: _ZoneProblem, n_earlier: int):
+        """Every earlier app decided exactly on the host, in order: the
+        lane for snapshots outside the device score's numeric bounds."""
+        self.last_path = self.last_queue_lane = "host"
+        self.last_zone_choices = {"host-queue": n_earlier}
+        avail = zones.problem.avail.astype(np.int32).copy()
+        feasible = np.zeros(n_earlier, bool)
+        for i in range(n_earlier):
+            pick = zones.pick(avail, i)
+            if pick is not None:
+                feasible[i] = True
+                zones.subtract(avail, pick, i)
+        return feasible, avail, None
+
+    def _device_queue_pass(self, zones, n_earlier, queue_valid, score_inputs, gate_span):
+        """The device pass and its valve.  The pass flags every app whose
+        zone its score cannot certify, goes on with the score's choice
+        and leaves a snapshot; each flagged app is decided here in
+        float64 from its snapshot, and only where that differs from the
+        score's choice (or the pass ran out of slots and halted) is the
+        pass launched again, from that app, with its zone forced.  The
+        request's own app rides along as a probe: its snapshot is what
+        ``binpack`` chooses from."""
+        from .batch_solver import FORCE_NONE, HINT_BASE
+
+        pallas = self._use_pallas()
+        self.last_path = "fused"
+        self.last_queue_lane = "pallas" if pallas else "xla"
+        valid = queue_valid.astype(np.int32)
+        valid[n_earlier] = 2
+        launch = self._launcher(zones, valid, score_inputs, pallas)
+        forced = np.full(valid.shape[0], FORCE_NONE, np.int32)
+        # what was decided for an app last time is the best guess for a
+        # pass that cannot tell this time: consecutive requests see much
+        # the same queue on much the same cluster.  A guess is checked
+        # like the score's own choice; a stale one costs a launch, never
+        # an answer.
+        app_keys = self._earlier_tensor_cache[0]
+        if self._zone_memo and zones.n_zones < HINT_BASE:
+            for u, key in enumerate(app_keys):
+                known = self._zone_memo.get(key)
+                if known is not None and 0 <= known[0] < zones.n_zones:
+                    forced[u] = HINT_BASE + known[0]
+        memo = {}
+        feasible = np.zeros(n_earlier, bool)
+        carry, start, launches, resolved = None, 0, 0, 0
+        while True:
+            columns, avail_dev, snapshots_dev = launch(carry, forced, start)
+            launches += 1
+            flagged = start + np.flatnonzero(columns[start : n_earlier + 1, 3])
+            slots = columns[flagged, 4]
+            snapshots = _readback(snapshots_dev) if (slots >= 0).any() else None
+            redo = probe = avail = None
+            with tracing.aggregate_span("fifo_gate.zone_resolve"):
+                for u, slot in zip(flagged.tolist(), slots.tolist()):
+                    if slot < 0:  # out of slots: the pass halted here
+                        redo = u
+                        break
+                    if u == n_earlier:
+                        probe = snapshots[slot]
+                        break
+                    # the decision is a function of what the snapshot shows of
+                    # the nodes its packings occupy: the same evidence as last
+                    # time is the same decision, with no arithmetic
+                    evidence = zones.evidence(snapshots[slot], u)
+                    known = self._zone_memo.get(app_keys[u])
+                    if known is not None and _same_evidence(known[1], evidence):
+                        zone = known[0]
+                    else:
+                        zone = zones.candidate(zones.pick_from_snapshot(snapshots[slot], u)[0])
+                    memo[app_keys[u]] = (zone, evidence)
+                    resolved += 1
+                    if zone != zones.candidate(int(columns[u, 2])):
+                        forced[u] = zone
+                        redo, avail = u, snapshots[slot][:3].T
+                        break
+                stop = n_earlier if redo is None else redo
+                feasible[start:stop] = columns[start:stop, 0] != 0
+            if redo is None:
+                break
+            if avail is None:
+                avail = _readback(avail_dev)
+                if redo == n_earlier:
+                    break  # the probe found no slot: binpack packs on the host
+                with tracing.aggregate_span("fifo_gate.zone_resolve"):
+                    forced[redo] = zones.candidate(zones.pick(avail, redo))
+                resolved += 1
+                carry = avail_dev
+            else:
+                carry = _upload(np.ascontiguousarray(avail, np.int32))[0]
+            start = redo
+        gate_span.tag("zoneResolved", resolved).tag("launches", launches)
+        self._zone_memo = memo
+        self.last_launches = launches
+        self.last_zone_choices = {"certified": n_earlier - resolved, "resolved": resolved}
+        if probe is not None:
+            avail = np.ascontiguousarray(probe[:3].T)
+        elif avail is None:
+            avail = _readback(avail_dev)
+        return feasible, avail, probe
+
+    def _launcher(self, zones, valid, score_inputs, pallas):
+        """launch(carry | None, forced, start) -> (host verdict columns
+        [A, 5]: placed, driver node, zone, flagged, snapshot slot; device
+        availability afterwards; device snapshots).  What does not
+        change between the launches of one request is uploaded once."""
+        from .batch_solver import snapshot_slots
+
+        problem = zones.problem
+        s_cpu, s_gpu, inv_m, th_m, scale_c, scale_g = score_inputs
+        n_slots = snapshot_slots(problem.avail.shape[0])
+        if pallas:
+            from .pallas_queue import pallas_solve_queue_single_az_packed as kernel
+
+            node_cols = np.stack(
+                [problem.driver_rank, problem.exec_ok.astype(np.int32), zones.zone_vec,
+                 s_cpu, s_gpu, th_m, inv_m.view(np.int32)], axis=1,
+            )
+            avail0, nodes_dev = _upload(problem.avail, node_cols)
+            app_cols = np.concatenate(
+                [problem.driver, problem.executor, problem.count[:, None], valid[:, None]],
+                axis=1,
+            )
+
+            def launch(carry, forced, start):
+                apps_dev, scalars = _upload(
+                    np.concatenate([app_cols, forced[:, None]], axis=1),
+                    np.array([scale_c, scale_g, start], np.int32),
+                )
+                with default_profiler.profile(
+                    "fifo_queue_single_az", lane="pallas", fn=kernel
+                ) as rec:
+                    columns, avail_after, snapshots = kernel(
+                        avail0 if carry is None else carry,
+                        nodes_dev, apps_dev, scalars,
+                        n_zones=zones.n_zones, az_aware=self.az_aware,
+                        interpret=self.interpret, minfrag=zones.minfrag,
+                        strict=self.strict_reference_parity, n_slots=n_slots,
+                    )
+                    rec.sync(avail_after)
+                return _readback(columns), avail_after, snapshots
+
+            return launch
+
+        from .batch_solver import solve_queue_single_az
+
+        zone_masks = zones.zone_vec[None, :] == np.arange(max(zones.n_zones, 1))[:, None]
+        fixed = _upload(
+            problem.driver_rank, problem.exec_ok, zone_masks, problem.driver,
+            problem.executor, problem.count, valid, s_cpu, s_gpu, inv_m, th_m,
         )
-        if not bool(solve.feasible):
-            return None
-        return int(solve.driver_idx), np.asarray(solve.exec_counts)[:n]
+        avail0 = _upload(problem.avail)[0]
 
-    @staticmethod
-    def _subtract(avail, d_idx, counts, problem, app_idx, n):
-        """The reference's usage-overwrite quirk in scaled int space."""
-        exec_mask = counts > 0
-        delta = np.zeros((avail.shape[0], 3), np.int32)
-        delta[:n][exec_mask] = problem.executor[app_idx]
-        if not exec_mask[d_idx]:
-            delta[d_idx] = problem.driver[app_idx]
-        avail -= delta
+        def launch(carry, forced, start):
+            forced_dev, start_dev = _upload(forced.copy(), np.int32(start))
+            with default_profiler.profile(
+                "fifo_queue_single_az", lane="xla", fn=solve_queue_single_az
+            ) as rec:
+                out = solve_queue_single_az(
+                    avail0 if carry is None else carry, *fixed,
+                    np.int32(scale_c), np.int32(scale_g), forced_dev, start_dev,
+                    az_aware=self.az_aware, minfrag=zones.minfrag,
+                    strict=self.strict_reference_parity, n_slots=n_slots,
+                )
+                rec.sync(out.avail_after)
+            columns = np.stack(
+                [_readback(col) for col in
+                 (out.feasible, out.driver_idx, out.zone_idx, out.uncertain, out.slot)],
+                axis=1,
+            ).astype(np.int32)
+            return columns, out.avail_after, out.snapshots
+
+        return launch
 
 
 def _reserved_rows(n, d_idx, counts, problem, app_idx):

@@ -30,7 +30,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .batch_solver import EFF_SHIFT, MF_SENT
+from .batch_solver import DRIVER_BIT, EFF_SHIFT, FORCE_NONE, HINT_BASE, MF_SENT
 
 LANES = 128
 BIG = 2**31 - 1  # plain int: a module-level jnp scalar would be a captured const in the kernel
@@ -363,17 +363,19 @@ def _solve_tightly(cpu, mem, gpu, rank, exec_ok, dr, ex, k, node_ids):
 def _singleaz_kernel(
     # scalar prefetch (SMEM)
     dcpu, dmem, dgpu, ecpu, emem, egpu, ks, valids, scale_c_ref, scale_g_ref,
+    forced_ref, start_ref,
     # VMEM planes
     avail0, availm0, availg0, rank_ref, execok_ref, zone_ref,
     scpu_ref, sgpu_ref, thm_ref, invm_ref,
     # outputs
-    feas_ref, avail_out, availm_out, availg_out,
-    # scratch
-    ac, am, ag,
+    feas_ref, avail_out, availm_out, availg_out, snap_ref,
+    # scratch: availability carry; SMEM [halted, snapshot slots taken]
+    ac, am, ag, state,
     *,
     n_zones: int,
     az_aware: bool,
     n_apps: int,
+    n_slots: int,
     minfrag: bool = False,
     strict: bool = True,
 ):
@@ -383,7 +385,23 @@ def _singleaz_kernel(
     when minfrag=True, with driver-only efficiency reservations under
     strict parity — certified fixed-point zone score at EFF_SHIFT=18,
     strict-improvement choice in zone order, az-aware cross-zone
-    fallback, subtraction quirk)."""
+    fallback, subtraction quirk).
+
+    An app whose zone the score cannot certify is *flagged*.  While a
+    snapshot slot is free the pass goes on with the score's own choice
+    and leaves in the slot what the exact decision needs: the carry as
+    it stood before the app and every zone's packing (one plane, the
+    zones are disjoint: executor count, bit 30 = the driver's node).
+    The caller checks each flagged app in float64 afterwards; where it
+    decides otherwise it launches again from that app (``start``:
+    earlier steps do nothing) with the slot's carry and the zone in
+    ``forced`` (FORCE_NONE = the kernel's choice, -1 = no zone, z = take
+    zone z, HINT_BASE + z = a guess: zone z if the app is flagged, and it
+    stays flagged).  Out of slots, the pass halts at the flagged app: the
+    carry stays as it stood, every later step does nothing, and
+    ``avail_out`` is that carry.  ``valid`` 2 marks a probe (the
+    request's own app): every zone packed into a slot, nothing placed.
+    Padding apps (valid 0) are skipped outright."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -391,129 +409,192 @@ def _singleaz_kernel(
         ac[...] = avail0[...]
         am[...] = availm0[...]
         ag[...] = availg0[...]
+        state[0] = 0
+        state[1] = 0
 
-    rank = rank_ref[...]
-    exec_ok = execok_ref[...] != 0
-    zone_plane = zone_ref[...]
-    s_cpu = scpu_ref[...]
-    s_gpu = sgpu_ref[...]
-    th_m = thm_ref[...]
-    inv_m = invm_ref[...]
-    scale_c = scale_c_ref[0]
-    scale_g = scale_g_ref[0]
-    rows, lanes = rank.shape
-    row_ids = lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
-    lane_ids = lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
-    node_ids = row_ids * lanes + lane_ids
+    rows, lanes = rank_ref.shape
     out_lanes = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    active = (i >= start_ref[0]) & (state[0] == 0) & (valids[i] != 0)
 
-    dr = jnp.array([dcpu[i], dmem[i], dgpu[i]], dtype=jnp.int32)
-    ex = jnp.array([ecpu[i], emem[i], egpu[i]], dtype=jnp.int32)
-    k = ks[i]
-    valid = valids[i]
-    band = 2 * (k + 1) + 2
-
-    cpu, mem, gpu = ac[...], am[...], ag[...]
-    den_c = jnp.maximum(lax.div(s_cpu + 999, jnp.int32(1000)), 1)
-    den_g = jnp.maximum(lax.div(s_gpu + 999, jnp.int32(1000)), 1)
-    has_gpu = s_gpu > 0
-
-    best_q = jnp.int32(0)
-    best_zone = jnp.int32(-1)
-    uncertain = jnp.int32(0)
-    # int32 planes (not bool): mosaic cannot legalize a select over i1
-    # vectors with a scalar predicate
-    chosen_exec = jnp.zeros((rows, lanes), jnp.int32)
-    chosen_driver = jnp.zeros((rows, lanes), jnp.int32)
-    chosen_idx = jnp.int32(rows * lanes)
-
-    def score(x, is_driver, res=None):
-        # x weights the occurrences; `res` (default x) is the
-        # reservation seen by the efficiency numerators — they differ
-        # only under min-frag strict parity (the no-write-back quirk)
-        res = x if res is None else res
-        w = x + is_driver.astype(jnp.int32)
-        new_c = res * ex[0] + jnp.where(is_driver, dr[0], 0)
-        new_m = res * ex[1] + jnp.where(is_driver, dr[1], 0)
-        new_g = res * ex[2] + jnp.where(is_driver, dr[2], 0)
-        m_c = cpu - new_c
-        m_m = mem - new_m
-        m_g = gpu - new_g
-        num_cq = s_cpu - m_c * scale_c
-        num_gq = s_gpu - m_g * scale_g
-        num_cores = lax.div(num_cq + 999, jnp.int32(1000))
-        num_gcores = lax.div(num_gq + 999, jnp.int32(1000))
-        ratio_c = num_cores.astype(jnp.float32) / den_c.astype(jnp.float32)
-        ratio_g = jnp.where(
-            has_gpu, num_gcores.astype(jnp.float32) / den_g.astype(jnp.float32), 0.0
-        )
-        ratio_m = jnp.maximum(1.0 - m_m.astype(jnp.float32) * inv_m, 0.0)
-        eff = jnp.maximum(jnp.maximum(ratio_c, ratio_m), ratio_g)
-        q = jnp.floor(eff * jnp.float32(2**EFF_SHIFT) + 0.5).astype(jnp.int32)
-        q_sum = jnp.sum(jnp.where(w > 0, w * q, 0))
-        nz = jnp.any(
-            (w > 0) & ((num_cq > 0) | (m_m < th_m) | (has_gpu & (num_gq > 0)))
-        )
-        return q_sum, nz
-
-    for z in range(n_zones):
-        mask = zone_plane == z
-        if minfrag:
-            f, flat_idx, is_driver, x = _solve_min_frag(
-                cpu, mem, gpu,
-                jnp.where(mask, rank, BIG), exec_ok & mask, dr, ex, k, node_ids,
-            )
-            res = jnp.zeros_like(x) if strict else x
-            q_sum, nz = score(x, is_driver, res=res)
-        else:
-            f, flat_idx, is_driver, x = _solve_tightly(
-                cpu, mem, gpu,
-                jnp.where(mask, rank, BIG), exec_ok & mask, dr, ex, k, node_ids,
-            )
-            q_sum, nz = score(x, is_driver)
-        first = best_zone < 0
-        better = f & jnp.where(first, nz, q_sum > best_q)
-        uncertain = uncertain | (
-            f & (~first) & (q_sum != best_q) & (jnp.abs(q_sum - best_q) <= band)
-        ).astype(jnp.int32)
-        best_q = jnp.where(better, q_sum, best_q)
-        best_zone = jnp.where(better, jnp.int32(z), best_zone)
-        chosen_exec = jnp.where(better, (x > 0).astype(jnp.int32), chosen_exec)
-        chosen_driver = jnp.where(better, is_driver.astype(jnp.int32), chosen_driver)
-        chosen_idx = jnp.where(better, flat_idx, chosen_idx)
-
-    if az_aware:
-        f, flat_idx, is_driver, x = _solve_tightly(
-            cpu, mem, gpu, rank, exec_ok, dr, ex, k, node_ids
-        )
-        use_cross = (best_zone < 0) & f
-        chosen_exec = jnp.where(use_cross, (x > 0).astype(jnp.int32), chosen_exec)
-        chosen_driver = jnp.where(use_cross, is_driver.astype(jnp.int32), chosen_driver)
-        chosen_idx = jnp.where(use_cross, flat_idx, chosen_idx)
-        best_zone = jnp.where(use_cross, jnp.int32(n_zones), best_zone)
-
-    placed = (best_zone >= 0) & (valid != 0)
-    exec_mask = (chosen_exec != 0) & placed
-    driver_mask = (chosen_driver != 0) & placed & ~exec_mask
-
-    ac[...] = cpu - jnp.where(exec_mask, ex[0], jnp.where(driver_mask, dr[0], 0))
-    am[...] = mem - jnp.where(exec_mask, ex[1], jnp.where(driver_mask, dr[1], 0))
-    ag[...] = gpu - jnp.where(exec_mask, ex[2], jnp.where(driver_mask, dr[2], 0))
-
-    idx_val = jnp.where(placed, chosen_idx, jnp.int32(rows * lanes))
-    zone_val = jnp.where(placed, best_zone, jnp.int32(-1))
-    out_row = jnp.where(
-        out_lanes == 0,
-        placed.astype(jnp.int32),
-        jnp.where(
+    @pl.when(jnp.logical_not(active))
+    def _skip():
+        feas_ref[pl.ds(i % 8, 1), :] = jnp.where(
             out_lanes == 1,
-            idx_val,
+            jnp.int32(rows * lanes),
+            jnp.where((out_lanes == 2) | (out_lanes == 4), -1, 0),
+        )
+
+    @pl.when(active)
+    def _step():
+        rank = rank_ref[...]
+        exec_ok = execok_ref[...] != 0
+        zone_plane = zone_ref[...]
+        s_cpu = scpu_ref[...]
+        s_gpu = sgpu_ref[...]
+        th_m = thm_ref[...]
+        inv_m = invm_ref[...]
+        scale_c = scale_c_ref[0]
+        scale_g = scale_g_ref[0]
+        row_ids = lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
+        lane_ids = lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+        node_ids = row_ids * lanes + lane_ids
+
+        dr = jnp.array([dcpu[i], dmem[i], dgpu[i]], dtype=jnp.int32)
+        ex = jnp.array([ecpu[i], emem[i], egpu[i]], dtype=jnp.int32)
+        k = ks[i]
+        forced = forced_ref[i]
+        hinted = forced >= HINT_BASE  # a guess for a flagged app, checked like the score's own
+        is_forced = (forced != FORCE_NONE) & jnp.logical_not(hinted)
+        band = 2 * (k + 1) + 2
+
+        cpu, mem, gpu = ac[...], am[...], ag[...]
+        den_c = jnp.maximum(lax.div(s_cpu + 999, jnp.int32(1000)), 1)
+        den_g = jnp.maximum(lax.div(s_gpu + 999, jnp.int32(1000)), 1)
+        has_gpu = s_gpu > 0
+
+        best_q = jnp.int32(0)
+        best_zone = jnp.int32(-1)
+        uncertain = jnp.int32(0)
+        # int32 planes (not bool): mosaic cannot legalize a select over i1
+        # vectors with a scalar predicate
+        chosen_exec = jnp.zeros((rows, lanes), jnp.int32)
+        chosen_driver = jnp.zeros((rows, lanes), jnp.int32)
+        chosen_idx = jnp.int32(rows * lanes)
+        hint_exec = jnp.zeros((rows, lanes), jnp.int32)
+        hint_driver = jnp.zeros((rows, lanes), jnp.int32)
+        hint_idx = jnp.int32(rows * lanes)
+        hint_fits = jnp.int32(0)
+        packings = jnp.zeros((rows, lanes), jnp.int32)
+
+        def score(x, is_driver, res=None):
+            # x weights the occurrences; `res` (default x) is the
+            # reservation seen by the efficiency numerators — they differ
+            # only under min-frag strict parity (the no-write-back quirk)
+            res = x if res is None else res
+            w = x + is_driver.astype(jnp.int32)
+            new_c = res * ex[0] + jnp.where(is_driver, dr[0], 0)
+            new_m = res * ex[1] + jnp.where(is_driver, dr[1], 0)
+            new_g = res * ex[2] + jnp.where(is_driver, dr[2], 0)
+            m_c = cpu - new_c
+            m_m = mem - new_m
+            m_g = gpu - new_g
+            num_cq = s_cpu - m_c * scale_c
+            num_gq = s_gpu - m_g * scale_g
+            num_cores = lax.div(num_cq + 999, jnp.int32(1000))
+            num_gcores = lax.div(num_gq + 999, jnp.int32(1000))
+            ratio_c = num_cores.astype(jnp.float32) / den_c.astype(jnp.float32)
+            ratio_g = jnp.where(
+                has_gpu, num_gcores.astype(jnp.float32) / den_g.astype(jnp.float32), 0.0
+            )
+            ratio_m = jnp.maximum(1.0 - m_m.astype(jnp.float32) * inv_m, 0.0)
+            eff = jnp.maximum(jnp.maximum(ratio_c, ratio_m), ratio_g)
+            q = jnp.floor(eff * jnp.float32(2**EFF_SHIFT) + 0.5).astype(jnp.int32)
+            q_sum = jnp.sum(jnp.where(w > 0, w * q, 0))
+            nz = jnp.any(
+                (w > 0) & ((num_cq > 0) | (m_m < th_m) | (has_gpu & (num_gq > 0)))
+            )
+            return q_sum, nz
+
+        for z in range(n_zones):
+            mask = zone_plane == z
+            if minfrag:
+                f, flat_idx, is_driver, x = _solve_min_frag(
+                    cpu, mem, gpu,
+                    jnp.where(mask, rank, BIG), exec_ok & mask, dr, ex, k, node_ids,
+                )
+                res = jnp.zeros_like(x) if strict else x
+                q_sum, nz = score(x, is_driver, res=res)
+            else:
+                f, flat_idx, is_driver, x = _solve_tightly(
+                    cpu, mem, gpu,
+                    jnp.where(mask, rank, BIG), exec_ok & mask, dr, ex, k, node_ids,
+                )
+                q_sum, nz = score(x, is_driver)
+            first = best_zone < 0
+            better = f & jnp.where(first, nz, q_sum > best_q)
+            # inside the band the float64 oracle may order the two either
+            # way — equal scores included: equal Q from different inputs is
+            # no proof of equal sums, and equal sums added up in another
+            # order need not compare equal in float64 either
+            uncertain = uncertain | (
+                f & (~first) & (jnp.abs(q_sum - best_q) <= band)
+            ).astype(jnp.int32)
+            best_q = jnp.where(better, q_sum, best_q)
+            packings = packings + x + (is_driver.astype(jnp.int32) << DRIVER_BIT)
+            take = jnp.where(is_forced, f & (forced == z), better)
+            best_zone = jnp.where(take, jnp.int32(z), best_zone)
+            chosen_exec = jnp.where(take, (x > 0).astype(jnp.int32), chosen_exec)
+            chosen_driver = jnp.where(take, is_driver.astype(jnp.int32), chosen_driver)
+            chosen_idx = jnp.where(take, flat_idx, chosen_idx)
+            guess = hinted & f & (forced == HINT_BASE + z)
+            hint_exec = jnp.where(guess, (x > 0).astype(jnp.int32), hint_exec)
+            hint_driver = jnp.where(guess, is_driver.astype(jnp.int32), hint_driver)
+            hint_idx = jnp.where(guess, flat_idx, hint_idx)
+            hint_fits = hint_fits | guess.astype(jnp.int32)
+
+        # a forced app was decided exactly by the caller; any other app the
+        # score cannot certify, and a probe, is flagged: into a slot while
+        # there is one, a halt before the carry is touched once there is none
+        probe = valids[i] == 2
+        flagged = jnp.where(is_forced, 0, uncertain) | probe.astype(jnp.int32)
+        # where the score cannot tell, the caller's guess (what it decided
+        # for this app last time) stands in for the score's own choice
+        use_hint = (uncertain != 0) & (hint_fits != 0)
+        best_zone = jnp.where(use_hint, forced - HINT_BASE, best_zone)
+        chosen_exec = jnp.where(use_hint, hint_exec, chosen_exec)
+        chosen_driver = jnp.where(use_hint, hint_driver, chosen_driver)
+        chosen_idx = jnp.where(use_hint, hint_idx, chosen_idx)
+        slot = state[1]
+        keep = (flagged != 0) & (slot < n_slots)
+        halt = (flagged != 0) & (slot >= n_slots)
+        state[0] = halt.astype(jnp.int32)
+
+        @pl.when(keep)
+        def _snapshot():
+            snap_ref[slot, 0] = cpu
+            snap_ref[slot, 1] = mem
+            snap_ref[slot, 2] = gpu
+            snap_ref[slot, 3] = packings
+            state[1] = slot + 1
+
+        if az_aware:
+            f, flat_idx, is_driver, x = _solve_tightly(
+                cpu, mem, gpu, rank, exec_ok, dr, ex, k, node_ids
+            )
+            use_cross = (best_zone < 0) & f
+            chosen_exec = jnp.where(use_cross, (x > 0).astype(jnp.int32), chosen_exec)
+            chosen_driver = jnp.where(use_cross, is_driver.astype(jnp.int32), chosen_driver)
+            chosen_idx = jnp.where(use_cross, flat_idx, chosen_idx)
+            best_zone = jnp.where(use_cross, jnp.int32(n_zones), best_zone)
+
+        placed = (best_zone >= 0) & jnp.logical_not(halt | probe)
+        exec_mask = (chosen_exec != 0) & placed
+        driver_mask = (chosen_driver != 0) & placed & ~exec_mask
+
+        ac[...] = cpu - jnp.where(exec_mask, ex[0], jnp.where(driver_mask, dr[0], 0))
+        am[...] = mem - jnp.where(exec_mask, ex[1], jnp.where(driver_mask, dr[1], 0))
+        ag[...] = gpu - jnp.where(exec_mask, ex[2], jnp.where(driver_mask, dr[2], 0))
+
+        idx_val = jnp.where(placed, chosen_idx, jnp.int32(rows * lanes))
+        zone_val = jnp.where(placed, best_zone, jnp.int32(-1))
+        out_row = jnp.where(
+            out_lanes == 0,
+            placed.astype(jnp.int32),
             jnp.where(
-                out_lanes == 2, zone_val, jnp.where(out_lanes == 3, uncertain, 0)
+                out_lanes == 1,
+                idx_val,
+                jnp.where(
+                    out_lanes == 2,
+                    zone_val,
+                    jnp.where(
+                        out_lanes == 3,
+                        flagged,
+                        jnp.where(out_lanes == 4, jnp.where(keep, slot, -1), 0),
+                    ),
+                ),
             ),
-        ),
-    )
-    feas_ref[pl.ds(i % 8, 1), :] = out_row
+        )
+        feas_ref[pl.ds(i % 8, 1), :] = out_row
 
     @pl.when(i == n_apps - 1)
     def _final():
@@ -522,9 +603,81 @@ def _singleaz_kernel(
         availg_out[...] = ag[...]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_zones", "az_aware", "interpret", "minfrag", "strict")
-)
+def _single_az_call(
+    avail, driver_rank, exec_ok, zone_id, drivers, executors, counts, app_valid,
+    s_cpu_milli, s_gpu_milli, inv_mem, th_mem, scale_cpu, scale_gpu, forced, start,
+    *, n_zones, az_aware, interpret, minfrag, strict, n_slots,
+):
+    """The single-AZ ``pallas_call``: per-app rows [A, 128] int32 (lane
+    0 placed, 1 driver node, 2 zone, 3 flagged, 4 snapshot slot or -1),
+    avail_after [N, 3] and the snapshots [max(n_slots, 1), 4, N]
+    (availability planes cpu, memory, gpu, then the packings)."""
+    assert not (az_aware and minfrag)
+    n = avail.shape[0]
+    a = drivers.shape[0]
+    rows, padded = _row_layout(n)
+
+    def plane(v, fill=0, dtype=jnp.int32):
+        flat = jnp.full((padded,), fill, dtype=dtype)
+        flat = flat.at[:n].set(v.astype(dtype))
+        return flat.reshape(rows, LANES)
+
+    slots = max(n_slots, 1)
+    kernel = functools.partial(
+        _singleaz_kernel, n_zones=n_zones, az_aware=az_aware, n_apps=a,
+        n_slots=n_slots, minfrag=minfrag, strict=strict,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=12,
+        grid=(a,),
+        in_specs=[pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0))] * 10,
+        out_specs=[
+            pl.BlockSpec((8, LANES), lambda i, *refs: (i // 8, 0)),
+            pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0)),
+            pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0)),
+            pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0)),
+            pl.BlockSpec((slots, 4, rows, LANES), lambda i, *refs: (0, 0, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.int32)] * 3
+        + [pltpu.SMEM((2,), jnp.int32)],
+    )
+    out_shape = [
+        jax.ShapeDtypeStruct((a, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        jax.ShapeDtypeStruct((slots, 4, rows, LANES), jnp.int32),
+    ]
+    feas, c_out, m_out, g_out, snaps = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+        name="pallas_solve_queue_single_az",  # the device trace's name for it
+    )(
+        drivers[:, 0], drivers[:, 1], drivers[:, 2],
+        executors[:, 0], executors[:, 1], executors[:, 2],
+        counts, app_valid.astype(jnp.int32),
+        scale_cpu.astype(jnp.int32), scale_gpu.astype(jnp.int32),
+        forced.astype(jnp.int32), start.astype(jnp.int32),
+        plane(avail[:, 0]), plane(avail[:, 1]), plane(avail[:, 2]),
+        plane(driver_rank, fill=int(BIG)),
+        plane(exec_ok.astype(jnp.int32)),
+        plane(zone_id, fill=-1),
+        plane(s_cpu_milli), plane(s_gpu_milli),
+        plane(th_mem),
+        plane(inv_mem, fill=0, dtype=jnp.float32),
+    )
+    avail_after = jnp.stack(
+        [c_out.reshape(-1)[:n], m_out.reshape(-1)[:n], g_out.reshape(-1)[:n]], axis=1
+    )
+    return feas, avail_after, snaps.reshape(slots, 4, padded)[:, :, :n]
+
+
+_SINGLE_AZ_STATIC = ("n_zones", "az_aware", "interpret", "minfrag", "strict", "n_slots")
+
+
+@functools.partial(jax.jit, static_argnames=_SINGLE_AZ_STATIC)
 def pallas_solve_queue_single_az(
     avail: jnp.ndarray,        # [N, 3] int32
     driver_rank: jnp.ndarray,  # [N] int32
@@ -540,77 +693,71 @@ def pallas_solve_queue_single_az(
     th_mem: jnp.ndarray,       # [N] int32
     scale_cpu: jnp.ndarray,    # [1] int32
     scale_gpu: jnp.ndarray,    # [1] int32
+    forced: jnp.ndarray | None = None,  # [A] int32 — FORCE_NONE, -1 or a zone
+    start: jnp.ndarray | None = None,   # [1] int32 — first app this launch solves
     n_zones: int = 1,
     az_aware: bool = False,
     interpret: bool = False,
     minfrag: bool = False,
     strict: bool = True,
+    n_slots: int = 0,
 ):
     """Single-kernel single-AZ FIFO solve.  Returns (feasible[A],
-    zone_idx[A], driver_idx[A], uncertain[A], avail_after[N, 3]) with
+    zone_idx[A], driver_idx[A], flagged[A], avail_after[N, 3]) with
     decisions identical to batch_solver.solve_queue_single_az
-    (tests/test_pallas_queue.py proves it on randomized queues).
-    minfrag=True gives the single-az-minimal-fragmentation inner policy
-    (no az_aware variant exists in the reference; caller guards
-    mf_sentinel_safe)."""
-    assert not (az_aware and minfrag)
-    n = avail.shape[0]
+    (tests/test_pallas_queue.py proves it on randomized queues),
+    ``forced``, ``start`` and the flagged apps included (see
+    ``_singleaz_kernel``; without slots, the default here, the pass
+    halts at the first one).  minfrag=True gives the
+    single-az-minimal-fragmentation inner policy (no az_aware variant
+    exists in the reference; caller guards mf_sentinel_safe)."""
     a = drivers.shape[0]
-    rows, padded = _row_layout(n)
-
-    def plane(v, fill=0, dtype=jnp.int32):
-        flat = jnp.full((padded,), fill, dtype=dtype)
-        flat = flat.at[:n].set(v.astype(dtype))
-        return flat.reshape(rows, LANES)
-
-    kernel = functools.partial(
-        _singleaz_kernel, n_zones=n_zones, az_aware=az_aware, n_apps=a,
-        minfrag=minfrag, strict=strict,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=10,
-        grid=(a,),
-        in_specs=[pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0))] * 10,
-        out_specs=[
-            pl.BlockSpec((8, LANES), lambda i, *refs: (i // 8, 0)),
-            pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0)),
-            pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0)),
-            pl.BlockSpec((rows, LANES), lambda i, *refs: (0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.int32)] * 3,
-    )
-    out_shape = [
-        jax.ShapeDtypeStruct((a, LANES), jnp.int32),
-        jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-    ]
-    feas, c_out, m_out, g_out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(
-        drivers[:, 0], drivers[:, 1], drivers[:, 2],
-        executors[:, 0], executors[:, 1], executors[:, 2],
-        counts, app_valid.astype(jnp.int32),
-        scale_cpu.astype(jnp.int32), scale_gpu.astype(jnp.int32),
-        plane(avail[:, 0]), plane(avail[:, 1]), plane(avail[:, 2]),
-        plane(driver_rank, fill=int(BIG)),
-        plane(exec_ok.astype(jnp.int32)),
-        plane(zone_id, fill=-1),
-        plane(s_cpu_milli), plane(s_gpu_milli),
-        plane(th_mem),
-        plane(inv_mem, fill=0, dtype=jnp.float32),
+    if forced is None:  # schedlint: disable=JX001 -- None is the argument's absence, static under jit
+        forced = jnp.full((a,), FORCE_NONE, jnp.int32)
+    if start is None:  # schedlint: disable=JX001 -- None is the argument's absence, static under jit
+        start = jnp.zeros((1,), jnp.int32)
+    feas, avail_after, _ = _single_az_call(
+        avail, driver_rank, exec_ok, zone_id, drivers, executors, counts, app_valid,
+        s_cpu_milli, s_gpu_milli, inv_mem, th_mem, scale_cpu, scale_gpu, forced, start,
+        n_zones=n_zones, az_aware=az_aware, interpret=interpret, minfrag=minfrag,
+        strict=strict, n_slots=n_slots,
     )
     feasible = feas[:, 0] != 0
-    driver_idx = jnp.where(feasible, feas[:, 1], jnp.int32(n))
-    zone_idx = feas[:, 2]
-    uncertain = feas[:, 3] != 0
-    avail_after = jnp.stack(
-        [c_out.reshape(-1)[:n], m_out.reshape(-1)[:n], g_out.reshape(-1)[:n]], axis=1
+    driver_idx = jnp.where(feasible, feas[:, 1], jnp.int32(avail.shape[0]))
+    return feasible, feas[:, 2], driver_idx, feas[:, 3] != 0, avail_after
+
+
+@functools.partial(jax.jit, static_argnames=_SINGLE_AZ_STATIC)
+def pallas_solve_queue_single_az_packed(
+    avail: jnp.ndarray,      # [N, 3] int32 — the carry this launch starts from
+    node_cols: jnp.ndarray,  # [N, 7] int32: driver rank, executor ok, zone, schedulable
+    # milli-cpu, schedulable milli-gpu, memory threshold, the bits of f32 inv_mem
+    app_cols: jnp.ndarray,   # [A, 9] int32: driver (3), executor (3), count, valid, forced
+    scalars: jnp.ndarray,    # [3] int32: scale_cpu, scale_gpu, start
+    n_zones: int = 1,
+    az_aware: bool = False,
+    interpret: bool = False,
+    minfrag: bool = False,
+    strict: bool = True,
+    n_slots: int = 0,
+):
+    """``pallas_solve_queue_single_az`` as the served path launches it:
+    the inputs in four arrays (an upload costs the host the same
+    whatever its size; ``valid`` 2 marks the probe) and the per-app
+    results in one, [A, 5] int32 (placed, driver node, zone, flagged,
+    snapshot slot), then avail_after [N, 3] and the snapshots
+    [n_slots, 4, N]."""
+    feas, avail_after, snaps = _single_az_call(
+        avail, node_cols[:, 0], node_cols[:, 1], node_cols[:, 2],
+        app_cols[:, 0:3], app_cols[:, 3:6], app_cols[:, 6], app_cols[:, 7],
+        node_cols[:, 3], node_cols[:, 4],
+        lax.bitcast_convert_type(node_cols[:, 6], jnp.float32), node_cols[:, 5],
+        scalars[0:1], scalars[1:2], app_cols[:, 8], scalars[2:3],
+        n_zones=n_zones, az_aware=az_aware, interpret=interpret, minfrag=minfrag,
+        strict=strict, n_slots=n_slots,
     )
-    return feasible, zone_idx, driver_idx, uncertain, avail_after
+    node = jnp.where(feas[:, 0] != 0, feas[:, 1], jnp.int32(avail.shape[0]))
+    return feas[:, 0:5].at[:, 1].set(node), avail_after, snaps
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

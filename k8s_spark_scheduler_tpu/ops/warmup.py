@@ -67,14 +67,17 @@ def warm_queue_solver(
     if native_lane and not binpacker.is_single_az:
         return
     one = Resources.of("1", "1Gi")
-    node = Resources.of("8", "8Gi")
+    # a node size per zone: on identical zones every app of the queue
+    # ties, and the single-AZ pass would stop at each of them to have the
+    # host decide (a launch per app instead of one)
+    sizes = [Resources.of(str(8 << z), f"{8 << z}Gi") for z in range(WARM_ZONES)]
     for n_nodes, n_apps in shapes:
         if should_stop():
             return
         metadata = {
             f"warm-{i:06d}": NodeSchedulingMetadata(
-                available=node,
-                schedulable=node,
+                available=sizes[i % WARM_ZONES],
+                schedulable=sizes[i % WARM_ZONES],
                 zone_label=f"warm-z{i % WARM_ZONES}",
             )
             for i in range(n_nodes)
@@ -90,17 +93,3 @@ def warm_queue_solver(
                 f"solver warm-up for {binpack_algo} at {n_nodes}x{n_apps} did "
                 "not reach the queue solve (synthetic problem refused)"
             )
-        if getattr(solver, "az_aware", False):
-            # the cross-zone fallback only runs when no single zone fits,
-            # which the all-feasible problem above never reaches
-            import jax.numpy as jnp
-
-            from .batch_solver import solve_single
-
-            row = jnp.zeros((3,), jnp.int32)
-            solve_single(
-                jnp.zeros((n_nodes, 3), jnp.int32),
-                jnp.zeros((n_nodes,), jnp.int32),
-                jnp.zeros((n_nodes,), bool),
-                row, row, jnp.int32(0),
-            ).feasible.block_until_ready()

@@ -744,8 +744,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         (FifoOutcome, zones) or None to use the Quantity path."""
         solver = getattr(self.binpacker, "queue_solver", None)
         # the tensor-snapshot lane needs a solver that accepts prebuilt
-        # tensors; the single-AZ FIFO solver requires Quantity metadata
-        # (zone efficiency choice) and goes through the metadata path
+        # tensors (both solver families do)
         if (
             solver is None
             or not hasattr(solver, "solve_tensor")
@@ -846,6 +845,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                 skip_allowed,
                 current,
             )
+            self._count_zone_choices(solver)
             if not outcome.supported:
                 return self._lane_neutral("tensor_driver")
             if self._lane_health is not None:
@@ -857,6 +857,14 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             self._lane_fault("tensor_driver")
             logger.exception("tensor-snapshot fast path failed; using Quantity path")
             return None
+
+    def _count_zone_choices(self, solver) -> None:
+        """Single-AZ solvers say who chose each queue app's zone in the
+        last request: the device's certified score, the exact host
+        decision for that app alone, or the whole-queue host lane."""
+        for result, apps in getattr(solver, "last_zone_choices", {}).items():
+            if apps:
+                self._metrics.counter(mnames.FIFO_ZONE_CHOICE, {"result": result}, apps)
 
     def _try_device_fifo(
         self,
@@ -903,12 +911,13 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             )
             lane = getattr(solver, "last_path", None)
             if lane is not None:
-                # single-AZ solvers report fused (one-dispatch) vs host
-                # (exact fallback) — the ops signal for how often the
-                # certified fixed-point zone choice holds
+                # single-AZ solvers report fused (the device pass) vs
+                # host (the whole queue decided on the host, behind the
+                # score's numeric guards)
                 self._metrics.counter(
                     mnames.SINGLEAZ_LANE, {"lane": lane}
                 )
+            self._count_zone_choices(solver)
             if self._lane_health is not None:
                 self._lane_health.record_success(
                     "device_fifo", self._lane_elapsed(t0, compile0)

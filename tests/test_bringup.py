@@ -105,12 +105,14 @@ def test_loaded_native_libraries_are_named_by_this_hosts_hash():
 
 
 def _refuse_zone_kernel(monkeypatch):
-    from k8s_spark_scheduler_tpu.ops import batch_solver
+    """What the single-AZ policy's queue pass runs on this platform (the
+    native lane on a CPU host) refuses, as a kernel Mosaic rejects would."""
+    from k8s_spark_scheduler_tpu.native import fifo
 
     def refuse(*args, **kwargs):
         raise RuntimeError("injected: Mosaic failed to compile TPU kernel")
 
-    monkeypatch.setattr(batch_solver, "solve_zones_jit", refuse)
+    monkeypatch.setattr(fifo, "solve_queue_single_az_native", refuse)
 
 
 def test_warmup_compile_error_fails_readiness(monkeypatch):
@@ -160,10 +162,10 @@ def test_server_process_exits_nonzero_when_warmup_fails(tmp_path):
         f"""
         import sys
         sys.path.insert(0, {REPO!r})
-        from k8s_spark_scheduler_tpu.ops import batch_solver
+        from k8s_spark_scheduler_tpu.native import fifo
         def refuse(*a, **k):
             raise RuntimeError("injected: Mosaic failed to compile TPU kernel")
-        batch_solver.solve_zones_jit = refuse
+        fifo.solve_queue_single_az_native = refuse
         from k8s_spark_scheduler_tpu.server.__main__ import main
         sys.exit(main(["--port", "0", "--config", {str(cfg)!r}]))
         """
@@ -342,6 +344,34 @@ def test_chip_smoke_fails_when_a_device_lane_is_not_traced(chip_smoke, monkeypat
     monkeypatch.setattr(fifo_solver, "_readback", fifo_solver._on_host)
     with pytest.raises(chip_smoke.SmokeFailure, match="device.readback"):
         chip_smoke.run_phase("tpu-batch", "tightly-pack", 48, 6, seed=7, expect_lane="xla")
+
+
+@pytest.mark.parametrize("lane", ["xla", "pallas"])
+def test_chip_smoke_reports_the_single_az_valve(chip_smoke, monkeypatch, lane):
+    """The single-AZ drive on a lane with the valve (the jnp twin, and
+    the kernel in interpret mode): launches and zoneResolved are read
+    from the fifo_gate span, and a drive that was to meet uncertified
+    apps and met none fails."""
+    start_stack = chip_smoke.start_stack
+
+    def on_a_device_lane(policy, name, **kwargs):
+        stack = start_stack(policy, name, **kwargs)
+        if name == "device":
+            stack.scheduler.extender.delta_engine = None
+            stack.solver.backend = lane
+            stack.solver.interpret = True
+        return stack
+
+    monkeypatch.setattr(chip_smoke, "start_stack", on_a_device_lane)
+    args = ("tpu-batch-single-az", "single-az-tightly-pack", 48, 6)
+    report = chip_smoke.run_phase(*args, seed=7, expect_lane=lane)
+    drivers = chip_smoke.NEW_DRIVERS + 1
+    assert report.granted_drivers >= 1 and report.launches >= drivers
+    if report.zone_resolved == 0:
+        with pytest.raises(chip_smoke.SmokeFailure, match="take another --seed"):
+            chip_smoke.run_phase(*args, seed=7, expect_lane=lane, expect_resolved=True)
+    else:
+        chip_smoke.run_phase(*args, seed=7, expect_lane=lane, expect_resolved=True)
 
 
 def test_chip_smoke_catches_a_counted_fallback(chip_smoke):

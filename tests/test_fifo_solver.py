@@ -290,9 +290,11 @@ def _byte_app(k=1, mem="100000"):
 
 
 def test_single_az_fused_symmetric_tie_keeps_first_zone():
-    """Mathematically equal zone scores (identical zones) stay on the
-    fused lane and pick the earlier zone, exactly like the float64
-    oracle's strict-improvement rule (single_az.go:88-94)."""
+    """Mathematically equal zone scores (identical zones) keep the
+    earlier zone, exactly like the float64 oracle's strict-improvement
+    rule (single_az.go:88-94) — decided by that rule itself: equal
+    fixed-point scores certify nothing, so the app is resolved on the
+    host and the device pass goes on from it."""
     from k8s_spark_scheduler_tpu.ops.fifo_solver import TpuSingleAzFifoSolver
 
     metadata = _two_zone_cluster(600000, 600000)
@@ -302,6 +304,7 @@ def test_single_az_fused_symmetric_tie_keeps_first_zone():
     solver = TpuSingleAzFifoSolver(az_aware=False, backend="xla")
     outcome = solver.solve(metadata, order, order, earlier, [False], current)
     assert solver.last_path == "fused"
+    assert solver.last_zone_choices == {"certified": 0, "resolved": 1}
     expected_ok, expected = host_single_az_fifo_oracle(
         metadata, order, order, earlier, [False], current, az_aware=False
     )
@@ -310,10 +313,11 @@ def test_single_az_fused_symmetric_tie_keeps_first_zone():
     assert outcome.result.executor_nodes == expected.executor_nodes
 
 
-def test_single_az_fused_near_tie_falls_back_to_host():
+def test_single_az_fused_near_tie_is_resolved_for_that_app_alone():
     """Zone scores that are distinct but inside the fixed-point margin
-    must flag `uncertain`, re-solve on the exact host lane, and still
-    match the oracle decision-for-decision."""
+    must flag `uncertain`: that app is decided exactly on the host, the
+    device pass stays the lane that served, and the answer still matches
+    the oracle decision-for-decision."""
     from k8s_spark_scheduler_tpu.ops.fifo_solver import TpuSingleAzFifoSolver
 
     # efficiencies 0.6 vs 0.599995 — a 5e-6 gap, ~1.3 fixed-point ulps at
@@ -324,7 +328,8 @@ def test_single_az_fused_near_tie_falls_back_to_host():
     current = _byte_app()
     solver = TpuSingleAzFifoSolver(az_aware=False, backend="xla")
     outcome = solver.solve(metadata, order, order, earlier, [False], current)
-    assert solver.last_path == "host"
+    assert solver.last_path == "fused" and solver.last_queue_lane == "xla"
+    assert solver.last_zone_choices == {"certified": 0, "resolved": 1}
     expected_ok, expected = host_single_az_fifo_oracle(
         metadata, order, order, earlier, [False], current, az_aware=False
     )

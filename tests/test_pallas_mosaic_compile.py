@@ -52,6 +52,21 @@ def _single_az_args(n, a):
     )
 
 
+def _single_az_packed_args(n, a):
+    """(avail, node_cols, app_cols, scalars) as the served single-AZ
+    path uploads them (TpuSingleAzFifoSolver._launcher)."""
+    return (
+        _sds((n, 3), jnp.int32), _sds((n, 7), jnp.int32), _sds((a, 9), jnp.int32),
+        _sds((3,), jnp.int32),
+    )
+
+
+def _served_slots(n):
+    from k8s_spark_scheduler_tpu.ops.batch_solver import snapshot_slots
+
+    return snapshot_slots(n)
+
+
 # (registry policy, jitted entry point, arg builder, static kwargs)
 VARIANTS = [
     ("tpu-batch", pq.pallas_solve_queue, _queue_args, dict(evenly=False)),
@@ -66,6 +81,14 @@ VARIANTS = [
     ("tpu-batch-single-az-minimal-fragmentation (strict off)",
      pq.pallas_solve_queue_single_az, _single_az_args,
      dict(n_zones=3, minfrag=True, strict=False)),
+    # the served launches: packed inputs, the snapshot slots of the shape
+    ("tpu-batch-single-az (served)", pq.pallas_solve_queue_single_az_packed,
+     _single_az_packed_args, dict(n_zones=3, az_aware=False, n_slots=_served_slots)),
+    ("tpu-batch-az-aware (served)", pq.pallas_solve_queue_single_az_packed,
+     _single_az_packed_args, dict(n_zones=3, az_aware=True, n_slots=_served_slots)),
+    ("tpu-batch-single-az-minimal-fragmentation (served)",
+     pq.pallas_solve_queue_single_az_packed, _single_az_packed_args,
+     dict(n_zones=3, minfrag=True, strict=True, n_slots=_served_slots)),
 ]
 
 
@@ -74,6 +97,7 @@ VARIANTS = [
 def test_kernel_compiles_for_v5e(v5e_sharding, policy, fn, args_of, static, n, a):
     # the module-level entry points are already jitted for the default
     # backend; re-jit the underlying function for the topology's device
+    static = {k: v(n) if callable(v) else v for k, v in static.items()}
     target = jax.jit(
         functools.partial(fn.__wrapped__, **static),
         in_shardings=v5e_sharding,

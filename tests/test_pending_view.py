@@ -458,12 +458,16 @@ def drivers_mix(h, count, wave="w"):
     "binpack_algo, path",
     [
         ("tpu-batch", "fast"),  # _try_fast_driver_path: the span the benchmark reads
-        ("tpu-batch-single-az", "device-fifo"),  # _try_device_fifo over Quantity metadata
+        ("tpu-batch-single-az", "fast"),  # the single-AZ solver takes tensors too (PR 29)
+        # _try_device_fifo over Quantity metadata: where the tensor mirror cannot serve
+        ("tpu-batch-single-az", "device-fifo"),
         ("tightly-pack", "host"),  # no queue solver: the host loop lists the view's pods
     ],
 )
 def test_every_driver_filter_of_the_drivers_mix_reads_the_view(binpack_algo, path):
     h = serving(binpack_algo=binpack_algo)
+    if path == "device-fifo":
+        h.extender._fast_path_ok = False
     try:
         first = 0 if path == "host" else 1
         drivers_mix(h, 1, "first")  # the process's first: builds the view from the store

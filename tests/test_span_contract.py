@@ -65,6 +65,52 @@ EXPECTED = {
 }
 
 
+# the same request under ``tpu-batch-single-az``: the queue pass's inputs
+# go up in four arrays, the request's own app rides the pass as a probe
+# (no second kernel), ``fifo_gate.zone_resolve`` is the valve's host time
+# (an aggregate child, one phase per launch) and ``fast_path.zone_choice``
+# the current driver's exact choice
+SINGLE_AZ_TAIL = [
+    ("binpack", [("fast_path.zone_choice", [])]),
+    ("fast_path.decode", []),
+    ("fast_path.efficiency", []),
+    FINISH,
+    ("provenance.finish", []),
+]
+EXPECTED_SINGLE_AZ = {
+    "native": [
+        *((name, []) for name in HOST_LEAVES),
+        ("fifo_gate", [("kernel:fifo_queue_single_az", [])]),
+        *SINGLE_AZ_TAIL,
+    ],
+    "xla": [
+        *((name, []) for name in HOST_LEAVES),
+        ("fifo_gate", [
+            ("device.upload", []),  # what stays on the device between launches
+            ("device.upload", []),  # the cluster's availability
+            ("device.upload", []),  # forced zones and the start, per launch
+            ("kernel:fifo_queue_single_az", KERNEL_PHASES),
+            *[("device.readback", [])] * 5,  # the five verdict columns
+            ("device.readback", []),  # the snapshots
+            ("fifo_gate.zone_resolve", []),
+        ]),
+        *SINGLE_AZ_TAIL,
+    ],
+    "pallas": [
+        *((name, []) for name in HOST_LEAVES),
+        ("fifo_gate", [
+            ("device.upload", []),  # availability and the per-node columns
+            ("device.upload", []),  # the per-app columns and the scalars, per launch
+            ("kernel:fifo_queue_single_az", KERNEL_PHASES),
+            ("device.readback", []),  # the verdict columns
+            ("device.readback", []),  # the snapshots
+            ("fifo_gate.zone_resolve", []),
+        ]),
+        *SINGLE_AZ_TAIL,
+    ],
+}
+
+
 def shape(span):
     return [(c.name, shape(c)) for c in span.children]
 
@@ -86,15 +132,16 @@ def find(span, name):
     return None
 
 
-def served_harness(lane):
+def served_harness(lane, binpack_algo="tpu-batch"):
     """The full wiring at a small size with a short pending queue, the
     warm delta-solve lane off so that ``solve_tensor`` serves, on the
     queue lane asked for."""
-    h = Harness(binpack_algo="tpu-batch")
-    for name in NODES:
-        h.new_node(name)
+    h = Harness(binpack_algo=binpack_algo)
+    for i, name in enumerate(NODES):
+        h.new_node(name, zone=f"zone{1 + i % 2}" if "single-az" in binpack_algo else "zone1")
     h.extender.delta_engine = None
     h.extender.binpacker.queue_solver.backend = lane
+    h.extender.binpacker.queue_solver.interpret = True  # the pallas lane on the CPU
     for i in range(3):
         queued = h.static_allocation_spark_pods(f"app-queued-{i}", 1)[0]
         queued.meta.creation_timestamp = time.time() - 100 + i
@@ -137,11 +184,41 @@ def test_granted_driver_filter_has_exactly_the_documented_children(lane):
         h.close()
 
 
-def test_spans_a_self_metric_reads_keep_no_child():
+@pytest.mark.parametrize("lane", ["native", "xla", "pallas"])
+def test_single_az_driver_filter_has_exactly_the_documented_children(lane):
+    h = served_harness(lane, "tpu-batch-single-az")
+    try:
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
+        roots = roots_of(h)
+        driver = h.static_allocation_spark_pods("app-new", 2)[0]
+        h.assert_success(h.schedule(driver, NODES))
+        (root,) = [r for r in roots if r.name == "predicate"]
+        assert shape(root) == EXPECTED_SINGLE_AZ[lane]
+        gate = find(root, "fifo_gate")
+        assert gate.tags["lane"] == lane and gate.tags["earlierApps"] == 3
+        assert gate.tags["earlierOk"] is True
+        if lane == "native":
+            assert "launches" not in gate.tags
+        else:
+            assert gate.tags["launches"] == 1 and gate.tags["zoneResolved"] >= 0
+            resolve = find(gate, "fifo_gate.zone_resolve")
+            assert type(resolve) is tracing.AggregateSpan and resolve.tags["count"] == 1
+            assert h.server.metrics.get_counter(
+                mnames.FIFO_ZONE_CHOICE, {"result": "certified"}
+            ) + h.server.metrics.get_counter(
+                mnames.FIFO_ZONE_CHOICE, {"result": "resolved"}
+            ) == 6  # every queued app of both requests, by who chose its zone
+        assert find(root, "binpack").tags["lane"] == "host"
+    finally:
+        h.close()
+
+
+@pytest.mark.parametrize("binpack_algo", ["tpu-batch", "tpu-batch-single-az"])
+def test_spans_a_self_metric_reads_keep_no_child(binpack_algo):
     """``serde_ms``, ``executor_serde_ms`` and ``write_back_ms`` read the
     self time of these spans: a child under one would move an accepted
     metric."""
-    h = served_harness("xla")
+    h = served_harness("xla", binpack_algo)
     http = ExtenderHTTPServer(h.server, port=0)
     http.start()
     try:
