@@ -276,10 +276,11 @@ def tpu_solver_lane() -> dict:
 
     def one_solve(avail, rest):
         # the production Filter cost: the queue pass PLUS the current
-        # driver's placement decode (TpuFifoSolver runs solve_single
-        # on the post-queue availability to produce the executor
-        # list) — fold the decode outputs into the carry so the
-        # decode is actually materialized every solve
+        # driver's placement decode (TpuFifoSolver's program runs
+        # solve_app on the post-queue availability to produce the
+        # executor list: batch_solver.solve_filter) — fold the decode
+        # outputs into the carry so the decode is actually
+        # materialized every solve
         rank, exec_ok, drivers, executors, counts, valid = rest
         feas, didx, avail_after = pallas_solve_queue(avail, *rest)
         # the current driver's decode (excluded from the queue above,

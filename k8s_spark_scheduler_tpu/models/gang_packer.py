@@ -79,9 +79,9 @@ class GangPacker:
             def pallas_wrapped(*args):
                 # decision-latency contract: per-app (feasible, driver)
                 # plus the final availability.  Any single app's executor
-                # placements are recovered with one O(N) solve_single on
-                # the carried availability — exactly how TpuFifoSolver
-                # decodes the current driver in production, and what the
+                # placements are recovered with one O(N) solve_app on
+                # the carried availability — exactly how TpuFifoSolver's
+                # program decodes the current driver, and what the
                 # bench measures as part of the headline op.  exec_counts
                 # is therefore intentionally empty here (an [A, N]
                 # placement matrix would be dead output for the FIFO

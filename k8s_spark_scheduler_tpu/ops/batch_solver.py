@@ -425,6 +425,60 @@ def solve_single(
     return solve_app(avail, driver_rank, exec_ok, driver, executor, k)
 
 
+# ``valid`` column of ``solve_filter``'s app block: a queue app, the
+# request's own app (as in the single-AZ passes, where 2 marks the probe)
+APP_QUEUED = 1
+APP_CURRENT = 2
+
+
+@functools.partial(jax.jit, static_argnames=("policy", "pallas", "interpret"))
+def solve_filter(
+    node_cols: jnp.ndarray,  # [N, 5] int32: availability (3), driver rank, executor ok
+    app_cols: jnp.ndarray,   # [A, 8] int32: driver (3), executor (3), count, valid
+    policy: str,
+    pallas: bool,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """A driver Filter's whole device work as one program: the queue
+    pass over the apps marked APP_QUEUED (the Pallas kernel of the
+    policy, or its XLA scan), then ``solve_app`` for the row marked
+    APP_CURRENT on the availability the pass leaves; the same calls on
+    the same values as the pass followed by ``solve_single``.  The inputs
+    are two arrays and the result is one (an upload and a read-back cost
+    the host the same whatever their size), int32 [4N + A + 2]:
+    avail_after [N, 3] row-major, then per node the current app's
+    executor counts (its capacities under distribute-evenly), the
+    queue's verdicts [A], the current app's feasible and driver node."""
+    avail, driver_rank, exec_ok = node_cols[:, 0:3], node_cols[:, 3], node_cols[:, 4] != 0
+    drivers, executors, counts = app_cols[:, 0:3], app_cols[:, 3:6], app_cols[:, 6]
+    queued = app_cols[:, 7] == APP_QUEUED
+    queue_args = (avail, driver_rank, exec_ok, drivers, executors, counts, queued)
+    evenly = policy == "distribute-evenly"
+    if policy == "minimal-fragmentation":
+        if pallas:
+            from .pallas_queue import pallas_solve_queue_min_frag
+
+            verdicts, _, avail_after = pallas_solve_queue_min_frag(*queue_args, interpret=interpret)
+        else:
+            out = solve_queue_min_frag(*queue_args, with_placements=False)
+            verdicts, avail_after = out.feasible, out.avail_after
+    elif pallas:
+        from .pallas_queue import pallas_solve_queue
+
+        verdicts, _, avail_after = pallas_solve_queue(*queue_args, evenly=evenly, interpret=interpret)
+    else:
+        out = solve_queue(*queue_args, evenly=evenly, with_placements=False)
+        verdicts, avail_after = out.feasible, out.avail_after
+    current = app_cols[jnp.argmax(app_cols[:, 7] == APP_CURRENT)]
+    solve = solve_app(avail_after, driver_rank, exec_ok, current[0:3], current[3:6], current[6])
+    return jnp.concatenate([
+        avail_after.reshape(-1),
+        solve.exec_capacity if evenly else solve.exec_counts,
+        verdicts.astype(jnp.int32),
+        jnp.stack([solve.feasible.astype(jnp.int32), solve.driver_idx]),
+    ])
+
+
 class ZoneQueueSolve(NamedTuple):
     """Per-app outcome of the fused single-AZ FIFO scan."""
 
@@ -762,6 +816,7 @@ def compilation_cache_stats() -> dict:
         ("solve_queue", solve_queue),
         ("solve_queue_min_frag", solve_queue_min_frag),
         ("solve_single", solve_single),
+        ("solve_filter", solve_filter),
         ("solve_queue_single_az", solve_queue_single_az),
         ("solve_zones", solve_zones_jit),
     ):

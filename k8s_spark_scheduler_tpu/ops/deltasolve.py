@@ -476,8 +476,7 @@ class DeltaSolveEngine:
             ok=True,
         )
         outcome = solver._pack_current(
-            sess.cluster, problem, avail_after, n_earlier, current_app,
-            metadata=None, use_native=True,
+            sess.cluster, problem, avail_after, n_earlier, current_app
         )
         return outcome, sess.zones
 

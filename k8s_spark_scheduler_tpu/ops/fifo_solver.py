@@ -55,17 +55,11 @@ def _upload(*arrays):
         return tuple(jnp.asarray(a) for a in arrays)
 
 
-def _readback(value, to=np.asarray):
-    """A device value brought to the host under a ``device.readback``
-    span (``to`` converts: ``np.asarray``, ``bool``, ``int``)."""
-    with tracing.child_span("device.readback"):
-        return to(value)
-
-
-def _on_host(value, to=np.asarray):
-    """``_readback`` for the native lanes, whose values are host arrays
-    already: no transfer, no span."""
-    return to(value)
+def _readback(value):
+    """A device array brought to the host under a ``device.readback``
+    span, tagged as ``device.upload`` is."""
+    with tracing.child_span("device.readback", {"arrays": 1, "bytes": value.nbytes}):
+        return np.asarray(value)
 
 
 def _pallas_selected(backend: str) -> bool:
@@ -285,6 +279,34 @@ def _tensorize_with_cache(solver, earlier, current_app):
     )
 
 
+def _filter_blocks(problem, n_earlier: int):
+    """batch_solver.solve_filter's two inputs from a scaled problem whose
+    row ``n_earlier`` is the request's own app: the node-side block
+    [N, 5] and the app-side block [A, 8], int32."""
+    from .batch_solver import APP_CURRENT, APP_QUEUED
+
+    node_cols = np.empty((problem.avail.shape[0], 5), np.int32)
+    node_cols[:, 0:3] = problem.avail
+    node_cols[:, 3] = problem.driver_rank
+    node_cols[:, 4] = problem.exec_ok
+    app_cols = np.zeros((problem.count.shape[0], 8), np.int32)
+    app_cols[:, 0:3] = problem.driver
+    app_cols[:, 3:6] = problem.executor
+    app_cols[:, 6] = problem.count
+    app_cols[:n_earlier, 7] = np.where(problem.app_valid[:n_earlier], APP_QUEUED, 0)
+    app_cols[n_earlier, 7] = APP_CURRENT
+    return node_cols, app_cols
+
+
+def _earlier_ok(gate_span, feasible, earlier_skip_allowed) -> bool:
+    """An enforced (old-enough) earlier driver that doesn't fit fails the
+    whole request (resource.go:244-253)."""
+    blocked = ~np.asarray(feasible, bool) & ~np.asarray(earlier_skip_allowed, bool)
+    ok = not blocked.any()
+    gate_span.tag("earlierOk", ok)
+    return ok
+
+
 @dataclass
 class FifoOutcome:
     """Result of the combined earlier-drivers + current-driver solve."""
@@ -295,7 +317,8 @@ class FifoOutcome:
 
 
 class TpuFifoSolver:
-    """One device round for the whole FIFO queue + the current driver.
+    """One device round for the whole FIFO queue + the current driver:
+    two uploads, one program (batch_solver.solve_filter), one read-back.
 
     backend: "auto" (pallas kernel on TPU, native C++ solver on CPU
     hosts, XLA scan otherwise), "xla", "pallas", or "native".  The
@@ -314,16 +337,21 @@ class TpuFifoSolver:
         assignment_policy: str = "tightly-pack",
         backend: str = "auto",
         strict_reference_parity: bool = compat.DEFAULT_STRICT,
+        interpret: bool = False,
     ):
         self.assignment_policy = assignment_policy
         self.backend = backend
+        # interpret=True runs the pallas kernels in interpreter mode so the
+        # solver's pallas lane is testable on CPU
+        self.interpret = interpret
         # min-frag only: whether the reference's no-efficiency-write-back
         # quirk applies to the current driver's reported efficiencies
         self.strict_reference_parity = strict_reference_parity
         # which lane served the last queue pass — one of "native",
         # "native-minfrag", "pallas", "pallas-minfrag", "xla",
-        # "minfrag-xla"; None = no queue pass ran — observable for tests
-        # and the tpu.fastpath lane counters
+        # "minfrag-xla"; None = no queue pass ran (a device lane runs its
+        # program on an empty queue too) — observable for tests and the
+        # tpu.fastpath lane counters
         self.last_queue_lane: Optional[str] = None
         # (ids, strong refs, AppTensor) of the last earlier-apps list:
         # consecutive Filters tensorize the same pending queue, and the
@@ -408,12 +436,9 @@ class TpuFifoSolver:
         """Solve from a prebuilt ClusterTensor (the tensor-snapshot fast
         path passes one directly; `metadata` is only used for the
         Quantity-based efficiency computation when provided)."""
-        from .batch_solver import solve_queue, solve_queue_min_frag
-
         with tracing.child_span("fast_path.tensorize_apps"):
             apps = self._tensorize_with_cache(list(earlier_apps), current_app)
         self.last_queue_lane = None
-        evenly = self.assignment_policy == "distribute-evenly"
         minfrag = self.assignment_policy == "minimal-fragmentation"
         with tracing.child_span("fast_path.scale_problem"):
             problem = scale_problem(cluster, apps)
@@ -426,131 +451,109 @@ class TpuFifoSolver:
                     # a real capacity could collide with the device kernel's
                     # unbounded-capacity sentinel (batch_solver.MF_SENT)
                     return FifoOutcome(supported=False)
-        n_earlier = len(earlier_apps)
         # the native C++ lane serves every policy; decisions are
         # differential-tested bit-identical to the device scans
-        use_native = self._use_native()
+        solve = self._solve_native if self._use_native() else self._solve_on_device
+        return solve(cluster, problem, earlier_skip_allowed, current_app, metadata)
 
-        didx_all = None  # native lanes keep per-position driver indices
-        if n_earlier > 0:
-            # whole-queue pass over the earlier drivers only.  The
-            # fifo_gate span is the request's "earlier drivers fit?"
-            # phase; the kernel profiles inside it split the dispatch
-            # into jit-compile vs execute time (tracing/profiling.py).
-            with tracing.child_span(
-                "fifo_gate", {"earlierApps": n_earlier}
-            ) as gate_span:
+    def _solve_native(self, cluster, problem, earlier_skip_allowed, current_app, metadata):
+        """The queue pass, then the current driver's pack, in the C++
+        library on the host."""
+        n_earlier = len(earlier_skip_allowed)
+        minfrag = self.assignment_policy == "minimal-fragmentation"
+        # the fifo_gate span is the request's "earlier drivers fit?" phase
+        with tracing.child_span("fifo_gate", {"earlierApps": n_earlier}) as gate_span:
+            feasible, didx_all, avail_after = np.zeros(0, dtype=bool), None, problem.avail
+            if n_earlier > 0:
                 queue_valid = problem.app_valid.copy()
                 queue_valid[n_earlier:] = False
-                if use_native and minfrag:
-                    from ..native.fifo import solve_queue_min_frag_native
+                queue_args = (
+                    problem.avail, problem.driver_rank, problem.exec_ok,
+                    problem.driver, problem.executor, problem.count, queue_valid,
+                )
+                self.last_queue_lane = "native-minfrag" if minfrag else "native"
+                with default_profiler.profile(
+                    "fifo_queue", lane=self.last_queue_lane, jit=False
+                ):
+                    if minfrag:
+                        from ..native.fifo import solve_queue_min_frag_native
 
-                    self.last_queue_lane = "native-minfrag"
-                    with default_profiler.profile(
-                        "fifo_queue", lane="native-minfrag", jit=False
-                    ):
                         feasible_all, didx_all, avail_after = solve_queue_min_frag_native(
-                            problem.avail, problem.driver_rank, problem.exec_ok,
-                            problem.driver, problem.executor, problem.count,
-                            queue_valid,
+                            *queue_args
                         )
-                    feasible = feasible_all[:n_earlier]
-                elif use_native:
-                    from ..native.fifo import solve_queue_native
-
-                    self.last_queue_lane = "native"
-                    with default_profiler.profile(
-                        "fifo_queue", lane="native", jit=False
-                    ):
-                        feasible_all, didx_all, avail_after = solve_queue_native(
-                            problem.avail, problem.driver_rank, problem.exec_ok,
-                            problem.driver, problem.executor, problem.count,
-                            queue_valid, evenly=evenly,
-                        )
-                    feasible = feasible_all[:n_earlier]
-                else:
-                    queue_args = _upload(
-                        problem.avail,
-                        problem.driver_rank,
-                        problem.exec_ok,
-                        problem.driver,
-                        problem.executor,
-                        problem.count,
-                        queue_valid,
-                    )
-                    if minfrag and self._use_pallas():
-                        from .pallas_queue import pallas_solve_queue_min_frag
-
-                        self.last_queue_lane = "pallas-minfrag"
-                        with default_profiler.profile(
-                            "fifo_queue", lane="pallas-minfrag",
-                            fn=pallas_solve_queue_min_frag,
-                        ) as rec:
-                            feasible_dev, _, avail_after = pallas_solve_queue_min_frag(
-                                *queue_args
-                            )
-                            rec.sync(avail_after)
-                        feasible = _readback(feasible_dev)[:n_earlier]
-                    elif minfrag:
-                        self.last_queue_lane = "minfrag-xla"
-                        with default_profiler.profile(
-                            "fifo_queue", lane="minfrag-xla",
-                            fn=solve_queue_min_frag,
-                        ) as rec:
-                            out = solve_queue_min_frag(*queue_args, with_placements=False)
-                            rec.sync(out.avail_after)
-                        feasible = _readback(out.feasible)[:n_earlier]
-                        avail_after = out.avail_after
-                    elif self._use_pallas():
-                        from .pallas_queue import pallas_solve_queue
-
-                        self.last_queue_lane = "pallas"
-                        with default_profiler.profile(
-                            "fifo_queue", lane="pallas", fn=pallas_solve_queue
-                        ) as rec:
-                            feasible_dev, _, avail_after = pallas_solve_queue(
-                                *queue_args, evenly=evenly
-                            )
-                            rec.sync(avail_after)
-                        feasible = _readback(feasible_dev)[:n_earlier]
                     else:
-                        self.last_queue_lane = "xla"
-                        with default_profiler.profile(
-                            "fifo_queue", lane="xla", fn=solve_queue
-                        ) as rec:
-                            out = solve_queue(*queue_args, evenly=evenly, with_placements=False)
-                            rec.sync(out.avail_after)
-                        feasible = _readback(out.feasible)[:n_earlier]
-                        avail_after = out.avail_after
+                        from ..native.fifo import solve_queue_native
+
+                        feasible_all, didx_all, avail_after = solve_queue_native(
+                            *queue_args,
+                            evenly=self.assignment_policy == "distribute-evenly",
+                        )
+                feasible = feasible_all[:n_earlier]
                 gate_span.tag("lane", self.last_queue_lane)
-                # capture BEFORE the blocked-earlier verdict below: a
-                # FAILURE_EARLIER_DRIVER refusal is exactly the decision
-                # the provenance explainer must be able to decompose
-                if self.capture_sink is not None:
-                    self._capture_solve(
-                        cluster, problem, earlier_skip_allowed, n_earlier,
-                        feasible, didx_all, avail_after,
-                    )
-                # an enforced (old-enough) earlier driver that doesn't fit
-                # fails the whole request (resource.go:244-253)
-                for i in range(n_earlier):
-                    if not feasible[i] and not earlier_skip_allowed[i]:
-                        gate_span.tag("earlierOk", False)
-                        return FifoOutcome(supported=True, earlier_ok=False)
-                gate_span.tag("earlierOk", True)
-        else:
-            with tracing.child_span("fifo_gate", {"earlierApps": 0, "earlierOk": True}):
-                avail_after = problem.avail if use_native else _upload(problem.avail)[0]
-            feasible = np.zeros(0, dtype=bool)
+            # capture BEFORE the blocked-earlier verdict below: a
+            # FAILURE_EARLIER_DRIVER refusal is exactly the decision
+            # the provenance explainer must be able to decompose
             if self.capture_sink is not None:
                 self._capture_solve(
                     cluster, problem, earlier_skip_allowed, n_earlier,
                     feasible, didx_all, avail_after,
                 )
-
+            if not _earlier_ok(gate_span, feasible, earlier_skip_allowed):
+                return FifoOutcome(supported=True, earlier_ok=False)
         return self._pack_current(
-            cluster, problem, avail_after, n_earlier, current_app,
-            metadata=metadata, use_native=use_native,
+            cluster, problem, avail_after, n_earlier, current_app, metadata=metadata
+        )
+
+    def _solve_on_device(self, cluster, problem, earlier_skip_allowed, current_app, metadata):
+        """One device round: the queue pass and the current driver's
+        solve as one program (batch_solver.solve_filter), two uploads and
+        one read-back.  With no earlier driver the same program runs on
+        an empty queue."""
+        from .batch_solver import solve_filter
+
+        n_earlier = len(earlier_skip_allowed)
+        minfrag = self.assignment_policy == "minimal-fragmentation"
+        pallas = self._use_pallas()
+        if minfrag:
+            lane = "pallas-minfrag" if pallas else "minfrag-xla"
+        else:
+            lane = "pallas" if pallas else "xla"
+        self.last_queue_lane = lane
+        nb = problem.avail.shape[0]
+        with tracing.child_span(
+            "fifo_gate", {"earlierApps": n_earlier, "lane": lane}
+        ) as gate_span:
+            nodes_dev, apps_dev = _upload(*_filter_blocks(problem, n_earlier))
+            with default_profiler.profile("fifo_queue", lane=lane, fn=solve_filter) as rec:
+                out_dev = solve_filter(
+                    nodes_dev, apps_dev, policy=self.assignment_policy,
+                    pallas=pallas, interpret=self.interpret,
+                )
+                rec.sync(out_dev)
+            out = _readback(out_dev)
+            avail_after = out[: 3 * nb].reshape(nb, 3)
+            per_node = out[3 * nb : 4 * nb]
+            feasible = out[4 * nb : 4 * nb + n_earlier] != 0
+            # capture BEFORE the blocked-earlier verdict, as the native lane does
+            if self.capture_sink is not None:
+                self._capture_solve(
+                    cluster, problem, earlier_skip_allowed, n_earlier,
+                    feasible, None, avail_after,
+                )
+            if not _earlier_ok(gate_span, feasible, earlier_skip_allowed):
+                return FifoOutcome(supported=True, earlier_ok=False)
+        # the current driver was solved in the program above: what is
+        # left of its step on the host is reading the verdict
+        with tracing.child_span(
+            "binpack", {"policy": self.assignment_policy, "lane": "xla"}
+        ) as binpack_span:
+            current_fits, driver_idx = bool(out[-2]), int(out[-1])
+            binpack_span.tag("feasible", current_fits)
+        if not current_fits:
+            return FifoOutcome(supported=True, earlier_ok=True, result=empty_packing_result())
+        return self._decode_current(
+            cluster, problem, avail_after, n_earlier, current_app, metadata,
+            driver_idx, per_node,
         )
 
     def _capture_solve(
@@ -558,9 +561,8 @@ class TpuFifoSolver:
         feasible, didx_all, avail_after,
     ) -> None:
         """Hand the queue solve's inputs + verdicts to the provenance
-        sink (provenance/tracker.py).  Array references, no copies but
-        the post-queue availability read back from a device lane; only
-        runs when wiring installed a sink."""
+        sink (provenance/tracker.py).  Array references, no copies;
+        only runs when wiring installed a sink."""
         try:
             from .batch_solver import queue_policy_code
             from ..provenance.tracker import SolveArtifacts
@@ -569,11 +571,6 @@ class TpuFifoSolver:
             if policy_code is None:
                 return
             with tracing.child_span("provenance.capture"):
-                avail_host = (
-                    avail_after
-                    if isinstance(avail_after, np.ndarray)
-                    else _readback(avail_after)
-                )
                 na = n_earlier + 1
                 packed = np.empty((na, 8), dtype=np.int32)
                 packed[:, 0:3] = problem.driver[:na]
@@ -595,7 +592,7 @@ class TpuFifoSolver:
                         else None
                     ),
                     resume=0,
-                    avail_after=np.asarray(avail_host, dtype=np.int32),
+                    avail_after=np.asarray(avail_after, dtype=np.int32),
                     scale=problem.scale,
                     node_names=cluster.node_names,
                     zone_names=cluster.zone_names,
@@ -613,72 +610,62 @@ class TpuFifoSolver:
         n_earlier: int,
         current_app: AppDemand,
         metadata: Optional[NodeGroupSchedulingMetadata] = None,
-        use_native: bool = False,
     ) -> FifoOutcome:
         """The current driver's gang pack against the post-queue
-        availability carry: solve + placement decode + efficiency rows.
-        Shared tail of solve_tensor and the delta-solve engine
-        (ops/deltasolve.py), which substitutes its session's warm carry
-        for the cold queue pass and hands the identical arguments here."""
-        from .batch_solver import solve_single
+        availability carry on the native lane: solve, then the decode
+        the device lanes share.  The tail of ``_solve_native`` and of the
+        delta-solve engine (ops/deltasolve.py), which substitutes its
+        session's warm carry for the cold queue pass and hands the
+        identical arguments here."""
+        from ..native.fifo import solve_app_native
 
-        evenly = self.assignment_policy == "distribute-evenly"
-        minfrag = self.assignment_policy == "minimal-fragmentation"
-        to_host = _on_host if use_native else _readback
+        avail_after = np.asarray(avail_after)
         with tracing.child_span(
-            "binpack", {"policy": self.assignment_policy}
+            "binpack", {"policy": self.assignment_policy, "lane": "native"}
         ) as binpack_span:
-            if use_native:
-                from ..native.fifo import solve_app_native
-
-                binpack_span.tag("lane", "native")
-                with default_profiler.profile(
-                    "solve_app", lane="native", jit=False
-                ):
-                    nat_feas, nat_didx, nat_counts, nat_caps = solve_app_native(
-                        np.asarray(avail_after), problem.driver_rank, problem.exec_ok,
-                        problem.driver[n_earlier], problem.executor[n_earlier],
-                        int(problem.count[n_earlier]),
-                    )
-                from .batch_solver import AppSolve
-
-                solve = AppSolve(
-                    feasible=np.bool_(nat_feas),
-                    driver_idx=np.int32(nat_didx),
-                    exec_counts=nat_counts,
-                    exec_capacity=nat_caps,
+            with default_profiler.profile("solve_app", lane="native", jit=False):
+                feasible, driver_idx, counts, caps = solve_app_native(
+                    avail_after, problem.driver_rank, problem.exec_ok,
+                    problem.driver[n_earlier], problem.executor[n_earlier],
+                    int(problem.count[n_earlier]),
                 )
-            else:
-                binpack_span.tag("lane", "xla")
-                single_args = _upload(
-                    problem.driver_rank,
-                    problem.exec_ok,
-                    problem.driver[n_earlier],
-                    problem.executor[n_earlier],
-                    problem.count[n_earlier],
-                )
-                with default_profiler.profile(
-                    "solve_single", lane="xla", fn=solve_single
-                ) as rec:
-                    solve = solve_single(avail_after, *single_args)
-                    rec.sync(solve.exec_counts)
-            feasible = to_host(solve.feasible, bool)
-            binpack_span.tag("feasible", feasible)
+            binpack_span.tag("feasible", bool(feasible))
         if not feasible:
             return FifoOutcome(supported=True, earlier_ok=True, result=empty_packing_result())
+        evenly = self.assignment_policy == "distribute-evenly"
+        return self._decode_current(
+            cluster, problem, avail_after, n_earlier, current_app, metadata,
+            int(driver_idx), caps if evenly else counts,
+        )
 
+    def _decode_current(
+        self,
+        cluster,
+        problem,
+        avail_after: np.ndarray,
+        n_earlier: int,
+        current_app: AppDemand,
+        metadata: Optional[NodeGroupSchedulingMetadata],
+        driver_idx: int,
+        per_node: np.ndarray,
+    ) -> FifoOutcome:
+        """The feasible current driver's placements and efficiency rows,
+        on the host from host arrays: ``avail_after`` the post-queue
+        carry, ``per_node`` the solve's executor counts (its capacities
+        after the driver under distribute-evenly; min-frag reads
+        neither and assigns from the carry)."""
+        evenly = self.assignment_policy == "distribute-evenly"
+        minfrag = self.assignment_policy == "minimal-fragmentation"
         names = cluster.node_names
         k = current_app.min_executor_count
         with tracing.child_span("fast_path.decode"):
-            driver_idx = to_host(solve.driver_idx, int)
             driver_node = names[driver_idx]
             if evenly:
-                cap = to_host(solve.exec_capacity)[: len(names)]
-                counts = evenly_counts(cap, k)
+                counts = evenly_counts(per_node[: len(names)], k)
                 executor_nodes = counts_to_evenly_list(names, counts)
             elif minfrag:
                 cap = min_frag_unclamped_caps(
-                    to_host(avail_after)[: len(names)],
+                    avail_after[: len(names)],
                     problem.executor[n_earlier],
                     np.asarray(problem.exec_ok[: len(names)]),
                     driver_idx,
@@ -698,27 +685,24 @@ class TpuFifoSolver:
                     for node in executor_nodes:
                         counts[pos[node]] += 1
             else:
-                counts = to_host(solve.exec_counts)[: len(names)]
+                counts = per_node[: len(names)]
                 executor_nodes = counts_to_tightly_list(names, counts)
 
         # efficiencies feed metrics only on this path (non-single-AZ
         # policies); the host lane computes them against the metadata
         # MUTATED by the earlier-drivers pass (resource.go:255-259 then
         # binpack on the same map), so both branches use the post-queue
-        # availability carried out of the device scan.  Domain contract:
+        # availability carried out of the queue pass.  Domain contract:
         # the rows branch averages over cluster.node_names, which the
         # production caller (build_cluster_tensor) populates with EVERY
         # affinity-matching node — the same domain as the host lane's
         # metadata — not just schedulable candidates.
         def post_queue_avail_rows():
             if n_earlier == 0:
-                # no queue pass ran: skip the device→host sync + multiply
+                # the queue was empty: skip the multiply
                 return cluster.avail[: len(names)]
             scale = problem.scale.astype(np.int64)
-            return (
-                to_host(avail_after)[: len(names)].astype(np.int64)
-                * scale[None, :]
-            )
+            return avail_after[: len(names)].astype(np.int64) * scale[None, :]
 
         with tracing.child_span("fast_path.efficiency"):
             if metadata is not None:

@@ -2,7 +2,8 @@
 
 The warm-up drives a private instance of the configured policy's queue
 solver over synthetic problems of each wanted shape, through the same
-``solve`` entry the extender calls.  Whatever that policy dispatches on
+``solve_tensor`` entry the extender calls and the ``feasible_tensor``
+entry the unschedulable-pod marker calls.  Whatever that policy dispatches on
 this platform — Pallas queue kernel on a TPU, XLA zone solves on a CPU
 host, the native C++ lane — is therefore what gets compiled; no second
 list of "the kernels policy X uses" exists to drift from the solver.
@@ -19,7 +20,7 @@ from typing import Callable, Iterable, Tuple
 from ..types.resources import NodeSchedulingMetadata, Resources
 from .registry import select_binpacker
 from .sparkapp import AppDemand
-from .tensorize import APP_BUCKETS, NODE_BUCKETS, bucket_size
+from .tensorize import APP_BUCKETS, NODE_BUCKETS, bucket_size, tensorize_cluster
 
 WARM_ZONES = 3  # zone count is a compile shape; 3 AZs is typical
 
@@ -82,14 +83,21 @@ def warm_queue_solver(
             )
             for i in range(n_nodes)
         }
-        order = list(metadata)
+        cluster = tensorize_cluster(metadata, list(metadata), list(metadata))
         earlier = [AppDemand(one, one, 1) for _ in range(n_apps - 1)]
-        outcome = solver.solve(
-            metadata, order, order, earlier, [True] * len(earlier),
-            AppDemand(one, one, 1),
+        outcome = solver.solve_tensor(
+            cluster, earlier, [True] * len(earlier), AppDemand(one, one, 1)
         )
         if not outcome.supported or solver.last_queue_lane is None:
             raise RuntimeError(
                 f"solver warm-up for {binpack_algo} at {n_nodes}x{n_apps} did "
                 "not reach the queue solve (synthetic problem refused)"
+            )
+        # the unschedulable-pod marker's verdicts are a program of their
+        # own (solve_single) at the cluster's shape: its first scan
+        # begins a minute after start, among the requests
+        if solver.feasible_tensor(cluster, AppDemand(one, one, 1)) is None:
+            raise RuntimeError(
+                f"solver warm-up for {binpack_algo} at {n_nodes} nodes did not "
+                "reach the feasibility solve (synthetic problem refused)"
             )

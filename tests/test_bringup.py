@@ -13,6 +13,7 @@ import sys
 import textwrap
 import time
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -153,6 +154,37 @@ def test_warmup_compiles_through_the_configured_solver():
     assert warmup.warm_shapes(0, 0) == warmup._BASE_SHAPES
     assert warmup.warm_shapes(10_000, 1_000)[-1] == (10240, 1024)
     assert warmup.warm_shapes(60, 3) == warmup._BASE_SHAPES
+
+
+def test_after_warmup_a_filter_and_a_marker_verdict_compile_nothing(monkeypatch):
+    """The marker's first scan begins a minute after start, among the
+    requests: its ``feasible_tensor`` program (``solve_single``) has to
+    be warm like the Filter's own (``solve_filter``)."""
+    import random
+
+    from test_batch_parity import orders_for, random_app, random_cluster
+
+    from k8s_spark_scheduler_tpu.ops import fifo_solver, warmup
+    from k8s_spark_scheduler_tpu.ops.batch_solver import solve_filter, solve_single
+    from k8s_spark_scheduler_tpu.ops.registry import select_binpacker
+    from k8s_spark_scheduler_tpu.ops.tensorize import tensorize_cluster
+    from k8s_spark_scheduler_tpu.tracing.profiling import jit_cache_size
+
+    # the XLA lane, as on a host with neither a TPU nor the C++ library
+    monkeypatch.setattr(fifo_solver, "_native_selected", lambda backend: False)
+    warmup.warm_queue_solver("tpu-batch", True, [(256, 64)])
+    warm = jit_cache_size(solve_filter), jit_cache_size(solve_single)
+    assert min(warm) >= 1
+
+    rng = random.Random(30)
+    metadata = random_cluster(rng, 200)
+    cluster = tensorize_cluster(metadata, *orders_for(metadata, rng))
+    earlier = [random_app(rng) for _ in range(40)]
+    solver = select_binpacker("tpu-batch").queue_solver
+    assert solver.feasible_tensor(cluster, random_app(rng)) is not None
+    outcome = solver.solve_tensor(cluster, earlier, [True] * 40, random_app(rng))
+    assert outcome.supported and solver.last_queue_lane == "xla"
+    assert (jit_cache_size(solve_filter), jit_cache_size(solve_single)) == warm
 
 
 def test_server_process_exits_nonzero_when_warmup_fails(tmp_path):
@@ -341,7 +373,7 @@ def test_chip_smoke_fails_when_a_device_lane_is_not_traced(chip_smoke, monkeypat
     monkeypatch.setattr(chip_smoke, "start_stack", on_the_xla_lane)
     report = chip_smoke.run_phase("tpu-batch", "tightly-pack", 48, 6, seed=7, expect_lane="xla")
     assert report.granted_drivers >= 1
-    monkeypatch.setattr(fifo_solver, "_readback", fifo_solver._on_host)
+    monkeypatch.setattr(fifo_solver, "_readback", np.asarray)  # the transfer without its span
     with pytest.raises(chip_smoke.SmokeFailure, match="device.readback"):
         chip_smoke.run_phase("tpu-batch", "tightly-pack", 48, 6, seed=7, expect_lane="xla")
 

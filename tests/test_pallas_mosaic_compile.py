@@ -105,3 +105,30 @@ def test_kernel_compiles_for_v5e(v5e_sharding, policy, fn, args_of, static, n, a
     )
     compiled = target.lower(*args_of(n, a)).compile()
     assert compiled is not None
+
+
+# the served program of the plain policies: queue pass and the current
+# driver's solve in one (batch_solver.solve_filter), with the queue
+# kernel's name the device trace has to show for it
+# (benchmarks/readers/device_op_ms.py finds the kernel by that name)
+SERVED = [
+    ("tightly-pack", "pallas_solve_queue"),
+    ("distribute-evenly", "pallas_solve_queue"),
+    ("minimal-fragmentation", "pallas_solve_queue_min_frag"),
+]
+
+
+@pytest.mark.parametrize("n,a", SHAPES, ids=lambda v: str(v))
+@pytest.mark.parametrize("policy,kernel", SERVED, ids=[v[0] for v in SERVED])
+def test_served_filter_program_compiles_for_v5e_and_names_its_kernel(v5e_sharding, policy, kernel, n, a):
+    from k8s_spark_scheduler_tpu.ops.batch_solver import solve_filter
+
+    target = jax.jit(
+        functools.partial(solve_filter.__wrapped__, policy=policy, pallas=True),
+        in_shardings=v5e_sharding,
+        out_shardings=v5e_sharding,
+    )
+    compiled = target.lower(_sds((n, 5), jnp.int32), _sds((a, 8), jnp.int32)).compile()
+    calls = [line.split(" = ")[0].strip().lstrip("%") for line in compiled.as_text().splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1 and calls[0].split(".")[0] == kernel, calls

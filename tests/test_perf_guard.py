@@ -179,8 +179,8 @@ TRACE_TREE_BUDGET_US = float(os.environ.get("PERF_GUARD_TRACE_TREE_US", "500"))
 
 
 def test_span_tree_overhead_budget():
-    """The tree a granted driver Filter leaves on a device lane (29
-    spans, 30 with provenance on; docs/observability.md).  The budget per span is what it was
+    """The tree a granted driver Filter leaves on a device lane (24
+    spans, 25 with provenance on; docs/observability.md).  The budget per span is what it was
     when the tree had 7 (120 µs / 7 ≈ 17 µs, ~3x the ~5 µs measured)."""
     from k8s_spark_scheduler_tpu.tracing import Tracer, child_span
 
@@ -191,15 +191,6 @@ def test_span_tree_overhead_budget():
             with child_span(name):
                 pass
 
-    def dispatch(parent, arrays, kernel):
-        with child_span(parent, {"policy": "tightly-pack", "earlierApps": 3}):
-            with child_span("device.upload", {"arrays": arrays, "bytes": 51_000}):
-                pass
-            with tracer.span("kernel:" + kernel, {"lane": "xla"}) as k:
-                leaves("device.dispatch", "device.wait")
-                k.tag("executeMs", 0.2)
-            leaves("device.readback")
-
     def one_request():
         with tracer.span("http.request", {"path": "/predicates"}):
             leaves("http.read", "serde.decode")
@@ -208,11 +199,17 @@ def test_span_tree_overhead_budget():
                     "fast_path.snapshot", "fast_path.queue_assemble", "fast_path.build_tensor",
                     "fast_path.tensorize_apps", "fast_path.scale_problem",
                 )
-                dispatch("fifo_gate", 7, "fifo_queue")
-                dispatch("binpack", 5, "solve_single")
-                with child_span("fast_path.decode"):
-                    leaves("device.readback")
-                leaves("fast_path.efficiency")
+                with child_span("fifo_gate", {"earlierApps": 3, "lane": "xla"}):
+                    with child_span("device.upload", {"arrays": 2, "bytes": 238_000}):
+                        pass
+                    with tracer.span("kernel:fifo_queue", {"lane": "xla"}) as k:
+                        leaves("device.dispatch", "device.wait")
+                        k.tag("executeMs", 0.2)
+                    with child_span("device.readback", {"arrays": 1, "bytes": 168_000}):
+                        pass
+                with child_span("binpack", {"policy": "tightly-pack", "lane": "xla"}):
+                    pass
+                leaves("fast_path.decode", "fast_path.efficiency")
                 with tracer.span("driver.finish"):
                     with tracer.span("reservation.writeback", {"app": "a"}):
                         leaves("state.writeback.enqueue")

@@ -43,25 +43,30 @@ EXPECTED = {
         FINISH,
         ("provenance.finish", []),
     ],
-    "xla": [
+    # the device lanes (the XLA scans, the Pallas kernels; plain and
+    # min-frag alike) cross the boundary once each way: the queue pass and
+    # the current driver's solve are one program, everything after the
+    # read-back reads that one host copy
+    "device": [
         *((name, []) for name in HOST_LEAVES),
         ("fifo_gate", [
-            ("device.upload", []),
+            ("device.upload", []),  # a node-side and an app-side block
             ("kernel:fifo_queue", KERNEL_PHASES),
-            ("device.readback", []),
-            ("provenance.capture", [("device.readback", [])]),  # the post-queue availability
+            ("device.readback", []),  # verdicts, the current driver's solve, the availability
+            ("provenance.capture", []),
         ]),
-        ("binpack", [
-            ("device.upload", []),
-            ("kernel:solve_single", KERNEL_PHASES),
-            ("device.readback", []),
-        ]),
-        # tightly-pack decodes the driver index and the executor counts
-        ("fast_path.decode", [("device.readback", []), ("device.readback", [])]),
-        ("fast_path.efficiency", [("device.readback", [])]),
+        ("binpack", []),
+        ("fast_path.decode", []),
+        ("fast_path.efficiency", []),
         FINISH,
         ("provenance.finish", []),
     ],
+}
+DEVICE_LANES = {
+    ("tpu-batch", "xla"): "xla",
+    ("tpu-batch", "pallas"): "pallas",
+    ("tpu-batch-minimal-fragmentation", "xla"): "minfrag-xla",
+    ("tpu-batch-minimal-fragmentation", "pallas"): "pallas-minfrag",
 }
 
 
@@ -158,28 +163,48 @@ def roots_of(h):
 # -- (a) the documented children, once per lane --------------------------------
 
 
-@pytest.mark.parametrize("lane", ["native", "xla"])
-def test_granted_driver_filter_has_exactly_the_documented_children(lane):
-    h = served_harness(lane)
+def granted_driver_root(h):
+    # the first request of an idle server reconciles (and compiles)
+    h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
+    roots = roots_of(h)
+    driver = h.static_allocation_spark_pods("app-new", 2)[0]
+    h.assert_success(h.schedule(driver, NODES))
+    (root,) = [r for r in roots if r.name == "predicate"]
+    return root
+
+
+def test_granted_driver_filter_has_exactly_the_documented_children_on_the_native_lane():
+    h = served_harness("native")
     try:
-        # the first request of an idle server reconciles (and compiles)
-        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
-        roots = roots_of(h)
-        driver = h.static_allocation_spark_pods("app-new", 2)[0]
-        h.assert_success(h.schedule(driver, NODES))
-        (root,) = [r for r in roots if r.name == "predicate"]
-        assert shape(root) == EXPECTED[lane]
+        root = granted_driver_root(h)
+        assert shape(root) == EXPECTED["native"]
         gate = find(root, "fifo_gate")
-        assert gate.tags["lane"] == lane and gate.tags["earlierApps"] == 3
+        assert gate.tags["lane"] == "native" and gate.tags["earlierApps"] == 3
         assert find(root, "fast_path.queue_assemble").tags["earlierApps"] == 3
+        assert [n for n in names(root) if n.startswith("device.")] == []
+    finally:
+        h.close()
+
+
+@pytest.mark.parametrize("binpack_algo,backend", sorted(DEVICE_LANES))
+def test_granted_driver_filter_crosses_the_device_boundary_once_each_way(binpack_algo, backend):
+    h = served_harness(backend, binpack_algo)
+    try:
+        root = granted_driver_root(h)
+        assert shape(root) == EXPECTED["device"]
+        gate = find(root, "fifo_gate")
+        assert gate.tags["lane"] == DEVICE_LANES[binpack_algo, backend]
+        assert gate.tags["earlierApps"] == 3 and gate.tags["earlierOk"] is True
+        assert find(root, "kernel:fifo_queue").tags[mnames.TAG_LANE] == gate.tags["lane"]
+        # the crossings of a request are these two tags: 2 arrays up, 1 down
+        upload, readback = find(gate, "device.upload"), find(gate, "device.readback")
+        assert upload.tags["arrays"] == 2 and upload.tags["bytes"] == 4 * (64 * 5 + 16 * 8)
+        assert readback.tags == {"arrays": 1, "bytes": 4 * (4 * 64 + 16 + 2)}
+        assert find(root, "binpack").tags["feasible"] is True
         device = [n for n in names(root) if n.startswith("device.")]
-        if lane == "native":
-            assert device == []
-        else:
-            upload = find(gate, "device.upload")
-            assert upload.tags["arrays"] == 7 and upload.tags["bytes"] > 0
-            assert find(find(root, "binpack"), "device.upload").tags["arrays"] == 5
-            assert {"device.upload", "device.dispatch", "device.wait", "device.readback"} == set(device)
+        assert sorted(device) == [
+            "device.dispatch", "device.readback", "device.upload", "device.wait",
+        ]
     finally:
         h.close()
 
@@ -422,7 +447,7 @@ def test_spans_lie_on_the_profilers_clock_only_while_a_session_is_active(tmp_pat
     for outer, inner in zip(chain, chain[1:]):
         (interval,) = sched[outer]
         assert inside(sched[inner], interval), (outer, inner)
-    assert len(sched["sched.device.upload"]) == 2  # the queue's and solve_single's
+    assert len(sched["sched.device.upload"]) == 1  # one round: both blocks together
     assert inside(sched["sched.http.request"], client)  # the same clock as the client's annotation
     assert {"sched.device.wait", "sched.device.readback", "sched.driver.finish"} <= set(sched)
 
