@@ -1442,9 +1442,8 @@ def _config5_e2e() -> dict | None:
             f"lock hold p95={lane.get('lock_hold_ms_p95', 0.0)}ms",
             file=sys.stderr,
         )
-        _concurrent_admission_measure(scheduler, api, names, base)
-        # every Filter above — probes and concurrent rounds — must have
-        # been answered by the lane this platform is benched for
+        # every Filter above must have been answered by the lane this
+        # platform is benched for
         stats["fallbacks"] = scheduler.extender.host_fallbacks()
         if stats["fallbacks"]:
             raise RuntimeError(
@@ -1476,192 +1475,6 @@ def _config5_e2e() -> dict | None:
         if scheduler is not None:
             scheduler.stop()
         logging.disable(logging.NOTSET)
-
-
-def _concurrent_admission_measure(scheduler, api, names, base_ts) -> None:
-    """Concurrent admission throughput on the live e2e
-    server: the same probe workload pushed through the serial extender
-    and then through the speculate→FIFO-commit engine at 1/2/4/8 client
-    threads, decisions/sec per lane, with byte-identity asserted every
-    round (the engine's contract: commits ARE the serial extender in
-    ticket order, so the decision stream never changes — only the
-    wall-clock does).  The per-round commit_results record how the
-    speculative verdicts fared (seq/memcmp hits vs conflicts vs serial
-    declines) — the conflict rate is the operator's tuning signal.
-    ``p99_ms`` (request latency at 8 clients, gate wait included) rides
-    in the lane so tools/perf_regression.py band-gates it like every
-    other lane."""
-    import threading
-
-    from k8s_spark_scheduler_tpu.concurrent import ConcurrentAdmissionEngine
-    from k8s_spark_scheduler_tpu.config import ConcurrentConfig
-    from k8s_spark_scheduler_tpu.testing.harness import Harness
-    from k8s_spark_scheduler_tpu.types.extenderapi import ExtenderArgs
-
-    probes = int(os.environ.get("BENCH_CONCURRENT_PROBES", "48"))
-    if probes <= 0:
-        return
-    rr_cache = scheduler.resource_reservation_cache
-    rng = np.random.RandomState(18)
-    specs = [
-        (
-            f"cprobe-{i:04d}",
-            int(rng.randint(1, 32)),
-            str(int(rng.randint(1, 8))),
-            f"{int(rng.randint(2, 16))}Gi",
-        )
-        for i in range(probes)
-    ]
-
-    def create_batch():
-        pods = []
-        for i, (app, execs, cpu, mem) in enumerate(specs):
-            d = Harness.static_allocation_spark_pods(
-                app,
-                execs,
-                executor_cpu=cpu,
-                executor_mem=mem,
-                creation_timestamp=base_ts + 50_000 + i,
-            )[0]
-            pods.append(api.create(d))
-        return pods
-
-    def retire_batch(pods):
-        """The app-finished flow for the whole batch: every probe pod
-        deleted and its reservation collected, so the next round sees
-        the identical steady-state problem."""
-        for pod in pods:
-            try:
-                api.delete("Pod", pod.namespace, pod.name)
-            except Exception:
-                pass
-        deadline = time.monotonic() + 30.0
-        while time.monotonic() < deadline:
-            if all(
-                rr_cache.get(p.namespace, p.labels.get("spark-app-id", ""))
-                is None
-                for p in pods
-            ):
-                return
-            time.sleep(0.005)
-
-    def decision_of(pod, result):
-        return (
-            pod.name,
-            tuple(result.node_names or ()),
-            tuple(sorted((result.failed_nodes or {}).items())),
-        )
-
-    def serial_round():
-        pods = create_batch()
-        ext = scheduler.extender
-        out = [None] * len(pods)
-        lat = [0.0] * len(pods)
-        t0 = time.perf_counter()
-        for i, pod in enumerate(pods):
-            t1 = time.perf_counter()
-            res = ext.predicate(ExtenderArgs(pod=pod, node_names=names))
-            lat[i] = (time.perf_counter() - t1) * 1000.0
-            out[i] = decision_of(pod, res)
-        wall = time.perf_counter() - t0
-        retire_batch(pods)
-        return out, wall, lat
-
-    def concurrent_round(n_clients):
-        engine = ConcurrentAdmissionEngine(
-            scheduler.extender,
-            ConcurrentConfig(enabled=True),
-            metrics=scheduler.metrics,
-        )
-        pods = create_batch()
-        # tickets preassigned in workload order: the FIFO commit order
-        # is the serial order regardless of thread interleaving
-        tickets = [engine.gate.ticket() for _ in pods]
-        out = [None] * len(pods)
-        lat = [0.0] * len(pods)
-        errs = []
-
-        def worker(idx):
-            try:
-                for j in range(idx, len(pods), n_clients):
-                    t1 = time.perf_counter()
-                    res = engine.predicate(
-                        ExtenderArgs(pod=pods[j], node_names=names),
-                        ticket=tickets[j],
-                    )
-                    lat[j] = (time.perf_counter() - t1) * 1000.0
-                    out[j] = decision_of(pods[j], res)
-            except BaseException as err:  # noqa: BLE001 - reraised below
-                errs.append(err)
-
-        workers = [
-            threading.Thread(target=worker, args=(i,), daemon=True)
-            for i in range(n_clients)
-        ]
-        t0 = time.perf_counter()
-        for t in workers:
-            t.start()
-        for t in workers:
-            t.join(600)
-        wall = time.perf_counter() - t0
-        retire_batch(pods)
-        if errs:
-            raise errs[0]
-        return out, wall, lat, engine.stats()
-
-    serial_dec, serial_wall, serial_lat = serial_round()
-    serial_dps = probes / max(serial_wall, 1e-9)
-    lane = {
-        "probes": probes,
-        "serial_dps": round(serial_dps, 1),
-        "serial_wall_s": round(serial_wall, 3),
-        # serial per-decision p50 is solve-dominated at this shape: the
-        # acceptance comparison partner for the commit lock hold below
-        "solve_p50_ms": round(float(np.percentile(np.array(serial_lat), 50)), 3),
-        "clients": {},
-        "identical": True,
-    }
-    for c in (1, 2, 4, 8):
-        dec, wall, lat, stats = concurrent_round(c)
-        identical = dec == serial_dec
-        lane["identical"] = lane["identical"] and identical
-        results = stats["commit_results"]
-        arr = np.array(lat)
-        lane["clients"][str(c)] = {
-            "dps": round(probes / max(wall, 1e-9), 1),
-            "wall_s": round(wall, 3),
-            "p99_ms": round(float(np.percentile(arr, 99)), 3),
-            "commit_results": results,
-            "conflicts": sum(
-                v
-                for k, v in results.items()
-                if k in ("conflict", "queue-drift", "skip-drift", "candidate-drift")
-            ),
-            "identical": identical,
-        }
-    eight = lane["clients"]["8"]
-    lane["dps_8clients"] = eight["dps"]
-    lane["speedup_8clients"] = round(eight["dps"] / max(serial_dps, 1e-9), 2)
-    lane["p99_ms"] = eight["p99_ms"]
-    # the commit critical section replaces solver tenure under the
-    # predicate lock: its hold p95 must sit below the serial solve p50
-    # (ISSUE 18 acceptance) — read from the lock's own timekeeper
-    lane["lock_hold_ms_p95"] = scheduler.extender._predicate_lock.snapshot()[
-        "holdMs"
-    ]["p95"]
-    LANES[f"concurrent-admission {_device_info()['platform']}"] = lane
-    SECONDARY["concurrent_admission_speedup_8"] = lane["speedup_8clients"]
-    SECONDARY["concurrent_admission_identical"] = lane["identical"]
-    print(
-        f"# concurrent-admission {probes} probes: serial {serial_dps:.1f}/s, "
-        + ", ".join(
-            f"{c}cl {lane['clients'][c]['dps']:.1f}/s" for c in ("1", "2", "4", "8")
-        )
-        + f", speedup(8)={lane['speedup_8clients']}x "
-        f"identical={lane['identical']} "
-        f"conflicts(8)={eight['conflicts']}",
-        file=sys.stderr,
-    )
 
 
 def _config3(nodes_per_group: int) -> None:

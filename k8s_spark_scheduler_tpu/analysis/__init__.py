@@ -17,8 +17,7 @@ ordinary linters cannot see:
   of those is a silent-retrace (or outright crash) hazard on the
   binpack hot path.
 - **PC protocol discipline** — flow-sensitive typestate over a real CFG
-  (:mod:`.flow`): commit-gate tickets retire on *every* path including
-  exceptional ones, kube mutations are dominated by a fencing check
+  (:mod:`.flow`): kube mutations are dominated by a fencing check
   from their entry points, journal intents are never acked before their
   execute, spans/locks close path-completely, and the extender's phase
   ladder re-arms its deadline at each boundary (:mod:`.rules_protocol`).

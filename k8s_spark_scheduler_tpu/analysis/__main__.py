@@ -74,8 +74,6 @@ RULE_FAMILIES: Tuple[Tuple[str, str, Tuple[Tuple[str, str], ...]], ...] = (
         "PC",
         "protocol (flow-sensitive typestate over the CFG)",
         (
-            ("PC001", "CommitGate ticket can leak: a path reaches an exit without retire"),
-            ("PC002", "double retire: a retire may run on an already-retired ticket"),
             ("PC003", "kube-mutating call not dominated by a FencedWriter.check from its entry point"),
             ("PC004", "journal intent acked on a path where the execute may not have happened"),
             ("PC005", "manually opened span/lock not closed on every path"),

@@ -775,117 +775,7 @@ def _fencing_scenario() -> Scenario:
 
 
 # ---------------------------------------------------------------------------
-# 9. CommitGate: linearizable FIFO under speculate/conflict/abort/recommit
-# ---------------------------------------------------------------------------
-
-
-def _concurrent_commit_scenario() -> Scenario:
-    """The concurrent admission engine's ordering contract
-    (concurrent/commitgate.py): requests speculate against a
-    seq-stamped basis in parallel, then commit strictly in ticket
-    order.  Aborts (deadline expiry before the turn) must not stall the
-    queue; a commit whose speculative basis moved must observe that at
-    revalidation (conflict → re-solve) — never consume the stale
-    verdict.  FIFO among committed requests is the linearizability the
-    scenario proves over every explored interleaving."""
-    from ..concurrent.commitgate import CommitGate
-    from .modelcheck import CoopEvent
-
-    @guarded_by("_lock", "basis_seq", "commit_log", "aborted")
-    class State:
-        def __init__(self):
-            # CoopEvent so a parked turn stays visible to the
-            # cooperative scheduler (a raw Event.wait would read as a
-            # stuck schedule)
-            self.gate = CommitGate(event_factory=CoopEvent)
-            self._lock = threading.Lock()
-            # the shared basis, stood in by its ChangeFeed sequence:
-            # success-shaped commits bump it (the reservation
-            # write-back); refusals leave it alone
-            self.basis_seq = 0
-            self.commit_log: List[tuple] = []  # (ticket, reason), commit order
-            self.aborted: List[int] = []
-
-    def setup():
-        return State()
-
-    def threads(st: State):
-        def request(abort: bool, mutates: bool):
-            ticket = st.gate.ticket()
-            committed = False
-            try:
-                # the speculative solve: an off-lock snapshot read of
-                # the basis, concurrent with every other request's
-                with st._lock:
-                    racecheck.note_access(st, "basis_seq")
-                    spec_seq = st.basis_seq
-                checkpoint("speculated")
-                if abort:
-                    # deadline expired before the turn: retire without
-                    # committing — later tickets must skip this one
-                    with st._lock:
-                        racecheck.note_access(st, "aborted")
-                        st.aborted.append(ticket)
-                    return
-                st.gate.await_turn(ticket)
-                # the commit: revalidate the speculation against the
-                # then-current basis — O(1) seq check, conflict → re-solve
-                with st._lock:
-                    racecheck.note_access(st, "basis_seq")
-                    racecheck.note_access(st, "commit_log")
-                    reason = "seq-hit" if st.basis_seq == spec_seq else "conflict"
-                    st.commit_log.append((ticket, reason))
-                    if mutates:
-                        st.basis_seq += 1
-                committed = True
-            finally:
-                st.gate.retire(ticket, committed)
-
-        return [
-            ("commit-a", lambda: request(False, True)),
-            ("commit-b", lambda: request(False, False)),
-            ("abort-c", lambda: request(True, False)),
-            ("commit-d", lambda: request(False, True)),
-        ]
-
-    def invariant(st: State):
-        with st._lock:
-            log = list(st.commit_log)
-        tickets = [t for t, _ in log]
-        assert tickets == sorted(tickets), (
-            f"commits out of FIFO ticket order: {tickets}"
-        )
-
-    def final(st: State):
-        with st._lock:
-            log = list(st.commit_log)
-            aborted = list(st.aborted)
-        tickets = [t for t, _ in log]
-        assert tickets == sorted(tickets), f"final order not FIFO: {tickets}"
-        assert len(log) == 3, f"expected 3 commits, got {log}"
-        assert len(aborted) == 1, f"expected 1 abort, got {aborted}"
-        assert not set(tickets) & set(aborted), "a ticket both committed and aborted"
-        stats = st.gate.stats()
-        assert stats["committed"] == 3 and stats["aborted"] == 1
-        assert stats["head"] == stats["issued"] == 4, (
-            f"gate head did not drain: {stats}"
-        )
-
-    return Scenario(
-        name="concurrent-commit-fifo",
-        setup=setup,
-        threads=threads,
-        invariant=invariant,
-        final=final,
-        description="speculate/conflict/abort/recommit through the "
-        "commit gate: commits land strictly in ticket order, aborts "
-        "never stall the queue, and the gate drains to head==issued on "
-        "every interleaving",
-    )
-
-
-# ---------------------------------------------------------------------------
-# 10. ClassIndex: concurrent class rebuild vs. the digest warm check
+# 9. ClassIndex: concurrent class rebuild vs. the digest warm check
 # ---------------------------------------------------------------------------
 
 
@@ -1038,6 +928,5 @@ def corpus() -> List[Scenario]:
         _sampler_scenario(),
         _preemption_scenario(),
         _fencing_scenario(),
-        _concurrent_commit_scenario(),
         _class_rebuild_scenario(),
     ]

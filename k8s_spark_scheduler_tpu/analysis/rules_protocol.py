@@ -1,17 +1,10 @@
 """PC — flow-sensitive protocol rules over :mod:`.flow` CFGs.
 
-PR 18 made admission concurrent; PRs 14–16 made correctness hinge on
-*protocol discipline* rather than any single call site.  These rules
-prove the lifecycles hold on **every** path — including the exception
-paths tests never take — by running typestate dataflow over the
-per-function CFGs from :mod:`.flow`:
+PRs 14–16 made correctness hinge on *protocol discipline* rather than
+any single call site.  These rules prove the lifecycles hold on
+**every** path — including the exception paths tests never take — by
+running typestate dataflow over the per-function CFGs from :mod:`.flow`:
 
-- **PC001** — a :class:`~..concurrent.commitgate.CommitGate` ticket is
-  issued (``gate.ticket()``) but some path to the function's normal or
-  raise exit never retires it.  A leaked ticket is a *permanent*
-  head-of-line stall: every later ticket waits on it forever.
-- **PC002** — a path may retire the same ticket twice (double-retire
-  releases somebody else's turn).
 - **PC003** — a kube-mutating call (CRD create/update/delete/patch on
   an api/client receiver) is reachable from a configured entry point
   without a dominating ``FencedWriter.check`` — computed
@@ -39,7 +32,7 @@ per-function CFGs from :mod:`.flow`:
 
 Scope and deliberate imprecision
 --------------------------------
-* Typestate tracking keys on **local names** (tickets, spans, locks).
+* Typestate tracking keys on **local names** (spans, locks).
   A resource stored into ``self.*`` or returned escapes the
   intra-procedural discipline and is dropped — cross-method lifecycles
   (e.g. a server's root span) are out of scope by design.
@@ -81,11 +74,6 @@ DEFAULT_ENTRYPOINTS: Dict[str, Tuple[str, ...]] = {
         "AsyncClient._run_worker",
         "AsyncClient.replay_journal",
         "AsyncClient.nudge_recovery",
-    ),
-    "concurrent/engine.py": (
-        "ConcurrentAdmissionEngine.predicate",
-        "ConcurrentAdmissionEngine.submit_intent",
-        "ConcurrentAdmissionEngine.make_intent",
     ),
 }
 
@@ -130,10 +118,6 @@ def _attr_parts(expr: ast.expr) -> Optional[List[str]]:
         parts.reverse()
         return parts
     return None
-
-
-def _is_gateish(comp: str) -> bool:
-    return "gate" in comp.lower()
 
 
 def _is_fenceish(recv: Sequence[str]) -> bool:
@@ -185,14 +169,7 @@ def _events_for_call(call: ast.Call) -> List[_Event]:
         return events
     attr, recv = parts[-1], parts[:-1]
     dotted = ".".join(recv)
-    if attr == "ticket" and _is_gateish(recv[-1]):
-        events.append(_Event("ticket-open", call))
-    elif attr == "retire" and _is_gateish(recv[-1]):
-        var = None
-        if call.args and isinstance(call.args[0], ast.Name):
-            var = call.args[0].id
-        events.append(_Event("ticket-retire", call, var=var))
-    elif attr == "check" and _is_deadlineish(recv) or (
+    if attr == "check" and _is_deadlineish(recv) or (
         attr in ("_check_deadline", "check_deadline")
     ):
         phase = _const_str(call.args[0]) if call.args else None
@@ -288,7 +265,6 @@ class _UnitEvents:
         self.cfg = unit.cfg()
         self.events: Dict[int, List[_Event]] = {}
         self.calls: Dict[int, List[ast.Call]] = {}
-        self.ticket_opens: Dict[int, str] = {}  # node -> var bound by `v = gate.ticket()`
         self.escapes: Dict[int, Set[str]] = {}
         for node in self.cfg.nodes:
             if node.stmt is None:
@@ -319,20 +295,6 @@ class _UnitEvents:
                                         var=f"span:{name}",
                                     )
                                 )
-            if node.kind != flow.TEST and isinstance(stmt, (ast.Assign, ast.AnnAssign)):
-                targets = (
-                    stmt.targets if isinstance(stmt, ast.Assign) else [stmt.target]
-                )
-                value = stmt.value
-                if (
-                    value is not None
-                    and isinstance(value, ast.Call)
-                    and len(targets) == 1
-                    and isinstance(targets[0], ast.Name)
-                ):
-                    for ev in _events_for_call(value):
-                        if ev.kind == "ticket-open":
-                            self.ticket_opens[node.idx] = targets[0].id
             esc = _escaping_names(stmt, node.kind)
             if esc:
                 self.escapes[node.idx] = esc
@@ -349,8 +311,8 @@ def _escaping_names(stmt: ast.AST, kind: str) -> Set[str]:
     """Local names this statement aliases, returns, yields or stores —
     tracked resources named here leave the function's custody, so the
     typestate rules stop tracking them.  Names that only appear as call
-    *arguments* do not escape (passing a ticket to ``speculate`` does
-    not transfer the retire obligation)."""
+    *arguments* do not escape (passing a span to a helper does not
+    transfer the close obligation)."""
 
     def direct_names(expr: ast.AST) -> Set[str]:
         found: Set[str] = set()
@@ -382,11 +344,8 @@ def _escaping_names(stmt: ast.AST, kind: str) -> Set[str]:
 
 
 # ---------------------------------------------------------------------------
-# PC001 / PC002 — ticket typestate
+# typestate maps (PC005)
 # ---------------------------------------------------------------------------
-
-_ISSUED = "issued"
-_RETIRED = "retired"
 
 StateMap = Dict[str, FrozenSet[str]]
 
@@ -396,94 +355,6 @@ def _join_maps(a: StateMap, b: StateMap) -> StateMap:
     for var, states in b.items():
         out[var] = out.get(var, frozenset()) | states
     return out
-
-
-def _check_tickets(ue: _UnitEvents) -> List[Finding]:
-    cfg, unit = ue.cfg, ue.unit
-    if not any(
-        e.kind in ("ticket-open", "ticket-retire")
-        for evs in ue.events.values()
-        for e in evs
-    ):
-        return []
-
-    def apply(node: flow.Node, state: StateMap, on_raise: bool) -> StateMap:
-        out = dict(state)
-        for var in ue.escapes.get(node.idx, ()):
-            out.pop(var, None)
-        opened = ue.ticket_opens.get(node.idx)
-        if opened is not None and not on_raise:
-            # acquisition that raises never bound the name (RAII)
-            out[opened] = frozenset({_ISSUED})
-        for ev in ue.node_events(node.idx, "ticket-retire"):
-            if ev.var is not None:
-                # retire applies even on the raise edge: a retire that
-                # itself raised cannot be meaningfully re-driven
-                out[ev.var] = frozenset({_RETIRED})
-        return out
-
-    in_state = flow.forward_dataflow(
-        cfg,
-        init={},
-        transfer=lambda n, s: apply(n, s, on_raise=False),
-        transfer_exc=lambda n, s: apply(n, s, on_raise=True),
-        join=_join_maps,
-    )
-
-    findings: List[Finding] = []
-    open_lines: Dict[str, int] = {}
-    for idx, var in ue.ticket_opens.items():
-        open_lines.setdefault(var, cfg.nodes[idx].line)
-    # PC002: retire may run on an already-retired ticket
-    for idx, evs in sorted(ue.events.items()):
-        state = in_state.get(idx)
-        if state is None:
-            continue
-        for ev in evs:
-            if ev.kind == "ticket-retire" and ev.var is not None:
-                if _RETIRED in state.get(ev.var, frozenset()):
-                    findings.append(
-                        Finding(
-                            rule="PC002",
-                            category=CATEGORY,
-                            file=unit.relpath,
-                            line=cfg.nodes[idx].line,
-                            col=ev.call.col_offset,
-                            message=(
-                                f"ticket '{ev.var}' may already be retired when "
-                                "this retire runs (double-retire releases "
-                                "someone else's commit turn)"
-                            ),
-                            symbol=unit.qualname,
-                        )
-                    )
-    # PC001: a leak path to either exit
-    for exit_idx, how in ((cfg.exit, "a fall-through"), (cfg.raise_exit, "an exception")):
-        state = in_state.get(exit_idx)
-        if not state:
-            continue
-        for var, states in sorted(state.items()):
-            if _ISSUED in states:
-                line = open_lines.get(var)
-                if line is None:
-                    continue  # ticket came from a parameter — caller owns it
-                findings.append(
-                    Finding(
-                        rule="PC001",
-                        category=CATEGORY,
-                        file=unit.relpath,
-                        line=line,
-                        col=0,
-                        message=(
-                            f"ticket '{var}' issued here may never be retired on "
-                            f"{how} path — a leaked CommitGate ticket stalls "
-                            "the FIFO line forever; retire in a finally that "
-                            "cannot be skipped"
-                        ),
-                        symbol=unit.qualname,
-                    )
-                )
-    return findings
 
 
 # ---------------------------------------------------------------------------
@@ -898,7 +769,6 @@ def check_package(contexts: Sequence[FileContext]) -> List[Finding]:
     mutation_summary = _MutationSummary(index, events)
     for key in sorted(events):
         ue = events[key]
-        findings.extend(_check_tickets(ue))
         findings.extend(_check_journal(ue, index, mutation_summary))
         findings.extend(_check_spans(ue))
         findings.extend(_check_phases(ue))

@@ -7,6 +7,7 @@ and YAML when pyyaml is installed (the optional ``[yaml]`` extra).
 
 from __future__ import annotations
 
+import logging
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
@@ -307,43 +308,6 @@ class HAConfig:
 
 
 @dataclass
-class ConcurrentConfig:
-    """Concurrent admission engine (concurrent/): optimistic speculative
-    solves committed through a FIFO commit gate.
-
-    Disabled (the default) wires nothing — Filter requests run the
-    serial extender exactly as before, byte-identical decisions.
-    Enabled, independent requests speculate in parallel against
-    seq-stamped snapshot bases and commit in strict arrival order; the
-    commit gate revalidates every verdict, so decisions are *still*
-    byte-identical to the serial extender (the 5-seed property test
-    pins this) — the switch trades nothing but CPU for latency.
-    """
-
-    enabled: bool = False
-    # run the speculative solve at all; off = requests still serialize
-    # through the FIFO commit gate but never solve outside the lock
-    # (a degraded mode for conflict-storm fallback — see
-    # docs/operations.md "running multi-active admission")
-    speculation: bool = True
-    # concurrent speculations beyond this bound skip straight to the
-    # serial commit path (memory bound: each holds a snapshot basis)
-    max_inflight_speculations: int = 8
-    # accept forwarded commit intents from standby replicas (multi-
-    # active operation); requires the HA fabric for epoch fencing
-    multi_active: bool = False
-
-    @staticmethod
-    def from_dict(d: dict) -> "ConcurrentConfig":
-        return ConcurrentConfig(
-            enabled=d.get("enabled", False),
-            speculation=d.get("speculation", True),
-            max_inflight_speculations=d.get("max-inflight-speculations", 8),
-            multi_active=d.get("multi-active", False),
-        )
-
-
-@dataclass
 class ClassesConfig:
     """Equivalence-class node aggregation (ROADMAP 2): class-compressed
     native solves, the O(1) class-digest warm tier in the delta-solve
@@ -427,10 +391,6 @@ class Install:
     # gang lifecycle ledger + SLO burn-rate engine (lifecycle/) —
     # diagnostic only, decisions unchanged
     lifecycle: LifecycleConfig = field(default_factory=LifecycleConfig)
-    # concurrent admission engine: parallel speculative solves + FIFO
-    # commit gate (concurrent/) — disabled = serial extender, and
-    # enabled is still decision-identical by construction
-    concurrent: ConcurrentConfig = field(default_factory=ConcurrentConfig)
     # equivalence-class aggregation: class-compressed solves at scale +
     # class-digest warm tier (state/classindex.py, ops/deltasolve.py) —
     # byte-identical decisions either way
@@ -441,6 +401,11 @@ class Install:
         fifo_cfg = d.get("fifo-config", {})
         driver_label = d.get("driver-prioritized-node-label")
         executor_label = d.get("executor-prioritized-node-label")
+        if "concurrent" in d:
+            logging.getLogger(__name__).warning(
+                "install config: the 'concurrent:' block is ignored — the "
+                "concurrent admission engine was removed; decisions are unchanged"
+            )
         return Install(
             fifo=d.get("fifo", False),
             fifo_config=FifoConfig(
@@ -512,6 +477,5 @@ class Install:
             policy=PolicyConfig.from_dict(d.get("policy", {})),
             ha=HAConfig.from_dict(d.get("ha", {})),
             lifecycle=LifecycleConfig.from_dict(d.get("lifecycle", {})),
-            concurrent=ConcurrentConfig.from_dict(d.get("concurrent", {})),
             classes=ClassesConfig.from_dict(d.get("classes", {})),
         )

@@ -77,18 +77,13 @@ SPAN_SEGMENTS: Dict[str, str] = {
     "driver.finish": "finish",
     "provenance.finish": "finish",
     "http.write": "serde",
-    # the concurrent engine's speculative solve runs pre-lock on the
-    # request's own thread; classifying it apart from "solve" keeps the
-    # lock-tenure segment honest when speculation is on (a consumed
-    # verdict means the under-lock solve never ran)
-    "speculation.solve": "speculate",
     "reservation.writeback": "write-back",
     "state.writeback.enqueue": "write-back",
 }
 
 SEGMENT_NAMES = (
     "gate-queue", "lock-wait", "serde", "solve", "assemble", "upload",
-    "device-wait", "readback", "finish", "speculate", "write-back", "other",
+    "device-wait", "readback", "finish", "write-back", "other",
 )
 
 

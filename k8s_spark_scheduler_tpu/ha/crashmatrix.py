@@ -52,7 +52,6 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..config import (
-    ConcurrentConfig,
     HAConfig,
     Install,
     PolicyConfig,
@@ -87,13 +86,14 @@ _DIVERT_POINTS = {
     crashpoint.JOURNAL_PRE_APPEND,
     crashpoint.JOURNAL_POST_APPEND,
 }
-# speculation→commit window points (concurrent/engine.py): fire
-# synchronously on the Filter caller's thread inside engine.predicate
-_CONCURRENT_POINTS = {
-    crashpoint.CONCURRENT_SPECULATION_SOLVED,
-    crashpoint.CONCURRENT_COMMIT_REVALIDATED,
-    crashpoint.CONCURRENT_COMMIT_WRITTEN,
+# the first reservation write of a freshly admitted gang: the crash
+# lands between the Filter's answer and the reservation's durability
+_WRITEBACK_POINTS = {
+    crashpoint.WRITEBACK_PRE_COMMIT,
+    crashpoint.WRITEBACK_POST_COMMIT,
 }
+# executors of the gang every non-preemption cell admits
+_APP_EXECUTORS = 2
 
 
 def _wait(cond, timeout: float = 10.0, tick: float = 0.01) -> bool:
@@ -135,10 +135,6 @@ class CrashMatrix:
                 lease_duration_seconds=_LEASE_TTL_S,
                 identity=identity,
             ),
-            # every cell's Filter traffic runs through the concurrent
-            # admission engine, so the speculation→commit window's crash
-            # points sit on the live request path
-            concurrent=ConcurrentConfig(enabled=True),
         )
 
     def _boot(self, api: APIServer, identity: str, journal_path: str):
@@ -181,7 +177,9 @@ class CrashMatrix:
             )
 
     @staticmethod
-    def _schedule_app(server, api: APIServer, app_id: str, executors: int = 2) -> List[str]:
+    def _schedule_app(
+        server, api: APIServer, app_id: str, executors: int = _APP_EXECUTORS
+    ) -> List[str]:
         """Submit + schedule one gang through the real extender; binds
         successes exactly as the kube-scheduler would.  Returns bound
         pod names."""
@@ -190,13 +188,9 @@ class CrashMatrix:
             api.create(pod)
         node_names = sorted(n.name for n in api.list(Node.KIND))
         bound = []
-        engine = getattr(server, "concurrent", None)
-        predicate = (
-            engine.predicate if engine is not None else server.extender.predicate
-        )
         for pod in pods:
             fresh = api.get(Pod.KIND, pod.namespace, pod.name)
-            result = predicate(
+            result = server.extender.predicate(
                 ExtenderArgs(pod=fresh, node_names=list(node_names))
             )
             if result.node_names:
@@ -337,18 +331,6 @@ class CrashMatrix:
                 fired = _wait(lambda: crashpoint.armed() is None)
             return fired
 
-        if point in _CONCURRENT_POINTS:
-            # the speculation→commit window: the point fires on the
-            # Filter caller's thread inside engine.predicate — before
-            # the commit for speculation-solved / commit-revalidated,
-            # after the reservation write-back for commit-written
-            crashpoint.arm(point)
-            try:
-                self._schedule_app(server, api, "app-001")
-            except SimulatedCrash:
-                return True
-            return False
-
         # write-back commit and journal-ack points fire on the worker
         # thread during the very first reservation write
         crashpoint.arm(point)
@@ -375,23 +357,18 @@ class CrashMatrix:
                 except NotFoundError:
                     continue
                 violations.append(f"victim pod {name} still exists")
-        if point in _CONCURRENT_POINTS:
-            # exactly-once across the restart: a crash BEFORE the commit
-            # leaves zero reservation state (the gang was never
-            # admitted; the retry re-admits); a crash AFTER the
-            # reservation write leaves either the complete reservation
-            # or none (the bind never happened, so an unflushed write-
-            # back losing the race is still all-or-nothing) — never a
-            # half-committed gang
+        if point in _WRITEBACK_POINTS:
+            # all-or-nothing across the restart: the worker died around
+            # the reservation's API write, so the successor finds either
+            # the complete reservation (driver + every executor slot) or
+            # none (the retry re-admits) — never a half-committed gang
             rr = cache.get("default", "app-001")
             report["reservationPresent"] = rr is not None
-            if point != crashpoint.CONCURRENT_COMMIT_WRITTEN:
-                if rr is not None:
-                    violations.append(
-                        "crash before commit left a reservation for app-001"
-                    )
-            elif rr is not None and not rr.spec.reservations:
-                violations.append("app-001 reservation survived half-committed")
+            if rr is not None and len(rr.spec.reservations) != 1 + _APP_EXECUTORS:
+                violations.append(
+                    f"app-001 reservation survived half-committed: "
+                    f"{sorted(rr.spec.reservations)}"
+                )
         if report["journalDepth"] != 0:
             violations.append(f"{report['journalDepth']} write intents still pending")
         if report["evictJournalDepth"] != 0:

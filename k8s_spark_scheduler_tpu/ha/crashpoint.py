@@ -107,10 +107,3 @@ PREEMPT_MID_EXECUTE = register("preempt.mid-execute")
 PREEMPT_PRE_ACK = register("preempt.pre-ack")
 # lease renewal (ha/__init__.py step loop)
 LEASE_PRE_RENEW = register("lease.pre-renew")
-# concurrent admission engine (concurrent/engine.py): the
-# speculation→commit window — after the speculative solve, after the
-# commit gate admits the revalidated verdict, and after the reservation
-# write-back returned but before the response leaves
-CONCURRENT_SPECULATION_SOLVED = register("concurrent.speculation-solved")
-CONCURRENT_COMMIT_REVALIDATED = register("concurrent.commit-revalidated")
-CONCURRENT_COMMIT_WRITTEN = register("concurrent.commit-written")

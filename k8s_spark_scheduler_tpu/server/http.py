@@ -608,19 +608,9 @@ class _Handler(BaseHTTPRequestHandler):
         scheduling cycle, a failed-nodes response just requeues the pod."""
         from ..types.extenderapi import ExtenderFilterResult
 
-        # the concurrent admission engine (concurrent/engine.py) is a
-        # drop-in for extender.predicate: speculative solve on THIS
-        # request thread, then a FIFO-ordered commit through the serial
-        # extender — decisions stay byte-identical to serial operation
-        engine = getattr(self.scheduler, "concurrent", None)
-        predicate = (
-            engine.predicate
-            if engine is not None
-            else self.scheduler.extender.predicate
-        )
         kit = getattr(self.scheduler, "resilience", None)
         if kit is None:
-            return predicate(args)
+            return self.scheduler.extender.predicate(args)
         try:
             # admission-gate queueing is a named critical-path segment;
             # today's gate is non-blocking (admit-or-shed) so this is
@@ -634,7 +624,7 @@ class _Handler(BaseHTTPRequestHandler):
                         (time.perf_counter() - t_gate) * 1000.0, 4
                     )
                 with req_deadline.bind(kit.request_timeout):
-                    return predicate(args)
+                    return self.scheduler.extender.predicate(args)
         except AdmissionShed:
             span = tracing.current_span()
             if span is not None:

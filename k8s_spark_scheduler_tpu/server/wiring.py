@@ -78,7 +78,6 @@ class Server:
     ha: object = None  # HAFabric (ha/__init__.py)
     lifecycle: object = None  # LifecycleLedger (lifecycle/ledger.py)
     slo: object = None  # SloEngine (lifecycle/slo.py)
-    concurrent: object = None  # ConcurrentAdmissionEngine (concurrent/engine.py)
 
     def start_background(self) -> None:
         """Start async writers + periodic loops (cmd/server.go:221-230)."""
@@ -596,24 +595,6 @@ def init_server_with_clients(
             metrics=metrics,
             renew_interval_seconds=install.ha.renew_interval_seconds,
             writer=gate,
-        )
-
-    # concurrent admission engine (concurrent/): speculative solves in
-    # parallel, commits through the FIFO gate.  Built AFTER the HA block
-    # so multi-active intents are stamped with the live fencing epoch;
-    # before the invariants wrapper is fine — commits run the serial
-    # extender, so the wrapped _predicate_locked still fires per commit.
-    if install.concurrent.enabled:
-        from ..concurrent import ConcurrentAdmissionEngine
-
-        epoch_source = None
-        if server.ha is not None:
-            epoch_source = server.ha.fence.epoch
-        server.concurrent = ConcurrentAdmissionEngine(
-            extender,
-            install.concurrent,
-            metrics=metrics,
-            epoch_source=epoch_source,
         )
 
     from ..scheduler import invariants

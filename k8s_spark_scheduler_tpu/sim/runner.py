@@ -151,14 +151,13 @@ class Simulation:
         # chaos tests assert zero reports after the run
         racecheck.enable_if_env()
         extra_install = None
-        if sc.policy or sc.ha or sc.concurrent or sc.classes:
-            # thread the scenario's policy/ha/concurrent blocks into the
+        if sc.policy or sc.ha or sc.classes:
+            # thread the scenario's policy/ha/classes blocks into the
             # REAL wiring: the harness builds the same Install it would
-            # by default, plus the policy engine / HA fabric /
-            # concurrent admission engine (server/wiring.py)
+            # by default, plus the policy engine / HA fabric
+            # (server/wiring.py)
             from ..config import (
                 ClassesConfig,
-                ConcurrentConfig,
                 FifoConfig,
                 HAConfig,
                 Install,
@@ -177,11 +176,6 @@ class Simulation:
                 ha_cfg.enabled = True
                 ha_cfg.background = False
                 kwargs["ha"] = ha_cfg
-            if sc.concurrent:
-                conc_cfg = ConcurrentConfig.from_dict(sc.concurrent)
-                # presence of the block is the opt-in, mirroring ha
-                conc_cfg.enabled = True
-                kwargs["concurrent"] = conc_cfg
             if sc.classes:
                 kwargs["classes"] = ClassesConfig.from_dict(sc.classes)
             extra_install = Install(

@@ -112,8 +112,7 @@ class ScenarioError(ValueError):
 _SCENARIO_KEYS = {
     "name", "seed", "duration", "retry_interval", "binpack_algo",
     "fifo", "cluster", "workload", "autoscaler", "faults",
-    "unschedulable_scan_interval", "policy", "ha", "concurrent",
-    "classes",
+    "unschedulable_scan_interval", "policy", "ha", "classes",
 }
 _CLUSTER_KEYS = {"nodes", "cpu", "memory", "gpu", "zones", "instance_group"}
 _AUTOSCALER_KEYS = {
@@ -271,12 +270,6 @@ class Scenario:
     # no fabric.  background is forced off — the sim steps elections
     # on the virtual clock
     ha: Dict = field(default_factory=dict)
-    # Install.concurrent overrides (kebab-case,
-    # ConcurrentConfig.from_dict); empty = serial admission.  When
-    # enabled, every sim Filter routes through the concurrent engine's
-    # speculate→FIFO-commit path — decisions must stay byte-identical
-    # to the serial run of the same scenario
-    concurrent: Dict = field(default_factory=dict)
     # Install.classes overrides (kebab-case, ClassesConfig.from_dict);
     # empty = the Install defaults (enabled, min-nodes 20000).  Set
     # {"enabled": true, "min-nodes": 0} to force class-compressed
@@ -309,7 +302,7 @@ class Scenario:
         faults_d = d.pop("faults", [])
         _validate_faults(faults_d)
         _validate_workload(d.get("workload", {}))
-        for key in ("policy", "ha", "concurrent", "classes"):
+        for key in ("policy", "ha", "classes"):
             if key in d and not isinstance(d[key], dict):
                 raise ScenarioError(
                     f"scenario.{key}: expected an object, got {type(d[key]).__name__}"

@@ -295,13 +295,17 @@ class AsyncClient:
                 attempt, refresh, kind=self._kind, metrics=self._registry
             )
         except kerrors.NotFoundError:
-            if (
+            if not obj.meta.resource_version or (
                 self._journal is not None
                 and r.key in self._journal.pending_keys()
             ):
-                # journaled replay: the object's create was collapsed
-                # into this update intent while diverted (latest-wins per
-                # key) and never landed — upsert it.  The store holds the
+                # the object's create never landed and was collapsed
+                # into this update intent — upsert it.  Either the
+                # create's first attempt failed and its retry met this
+                # update already queued (the queue keeps one pending
+                # write per key; the store's copy then carries no
+                # server resourceVersion), or it was diverted to the
+                # journal (latest-wins per key).  The store holds the
                 # full newest content; _do_create acks the pending intent
                 # (create and update share the upsert ack class).
                 self._do_create(Request(r.key, CREATE, r.retry_count))

@@ -244,12 +244,7 @@ class Harness:
             pod = self.api.create(pod)
         else:
             pod = existing.deepcopy()
-        # route through the concurrent admission engine when wired —
-        # exactly what the HTTP layer does (server/http.py), so harness
-        # scheduling exercises the same speculate→commit path
-        engine = getattr(self.server, "concurrent", None)
-        predicate = engine.predicate if engine is not None else self.extender.predicate
-        result = predicate(ExtenderArgs(pod=pod, node_names=list(node_names)))
+        result = self.extender.predicate(ExtenderArgs(pod=pod, node_names=list(node_names)))
         if result.node_names:
             bound = self.api.get(Pod.KIND, pod.namespace, pod.name)
             bound.node_name = result.node_names[0]

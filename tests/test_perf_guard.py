@@ -370,42 +370,46 @@ def test_capacity_sampler_overhead_within_budget():
     """ISSUE 7 acceptance: the capacity observatory adds ~nothing to
     the Filter path — sampling is change-triggered on a background
     thread and NEVER runs under the extender lock, so the only hot-path
-    cost is the ChangeFeed's wakeup Event.set.  Budget: enabled ≤
-    disabled × 1.05 plus absolute CI-noise slack, same pattern as the
-    resilience/provenance guards."""
+    cost is the ChangeFeed's wakeup Event.set.  Counted, not timed:
+    while Filters admit gangs (every grant moves the feed) the sampler
+    does sample, every sample runs on its own thread, none on the
+    request thread and none inside the lock."""
+    import threading
+
+    from k8s_spark_scheduler_tpu.capacity import in_predicate_lock
     from k8s_spark_scheduler_tpu.testing.harness import Harness
-    from k8s_spark_scheduler_tpu.types.extenderapi import ExtenderArgs
 
     h = Harness(binpack_algo="tpu-batch", is_fifo=True)
     try:
-        h.new_node("n1")
-        h.new_node("n2")
-        driver = h.static_allocation_spark_pods("app-cap-perf", 1)[0]
-        h.assert_success(h.schedule(driver, ["n1", "n2"]))  # creates the RR
-
-        extender = h.server.extender
+        h.new_node("n1", cpu="32", memory="32Gi")
+        h.new_node("n2", cpu="32", memory="32Gi")
         sampler = h.server.capacity
         assert sampler is not None
-        args = ExtenderArgs(pod=driver, node_names=["n1", "n2"])
-        n = 50
+        request_thread = threading.get_ident()
+        sampled = []  # (thread, thread name, inside the extender lock)
+        build = sampler._build_sample
 
-        def batch():
-            for _ in range(n):
-                extender.predicate(args)
+        def recording(snap, trigger):
+            t = threading.current_thread()
+            sampled.append((t.ident, t.name, in_predicate_lock()))
+            return build(snap, trigger)
 
-        batch()  # warm caches/jit
-        sampler.stop()
-        disabled_s = _best_of(batch)
-        sampler.start()
-        batch()  # warm with the thread alive
-        enabled_s = _best_of(batch)
+        sampler._build_sample = recording
+        before = sampler.stats()["samples"]
+        n = 8
+        for i in range(n):
+            for pod in h.static_allocation_spark_pods(f"app-cap-{i}", 1):
+                h.assert_success(h.schedule(pod, ["n1", "n2"]))
+        feed = h.server.tensor_snapshot.feed
+        assert h.wait_for_api(
+            lambda: sampler.latest() is not None and sampler.latest().seq == feed.seq
+        ), "the sampler never caught up with the feed"
 
-        budget = disabled_s * 1.05 + n * 0.5e-3  # 5% relative + 0.5ms/request
-        assert enabled_s <= budget, (
-            f"capacity sampler overhead: {enabled_s * 1e3:.2f}ms per "
-            f"{n}-request batch enabled vs {disabled_s * 1e3:.2f}ms disabled "
-            f"(budget {budget * 1e3:.2f}ms)"
-        )
+        # (a sample still in flight has been recorded, not yet counted)
+        assert len(sampled) >= sampler.stats()["samples"] - before >= 1
+        assert all(ident != request_thread for ident, _, _ in sampled), sampled
+        assert {name for _, name, _ in sampled} == {"capacity-sampler"}
+        assert not any(locked for _, _, locked in sampled)
         # and it never probed from inside the extender lock
         assert sampler.lock_violations == 0
     finally:

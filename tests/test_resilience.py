@@ -197,7 +197,10 @@ def test_update_not_found_is_not_a_breaker_signal():
             raise NotFoundError("gone: owner GC beat the update")
 
     store = ObjectStore()
-    rr = ResourceReservation(meta=ObjectMeta(name="a", namespace="d"))
+    # resource_version: the server's, folded in when the create landed
+    rr = ResourceReservation(
+        meta=ObjectMeta(name="a", namespace="d", resource_version=7)
+    )
     store.put(rr)
     breaker = CircuitBreaker(failure_threshold=1)
     journal = IntentJournal()
@@ -215,6 +218,68 @@ def test_update_not_found_is_not_a_breaker_signal():
         r = r.with_incremented_retry_count()
     assert breaker.state == "closed"
     assert journal.depth() == 0  # dropped, never journaled
+
+
+def test_create_retry_folded_into_queued_update_still_lands():
+    """The queue keeps one pending write per key.  A create whose first
+    attempt times out re-enqueues itself; if the request thread has
+    queued an update of the same key meanwhile (the gang's first
+    executor bound while the write-back worker lagged), the retry folds
+    into that update — which finds nothing to update on the server.
+    The server lacks the object because it never got there, not because
+    owner GC took it: the update must upsert, or an admitted
+    reservation is lost with no journal entry to replay it from."""
+    from k8s_spark_scheduler_tpu.kube.apiserver import APIServer
+    from k8s_spark_scheduler_tpu.kube.informer import InformerFactory
+    from k8s_spark_scheduler_tpu.state.cache import (
+        AsyncClient,
+        TypedClient,
+        WriteBackCache,
+    )
+    from k8s_spark_scheduler_tpu.state.store import ObjectStore, ShardedUniqueQueue
+    from k8s_spark_scheduler_tpu.types.objects import ObjectMeta, ResourceReservation
+
+    api = APIServer()
+    timed_out = []
+
+    def first_create_times_out(op, kind, ns, name):
+        if op == "create" and not timed_out:
+            timed_out.append(name)
+            return APIError("client timeout")
+        return None
+
+    api.set_write_fault(first_create_times_out)
+    queue, store = ShardedUniqueQueue(1), ObjectStore()
+    cache = WriteBackCache(
+        queue, store, InformerFactory(api).informer(ResourceReservation.KIND)
+    )
+    journal = IntentJournal()
+    client = AsyncClient(
+        TypedClient(api, ResourceReservation.KIND),
+        queue,
+        store,
+        breaker=CircuitBreaker(),
+        journal=journal,
+        kind=ResourceReservation.KIND,
+    )
+    (shard,) = queue.get_consumers()
+
+    rr = ResourceReservation(meta=ObjectMeta(name="app-a", namespace="d"))
+    cache.create(rr)
+    create = shard.get_nowait()()  # the worker takes the create ...
+    bound = rr.deepcopy()
+    bound.status.pods["executor-1"] = "app-a-exec-1"
+    cache.update(bound)  # ... and lags: the executor's bind is queued
+    client._do_create(create)  # times out; its retry meets the update
+    assert timed_out == ["app-a"] and shard.qsize() == 1
+    update = shard.get_nowait()()
+    assert update.type == "update"
+    client._do_update(update)
+
+    landed = api.get(ResourceReservation.KIND, "d", "app-a")
+    assert landed.status.pods == {"executor-1": "app-a-exec-1"}
+    assert shard.qsize() == 0 and journal.depth() == 0
+    assert store.get(("d", "app-a")).meta.resource_version == landed.meta.resource_version
 
 
 # -- intent journal -----------------------------------------------------------

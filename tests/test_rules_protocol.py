@@ -1,15 +1,14 @@
 """PC protocol rules: broken-twin fixtures, fixed-twin counterparts,
 and runtime regressions for the true positives the pass surfaced.
 
-Each PC001–PC006 rule must catch its deliberately broken twin of real
+Each PC003–PC006 rule must catch its deliberately broken twin of real
 code at a *pinned* file:line (the fixtures under
 ``tests/fixtures/protocol/``), while the corrected shape — the one now
-living in the package — stays clean.  The three real findings the first
-run produced (ticket leak in ``ConcurrentAdmissionEngine.predicate`` /
-``make_intent`` when ``finish`` raises, the unfenced eviction replay in
-``PreemptionCoordinator.recover``) get behavioral regression tests
+living in the package — stays clean.  The real finding the first run
+produced (the unfenced eviction replay in
+``PreemptionCoordinator.recover``) gets a behavioral regression test
 here; the package-wide ``--strict`` self-check in ``test_schedlint.py``
-keeps them fixed statically.
+keeps it fixed statically.
 """
 
 import os
@@ -17,14 +16,11 @@ import os
 import pytest
 
 from k8s_spark_scheduler_tpu.analysis import AnalysisConfig, analyze_paths
-from k8s_spark_scheduler_tpu.concurrent.engine import ConcurrentAdmissionEngine
-from k8s_spark_scheduler_tpu.config import ConcurrentConfig
 from k8s_spark_scheduler_tpu.ha.fencing import (
     FencedWriter,
     FenceState,
     StaleEpochError,
 )
-from k8s_spark_scheduler_tpu.metrics.registry import MetricsRegistry
 from k8s_spark_scheduler_tpu.policy.preempt import EVICT_KIND, PreemptionCoordinator
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures", "protocol")
@@ -44,22 +40,6 @@ def _analyze_snippet(tmp_path, source):
 
 
 # -- the seeded broken twins, pinned file:line --------------------------------
-
-
-def test_pc001_catches_ticket_leak_twin():
-    findings = _analyze_fixture("broken_ticket_leak.py")
-    assert [(f.rule, f.file, f.line, f.symbol) for f in findings] == [
-        ("PC001", "broken_ticket_leak.py", 8, "BrokenPredicate.predicate"),
-    ]
-    assert "exception path" in findings[0].message
-
-
-def test_pc002_catches_double_retire_twin():
-    findings = _analyze_fixture("broken_double_retire.py")
-    assert [(f.rule, f.file, f.line, f.symbol) for f in findings] == [
-        ("PC002", "broken_double_retire.py", 15, "BrokenRequest.request"),
-    ]
-    assert "already be retired" in findings[0].message
 
 
 def test_pc003_catches_unfenced_write_twin():
@@ -100,37 +80,6 @@ def test_pc006_catches_phase_skip_twin():
 
 # -- the fixed shapes stay clean ----------------------------------------------
 
-
-FIXED_PREDICATE = """\
-class Engine:
-    def predicate(self, args):
-        ticket = self.gate.ticket()
-        committed = False
-        try:
-            verdict = self.speculator.speculate(ticket, args)
-            result = self.commit(args, verdict)
-            committed = True
-            return result
-        finally:
-            try:
-                self.speculator.finish(ticket)
-            finally:
-                self.gate.retire(ticket, committed)
-"""
-
-FIXED_REQUEST = """\
-class Request:
-    def request(self, st, abort):
-        ticket = st.gate.ticket()
-        committed = False
-        try:
-            if abort:
-                return
-            st.gate.await_turn(ticket)
-            committed = True
-        finally:
-            st.gate.retire(ticket, committed)
-"""
 
 FIXED_RECOVER = """\
 # schedlint: entrypoints=Coordinator.recover
@@ -186,14 +135,12 @@ class Extender:
 @pytest.mark.parametrize(
     "source",
     [
-        FIXED_PREDICATE,
-        FIXED_REQUEST,
         FIXED_RECOVER,
         FIXED_WORKER,
         FIXED_HANDLER,
         FIXED_PHASES,
     ],
-    ids=["predicate", "request", "recover", "worker", "handler", "phases"],
+    ids=["recover", "worker", "handler", "phases"],
 )
 def test_fixed_twin_is_clean(tmp_path, source):
     assert _analyze_snippet(tmp_path, source) == []
@@ -230,53 +177,7 @@ def test_pc004_allows_moot_acks_in_replay(tmp_path):
     assert _analyze_snippet(tmp_path, MOOT_ACK) == []
 
 
-# -- runtime regressions for the real findings --------------------------------
-
-
-class _StubExtender:
-    def predicate(self, args):
-        return {"ok": True, "args": args}
-
-    def _fail_with_message(self, kind, args, msg):  # pragma: no cover
-        return {"ok": False, "msg": msg}
-
-
-def _engine():
-    return ConcurrentAdmissionEngine(
-        _StubExtender(),
-        ConcurrentConfig(enabled=True, speculation=False),
-        metrics=MetricsRegistry(),
-    )
-
-
-def test_predicate_retires_ticket_even_when_finish_raises():
-    """The PC001 finding made real: `speculator.finish` raising inside
-    the finally must not skip the retire — a skipped retire stalls the
-    FIFO head forever."""
-    engine = _engine()
-
-    def exploding_finish(ticket):
-        raise RuntimeError("finish blew up")
-
-    engine.speculator.finish = exploding_finish
-    with pytest.raises(RuntimeError, match="finish blew up"):
-        engine.predicate(object())
-    # the ticket retired anyway: the head advanced and nothing is
-    # outstanding, so the next request commits immediately
-    assert engine.gate.depth() == 0
-    assert engine.gate.stats()["committed"] == 1
-
-
-def test_make_intent_retires_ticket_even_when_finish_raises():
-    engine = _engine()
-
-    def exploding_finish(ticket):
-        raise RuntimeError("finish blew up")
-
-    engine.speculator.finish = exploding_finish
-    with pytest.raises(RuntimeError, match="finish blew up"):
-        engine.make_intent(object())
-    assert engine.gate.depth() == 0
+# -- runtime regression for the real finding --------------------------------
 
 
 class _RecordingApi:

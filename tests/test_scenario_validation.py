@@ -134,6 +134,18 @@ def test_actionable_validation_errors(mutate, fragment):
     assert fragment in str(exc.value), str(exc.value)
 
 
+def test_scenario_with_concurrent_key_is_rejected():
+    """The ``concurrent`` block went with the engine it configured: a
+    scenario that still carries it fails up front like any unknown key,
+    instead of running with the block silently ignored."""
+    d = _base()
+    d["concurrent"] = {"enabled": True}
+    with pytest.raises(ScenarioError) as exc:
+        Scenario.from_dict(d)
+    assert "scenario: unknown keys ['concurrent']" in str(exc.value)
+    assert "'concurrent'" not in str(exc.value).split("(known:")[1]
+
+
 def test_non_dict_scenario():
     with pytest.raises(ScenarioError, match="scenario: expected an object, got list"):
         Scenario.from_dict([])
