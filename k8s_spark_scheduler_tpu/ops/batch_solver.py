@@ -68,6 +68,18 @@ def node_capacity(avail: jnp.ndarray, executor: jnp.ndarray, k: jnp.ndarray) -> 
     return jnp.clip(cap, 0, k)
 
 
+def _driver_candidates(avail, driver_rank, exec_ok, driver, executor, k):
+    """What a gang decision reads of each node: whether the driver fits
+    it, and its executor capacity without and with the driver on it."""
+    # driver fit mask (Resources.GreaterThan: any-dim; fits = all dims ≤)
+    driver_fits = jnp.all(avail >= driver[None, :], axis=1) & (driver_rank < BIG)
+    base_cap = jnp.where(exec_ok, node_capacity(avail, executor, k), 0)
+    cap_with_driver = jnp.where(
+        exec_ok, node_capacity(avail - driver[None, :], executor, k), 0
+    )
+    return driver_fits, base_cap, cap_with_driver
+
+
 def solve_app(
     avail: jnp.ndarray,        # [N, 3] int32
     driver_rank: jnp.ndarray,  # [N] int32 — driver priority position, BIG if not a candidate
@@ -78,14 +90,8 @@ def solve_app(
 ) -> AppSolve:
     """One gang decision, O(N) vector ops."""
     n = avail.shape[0]
-
-    # driver fit mask (Resources.GreaterThan: any-dim; fits = all dims ≤)
-    driver_fits = jnp.all(avail >= driver[None, :], axis=1) & (driver_rank < BIG)
-
-    # capacities without / with the driver on the node
-    base_cap = jnp.where(exec_ok, node_capacity(avail, executor, k), 0)
-    cap_with_driver = jnp.where(
-        exec_ok, node_capacity(avail - driver[None, :], executor, k), 0
+    driver_fits, base_cap, cap_with_driver = _driver_candidates(
+        avail, driver_rank, exec_ok, driver, executor, k
     )
 
     total = jnp.sum(base_cap)
@@ -421,8 +427,54 @@ def solve_single(
     executor: jnp.ndarray,
     k: jnp.ndarray,
 ) -> AppSolve:
-    """Single-app entry point for the Filter hot path."""
+    """Single-app entry point (ops/batch_adapter.py: the policies' own
+    ``binpack_func``)."""
     return solve_app(avail, driver_rank, exec_ok, driver, executor, k)
+
+
+# app rows of one ``feasible_apps`` program, whatever the node bucket: a
+# larger batch goes through the same program in blocks, so one compile
+# per node bucket is everything a scan of the marker runs
+VERDICT_ROWS = 1024
+
+
+@jax.jit
+def feasible_apps(
+    node_cols: jnp.ndarray,  # [N, 6] int32: availability (3), driver rank, executor ok, group
+    app_cols: jnp.ndarray,   # [VERDICT_ROWS, 8] int32: driver (3), executor (3), count, valid
+) -> jnp.ndarray:
+    """Whether each app's gang fits the SAME availability: ``solve_app``'s
+    feasibility rule (some node the driver fits whose group holds ``k``
+    executors with the driver on it) for every app row, with no carry
+    from one row to the next.  ``group`` is 0 on every node under the
+    plain policies; under single-AZ it is the node's candidate zone, -1
+    outside them, and the gang has to fit one zone whole: ``solve_zones``'
+    masked per-zone solves, any zone, from one evaluation of the
+    capacities.  int32 [VERDICT_ROWS], 1 = feasible; padding rows are the
+    caller's to drop.  A row is one step of a scan over O(N) vectors:
+    nothing of size [apps, N] exists, and the program compiles as fast
+    as ``solve_single`` (a vmapped app axis takes the TPU compiler
+    minutes at 10,240 nodes)."""
+    avail, driver_rank = node_cols[:, 0:3], node_cols[:, 3]
+    exec_ok, group = node_cols[:, 4] != 0, node_cols[:, 5]
+    n_groups = jnp.max(group) + 1
+
+    def one_app(app):
+        driver, k = app[0:3], app[6]
+        driver_fits, base_cap, cap_with_driver = _driver_candidates(
+            avail, driver_rank, exec_ok, driver, app[3:6], k
+        )
+
+        def add_group(z, total):
+            member = group == z
+            return jnp.where(member, jnp.sum(jnp.where(member, base_cap, 0)), total)
+
+        # per node, the total capacity of its own group
+        total = lax.fori_loop(0, n_groups, add_group, jnp.zeros_like(base_cap))
+        total_d = total - base_cap + cap_with_driver
+        return jnp.any(driver_fits & (group >= 0) & (total_d >= k))
+
+    return lax.map(one_app, app_cols).astype(jnp.int32)
 
 
 # ``valid`` column of ``solve_filter``'s app block: a queue app, the
@@ -816,6 +868,7 @@ def compilation_cache_stats() -> dict:
         ("solve_queue", solve_queue),
         ("solve_queue_min_frag", solve_queue_min_frag),
         ("solve_single", solve_single),
+        ("feasible_apps", feasible_apps),
         ("solve_filter", solve_filter),
         ("solve_queue_single_az", solve_queue_single_az),
         ("solve_zones", solve_zones_jit),

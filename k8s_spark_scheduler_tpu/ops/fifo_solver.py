@@ -33,7 +33,14 @@ from .efficiency import compute_packing_efficiencies
 from .packers import PackingResult, empty_packing_result
 from .sparkapp import AppDemand
 from .tensorize import _resources_to_base as _res_rows
-from .tensorize import INT32_SAFE, scale_problem, tensorize_apps, tensorize_cluster
+from .tensorize import (
+    INT32_SAFE,
+    AppTensor,
+    _app_base_rows,
+    scale_problem,
+    tensorize_apps,
+    tensorize_cluster,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -250,8 +257,6 @@ def _tensorize_with_cache(solver, earlier, current_app):
     """AppTensor for earlier + [current]: the earlier block is
     cached by object identity (see TpuFifoSolver._earlier_tensor_cache) and the
     current app's rows are appended."""
-    from .tensorize import AppTensor, _app_base_rows
-
     key = tuple(map(id, earlier))
     cached = solver._earlier_tensor_cache
     if cached is not None and cached[0] == key:
@@ -279,23 +284,40 @@ def _tensorize_with_cache(solver, earlier, current_app):
     )
 
 
+def _node_block(problem, *more) -> np.ndarray:
+    """The node side of a scaled problem as one int32 block: availability
+    (3 columns), driver rank, executor ok, then each [N] column of ``more``."""
+    node_cols = np.empty((problem.avail.shape[0], 5 + len(more)), np.int32)
+    node_cols[:, 0:3] = problem.avail
+    node_cols[:, 3] = problem.driver_rank
+    node_cols[:, 4] = problem.exec_ok
+    for i, column in enumerate(more):
+        node_cols[:, 5 + i] = column
+    return node_cols
+
+
+def _app_block(problem) -> np.ndarray:
+    """The app side as one int32 block [A, 8]: driver (3), executor (3),
+    count, valid."""
+    app_cols = np.empty((problem.count.shape[0], 8), np.int32)
+    app_cols[:, 0:3] = problem.driver
+    app_cols[:, 3:6] = problem.executor
+    app_cols[:, 6] = problem.count
+    app_cols[:, 7] = problem.app_valid
+    return app_cols
+
+
 def _filter_blocks(problem, n_earlier: int):
     """batch_solver.solve_filter's two inputs from a scaled problem whose
     row ``n_earlier`` is the request's own app: the node-side block
     [N, 5] and the app-side block [A, 8], int32."""
     from .batch_solver import APP_CURRENT, APP_QUEUED
 
-    node_cols = np.empty((problem.avail.shape[0], 5), np.int32)
-    node_cols[:, 0:3] = problem.avail
-    node_cols[:, 3] = problem.driver_rank
-    node_cols[:, 4] = problem.exec_ok
-    app_cols = np.zeros((problem.count.shape[0], 8), np.int32)
-    app_cols[:, 0:3] = problem.driver
-    app_cols[:, 3:6] = problem.executor
-    app_cols[:, 6] = problem.count
+    app_cols = _app_block(problem)
     app_cols[:n_earlier, 7] = np.where(problem.app_valid[:n_earlier], APP_QUEUED, 0)
+    app_cols[n_earlier:, 7] = 0
     app_cols[n_earlier, 7] = APP_CURRENT
-    return node_cols, app_cols
+    return _node_block(problem), app_cols
 
 
 def _earlier_ok(gate_span, feasible, earlier_skip_allowed) -> bool:
@@ -305,6 +327,88 @@ def _earlier_ok(gate_span, feasible, earlier_skip_allowed) -> bool:
     ok = not blocked.any()
     gate_span.tag("earlierOk", ok)
     return ok
+
+
+def _feasible_batch(solver, cluster, apps: Sequence[AppDemand], span) -> List[Optional[bool]]:
+    """``feasible_batch`` of both solvers: every app's verdict against
+    the same ``cluster``, as one problem.  The apps are tensorized once
+    and scaled once with the cluster (one GCD over them all); a device
+    lane then pays one round for the batch (the node block and the app
+    block up, ``batch_solver.feasible_apps``, [A] int32 down; a batch
+    over VERDICT_ROWS goes through in blocks of the one program), the
+    native lane loops over the rows on the host.  What the solvers
+    differ in is the node's group (``_verdict_groups``) and the host's
+    loop (``_host_verdicts``).
+
+    An app without exact base units gets None (the caller's host path)
+    and spoils no other verdict; where the joint problem still does not
+    scale (an int32 bound that a GCD over many apps misses), each app
+    is scaled alone, and None is what still fails.  ``span`` takes the
+    rounds' ``arrays`` and ``bytes`` as tags, summed over its phases."""
+    verdicts: List[Optional[bool]] = [None] * len(apps)
+    if not apps or not cluster.exact:
+        return verdicts
+    tensor = tensorize_apps(apps)
+    rows = np.arange(len(apps))
+    if not tensor.exact:
+        rows = rows[[_app_base_rows(app)[2] for app in apps]]
+    native = solver._use_native()
+
+    def judge(rows) -> bool:
+        bucket = None
+        if not native:
+            from .batch_solver import VERDICT_ROWS
+
+            bucket = -(-len(rows) // VERDICT_ROWS) * VERDICT_ROWS
+        problem = scale_problem(cluster, _take_apps(tensor, rows), app_bucket=bucket)
+        if problem.ok:
+            feasible = (
+                solver._host_verdicts(cluster, problem, len(rows))
+                if native
+                else _device_verdicts(solver, cluster, problem, len(rows), span)
+            )
+            for i, fits in zip(rows, feasible):
+                verdicts[i] = bool(fits)
+        return problem.ok
+
+    if len(rows) and not judge(rows) and len(rows) > 1:
+        for i in rows:
+            judge([i])
+    return verdicts
+
+
+def _take_apps(apps: AppTensor, rows) -> AppTensor:
+    return AppTensor(
+        driver=apps.driver[rows],
+        executor=apps.executor[rows],
+        count=apps.count[rows],
+        valid=apps.valid[rows],
+        exact=True,
+    )
+
+
+def _device_verdicts(solver, cluster, problem, n_apps: int, span) -> np.ndarray:
+    """One device round for the problem's first ``n_apps`` verdicts (one
+    more launch and read-back per further block of VERDICT_ROWS apps)."""
+    from .batch_solver import VERDICT_ROWS, feasible_apps
+
+    app_cols = _app_block(problem)
+    nodes_dev, *blocks_dev = _upload(
+        _node_block(problem, solver._verdict_groups(cluster, problem)),
+        *(app_cols[i : i + VERDICT_ROWS] for i in range(0, len(app_cols), VERDICT_ROWS)),
+    )
+    outs = []
+    for block_dev in blocks_dev:
+        with default_profiler.profile("feasible_apps", lane="xla", fn=feasible_apps) as rec:
+            outs.append(feasible_apps(nodes_dev, block_dev))
+            rec.sync(outs[-1])
+    feasible = np.concatenate([_readback(out) for out in outs])
+    span.tag("arrays", span.tags.get("arrays", 0) + 1 + 2 * len(outs))
+    span.tag(
+        "bytes",
+        span.tags.get("bytes", 0) + nodes_dev.nbytes + app_cols.nbytes + feasible.nbytes,
+    )
+    return feasible[:n_apps] != 0
 
 
 @dataclass
@@ -389,41 +493,36 @@ class TpuFifoSolver:
     def _tensorize_with_cache(self, earlier, current_app):
         return _tensorize_with_cache(self, earlier, current_app)
 
+    def feasible_batch(
+        self, cluster, apps: Sequence[AppDemand], span=tracing.NOOP_SPAN
+    ) -> List[Optional[bool]]:
+        """Feasibility of each app against one prebuilt ClusterTensor, as
+        one batch (``_feasible_batch``), with no placement decode and no
+        efficiency math: the unschedulable-marker's empty-cluster
+        verdicts.  Feasibility is policy-invariant across
+        tightly/evenly/min-frag (the work-conserving drain rule,
+        batch_solver docstring), identical to binpack_func's
+        has_capacity.  None = not exactly tensorizable (caller uses the
+        host path)."""
+        return _feasible_batch(self, cluster, apps, span)
+
     def feasible_tensor(self, cluster, app: AppDemand) -> Optional[bool]:
-        """Feasibility of one app against a prebuilt ClusterTensor with
-        no placement decode and no efficiency math — the
-        unschedulable-marker's empty-cluster verdict (its scan runs
-        every interval over the whole pending backlog, so the full
-        solve_tensor cost per pod was pure waste).  Feasibility is
-        policy-invariant across tightly/evenly/min-frag (the
-        work-conserving drain rule, batch_solver docstring), identical
-        to binpack_func's has_capacity.  None = not exactly
-        tensorizable (caller uses the host path)."""
-        apps = tensorize_apps([app])
-        problem = scale_problem(cluster, apps)
-        if not problem.ok:
-            return None
-        if self._use_native():
-            from ..native.fifo import solve_app_native
+        """The batch of one."""
+        return self.feasible_batch(cluster, [app])[0]
 
-            feas, _, _, _ = solve_app_native(
+    def _verdict_groups(self, cluster, problem):
+        return 0  # every node in the one group: the gang may spread over them all
+
+    def _host_verdicts(self, cluster, problem, n_apps: int):
+        from ..native.fifo import solve_app_native
+
+        return [
+            solve_app_native(
                 problem.avail, problem.driver_rank, problem.exec_ok,
-                problem.driver[0], problem.executor[0], int(problem.count[0]),
-            )
-            return bool(feas)
-        import jax.numpy as jnp
-
-        from .batch_solver import solve_single
-
-        solve = solve_single(
-            jnp.asarray(problem.avail),
-            jnp.asarray(problem.driver_rank),
-            jnp.asarray(problem.exec_ok),
-            jnp.asarray(problem.driver[0]),
-            jnp.asarray(problem.executor[0]),
-            jnp.asarray(problem.count[0]),
-        )
-        return bool(solve.feasible)
+                problem.driver[i], problem.executor[i], int(problem.count[i]),
+            )[0]
+            for i in range(n_apps)
+        ]
 
     def solve_tensor(
         self,
@@ -1121,6 +1220,9 @@ class TpuSingleAzFifoSolver:
     def _use_pallas(self) -> bool:
         return _pallas_selected(self.backend)
 
+    def _use_native(self) -> bool:
+        return not self._use_pallas() and _native_selected(self.backend)
+
     def solve(
         self,
         metadata: NodeGroupSchedulingMetadata,
@@ -1135,29 +1237,52 @@ class TpuSingleAzFifoSolver:
         cluster = tensorize_cluster(metadata, driver_order, executor_order)
         return self.solve_tensor(cluster, earlier_apps, earlier_skip_allowed, current_app)
 
+    def feasible_batch(
+        self, cluster, apps: Sequence[AppDemand], span=tracing.NOOP_SPAN
+    ) -> List[Optional[bool]]:
+        """Whether some zone takes each gang on ``cluster``, as one batch
+        (``_feasible_batch``): the unschedulable-marker's empty-cluster
+        verdicts, equal to binpack_func's has_capacity: zone feasibility
+        is the inner policies' shared tightly-pack feasibility (integers
+        only: the float64 score chooses which zone, never whether), and
+        a feasible packing reserves something (the driver asks for more
+        than nothing), so the all-zero-efficiency quirk cannot turn the
+        verdict.  None = not exactly tensorizable (caller uses the host
+        path)."""
+        return _feasible_batch(self, cluster, apps, span)
+
     def feasible_tensor(self, cluster, app: AppDemand) -> Optional[bool]:
-        """Whether some zone takes the gang on ``cluster`` — the
-        unschedulable-marker's empty-cluster verdict, equal to
-        binpack_func's has_capacity: zone feasibility is the inner
-        policies' shared tightly-pack feasibility, and a feasible packing
-        reserves something (the driver asks for more than nothing), so
-        the all-zero-efficiency quirk cannot turn the verdict.  None =
-        not exactly tensorizable (caller uses the host path)."""
-        problem = scale_problem(cluster, tensorize_apps([app]))
-        if not problem.ok:
-            return None
+        """The batch of one."""
+        return self.feasible_batch(cluster, [app])[0]
+
+    def _verdict_groups(self, cluster, problem):
+        """Per node the candidate zone a gang on it has to fit whole, -1
+        outside the candidates; the whole cluster as one group where the
+        policy falls back across zones."""
+        if self.az_aware:
+            return 0
+        return _ZoneProblem(
+            cluster, problem, False, "tightly-pack", self.strict_reference_parity
+        ).zone_vec
+
+    def _host_verdicts(self, cluster, problem, n_apps: int):
+        """The zones' tightly-pack solves in numpy, as the host decides a
+        zone: one ``_ZoneProblem`` and each zone's rows gathered once for
+        the batch."""
         zones = _ZoneProblem(
             cluster, problem, self.az_aware, "tightly-pack", self.strict_reference_parity
         )
-        n = zones.n
-        driver, executor, k = problem.driver[0], problem.executor[0], int(problem.count[0])
-        groups = [slice(0, n)] if self.az_aware else zones.zone_rows
-        return any(
-            _host_gang_solve(
-                problem.avail[rows], zones.rank[rows], zones.exec_ok[rows], driver, executor, k
-            ) is not None
-            for rows in groups
-        )
+        groups = [slice(0, zones.n)] if self.az_aware else zones.zone_rows
+        parts = [(problem.avail[rows], zones.rank[rows], zones.exec_ok[rows]) for rows in groups]
+        return [
+            any(
+                _host_gang_solve(
+                    *part, problem.driver[i], problem.executor[i], int(problem.count[i])
+                ) is not None
+                for part in parts
+            )
+            for i in range(n_apps)
+        ]
 
     def solve_tensor(
         self,
@@ -1251,7 +1376,7 @@ class TpuSingleAzFifoSolver:
         # min-frag inner: every fast lane runs the drain with the int32
         # MF_SENT sentinel, so the collision guard gates them all
         fast_ok = not zones.minfrag or mf_sentinel_safe(problem.avail)
-        if fast_ok and not self._use_pallas() and _native_selected(self.backend):
+        if fast_ok and self._use_native():
             from ..native.fifo import solve_queue_single_az_native
 
             self.last_path = self.last_queue_lane = "native"

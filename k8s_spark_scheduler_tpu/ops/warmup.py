@@ -2,7 +2,7 @@
 
 The warm-up drives a private instance of the configured policy's queue
 solver over synthetic problems of each wanted shape, through the same
-``solve_tensor`` entry the extender calls and the ``feasible_tensor``
+``solve_tensor`` entry the extender calls and the ``feasible_batch``
 entry the unschedulable-pod marker calls.  Whatever that policy dispatches on
 this platform — Pallas queue kernel on a TPU, XLA zone solves on a CPU
 host, the native C++ lane — is therefore what gets compiled; no second
@@ -94,9 +94,11 @@ def warm_queue_solver(
                 "not reach the queue solve (synthetic problem refused)"
             )
         # the unschedulable-pod marker's verdicts are a program of their
-        # own (solve_single) at the cluster's shape: its first scan
-        # begins a minute after start, among the requests
-        if solver.feasible_tensor(cluster, AppDemand(one, one, 1)) is None:
+        # own (batch_solver.feasible_apps: one app shape per node bucket,
+        # so a batch of one compiles what a scan of any backlog runs) at
+        # the cluster's shape: its first scan begins a minute after
+        # start, among the requests
+        if solver.feasible_batch(cluster, [AppDemand(one, one, 1)])[0] is None:
             raise RuntimeError(
                 f"solver warm-up for {binpack_algo} at {n_nodes} nodes did not "
                 "reach the feasibility solve (synthetic problem refused)"

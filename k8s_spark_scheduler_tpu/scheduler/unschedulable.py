@@ -6,6 +6,12 @@ whether the gang could fit an *otherwise-empty* cluster (zero usage, but
 still subtracting non-schedulable overhead — daemonset pods etc.,
 unschedulablepods.go:149-151).  Sets/clears the
 ``PodExceedsClusterCapacity`` pod condition.
+
+A scan is two passes: collect the aged drivers, then judge them in one
+batch per affinity signature (every verdict of a signature is against
+the same empty cluster, so a policy with a tensor solver answers them
+in one ``feasible_batch`` call: one device round on a TPU host), then
+mark them in the order found.
 """
 
 from __future__ import annotations
@@ -25,7 +31,7 @@ from ..types.objects import Pod, PodCondition
 from ..types.resources import Resources, node_scheduling_metadata_for_nodes
 from . import labels as L
 from .overhead import OverheadComputer
-from .sparkpods import AnnotationError, spark_resources
+from .sparkpods import AnnotationError, spark_app_demand_cached
 
 logger = logging.getLogger(__name__)
 
@@ -78,22 +84,26 @@ class UnschedulablePodMarker:
                 logger.exception("unschedulable pod scan failed")
 
     def scan_for_unschedulable_pods(self) -> None:
-        """unschedulablepods.go:93-129.
+        """unschedulablepods.go:93-129, in two passes.
 
-        A deep pending backlog shares a handful of affinity shapes and
-        app sizes, and the verdict is a pure function of (eligible node
-        set, zero-usage metadata, app resource triple) — so the scan
-        memoizes the empty-cluster metadata per affinity signature and
-        the binpack verdict per (signature, app triple) within one
-        sweep.  Without this, a 1k-deep backlog rebuilt 10k-node
-        Quantity metadata and ran a full pack PER POD every interval
-        (tens of seconds of CPU that, on a small host, came straight
-        out of live Filter latency).
+        The verdict is a pure function of (eligible node set, zero-usage
+        metadata, app resource triple), and a deep pending backlog
+        shares a handful of affinity shapes.  So the scan first walks
+        the pods and collects each aged driver with its verdict key;
+        then builds the empty-cluster metadata and tensor once per
+        affinity signature and asks the solver ONCE for the verdicts of
+        all the signature's distinct keys (``feasible_batch``: one
+        device round on a TPU host, where a verdict per pod was an
+        upload, a launch and a blocking read each, thousands of times
+        against the request thread); then marks the pods in the order
+        it found them, yielding between them.  The conditions written
+        and their order are those of judging and marking pod by pod.
 
-        One root span ``unschedulable.scan`` per scan; its solves,
-        metadata builds and condition writes are three aggregate
-        children (``scan.solve``, ``scan.metadata``, ``scan.mark``), the
-        yields between pods stay in the root's self time."""
+        One root span ``unschedulable.scan`` per scan; its verdict
+        batches, metadata builds and condition writes are three
+        aggregate children (``scan.solve``, one phase per signature;
+        ``scan.metadata``; ``scan.mark``), the walk and the yields
+        between pods stay in the root's self time."""
         span = (
             self._tracer.span("unschedulable.scan")
             if self._tracer is not None
@@ -109,9 +119,9 @@ class UnschedulablePodMarker:
 
     def _scan(self, span) -> None:
         now = timesource.now()
-        meta_cache: dict = {}
-        verdict_cache: dict = {}
-        pods = writes = 0
+        aged = []  # (pod, its verdict key, its demand), in list order
+        verdicts: dict = {}
+        pods = signatures = batches = writes = 0
         try:
             for pod in self._pod_informer.list():
                 if (
@@ -123,21 +133,27 @@ class UnschedulablePodMarker:
                 ):
                     pods += 1
                     try:
-                        exceeds = self._pod_exceeds_cached(pod, meta_cache, verdict_cache)
+                        aged.append((pod, *self._verdict_key(pod)))
                     except AnnotationError:
+                        # the pods before it are judged and marked, the
+                        # scan then ends
                         logger.exception("failed to check if pod was unschedulable")
-                        return
-                    if exceeds:
-                        logger.info("marking pod %s as exceeds capacity", pod.name)
-                    writes += self._mark_pod_cluster_capacity_status(pod, exceeds)
-                    # yield between pods: the scan is a background janitor —
-                    # a deep backlog must not monopolize a small host's core
-                    # against live Filter requests for seconds at a stretch
-                    time.sleep(0.0005)
+                        break
+            verdicts, signatures, batches = self._judge(aged)
+            for pod, key, _ in aged:
+                exceeds = verdicts[key]
+                if exceeds:
+                    logger.info("marking pod %s as exceeds capacity", pod.name)
+                writes += self._mark_pod_cluster_capacity_status(pod, exceeds)
+                # yield between pods: the scan is a background janitor —
+                # a deep backlog must not monopolize a small host's core
+                # against live Filter requests for seconds at a stretch
+                time.sleep(0.0005)
         finally:
             span.tag("pods", pods)
-            span.tag("verdictMisses", len(verdict_cache))
-            span.tag("signatures", len(meta_cache))
+            span.tag("verdictMisses", len(verdicts))
+            span.tag("verdictBatches", batches)
+            span.tag("signatures", signatures)
             span.tag("conditionWrites", writes)
 
     @staticmethod
@@ -153,94 +169,104 @@ class UnschedulablePodMarker:
             ),
         )
 
-    def _pod_exceeds_cached(self, driver: Pod, meta_cache: dict, verdict_cache: dict) -> bool:
-        sig = self._affinity_sig(driver)
-        app_resources = spark_resources(driver)
-        # Quantity is hashable (exact-value eq/hash); the Resources
-        # dataclass is not, so the key carries its quantities
+    def _verdict_key(self, driver: Pod):
+        """(key, the app's demand): everything the verdict depends on.
+        Quantity is hashable (exact-value eq/hash); the Resources
+        dataclass is not, so the key carries its quantities.  The demand
+        is the one the Filter's queue pass reads for this pod version
+        (parsed and brought to base units once, sparkpods' cache)."""
+        _, demand = spark_app_demand_cached(driver)
         key = (
-            sig,
+            self._affinity_sig(driver),
             *(
                 (r.cpu, r.memory, r.nvidia_gpu)
-                for r in (
-                    app_resources.driver_resources,
-                    app_resources.executor_resources,
-                )
+                for r in (demand.driver_resources, demand.executor_resources)
             ),
-            app_resources.min_executor_count,
+            demand.min_executor_count,
         )
-        hit = verdict_cache.get(key)
-        if hit is not None:
-            return hit
-        cached = meta_cache.get(sig)
-        if cached is None:
-            with tracing.aggregate_span("scan.metadata"):
-                nodes = self._node_informer.list_with_predicate(
-                    lambda n: driver.matches_node(n)
-                )
-                node_names = [n.name for n in nodes]
-                zero_usage = {n.name: Resources.zero() for n in nodes}
-                overhead = self._overhead.get_non_schedulable_overhead(nodes)
-                # chunked: one unbroken 10k-node Quantity build holds the
-                # GIL for ~0.5-1s and was the single biggest tail spike
-                # live Filters saw from this janitor
-                metadata = {}
-                for i in range(0, len(nodes), 512):
-                    chunk = nodes[i : i + 512]
-                    metadata.update(
-                        node_scheduling_metadata_for_nodes(chunk, zero_usage, overhead)
-                    )
-                    time.sleep(0.0005)
-                cluster = None
-                solver = getattr(self._binpacker, "queue_solver", None)
-                if solver is not None and hasattr(solver, "feasible_tensor"):
-                    # the tensor is pod-independent within the signature:
-                    # build once, then each verdict is one feasibility-only
-                    # solve on the device/native lane (identical to
-                    # binpack_func's has_capacity, per the differential
-                    # suites)
-                    from ..ops.tensorize import tensorize_cluster
+        return key, demand
 
-                    cluster = tensorize_cluster(metadata, node_names, node_names)
-                cached = (node_names, metadata, cluster, solver)
-                meta_cache[sig] = cached
-        node_names, metadata, cluster, solver = cached
-        exceeds = None
-        lane = "tensor"
-        with tracing.aggregate_span("scan.solve"):
-            if cluster is not None:
-                from ..ops.sparkapp import AppDemand
+    def _empty_cluster(self, driver: Pod):
+        """(node names, zero-usage metadata, its ClusterTensor or None,
+        the tensor solver or None) of the nodes ``driver``'s affinity
+        signature admits."""
+        with tracing.aggregate_span("scan.metadata"):
+            nodes = self._node_informer.list_with_predicate(
+                lambda n: driver.matches_node(n)
+            )
+            node_names = [n.name for n in nodes]
+            zero_usage = {n.name: Resources.zero() for n in nodes}
+            overhead = self._overhead.get_non_schedulable_overhead(nodes)
+            # chunked: one unbroken 10k-node Quantity build holds the
+            # GIL for ~0.5-1s and was the single biggest tail spike
+            # live Filters saw from this janitor
+            metadata = {}
+            for i in range(0, len(nodes), 512):
+                chunk = nodes[i : i + 512]
+                metadata.update(
+                    node_scheduling_metadata_for_nodes(chunk, zero_usage, overhead)
+                )
+                time.sleep(0.0005)
+            cluster = None
+            solver = getattr(self._binpacker, "queue_solver", None)
+            if solver is not None and hasattr(solver, "feasible_batch"):
+                # the tensor is pod-independent within the signature:
+                # build once, then the signature's verdicts are one
+                # feasibility-only batch on the device/native lane
+                # (identical to binpack_func's has_capacity, per the
+                # differential suites)
+                from ..ops.tensorize import tensorize_cluster
 
-                feasible = solver.feasible_tensor(
-                    cluster,
-                    AppDemand(
-                        app_resources.driver_resources,
-                        app_resources.executor_resources,
-                        app_resources.min_executor_count,
-                    ),
-                )
-                if feasible is not None:
-                    exceeds = not feasible
-            if exceeds is None:
-                lane = "host"
-                result = self._binpacker.binpack_func(
-                    app_resources.driver_resources,
-                    app_resources.executor_resources,
-                    app_resources.min_executor_count,
-                    node_names,
-                    node_names,
-                    metadata,
-                )
-                exceeds = not result.has_capacity
-        if self._metrics is not None:
-            self._metrics.counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": lane})
-        verdict_cache[key] = exceeds
-        return exceeds
+                cluster = tensorize_cluster(metadata, node_names, node_names)
+        return node_names, metadata, cluster, solver
+
+    def _judge(self, aged):
+        """(``verdicts[key]``, True = exceeds, for every distinct key of
+        ``aged``; signatures; batches asked of the tensor solver): per
+        affinity signature, in the order first met, one empty cluster
+        and one batch of verdicts."""
+        by_signature: dict = {}
+        for pod, key, demand in aged:
+            by_signature.setdefault(key[0], {}).setdefault(key, (pod, demand))
+        verdicts: dict = {}
+        batches = 0
+        for wanted in by_signature.values():
+            first_pod = next(iter(wanted.values()))[0]
+            node_names, metadata, cluster, solver = self._empty_cluster(first_pod)
+            demands = [demand for _, demand in wanted.values()]
+            with tracing.aggregate_span("scan.solve") as phase:
+                feasible = [None] * len(demands)
+                if cluster is not None:
+                    batches += 1
+                    feasible = solver.feasible_batch(cluster, demands, span=phase)
+                for key, demand, fits in zip(wanted, demands, feasible):
+                    if fits is None:
+                        fits = self._binpacker.binpack_func(
+                            demand.driver_resources,
+                            demand.executor_resources,
+                            demand.min_executor_count,
+                            node_names,
+                            node_names,
+                            metadata,
+                        ).has_capacity
+                        # a full pack on the host holds the interpreter:
+                        # yield after each, as between marks
+                        time.sleep(0.0005)
+                    verdicts[key] = not fits
+            if self._metrics is not None:
+                on_host = feasible.count(None)
+                for lane, n in (("tensor", len(feasible) - on_host), ("host", on_host)):
+                    if n:
+                        self._metrics.counter(
+                            mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": lane}, n
+                        )
+        return verdicts, len(by_signature), batches
 
     def does_pod_exceed_cluster_capacity(self, driver: Pod) -> bool:
         """unschedulablepods.go:132-166: binpack against zero usage plus
-        non-schedulable overhead."""
-        return self._pod_exceeds_cached(driver, {}, {})
+        non-schedulable overhead.  The scan's batch of one."""
+        key, demand = self._verdict_key(driver)
+        return self._judge([(driver, key, demand)])[0][key]
 
     def _mark_pod_cluster_capacity_status(self, driver: Pod, exceeds: bool) -> bool:
         """unschedulablepods.go:168-180 (condition update only when

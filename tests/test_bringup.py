@@ -156,35 +156,54 @@ def test_warmup_compiles_through_the_configured_solver():
     assert warmup.warm_shapes(60, 3) == warmup._BASE_SHAPES
 
 
-def test_after_warmup_a_filter_and_a_marker_verdict_compile_nothing(monkeypatch):
+def test_after_warmup_a_filter_and_a_marker_scan_compile_nothing(monkeypatch):
     """The marker's first scan begins a minute after start, among the
-    requests: its ``feasible_tensor`` program (``solve_single``) has to
-    be warm like the Filter's own (``solve_filter``)."""
+    requests: its verdict program (``feasible_apps``, one app shape per
+    node bucket whatever the backlog) has to be warm like the Filter's
+    own (``solve_filter``)."""
     import random
 
     from test_batch_parity import orders_for, random_app, random_cluster
 
-    from k8s_spark_scheduler_tpu.ops import fifo_solver, warmup
-    from k8s_spark_scheduler_tpu.ops.batch_solver import solve_filter, solve_single
+    from k8s_spark_scheduler_tpu.metrics import names as mnames
+    from k8s_spark_scheduler_tpu.ops import batch_solver, fifo_solver, warmup
     from k8s_spark_scheduler_tpu.ops.registry import select_binpacker
     from k8s_spark_scheduler_tpu.ops.tensorize import tensorize_cluster
-    from k8s_spark_scheduler_tpu.tracing.profiling import jit_cache_size
+    from k8s_spark_scheduler_tpu.testing.harness import Harness
 
     # the XLA lane, as on a host with neither a TPU nor the C++ library
     monkeypatch.setattr(fifo_solver, "_native_selected", lambda backend: False)
     warmup.warm_queue_solver("tpu-batch", True, [(256, 64)])
-    warm = jit_cache_size(solve_filter), jit_cache_size(solve_single)
-    assert min(warm) >= 1
+    h = Harness(binpack_algo="tpu-batch")
+    try:
+        h.server.wait_ready(timeout=300.0)  # the server's own warm-up: the small buckets
+        stats = batch_solver.compilation_cache_stats()
+        assert stats["solve_filter"] >= 1 and stats["feasible_apps"] >= 1
 
-    rng = random.Random(30)
-    metadata = random_cluster(rng, 200)
-    cluster = tensorize_cluster(metadata, *orders_for(metadata, rng))
-    earlier = [random_app(rng) for _ in range(40)]
-    solver = select_binpacker("tpu-batch").queue_solver
-    assert solver.feasible_tensor(cluster, random_app(rng)) is not None
-    outcome = solver.solve_tensor(cluster, earlier, [True] * 40, random_app(rng))
-    assert outcome.supported and solver.last_queue_lane == "xla"
-    assert (jit_cache_size(solve_filter), jit_cache_size(solve_single)) == warm
+        rng = random.Random(30)
+        metadata = random_cluster(rng, 200)
+        cluster = tensorize_cluster(metadata, *orders_for(metadata, rng))
+        earlier = [random_app(rng) for _ in range(40)]
+        solver = select_binpacker("tpu-batch").queue_solver
+        outcome = solver.solve_tensor(cluster, earlier, [True] * 40, random_app(rng))
+        assert outcome.supported and solver.last_queue_lane == "xla"
+        # scans over clusters of two warmed node buckets: a backlog of five
+        # on six nodes, then of thirteen on a hundred and six
+        for n_nodes, n_apps in ((6, 5), (100, 8)):
+            for i in range(n_nodes):
+                h.new_node(f"n{n_nodes}-{i}")
+            for i in range(n_apps):
+                pod = h.static_allocation_spark_pods(f"app-aged-{n_nodes}-{i}", 1 + i)[0]
+                pod.meta.creation_timestamp = time.time() - 3600
+                h.create_pod(pod)
+            h.unschedulable_marker.scan_for_unschedulable_pods()
+        # distinct gangs: five in the first scan, eight in the second
+        assert h.server.metrics.get_counter(
+            mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": "tensor"}
+        ) == 5 + 8
+    finally:
+        h.close()
+    assert batch_solver.compilation_cache_stats() == stats
 
 
 def test_server_process_exits_nonzero_when_warmup_fails(tmp_path):

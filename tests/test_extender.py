@@ -420,6 +420,117 @@ def test_unschedulable_marker_clears_when_fits(harness):
     assert cond is not None and cond.status == "False"
 
 
+def per_pod_reference_scan(h, packer, timeout=600.0):
+    """The scan as unschedulablepods.go:93-129 runs it, pod by pod on the
+    host oracle: the condition writes it would make, in order."""
+    from k8s_spark_scheduler_tpu.scheduler import labels as L
+    from k8s_spark_scheduler_tpu.scheduler.sparkpods import AnnotationError, spark_resources
+    from k8s_spark_scheduler_tpu.types.resources import (
+        Resources,
+        node_scheduling_metadata_for_nodes,
+    )
+
+    writes = []
+    server = h.server
+    for pod in server.pod_informer.list():
+        if not (
+            pod.scheduler_name == L.SPARK_SCHEDULER_NAME
+            and pod.node_name == ""
+            and pod.meta.deletion_timestamp is None
+            and pod.labels.get(L.SPARK_ROLE_LABEL) == L.DRIVER
+            and pod.creation_timestamp + timeout < time.time()
+        ):
+            continue
+        try:
+            app = spark_resources(pod)
+        except AnnotationError:
+            break
+        nodes = server.node_informer.list_with_predicate(pod.matches_node)
+        names = [n.name for n in nodes]
+        metadata = node_scheduling_metadata_for_nodes(
+            nodes,
+            {n.name: Resources.zero() for n in nodes},
+            server.overhead_computer.get_non_schedulable_overhead(nodes),
+        )
+        fits = packer(
+            app.driver_resources, app.executor_resources, app.min_executor_count,
+            names, names, metadata,
+        ).has_capacity
+        status = "False" if fits else "True"
+        current = pod.conditions.get("PodExceedsClusterCapacity")
+        if current is None or current.status != status:
+            writes.append((pod.name, status))
+    return writes
+
+
+@pytest.mark.parametrize("algo,oracle", [
+    ("tightly-pack", "tightly-pack"),
+    ("tpu-batch", "tightly-pack"),
+    ("tpu-batch-minimal-fragmentation", "minimal-fragmentation"),
+    ("tpu-batch-single-az", "single-az-tightly-pack"),
+])
+def test_two_pass_scan_writes_what_a_per_pod_scan_writes_in_its_order(algo, oracle):
+    """Two affinity signatures, repeated verdict keys, a pod younger than
+    the timeout, an annotation error in mid-list (the pods before it are
+    judged and marked, those after it are not), then a second scan after
+    the cluster grew: only the verdicts that turned are written."""
+    from k8s_spark_scheduler_tpu.ops.registry import select_binpacker
+    from k8s_spark_scheduler_tpu.scheduler import labels as L
+
+    h = Harness(binpack_algo=algo)
+    try:
+        for i in range(3):
+            h.new_node(f"big-{i}", cpu="16", memory="32Gi", instance_group="big", zone=f"z{i % 2}")
+        h.new_node("small-0", cpu="4", memory="8Gi", instance_group="small")
+        old = time.time() - 3600
+        backlog = [  # (app, executors, instance group, age)
+            ("a-fits", 4, "big", old),
+            ("b-too-many", 60, "big", old),
+            ("c-fits-small", 2, "small", old),
+            ("d-too-many-small", 4, "small", old),
+            ("e-repeats-a", 4, "big", old),
+            ("f-young", 90, "big", time.time() - 5),
+            ("g-repeats-d", 4, "small", old),
+            ("h-broken", 1, "big", old),
+            ("i-after-the-error", 60, "big", old),
+        ]
+        for app, count, group, created in backlog:
+            pod = h.static_allocation_spark_pods(
+                app, count, instance_group=group, creation_timestamp=created
+            )[0]
+            if app == "h-broken":
+                del pod.meta.annotations[L.EXECUTOR_COUNT]
+            h.create_pod(pod)
+        written = []
+        update = h.api.update
+
+        def recording(obj):
+            cond = getattr(obj, "conditions", {}).get("PodExceedsClusterCapacity")
+            if obj.KIND == "Pod" and cond is not None:
+                written.append((obj.name, cond.status))
+            return update(obj)
+
+        h.api.update = recording
+        packer = select_binpacker(oracle).binpack_func  # the host policy
+        want = per_pod_reference_scan(h, packer)
+        listed = [p.name for p in h.server.pod_informer.list()]
+        assert [name for name, _ in want] == [
+            n for n in listed if n[0] in "abcdeg"
+        ] and {s for _, s in want} == {"True", "False"}
+        h.unschedulable_marker.scan_for_unschedulable_pods()
+        assert written == want
+        # the cluster grows: "b-too-many" now fits, nothing else turns
+        for i in range(3, 8):
+            h.new_node(f"big-{i}", cpu="16", memory="32Gi", instance_group="big", zone=f"z{i % 2}")
+        del written[:]
+        want = per_pod_reference_scan(h, packer)
+        assert [name for name, _ in want] == ["b-too-many-driver"]
+        h.unschedulable_marker.scan_for_unschedulable_pods()
+        assert written == want
+    finally:
+        h.close()
+
+
 def test_dynamic_allocation_cross_node_compaction_keeps_reservation_node(harness):
     """resourcereservations.go:326-335: when a soft-reserved executor runs
     on node A and the only unbound hard reservation is on node B, the

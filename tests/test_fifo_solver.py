@@ -12,6 +12,8 @@ from k8s_spark_scheduler_tpu.ops.sparkapp import AppDemand
 from k8s_spark_scheduler_tpu.scheduler.sparkpods import spark_resource_usage
 from k8s_spark_scheduler_tpu.testing.harness import Harness
 from k8s_spark_scheduler_tpu.types.resources import (
+    NodeSchedulingMetadata,
+    Resources,
     copy_metadata,
     subtract_usage_if_exists,
 )
@@ -792,6 +794,193 @@ def test_feasible_tensor_matches_binpack_has_capacity():
             )
             assert feasible is not None
             assert feasible == result.has_capacity, policy
+
+
+# -- the marker's verdicts as one batch ----------------------------------------
+
+BATCH_POLICIES = ["tpu-batch", "tpu-batch-distribute-evenly", "tpu-batch-minimal-fragmentation"]
+BATCH_LANES = ["xla", "native"]
+BATCH_SIZES = [1, 17, 300]
+
+
+def batch_solver_on(policy, lane):
+    """(the policy's binpacker, its queue solver held to ``lane``)."""
+    from k8s_spark_scheduler_tpu.ops.registry import select_binpacker
+
+    binpacker = select_binpacker(policy)
+    binpacker.queue_solver.backend = lane
+    return binpacker, binpacker.queue_solver
+
+
+def has_capacity(binpacker, app, d_order, e_order, metadata):
+    return binpacker.binpack_func(
+        app.driver_resources, app.executor_resources, app.min_executor_count,
+        d_order, e_order, metadata,
+    ).has_capacity
+
+
+def check_feasible_batch(policy, lane, n_apps):
+    """A batch of ``n_apps`` random apps on a seeded random cluster:
+    every verdict is the binpacker's ``has_capacity``, and the per-app
+    entry's (all of them on the native lane, a stride of them on the
+    XLA lane, where each is a program of its own)."""
+    from k8s_spark_scheduler_tpu.ops.tensorize import tensorize_cluster
+
+    binpacker, solver = batch_solver_on(policy, lane)
+    rng = random.Random(f"{policy}/{n_apps}")  # both lanes see one problem
+    metadata = random_cluster(rng, rng.randint(2, 8))
+    d_order, e_order = orders_for(metadata, rng)
+    cluster = tensorize_cluster(metadata, d_order, e_order)
+    apps = [random_app(rng) for _ in range(n_apps)]
+    verdicts = solver.feasible_batch(cluster, apps)
+    assert len(verdicts) == n_apps and None not in verdicts
+    want = [has_capacity(binpacker, app, d_order, e_order, metadata) for app in apps]
+    assert verdicts == want
+    stride = 1 if lane == "native" else max(1, n_apps // 8)
+    assert [solver.feasible_tensor(cluster, app) for app in apps[::stride]] == want[::stride]
+    return want
+
+
+@pytest.mark.parametrize("n_apps", BATCH_SIZES)
+@pytest.mark.parametrize("lane", BATCH_LANES)
+@pytest.mark.parametrize("policy", BATCH_POLICIES)
+def test_feasible_batch_is_has_capacity_app_by_app(policy, lane, n_apps):
+    want = check_feasible_batch(policy, lane, n_apps)
+    assert n_apps < 17 or {True, False} <= set(want)
+
+
+def pinned_cluster():
+    """One node only a 3-cpu driver fits, two that take an executor each,
+    one overbooked."""
+    sizes = {"big": ("4", "8Gi"), "small-a": ("1", "8Gi"), "small-b": ("1", "8Gi"), "over": ("-2", "8Gi")}
+    return {
+        name: NodeSchedulingMetadata(
+            available=Resources.of(cpu, mem), schedulable=Resources.of("8", "8Gi"), zone_label="z0"
+        )
+        for name, (cpu, mem) in sizes.items()
+    }
+
+
+@pytest.mark.parametrize("lane", BATCH_LANES)
+@pytest.mark.parametrize("policy", BATCH_POLICIES)
+def test_feasible_batch_pinned_cases(policy, lane):
+    """Infeasible gangs, a zero requirement, a gang that fits only with
+    its driver on one particular node, and padding: three to seven rows
+    of the program's 1,024 are apps, the rest never show."""
+    from k8s_spark_scheduler_tpu.ops.tensorize import tensorize_cluster
+
+    binpacker, solver = batch_solver_on(policy, lane)
+    metadata = pinned_cluster()
+    order = list(metadata)
+    one, big_driver = Resources.of("1", "1Gi"), Resources.of("3", "1Gi")
+    apps = [
+        AppDemand(big_driver, one, 3),                     # driver on "big" only, one executor beside it, two elsewhere
+        AppDemand(big_driver, one, 4),                     # one executor too many
+        AppDemand(one, Resources.of("0", "1Gi"), 23),      # no cpu asked: memory alone, 8 a node on three nodes less the driver
+        AppDemand(one, Resources.of("0", "1Gi"), 24),      # "over" is negative in cpu: 0 there whatever is asked
+        AppDemand(Resources.of("5", "1Gi"), one, 0),       # the driver fits nowhere
+        AppDemand(one, Resources.of("0", "0"), 10_000),    # nothing asked: any number fits
+        AppDemand(one, one, 0),                            # a driver alone
+    ]
+    want = [True, False, True, False, False, True, True]
+    for order_d, expected in ((order, want), (order[1:], [False, False, *want[2:]])):
+        cluster = tensorize_cluster(metadata, order_d, order)
+        assert solver.feasible_batch(cluster, apps) == expected
+        assert [has_capacity(binpacker, a, order_d, order, metadata) for a in apps] == expected
+        assert solver.feasible_batch(cluster, apps[:3]) == expected[:3]
+    assert solver.feasible_batch(cluster, []) == []
+
+
+@pytest.mark.parametrize("policy", ["tpu-batch", "tpu-batch-single-az"])
+def test_feasible_batch_larger_than_one_app_block(policy):
+    """1,029 apps go through the one program in two blocks: three arrays
+    up, two down, the verdicts those of the native lane."""
+    from k8s_spark_scheduler_tpu import tracing
+    from k8s_spark_scheduler_tpu.ops.batch_solver import VERDICT_ROWS, feasible_apps
+    from k8s_spark_scheduler_tpu.ops.tensorize import tensorize_cluster
+
+    rng = random.Random(1029)
+    metadata = random_cluster(rng, 30)
+    d_order, e_order = orders_for(metadata, rng)
+    cluster = tensorize_cluster(metadata, d_order, e_order)
+    apps = [random_app(rng) for _ in range(VERDICT_ROWS + 5)]
+    _, device = batch_solver_on(policy, "xla")
+    binpacker, native = batch_solver_on(policy, "native")
+    compiled = feasible_apps._cache_size()
+    with tracing.Tracer().span("unschedulable.scan") as root:
+        with root.aggregate("scan.solve") as phase:
+            verdicts = device.feasible_batch(cluster, apps, span=phase)
+        with root.aggregate("scan.solve") as phase:
+            assert device.feasible_batch(cluster, apps[:7], span=phase) == verdicts[:7]
+    assert verdicts == native.feasible_batch(cluster, apps)
+    assert verdicts[-5:] == [has_capacity(binpacker, a, d_order, e_order, metadata) for a in apps[-5:]]
+    nb = 64
+    assert phase.tags == {
+        "count": 2,
+        "arrays": (1 + 2 * 2) + (1 + 2),
+        "bytes": (nb * 6 * 4 + 2 * VERDICT_ROWS * 9 * 4) + (nb * 6 * 4 + VERDICT_ROWS * 9 * 4),
+    }
+    assert not phase.children
+    # both batches ran the one program of the node bucket
+    assert feasible_apps._cache_size() <= compiled + 1
+
+
+@pytest.mark.parametrize("lane", BATCH_LANES)
+@pytest.mark.parametrize("policy", ["tpu-batch", "tpu-batch-single-az"])
+def test_feasible_batch_with_one_inexact_app(policy, lane):
+    """A sub-milli cpu ask has no exact base units: that app's verdict is
+    the host path's (None), the others still get theirs; and where the
+    cluster is inexact every verdict is None."""
+    from k8s_spark_scheduler_tpu.ops.tensorize import tensorize_cluster
+
+    binpacker, solver = batch_solver_on(policy, lane)
+    rng = random.Random(50)
+    metadata = random_cluster(rng, 12)
+    d_order, e_order = orders_for(metadata, rng)
+    cluster = tensorize_cluster(metadata, d_order, e_order)
+    apps = [random_app(rng) for _ in range(9)]
+    apps[4] = AppDemand(Resources.of("50u", "1Mi"), Resources.of("10u", "1Mi"), 2)
+    verdicts = solver.feasible_batch(cluster, apps)
+    assert verdicts[4] is None and solver.feasible_tensor(cluster, apps[4]) is None
+    assert [v for i, v in enumerate(verdicts) if i != 4] == [
+        has_capacity(binpacker, a, d_order, e_order, metadata) for i, a in enumerate(apps) if i != 4
+    ]
+    metadata["node-000"].available = Resources.of("100u", "1Gi")
+    inexact = tensorize_cluster(metadata, d_order, e_order)
+    assert solver.feasible_batch(inexact, apps[:3]) == [None] * 3
+
+
+@pytest.mark.parametrize("lane", BATCH_LANES)
+def test_feasible_batch_scales_apps_singly_where_the_joint_problem_does_not(lane):
+    """Nodes of 2^20 * 3^13 bytes scale with an ask of 2^20 bytes and
+    with one of 3^13, not with both (their joint GCD is a byte, and the
+    nodes pass int32): each app is then scaled alone, and each still
+    gets its tensor verdict."""
+    from k8s_spark_scheduler_tpu.ops.tensorize import (
+        scale_problem, tensorize_apps, tensorize_cluster,
+    )
+
+    binpacker, solver = batch_solver_on("tpu-batch", lane)
+    memory = str(2**20 * 3**13)
+    metadata = {
+        f"n{i}": NodeSchedulingMetadata(
+            available=Resources.of("64", memory), schedulable=Resources.of("64", memory), zone_label="z0"
+        )
+        for i in range(3)
+    }
+    order = list(metadata)
+    cluster = tensorize_cluster(metadata, order, order)
+    driver = Resources.of("1", "0")
+    apps = [
+        AppDemand(driver, Resources.of("1", str(2**20)), 191),  # 64 + 64 + 63 by cpu
+        AppDemand(driver, Resources.of("1", str(3**13)), 192),
+        AppDemand(driver, Resources.of("1", str(3**13)), 191),
+    ]
+    assert not scale_problem(cluster, tensorize_apps(apps)).ok
+    assert all(scale_problem(cluster, tensorize_apps([a])).ok for a in apps)
+    verdicts = solver.feasible_batch(cluster, apps)
+    assert verdicts == [has_capacity(binpacker, a, order, order, metadata) for a in apps]
+    assert verdicts == [True, False, True]
 
 
 def test_earlier_tensor_cache_hit_matches_fresh_solver():

@@ -204,6 +204,53 @@ def test_feasible_tensor_is_the_binpackers_has_capacity(policy):
     assert verdicts == {True, False}
 
 
+@pytest.mark.parametrize("n_apps", [1, 17, 300])
+@pytest.mark.parametrize("lane", ["xla", "native"])
+@pytest.mark.parametrize("policy", POLICIES)
+def test_feasible_batch_is_has_capacity_app_by_app(policy, lane, n_apps):
+    """The batch entry under the zone policies: the program's group
+    column (XLA lane) and the host's per-zone solves (native lane)."""
+    from test_fifo_solver import check_feasible_batch
+
+    want = check_feasible_batch(policy, lane, n_apps)
+    assert n_apps < 17 or {True, False} <= set(want)
+
+
+@pytest.mark.parametrize("lane", ["xla", "native"])
+@pytest.mark.parametrize("policy", POLICIES)
+def test_feasible_batch_leaves_out_a_zone_with_no_executor_candidate(policy, lane):
+    """Zone z1 holds the only node a 6-cpu driver fits, but none of its
+    nodes may take an executor: it is no candidate zone (single_az.go:
+    30-45), so under single-AZ the gang fits nowhere, while az-aware
+    falls back to the whole cluster.  A gang with a small driver fits z0
+    or z2 whole, never the two together."""
+    from test_fifo_solver import batch_solver_on, has_capacity
+
+    sizes = {"a0": ("4", "z0"), "a1": ("4", "z0"), "b0": ("8", "z1"), "c0": ("4", "z2"), "c1": ("2", "z2")}
+    metadata = {
+        name: NodeSchedulingMetadata(
+            available=Resources.of(cpu, "64Gi"), schedulable=Resources.of("8", "64Gi"), zone_label=zone
+        )
+        for name, (cpu, zone) in sizes.items()
+    }
+    d_order = list(metadata)
+    e_order = [n for n in d_order if n != "b0"]
+    cluster = tensorize_cluster(metadata, d_order, e_order)
+    one = Resources.of("1", "1Gi")
+    apps = [
+        AppDemand(Resources.of("6", "1Gi"), one, 1),  # the driver fits b0 alone
+        AppDemand(one, one, 7),    # z0 whole: 4 + 4 less the driver
+        AppDemand(one, one, 8),    # more than any one zone, less than z0 and z2 together
+        AppDemand(one, one, 14),   # a0, a1, c0, c1 hold 14, with the driver on b0
+        AppDemand(one, one, 15),
+    ]
+    binpacker, solver = batch_solver_on(policy, lane)
+    whole_cluster = policy == "tpu-batch-az-aware"
+    want = [whole_cluster, True, whole_cluster, whole_cluster, False]
+    assert solver.feasible_batch(cluster, apps) == want
+    assert [has_capacity(binpacker, a, d_order, e_order, metadata) for a in apps] == want
+
+
 def test_the_markers_scan_takes_the_tensor_lane_under_single_az():
     import time
 

@@ -316,7 +316,16 @@ def aged_backlog(h, fitting=3, oversized=1):
         h.create_pod(pod)
 
 
-def test_one_scan_is_one_trace_with_three_aggregate_children_and_two_metrics():
+@pytest.mark.parametrize("lane", ["native", "xla"])
+def test_one_scan_is_one_trace_with_three_aggregate_children_and_two_metrics(lane, monkeypatch):
+    """One signature: one metadata build, ONE batch of verdicts (a device
+    round on the XLA lane, whose upload and read-back open no span under
+    the aggregate: its crossings are the aggregate's tags), a mark per
+    pod."""
+    from k8s_spark_scheduler_tpu.ops import fifo_solver
+
+    if lane == "xla":  # as on a host with neither a TPU nor the C++ library
+        monkeypatch.setattr(fifo_solver, "_native_selected", lambda backend: False)
     h = Harness(binpack_algo="tpu-batch")
     try:
         for name in NODES:
@@ -326,20 +335,47 @@ def test_one_scan_is_one_trace_with_three_aggregate_children_and_two_metrics():
         h.unschedulable_marker.scan_for_unschedulable_pods()
         (root,) = roots
         assert root.name == "unschedulable.scan" and root.parent is None
-        assert root.tags == {"pods": 4, "verdictMisses": 4, "signatures": 1, "conditionWrites": 4}
-        assert {c.name: c.tags["count"] for c in root.children} == {
-            "scan.metadata": 1, "scan.solve": 4, "scan.mark": 4,
+        assert root.tags == {
+            "pods": 4, "verdictMisses": 4, "verdictBatches": 1, "signatures": 1, "conditionWrites": 4,
         }
+        children = {c.name: c for c in root.children}
+        assert {name: c.tags["count"] for name, c in children.items()} == {
+            "scan.metadata": 1, "scan.solve": 1, "scan.mark": 4,
+        }
+        crossings = {k: v for k, v in children["scan.solve"].tags.items() if k != "count"}
+        # the node block [64, 6], the app block [1024, 8] up, [1024] down, int32
+        assert crossings == ({"arrays": 3, "bytes": 4 * (64 * 6 + 1024 * 9)} if lane == "xla" else {})
         assert all(type(c) is tracing.AggregateSpan and not c.children for c in root.children)
         assert sum(c.duration for c in root.children) <= root.duration
         metrics = h.server.metrics
         assert metrics.get_counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": "tensor"}) == 4
+        assert metrics.get_counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": "host"}) == 0
         snapshot = json.dumps(metrics.snapshot())
         assert mnames.UNSCHEDULABLE_SCAN_TIME in snapshot
         # a second scan finds the conditions set: verdicts again, no writes
         h.unschedulable_marker.scan_for_unschedulable_pods()
-        assert roots[1].tags["conditionWrites"] == 0
+        assert roots[1].tags["conditionWrites"] == 0 and roots[1].tags["verdictBatches"] == 1
         assert "scan.mark" not in [c.name for c in roots[1].children]
+    finally:
+        h.close()
+
+
+def test_a_scan_under_a_host_policy_asks_no_batch():
+    """No tensor solver: every verdict is a full pack on the host, one
+    ``scan.solve`` phase per signature all the same."""
+    h = Harness(binpack_algo="tightly-pack")
+    try:
+        for name in NODES:
+            h.new_node(name)
+        aged_backlog(h)
+        roots = roots_of(h)
+        h.unschedulable_marker.scan_for_unschedulable_pods()
+        (root,) = roots
+        assert root.tags == {
+            "pods": 4, "verdictMisses": 4, "verdictBatches": 0, "signatures": 1, "conditionWrites": 4,
+        }
+        assert {c.name: c.tags for c in root.children}["scan.solve"] == {"count": 1}
+        assert h.server.metrics.get_counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": "host"}) == 4
     finally:
         h.close()
 
