@@ -65,6 +65,10 @@ KERNEL_EXECUTE_TIME = "foundry.spark.scheduler.tpu.kernel.execute.time"
 KERNEL_CACHE_HITS = "foundry.spark.scheduler.tpu.kernel.cache.hit.count"
 KERNEL_CACHE_MISSES = "foundry.spark.scheduler.tpu.kernel.cache.miss.count"
 KERNEL_JIT_CACHE_SIZE = "foundry.spark.scheduler.tpu.kernel.jit.cache.size"
+# programs compiled, by where: phase=warmup|request|background (a request
+# that compiles waits seconds under the predicate lock; 0 is the sound
+# reading for phase=request), tagged kernel=, lane=, phase=
+KERNEL_COMPILES = "foundry.spark.scheduler.tpu.kernel.compile.count"
 # per-span duration distributions (tracing/spans.py), tagged span=
 TRACE_SPAN_TIME = "foundry.spark.scheduler.trace.span.time"
 # unschedulable-pod marker (scheduler/unschedulable.py): seconds per
@@ -139,6 +143,10 @@ SINGLEAZ_LANE = "foundry.spark.scheduler.tpu.singleaz.lane"
 # earlier-drivers queue assemblies by how the kept pending-driver view
 # answered: result=hit|rebuild|stale|per-pod (scheduler/sparkpods.py)
 QUEUE_VIEW_READS = "foundry.spark.scheduler.fifo.queue.view.reads"
+# tensor builds by what the avail-independent prework cost them
+# (ops/fast_path.py, keyed by structure revision, affinity signature and
+# candidate list): result=hit|miss|uncacheable
+PREP_CACHE_READS = "foundry.spark.scheduler.tpu.fastpath.prepcache.reads"
 # queue apps of single-AZ driver Filters by who chose their zone
 # (result=certified|resolved|host-queue), ops/fifo_solver.py
 FIFO_ZONE_CHOICE = "foundry.spark.scheduler.fifo.zone.choice"

@@ -217,6 +217,18 @@ def _compute_prep(snap, driver_pod, candidate_names, dlp, elp) -> _BuildPrep:
     )
 
 
+def _note_prep(result: str) -> None:
+    """How the prework was come by (hit, miss, uncacheable): a tag on the
+    active span and a count in the server's registry, which the kernel
+    profiler is bound to."""
+    from ..metrics.names import PREP_CACHE_READS
+    from ..tracing import add_tag
+    from ..tracing.profiling import default_profiler
+
+    add_tag("prepCache", result)
+    default_profiler.metrics.counter(PREP_CACHE_READS, {"result": result})
+
+
 def build_prep_keyed(snap, driver_pod, candidate_names, dlp, elp):
     """(prep, key): the avail-independent prework plus the exact cache
     key it lives under — (structure revision, affinity signature,
@@ -224,8 +236,6 @@ def build_prep_keyed(snap, driver_pod, candidate_names, dlp, elp):
     affinity shape is uncacheable.  The delta-solve engine keys its
     native solver sessions by the same identity, so a session can only
     ever be consulted for the cluster/candidate shape it was built for."""
-    from ..tracing import add_tag
-
     aff = _single_in_sig(driver_pod)
     key = None
     if aff is not None and snap.structure_key[0] >= 0:
@@ -242,11 +252,11 @@ def build_prep_keyed(snap, driver_pod, candidate_names, dlp, elp):
             hit = _PREP_CACHE.get(key)
             if hit is not None:
                 _PREP_CACHE.move_to_end(key)
-                add_tag("prepCache", "hit")
+                _note_prep("hit")
                 return hit, key
     # a miss at 10k nodes is ~20ms of the request — worth seeing on the
     # span when hunting a latency outlier
-    add_tag("prepCache", "miss" if key is not None else "uncacheable")
+    _note_prep("miss" if key is not None else "uncacheable")
     prep = _compute_prep(snap, driver_pod, candidate_names, dlp, elp)
     if key is not None:
         with _prep_lock:

@@ -18,7 +18,7 @@ import numpy as np
 
 from .. import compat
 from ..tracing import spans as tracing
-from ..tracing.profiling import default_profiler
+from ..tracing.profiling import PHASE_REQUEST, default_profiler
 from ..types.resources import NodeGroupSchedulingMetadata
 from .batch_adapter import (
     build_reserved,
@@ -320,6 +320,21 @@ def _filter_blocks(problem, n_earlier: int):
     return _node_block(problem), app_cols
 
 
+def _gate_tags(cluster, problem, n_earlier: int) -> dict:
+    """What a ``fifo_gate`` span says of its request's shape: the nodes
+    the driver's affinity admitted and the drivers ahead of it in its
+    instance group's queue, the bucket each was padded to (the compiled
+    program's shape), and the programs request threads had compiled
+    before it (0 on a server whose warm-up covered its groups)."""
+    return {
+        "earlierApps": n_earlier,
+        "eligibleNodes": cluster.n_nodes,
+        "nodeBucket": problem.avail.shape[0],
+        "appBucket": problem.count.shape[0],
+        "requestCompiles": default_profiler.compiles(PHASE_REQUEST),
+    }
+
+
 def _earlier_ok(gate_span, feasible, earlier_skip_allowed) -> bool:
     """An enforced (old-enough) earlier driver that doesn't fit fails the
     whole request (resource.go:244-253)."""
@@ -561,7 +576,9 @@ class TpuFifoSolver:
         n_earlier = len(earlier_skip_allowed)
         minfrag = self.assignment_policy == "minimal-fragmentation"
         # the fifo_gate span is the request's "earlier drivers fit?" phase
-        with tracing.child_span("fifo_gate", {"earlierApps": n_earlier}) as gate_span:
+        with tracing.child_span(
+            "fifo_gate", _gate_tags(cluster, problem, n_earlier)
+        ) as gate_span:
             feasible, didx_all, avail_after = np.zeros(0, dtype=bool), None, problem.avail
             if n_earlier > 0:
                 queue_valid = problem.app_valid.copy()
@@ -620,7 +637,7 @@ class TpuFifoSolver:
         self.last_queue_lane = lane
         nb = problem.avail.shape[0]
         with tracing.child_span(
-            "fifo_gate", {"earlierApps": n_earlier, "lane": lane}
+            "fifo_gate", {**_gate_tags(cluster, problem, n_earlier), "lane": lane}
         ) as gate_span:
             nodes_dev, apps_dev = _upload(*_filter_blocks(problem, n_earlier))
             with default_profiler.profile("fifo_queue", lane=lane, fn=solve_filter) as rec:
@@ -1311,7 +1328,9 @@ class TpuSingleAzFifoSolver:
         # pass also the request's own app as the pass packed it (a snapshot)
         avail, probe = problem.avail, None
         if n_earlier > 0:
-            with tracing.child_span("fifo_gate", {"earlierApps": n_earlier}) as gate_span:
+            with tracing.child_span(
+                "fifo_gate", _gate_tags(cluster, problem, n_earlier)
+            ) as gate_span:
                 feasible, avail, probe = self._queue_pass(zones, n_earlier, gate_span)
                 gate_span.tag("lane", self.last_queue_lane)
                 blocked = ~feasible & ~np.asarray(earlier_skip_allowed, bool)
@@ -1321,7 +1340,9 @@ class TpuSingleAzFifoSolver:
                     # the whole request (resource.go:244-253)
                     return FifoOutcome(supported=True, earlier_ok=False)
         else:
-            with tracing.child_span("fifo_gate", {"earlierApps": 0, "earlierOk": True}):
+            with tracing.child_span(
+                "fifo_gate", {**_gate_tags(cluster, problem, 0), "earlierOk": True}
+            ):
                 pass
 
         with tracing.child_span(

@@ -15,8 +15,10 @@ request-time fallback.
 
 from __future__ import annotations
 
-from typing import Callable, Iterable, Tuple
+from collections import Counter
+from typing import Callable, Iterable, List, Optional, Tuple
 
+from ..tracing.profiling import default_profiler
 from ..types.resources import NodeSchedulingMetadata, Resources
 from .registry import select_binpacker
 from .sparkapp import AppDemand
@@ -25,15 +27,36 @@ from .tensorize import APP_BUCKETS, NODE_BUCKETS, bucket_size, tensorize_cluster
 WARM_ZONES = 3  # zone count is a compile shape; 3 AZs is typical
 
 # the shapes real clusters hit first; a server that starts against an
-# already-populated cluster adds its own (warm_shapes)
+# already-populated cluster adds each instance group's own (warm_shapes)
 _BASE_SHAPES = tuple((nb, APP_BUCKETS[0]) for nb in NODE_BUCKETS[:3])
 
 
-def warm_shapes(n_nodes: int, n_pending_drivers: int) -> Tuple[Tuple[int, int], ...]:
-    """(node bucket, app bucket) pairs to compile: the small buckets plus
-    the bucket of the cluster as observed at start-up, if any."""
+def observed_groups(
+    node_groups: Iterable[Optional[str]], driver_groups: Iterable[str]
+) -> List[Tuple[int, int]]:
+    """(nodes, pending drivers) of each instance group that has nodes,
+    from the group of every node (None: the node carries no
+    instance-group label and is in no group) and of every pending driver,
+    as the informers hold them at start.  A driver Filter is served at
+    its group's shape (the rows its node affinity admits, behind its
+    group's queue), never at the whole cluster's."""
+    nodes = Counter(node_groups)
+    nodes.pop(None, None)
+    pending = Counter(driver_groups)
+    return [(n, pending[group]) for group, n in nodes.items()]
+
+
+def warm_shapes(groups: Iterable[Tuple[int, int]]) -> Tuple[Tuple[int, int], ...]:
+    """(node bucket, app bucket) pairs to compile: the small buckets plus,
+    for each ``(nodes, pending drivers)`` of ``observed_groups``, the
+    bucket its next driver Filter is served at.  The bucket above is not
+    warmed, however near the queue stands to it: a queue that grows past
+    16 / 64 / 256 / 1,024 compiles on a request thread, and the profiler
+    counts it (``KERNEL_COMPILES``, ``phase=request``)."""
     shapes = list(_BASE_SHAPES)
-    if n_nodes > 0:
+    for n_nodes, n_pending_drivers in groups:
+        if n_nodes <= 0:
+            continue
         observed = (
             bucket_size(n_nodes),
             bucket_size(n_pending_drivers + 1, buckets=APP_BUCKETS),
@@ -43,6 +66,7 @@ def warm_shapes(n_nodes: int, n_pending_drivers: int) -> Tuple[Tuple[int, int], 
     return tuple(shapes)
 
 
+@default_profiler.warming()  # what compiles inside counts as phase=warmup
 def warm_queue_solver(
     binpack_algo: str,
     strict_reference_parity: bool,

@@ -132,6 +132,28 @@ class Server:
                 return False
         return True
 
+    def observed_groups(self) -> "list[tuple[int, int]]":
+        """(nodes, pending drivers) of each instance group as the
+        informers hold them: a server that starts against a populated
+        cluster warms the shape each group's drivers are served at
+        (ops/warmup.py).  Without the FIFO no driver is packed behind
+        another, so every queue counts as empty."""
+        from ..ops.warmup import observed_groups
+        from ..scheduler import labels as L
+
+        label = self.install.instance_group_label
+        found = (
+            L.find_instance_group_from_pod_spec(pod, label)
+            for pod in self.pod_informer.list(
+                label_selector={L.SPARK_ROLE_LABEL: L.DRIVER}
+            )
+            if self.install.fifo and not pod.node_name
+        )
+        return observed_groups(
+            (node.labels.get(label) for node in self.node_informer.list()),
+            (group for group, ok in found if ok),
+        )
+
     def _warm_solver_async(self) -> None:
         """Pre-compile, in the background, the kernels the configured
         policy dispatches on this platform (ops/warmup.py) so the first
@@ -156,22 +178,12 @@ class Server:
             import logging
 
             from ..ops.warmup import warm_queue_solver, warm_shapes
-            from ..scheduler import labels as L
 
             try:
-                # a server that starts against a populated cluster warms
-                # that cluster's own shape bucket too
-                pending = sum(
-                    1
-                    for pod in self.pod_informer.list(
-                        label_selector={L.SPARK_ROLE_LABEL: L.DRIVER}
-                    )
-                    if not pod.node_name
-                )
                 warm_queue_solver(
                     self.install.binpack_algo,
                     self.install.strict_reference_parity,
-                    warm_shapes(len(self.node_informer.list()), pending),
+                    warm_shapes(self.observed_groups()),
                     should_stop=self._warm_stop.is_set,
                 )
             except Exception as err:

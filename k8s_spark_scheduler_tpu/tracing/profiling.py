@@ -13,6 +13,11 @@ profiler splits every profiled dispatch into:
   (``KERNEL_EXECUTE_TIME``).  It holds the launch and the sync besides
   the device's own time; device time comes from a JAX profile only,
 - **cache hits/misses** — ``KERNEL_CACHE_HITS`` / ``KERNEL_CACHE_MISSES``,
+- **compiles by where they happened** — ``KERNEL_COMPILES`` with
+  ``phase=warmup`` (under ``KernelProfiler.warming()``), ``request`` (a
+  request's trace is active on the compiling thread: the caller waits
+  for the compiler, under the predicate lock) or ``background`` (the
+  unschedulable-pod marker's scan, a library caller),
 
 all tagged with the kernel name and the lane ("xla", "pallas",
 "native", …), and mirrored onto the active trace span so a span tree
@@ -32,11 +37,41 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Optional, Set, Tuple
+from contextlib import contextmanager
+from contextvars import ContextVar
+from typing import Any, Dict, Optional, Set, Tuple
 
 from ..metrics import names as mnames
-from .spans import NOOP_SPAN, Tracer, child_span, current_span, default_tracer
+from .spans import (
+    NOOP_SPAN,
+    REQUEST_ROOTS,
+    Tracer,
+    child_span,
+    current_span,
+    default_tracer,
+)
 from ..analysis.guarded import guarded_by
+
+
+PHASE_WARMUP, PHASE_REQUEST, PHASE_BACKGROUND = "warmup", "request", "background"
+# the phase a caller states for its thread (KernelProfiler.warming)
+_STATED_PHASE: ContextVar[Optional[str]] = ContextVar(
+    "k8s_spark_scheduler_tpu_compile_phase", default=None
+)
+
+
+def compile_phase() -> str:
+    """Where a compile on this thread happens: what the caller stated,
+    else ``request`` under a request's trace, else ``background``."""
+    stated = _STATED_PHASE.get()
+    if stated is not None:
+        return stated
+    span = current_span()
+    while span is not None and span.parent is not None:
+        span = span.parent
+    if span is not None and span.name in REQUEST_ROOTS:
+        return PHASE_REQUEST
+    return PHASE_BACKGROUND
 
 
 def jit_cache_size(fn) -> Optional[int]:
@@ -138,9 +173,11 @@ class _Profile:
         if miss:
             compile_s = t_dispatch - t0
             execute = t_end - t_dispatch
+            phase = compile_phase()
             metrics.counter(mnames.KERNEL_CACHE_MISSES, tags)
+            metrics.counter(mnames.KERNEL_COMPILES, {**tags, mnames.TAG_PHASE: phase})
             metrics.histogram(mnames.KERNEL_COMPILE_TIME, compile_s, tags)
-            prof._add_compile_seconds(compile_s)
+            prof._add_compile(compile_s, phase)
             self._span.tag("compileMs", round(compile_s * 1000.0, 4))
         else:
             # steady state: dispatch is µs-level, fold it into execute
@@ -151,7 +188,7 @@ class _Profile:
         self._span.tag("cacheHit", not miss)
 
 
-@guarded_by("_seen_lock", "_seen", "_compile_s")
+@guarded_by("_seen_lock", "_seen", "_compile_s", "_compiles")
 class KernelProfiler:
     """Profiling sink: records into a metrics registry and the active
     trace.  One module-level instance (``default_profiler``) is rebound
@@ -164,6 +201,7 @@ class KernelProfiler:
         self.tracer = tracer if tracer is not None else default_tracer
         self._seen: Set[Tuple[str, Any]] = set()
         self._compile_s = 0.0
+        self._compiles: Dict[str, int] = {}
         self._seen_lock = threading.Lock()
 
     def configure(self, metrics=None, tracer: Optional[Tracer] = None) -> None:
@@ -193,9 +231,26 @@ class KernelProfiler:
         with self._seen_lock:
             return self._compile_s
 
-    def _add_compile_seconds(self, seconds: float) -> None:
+    def compiles(self, phase: str) -> int:
+        """Programs compiled so far in ``phase`` (``compile_phase``).
+        ``request`` counts those a request waited for: 0 on a server
+        whose warm-up covered the shapes its requests are served at."""
+        with self._seen_lock:
+            return self._compiles.get(phase, 0)
+
+    @contextmanager
+    def warming(self):
+        """What this thread compiles inside is the warm-up's."""
+        token = _STATED_PHASE.set(PHASE_WARMUP)
+        try:
+            yield
+        finally:
+            _STATED_PHASE.reset(token)
+
+    def _add_compile(self, seconds: float, phase: str) -> None:
         with self._seen_lock:
             self._compile_s += seconds
+            self._compiles[phase] = self._compiles.get(phase, 0) + 1
 
     def _classify_miss(self, kernel, fn, shape_key, cache_before) -> bool:
         if fn is not None and cache_before is not None:

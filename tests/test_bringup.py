@@ -151,9 +151,9 @@ def test_warmup_compiles_through_the_configured_solver():
     assert solve_single._cache_size() >= single0
     # host policies have nothing to warm; unknown shapes are added once
     warmup.warm_queue_solver("tightly-pack", True, [(64, 16)])
-    assert warmup.warm_shapes(0, 0) == warmup._BASE_SHAPES
-    assert warmup.warm_shapes(10_000, 1_000)[-1] == (10240, 1024)
-    assert warmup.warm_shapes(60, 3) == warmup._BASE_SHAPES
+    assert warmup.warm_shapes([]) == warmup.warm_shapes([(0, 0)]) == warmup._BASE_SHAPES
+    assert warmup.warm_shapes([(10_000, 1_000)]) == warmup._BASE_SHAPES + ((10240, 1024),)
+    assert warmup.warm_shapes([(60, 3)]) == warmup._BASE_SHAPES
 
 
 def test_after_warmup_a_filter_and_a_marker_scan_compile_nothing(monkeypatch):
@@ -307,7 +307,7 @@ def test_first_compile_is_not_scored_against_the_latency_budget():
     try:
         t0 = time.perf_counter() - 12.0  # a 12 s request …
         compile0 = default_profiler.compile_seconds()
-        default_profiler._add_compile_seconds(10.0)  # … 10 s of it compiling
+        default_profiler._add_compile(10.0, "request")  # … 10 s of it compiling
         elapsed = h.extender._lane_elapsed(t0, compile0)
         assert 2.0 <= elapsed < 3.0
     finally:

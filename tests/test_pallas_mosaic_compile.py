@@ -132,3 +132,23 @@ def test_served_filter_program_compiles_for_v5e_and_names_its_kernel(v5e_shardin
     calls = [line.split(" = ")[0].strip().lstrip("%") for line in compiled.as_text().splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
     assert len(calls) == 1 and calls[0].split(".")[0] == kernel, calls
+
+
+# the shapes fifo10k-groups' five instance groups are served at (benchmarks/configs/
+# fifo10k-groups.json, ``shapes_served``): the Filter's program and the marker's
+GROUP_SHAPES = [(5120, 1024), (4096, 256), (1024, 256)]
+
+
+@pytest.mark.parametrize("n,a", GROUP_SHAPES, ids=lambda v: str(v))
+def test_programs_compile_for_v5e_at_the_shapes_instance_groups_are_served_at(v5e_sharding, n, a):
+    from k8s_spark_scheduler_tpu.ops.batch_solver import VERDICT_ROWS, feasible_apps, solve_filter
+
+    served = jax.jit(
+        functools.partial(solve_filter.__wrapped__, policy="tightly-pack", pallas=True),
+        in_shardings=v5e_sharding,
+        out_shardings=v5e_sharding,
+    )
+    compiled = served.lower(_sds((n, 5), jnp.int32), _sds((a, 8), jnp.int32)).compile()
+    assert "pallas_solve_queue" in compiled.as_text()
+    marker = jax.jit(feasible_apps.__wrapped__, in_shardings=v5e_sharding, out_shardings=v5e_sharding)
+    assert marker.lower(_sds((n, 6), jnp.int32), _sds((VERDICT_ROWS, 8), jnp.int32)).compile() is not None
