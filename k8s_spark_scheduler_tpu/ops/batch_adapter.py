@@ -11,7 +11,7 @@ numeric representation.
 from __future__ import annotations
 
 import logging
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -73,14 +73,15 @@ def build_reserved(
     from ..utils.quantity import Quantity
 
     reserved = {driver_node: driver_resources}
-    for name, c in zip(names, counts):
-        if c > 0:
-            total = Resources(
-                Quantity(executor_resources.cpu.exact * int(c)),
-                Quantity(executor_resources.memory.exact * int(c)),
-                Quantity(executor_resources.nvidia_gpu.exact * int(c)),
-            )
-            reserved[name] = reserved.get(name, Resources.zero()).add(total)
+    counts = np.asarray(counts)
+    hosts = np.flatnonzero(counts > 0)
+    for i, c in zip(hosts.tolist(), counts[hosts].tolist()):
+        total = Resources(
+            Quantity(executor_resources.cpu.exact * c),
+            Quantity(executor_resources.memory.exact * c),
+            Quantity(executor_resources.nvidia_gpu.exact * c),
+        )
+        reserved[names[i]] = reserved.get(names[i], Resources.zero()).add(total)
     return reserved
 
 
@@ -104,27 +105,110 @@ def min_frag_unclamped_caps(
     return np.where(exec_ok, cap, 0)
 
 
-def minimal_fragmentation_assignment(
-    names: List[str], cap: np.ndarray, k: int
-) -> Optional[List[str]]:
-    """Exact minimal-fragmentation placement from per-node integer
-    capacities (minimal_fragmentation.go:59-137): the capacities the
-    device returns equal the oracle's Fraction floor divisions, so the
-    host-side bisect algorithm reproduces the oracle list exactly."""
-    from .capacity import NodeAndExecutorCapacity
-    from .packers import minimal_fragmentation_from_capacities
+def minimal_fragmentation_rows(cap: np.ndarray, k: int) -> Optional[np.ndarray]:
+    """Minimal-fragmentation placement on arrays: the hosting rows of
+    ``cap`` (per-node integer capacities in priority order, unclamped),
+    one entry per executor in the order the reference emits them
+    (minimal_fragmentation.go:59-137), or None where ``k`` do not fit.
 
+    The served path's decode.  The same algorithm as the host oracle's
+    ``packers.minimal_fragmentation_from_capacities``, which walks a
+    sorted list of one ``NodeAndExecutorCapacity`` per node: here the
+    sort is one stable argsort of the positive capacities (ties keep
+    priority order, as the oracle's ``sorted``), "first node that fits
+    what is left" a searchsorted, ``remaining`` a prefix length, and the
+    Python loop turns once per drained capacity class (at most ``k``
+    times), never per node.  tests/test_minfrag_rows.py holds the two
+    equal, order included.  Capacities reach 2**62 (a zero requirement):
+    int64 throughout, and such a node always fits what is left."""
     if k == 0:
-        return []
-    capacities = [
-        NodeAndExecutorCapacity(name, int(c)) for name, c in zip(names, cap) if c > 0
-    ]
-    nodes, ok = minimal_fragmentation_from_capacities(k, capacities)
-    return nodes if ok else None
+        return np.zeros(0, dtype=np.int64)
+    cap = np.asarray(cap, dtype=np.int64)
+    positive = np.flatnonzero(cap > 0)
+    if positive.size == 0:
+        return None
+    rows = positive[np.argsort(cap[positive], kind="stable")]
+    caps = cap[rows]
+    max_capacity = int(caps[-1])
+    if k < max_capacity:
+        # try a subset that excludes the 'emptiest' nodes
+        below_target = int(np.searchsorted(caps, (k + max_capacity) // 2, side="left"))
+        placed = _drain_sorted(rows, caps, below_target, k)
+        if placed is not None:
+            return placed
+    return _drain_sorted(rows, caps, len(rows), k)
+
+
+def _drain_sorted(
+    rows: np.ndarray, caps: np.ndarray, length: int, k: int
+) -> Optional[np.ndarray]:
+    """minimal_fragmentation.go:96-137 over the first ``length`` entries
+    of the ascending ``caps`` (``rows`` their nodes): the oracle's
+    ``remaining`` is ``caps[:length]``, once more followed by what a
+    drain left of its capacity class."""
+    hosts, on_each = [], []
+    while length > 0:
+        # first node that can fit everything that's left
+        position = int(np.searchsorted(caps[:length], k, side="left"))
+        if position < length:
+            hosts.append(rows[position : position + 1])
+            on_each.append(k)
+            break
+        # drain max-capacity nodes, in priority order
+        max_capacity = int(caps[length - 1])
+        first_max = int(np.searchsorted(caps[:length], max_capacity, side="left"))
+        drained = min(k // max_capacity, length - first_max)
+        hosts.append(rows[first_max : first_max + drained])
+        on_each.extend([max_capacity] * drained)
+        k -= drained * max_capacity
+        if k == 0:
+            break
+        if first_max + drained < length:
+            # the class outlasted the drain: what is left (< its capacity)
+            # fits its next node, unless a smaller node fits it first
+            position = int(np.searchsorted(caps[:first_max], k, side="left"))
+            if position == first_max:
+                position += drained
+            hosts.append(rows[position : position + 1])
+            on_each.append(k)
+            break
+        length = first_max
+    else:
+        return None
+    return np.repeat(np.concatenate(hosts), on_each)
+
+
+def tightly_rows(counts: np.ndarray) -> np.ndarray:
+    """The hosting rows of per-node ``counts``, one entry per executor,
+    in priority order (tightly-pack's emission order)."""
+    counts = np.asarray(counts)
+    hosts = np.flatnonzero(counts > 0)
+    return np.repeat(hosts, counts[hosts])
+
+
+def evenly_rows(counts: np.ndarray) -> np.ndarray:
+    """Round-robin visit order: sweep t emits every node with count > t,
+    in priority order (matches the Go loop's append order)."""
+    counts = np.asarray(counts).astype(np.int64)
+    idx = np.flatnonzero(counts)
+    if idx.size == 0:
+        return idx
+    # (sweep, priority position) pairs for each emitted executor
+    sweeps = np.concatenate([np.arange(counts[i]) for i in idx])
+    positions = np.repeat(idx, counts[idx])
+    return positions[np.lexsort((positions, sweeps))]
+
+
+def names_of_rows(names: List[str], rows: np.ndarray) -> Tuple[List[str], int]:
+    """(the placement list of ``rows``, the distinct nodes in it).  The
+    one place a decode turns rows into Python objects: ``names`` is
+    indexed once per hosting node, never walked."""
+    hosts, slot = np.unique(rows, return_inverse=True)
+    host_names = [names[i] for i in hosts.tolist()]
+    return [host_names[j] for j in slot.tolist()], len(host_names)
 
 
 def min_frag_zone_decode(
-    names: List[str],
     avail_rows: np.ndarray,
     exec_row: np.ndarray,
     zone_exec_ok: np.ndarray,
@@ -133,46 +217,30 @@ def min_frag_zone_decode(
     k: int,
     strict_reference_parity: bool,
 ):
-    """Per-zone minimal-fragmentation decode shared by the single-AZ
-    single-app adapter and the FIFO solver's zone-choice lane: exact
-    bisect placements on device-equal capacities, the true per-node
-    counts (for the usage carry), and the efficiency-side counts —
-    zeroed under strict parity, where the reference's no-write-back
-    quirk makes the zone choice see only the driver's reservation.
-    Returns (executor_nodes, counts, eff_counts) or None (infeasible)."""
+    """Per-zone minimal-fragmentation decode on arrays, shared by the
+    single-AZ single-app adapter and the FIFO solver's zone choice:
+    exact placements on device-equal capacities (``minimal_fragmentation_rows``:
+    the hosting rows, one per executor in emission order; by name through
+    ``names_of_rows``), the true per-node counts (for the usage carry),
+    and the efficiency-side counts: zeroed under strict parity, where
+    the reference's no-write-back quirk makes the zone choice see only
+    the driver's reservation.
+    Returns (rows, counts, eff_counts) or None (infeasible)."""
     zcap = min_frag_unclamped_caps(avail_rows, exec_row, zone_exec_ok, d_idx, driver_row)
-    executor_nodes = minimal_fragmentation_assignment(names, zcap, k)
-    if executor_nodes is None:
+    rows = minimal_fragmentation_rows(zcap, k)
+    if rows is None:
         return None
-    counts = np.zeros(len(names), dtype=np.int64)
-    pos = {name: i for i, name in enumerate(names)}
-    for node in executor_nodes:
-        counts[pos[node]] += 1
+    counts = np.bincount(rows, minlength=len(zcap))
     eff_counts = np.zeros_like(counts) if strict_reference_parity else counts
-    return executor_nodes, counts, eff_counts
+    return rows, counts, eff_counts
 
 
 def counts_to_tightly_list(names: List[str], counts: np.ndarray) -> List[str]:
-    out: List[str] = []
-    for name, c in zip(names, counts):
-        if c > 0:
-            out.extend([name] * int(c))
-    return out
+    return names_of_rows(names, tightly_rows(counts))[0]
 
 
 def counts_to_evenly_list(names: List[str], counts: np.ndarray) -> List[str]:
-    """Round-robin visit order: sweep t emits every node with count > t,
-    in priority order (matches the Go loop's append order)."""
-    counts = counts.astype(np.int64)
-    k = int(counts.sum())
-    if k == 0:
-        return []
-    idx = np.flatnonzero(counts)
-    # (sweep, priority position) pairs for each emitted executor
-    sweeps = np.concatenate([np.arange(counts[i]) for i in idx])
-    positions = np.repeat(idx, counts[idx])
-    order = np.lexsort((positions, sweeps))
-    return [names[positions[j]] for j in order]
+    return names_of_rows(names, evenly_rows(counts))[0]
 
 
 class TpuBatchBinpacker:
@@ -297,9 +365,10 @@ class TpuBatchBinpacker:
                 driver_idx,
                 problem.driver[0],
             )
-            executor_nodes = minimal_fragmentation_assignment(names, cap, executor_count)
-            if executor_nodes is None:
+            rows = minimal_fragmentation_rows(cap, executor_count)
+            if rows is None:
                 return empty_packing_result()
+            executor_nodes = names_of_rows(names, rows)[0]
             # the reference's min-frag does NOT fold executor placements
             # into reserved for efficiency (packers.minimal_fragmentation
             # QUIRK, switchable) — under strict parity efficiency
@@ -307,9 +376,7 @@ class TpuBatchBinpacker:
             # placements in, mirroring the oracle's write-back
             counts = np.zeros(len(names), dtype=np.int64)
             if not self.strict_reference_parity:
-                pos = {name: i for i, name in enumerate(names)}
-                for node in executor_nodes:
-                    counts[pos[node]] += 1
+                counts = np.bincount(rows, minlength=len(names))
         else:
             cap = np.asarray(solve.exec_capacity)[: len(names)]
             counts = evenly_counts(cap, executor_count)
@@ -321,10 +388,10 @@ class TpuBatchBinpacker:
         # build reserved the same way the oracle mutates it
         dr = metadata[driver_node]  # noqa: F841 (existence check)
         reserved[driver_node] = self._scale_back(problem, problem.driver[0])
-        for name, c in zip(names, counts):
-            if c > 0:
-                add = self._scale_back(problem, problem.executor[0] * int(c))
-                reserved[name] = reserved.get(name, Resources.zero()).add(add)
+        hosts = np.flatnonzero(counts > 0)
+        for i, c in zip(hosts.tolist(), counts[hosts].tolist()):
+            add = self._scale_back(problem, problem.executor[0] * c)
+            reserved[names[i]] = reserved.get(names[i], Resources.zero()).add(add)
         return PackingResult(
             driver_node=driver_node,
             executor_nodes=executor_nodes,
@@ -416,8 +483,8 @@ class TpuSingleAzBinpacker:
     (device counts) or "minimal-fragmentation"
     (single_az_minimal_fragmentation semantics: zone feasibility and
     driver choice are policy-invariant, so the vmapped zone solves are
-    shared; placements come from the exact host bisect on device-equal
-    capacities, and under strict parity the reference's
+    shared; placements come from ``minimal_fragmentation_rows`` on
+    device-equal capacities, and under strict parity the reference's
     no-efficiency-write-back quirk makes the zone choice see only the
     driver's reservation)."""
 
@@ -502,7 +569,6 @@ class TpuSingleAzBinpacker:
             driver_node = names[d_idx]
             if self.inner_policy == "minimal-fragmentation":
                 decoded = min_frag_zone_decode(
-                    names,
                     problem.avail[:n],
                     problem.executor[0],
                     exec_ok_arr & zone_masks[zi][:n],
@@ -513,7 +579,8 @@ class TpuSingleAzBinpacker:
                 )
                 if decoded is None:  # unreachable: zone feasibility proven
                     continue
-                executor_nodes, _counts, eff_counts = decoded
+                rows, _counts, eff_counts = decoded
+                executor_nodes = names_of_rows(names, rows)[0]
             else:
                 zone_counts = counts[zi][:n]
                 executor_nodes = counts_to_tightly_list(names, zone_counts)

@@ -22,12 +22,13 @@ from ..tracing.profiling import PHASE_REQUEST, default_profiler
 from ..types.resources import NodeGroupSchedulingMetadata
 from .batch_adapter import (
     build_reserved,
-    counts_to_evenly_list,
-    counts_to_tightly_list,
     evenly_counts,
+    evenly_rows,
     min_frag_unclamped_caps,
     min_frag_zone_decode,
-    minimal_fragmentation_assignment,
+    minimal_fragmentation_rows,
+    names_of_rows,
+    tightly_rows,
 )
 from .efficiency import compute_packing_efficiencies
 from .packers import PackingResult, empty_packing_result
@@ -769,16 +770,28 @@ class TpuFifoSolver:
         on the host from host arrays: ``avail_after`` the post-queue
         carry, ``per_node`` the solve's executor counts (its capacities
         after the driver under distribute-evenly; min-frag reads
-        neither and assigns from the carry)."""
+        neither and assigns from the carry).
+
+        The decode computes on those arrays and makes Python objects for
+        the hosting nodes only: each policy yields the hosting rows, one
+        per executor in the reference's emission order (batch_adapter's
+        ``tightly_rows`` / ``evenly_rows`` / ``minimal_fragmentation_rows``;
+        the last is held equal to the host oracle's
+        ``packers.minimal_fragmentation_from_capacities``, which the
+        served path does not run, by tests/test_minfrag_rows.py), and
+        ``names_of_rows`` names them.  ``fast_path.decode`` says so in two
+        tags: ``hostNodes``, the distinct nodes that received an
+        executor, and ``objects``, the entries of the one per-node
+        Python list the decode built."""
         evenly = self.assignment_policy == "distribute-evenly"
         minfrag = self.assignment_policy == "minimal-fragmentation"
         names = cluster.node_names
         k = current_app.min_executor_count
-        with tracing.child_span("fast_path.decode"):
+        with tracing.child_span("fast_path.decode") as decode_span:
             driver_node = names[driver_idx]
             if evenly:
                 counts = evenly_counts(per_node[: len(names)], k)
-                executor_nodes = counts_to_evenly_list(names, counts)
+                rows = evenly_rows(counts)
             elif minfrag:
                 cap = min_frag_unclamped_caps(
                     avail_after[: len(names)],
@@ -787,22 +800,24 @@ class TpuFifoSolver:
                     driver_idx,
                     problem.driver[n_earlier],
                 )
-                executor_nodes = minimal_fragmentation_assignment(names, cap, k)
-                if executor_nodes is None:  # unreachable: feasibility proven above
+                rows = minimal_fragmentation_rows(cap, k)
+                if rows is None:  # unreachable: feasibility proven above
                     return FifoOutcome(
                         supported=True, earlier_ok=True, result=empty_packing_result()
                     )
                 # reference quirk: min-frag reports only the driver in
                 # reserved/efficiencies under strict parity (packers.
                 # make_minimal_fragmentation QUIRK, switchable)
-                counts = np.zeros(len(names), dtype=np.int64)
-                if not self.strict_reference_parity:
-                    pos = {name: i for i, name in enumerate(names)}
-                    for node in executor_nodes:
-                        counts[pos[node]] += 1
+                if self.strict_reference_parity:
+                    counts = np.zeros(len(names), dtype=np.int64)
+                else:
+                    counts = np.bincount(rows, minlength=len(names))
             else:
                 counts = per_node[: len(names)]
-                executor_nodes = counts_to_tightly_list(names, counts)
+                rows = tightly_rows(counts)
+            executor_nodes, host_nodes = names_of_rows(names, rows)
+            decode_span.tag("hostNodes", host_nodes)
+            decode_span.tag("objects", host_nodes)
 
         # efficiencies feed metrics only on this path (non-single-AZ
         # policies); the host lane computes them against the metadata
@@ -992,7 +1007,6 @@ class _ZoneProblem:
         for zi, z in enumerate(order):
             self.zone_vec[:n][members[z]] = zi
         self._zone_rows = None
-        self._index_of = None
 
     @property
     def zone_rows(self) -> List[np.ndarray]:
@@ -1003,11 +1017,6 @@ class _ZoneProblem:
                 np.flatnonzero(self.zone_vec[: self.n] == zi) for zi in range(self.n_zones)
             ]
         return self._zone_rows
-
-    def _index(self, name: str) -> int:
-        if self._index_of is None:
-            self._index_of = {nm: i for i, nm in enumerate(self.names)}
-        return self._index_of[name]
 
     def _average(self, avail, app_idx, d_idx, hosts, on_each, reserved_each, order=None) -> float:
         """The packing's average efficiency as single_az.go:75-97 takes
@@ -1116,17 +1125,18 @@ class _ZoneProblem:
                 # placements and their order are the drain's, from the
                 # exact host bisect on the same capacities
                 decoded = min_frag_zone_decode(
-                    self.names, avail[:n].astype(np.int64), executor,
+                    avail[:n].astype(np.int64), executor,
                     self.exec_ok & (self.zone_vec[:n] == zi), d_idx, driver, k, self.strict,
                 )
                 if decoded is None:  # unreachable: the zone is feasible
                     continue
-                nodes, counts, reserved_counts = decoded
+                rows, counts, reserved_counts = decoded
+                nodes = names_of_rows(self.names, rows)[0]
                 hosts = np.flatnonzero(counts)
                 on_each = counts[hosts]
                 avg = self._average(
                     avail, app_idx, d_idx, hosts, on_each, reserved_counts[hosts],
-                    order=[self._index(nm) for nm in nodes],
+                    order=rows.tolist(),
                 )
             else:
                 avg = self._average(avail, app_idx, d_idx, hosts, on_each, on_each)

@@ -70,6 +70,7 @@ def _host_oracle_single_az(
     from k8s_spark_scheduler_tpu.ops.batch_adapter import (
         counts_to_tightly_list,
         min_frag_zone_decode,
+        names_of_rows,
     )
     from k8s_spark_scheduler_tpu.ops.fifo_solver import efficiencies_from_rows
 
@@ -102,13 +103,14 @@ def _host_oracle_single_az(
             d_idx = int(zd[zi])
             if minfrag:
                 decoded = min_frag_zone_decode(
-                    names, avail.astype(np.int64)[:n], executors[ai],
+                    avail.astype(np.int64)[:n], executors[ai],
                     (exec_ok & zone_masks[zi])[:n], d_idx, drivers[ai],
                     int(counts[ai]), strict,
                 )
                 if decoded is None:
                     continue
-                executor_nodes, zcounts, eff_counts = decoded
+                rows, zcounts, eff_counts = decoded
+                executor_nodes = names_of_rows(names, rows)[0]
             else:
                 zcounts = zc[zi][:n].astype(np.int64)
                 executor_nodes = counts_to_tightly_list(names, zcounts)

@@ -209,6 +209,26 @@ def test_granted_driver_filter_crosses_the_device_boundary_once_each_way(binpack
         h.close()
 
 
+@pytest.mark.parametrize(
+    "binpack_algo",
+    ["tpu-batch", "tpu-batch-distribute-evenly", "tpu-batch-minimal-fragmentation"],
+)
+def test_the_decode_says_what_it_built_in_tags_and_the_tree_stays(binpack_algo):
+    """``fast_path.decode`` carries ``hostNodes`` (distinct nodes that
+    received an executor) and ``objects`` (per-node Python objects the
+    decode built): tags on a span the tree already had, no new child."""
+    h = served_harness("native", binpack_algo)
+    try:
+        root = granted_driver_root(h)
+        assert shape(root) == EXPECTED["native"]
+        decode = find(root, "fast_path.decode")
+        hosts = decode.tags["hostNodes"]
+        assert hosts in (1, 2)  # the granted driver asked for two executors
+        assert decode.tags == {"hostNodes": hosts, "objects": hosts}
+    finally:
+        h.close()
+
+
 @pytest.mark.parametrize("lane", ["native", "xla", "pallas"])
 def test_single_az_driver_filter_has_exactly_the_documented_children(lane):
     h = served_harness(lane, "tpu-batch-single-az")
