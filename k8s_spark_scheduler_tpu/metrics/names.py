@@ -38,6 +38,11 @@ EXECUTORS_WITH_NO_RESERVATION_COUNT = (
     "foundry.spark.scheduler.softreservation.executorswithnoreservations"
 )
 SOFT_RESERVATION_COMPACTION_TIME = "foundry.spark.scheduler.softreservation.compaction.time"
+# extra executors recorded as soft reservations, and soft-reserved
+# executors moved onto a freed hard slot by where the slot's node stood
+# (result=same-node|cross-node) (scheduler/reservations_manager.py)
+SOFT_RESERVATION_BINDS = "foundry.spark.scheduler.softreservation.binds"
+SOFT_RESERVATION_COMPACTIONS = "foundry.spark.scheduler.softreservation.compactions"
 POD_INFORMER_DELAY = "foundry.spark.scheduler.informer.delay"
 POD_INFORMER_DELAY_MAX = "foundry.spark.scheduler.informer.delay.max"
 SCHEDULING_WASTE = "foundry.spark.scheduler.scheduling.waste"

@@ -973,9 +973,21 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
     # -- executor path -------------------------------------------------------
 
     def _select_executor_node(self, executor: Pod, node_names: List[str]) -> Tuple[str, str]:
-        """resource.go:383-435."""
+        """resource.go:383-435.  One span, ``executor.select``, over the
+        whole choice; the reservation look-ups are one aggregate child
+        (``executor.reservation_lookup``, a phase per look-up), the
+        write of a rescheduled executor's reservation another
+        (``executor.soft_bind``)."""
+        with self._tracer.span("executor.select") as select:
+            return self._select_executor_node_traced(executor, node_names, select)
+
+    def _select_executor_node_traced(
+        self, executor: Pod, node_names: List[str], select
+    ) -> Tuple[str, str]:
+        lookup = "executor.reservation_lookup"
         try:
-            already_bound_node, found = self._rrm.find_already_bound_reservation_node(executor)
+            with select.aggregate(lookup):
+                already_bound_node, found = self._rrm.find_already_bound_reservation_node(executor)
         except KeyError as err:
             raise SchedulingFailure(
                 FAILURE_INTERNAL, f"error when looking for already bound reservations: {err}"
@@ -990,7 +1002,8 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             )
 
         try:
-            unbound_nodes, found_unbound = self._rrm.find_unbound_reservation_nodes(executor)
+            with select.aggregate(lookup):
+                unbound_nodes, found_unbound = self._rrm.find_unbound_reservation_nodes(executor)
         except KeyError as err:
             raise SchedulingFailure(
                 FAILURE_INTERNAL, f"error when looking for unbound reservations: {err}"
@@ -1007,9 +1020,10 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                 return result, SUCCESS
 
         try:
-            free_spots = self._rrm.get_remaining_allowed_executor_count(
-                executor.labels.get(L.SPARK_APP_ID_LABEL, ""), executor.namespace
-            )
+            with select.aggregate(lookup):
+                free_spots = self._rrm.get_remaining_allowed_executor_count(
+                    executor.labels.get(L.SPARK_APP_ID_LABEL, ""), executor.namespace
+                )
         except KeyError as err:
             raise SchedulingFailure(
                 FAILURE_INTERNAL, f"error when checking remaining allowed executors: {err}"
@@ -1018,7 +1032,8 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             is_extra_executor = not found_unbound
             node_name, outcome = self._reschedule_executor(executor, node_names, is_extra_executor)
             try:
-                self._rrm.reserve_for_executor_on_rescheduled_node(executor, node_name)
+                with self._tracer.span("executor.soft_bind"):
+                    self._rrm.reserve_for_executor_on_rescheduled_node(executor, node_name)
             except Exception as err:
                 raise SchedulingFailure(
                     FAILURE_INTERNAL, f"failed to reserve node for rescheduled executor: {err}"
@@ -1096,6 +1111,33 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                 executor, executor_resources, should_schedule_into_single_az, single_az_zone
             )
 
+        # the mirror's lane declined (inexact snapshot, demoted lane) or
+        # there is no mirror: the Quantity path answers, under a span of
+        # its own so that such a request can be told from a mirror-served one
+        with self._tracer.span(
+            "executor.quantity_reschedule", {"candidates": len(node_names)}
+        ):
+            return self._quantity_reschedule(
+                executor,
+                node_names,
+                executor_resources,
+                should_schedule_into_single_az,
+                single_az_zone,
+                potential_outcome,
+            )
+
+    def _quantity_reschedule(
+        self,
+        executor: Pod,
+        node_names: List[str],
+        executor_resources,
+        should_schedule_into_single_az: bool,
+        single_az_zone: str,
+        potential_outcome: str,
+    ) -> Tuple[str, str]:
+        """resource.go:617-672: Quantity arithmetic over every candidate
+        node's metadata, then first fit (or the min-frag variant) in
+        executor priority order."""
         available_nodes = self._get_nodes(node_names)
         if should_schedule_into_single_az:
             available_nodes = self._filter_nodes_to_zone(available_nodes, single_az_zone)
@@ -1217,16 +1259,19 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         from ..ops.fast_path import executor_reschedule_order
         from ..ops.tensorize import _resources_to_base
 
-        snap = self._tensor_snapshot.snapshot()
+        span.tag("candidates", len(node_names))
+        with self._tracer.span("executor.snapshot"):
+            snap = self._tensor_snapshot.snapshot()
         exec_row, exact = _resources_to_base(executor_resources)
         if not exact:
             return None
-        built = executor_reschedule_order(
-            snap,
-            list(node_names),
-            self._node_sorter.executor_label_priority,
-            zone,
-        )
+        with self._tracer.span("executor.order"):
+            built = executor_reschedule_order(
+                snap,
+                list(node_names),
+                self._node_sorter.executor_label_priority,
+                zone,
+            )
         if built is None:
             return None
         names, avail, overhead, res_entry = built

@@ -9,9 +9,10 @@ import logging
 import threading
 from typing import Dict, List, Optional, Tuple
 
-from .. import timesource
+from .. import timesource, tracing
 from ..kube.informer import Informer
 from ..analysis.guarded import guarded_by
+from ..metrics import names as mnames
 from ..state.softreservations import SoftReservation, SoftReservationStore
 from ..state.typed_caches import ResourceReservationCache
 from ..types.objects import (
@@ -235,7 +236,10 @@ class ResourceReservationManager:
         """Move soft reservations onto hard reservations freed by dead
         executors (resourcereservations.go:268-298)."""
         apps = self._drain_da_compaction_apps()
-        with self._mutex:
+        if not apps:
+            return  # every Filter comes here; most find nothing queued
+        with tracing.child_span("da.compact", {"apps": len(apps)}) as span, self._mutex:
+            moved = 0
             for app_id, namespace in apps.items():
                 sr, ok = self._soft_reservations.get_soft_reservation(app_id)
                 if not ok:
@@ -245,24 +249,27 @@ class ResourceReservationManager:
                     pod = pods.get(pod_name)
                     if pod is None:
                         continue  # no longer active
-                    self._compact_soft_reservation_pod(pod)
+                    moved += self._compact_soft_reservation_pod(pod)
+            span.tag("moved", moved)
 
-    def _compact_soft_reservation_pod(self, pod: Pod) -> None:
-        """resourcereservations.go:302-336 (caller holds the mutex)."""
+    def _compact_soft_reservation_pod(self, pod: Pod) -> bool:
+        """resourcereservations.go:302-336 (caller holds the mutex).
+        True where the pod took over a hard reservation."""
         app_id = pod.labels.get(L.SPARK_APP_ID_LABEL, "")
         try:
             unbound = self._get_unbound_reservations(app_id, pod.namespace)
         except KeyError:
             logger.exception("failed to get unbound reservations for %s", pod.name)
-            return
+            return False
         if not unbound:
-            return
+            return False
         # prefer an unbound reservation on the pod's own node
         for reservation_name, reservation_node in unbound.items():
             if reservation_node == pod.node_name:
                 self._bind_executor_to_resource_reservation(pod, reservation_name, reservation_node)
                 self._soft_reservations.remove_executor_reservation(app_id, pod.name)
-                return
+                self._metrics.counter(mnames.SOFT_RESERVATION_COMPACTIONS, {"result": "same-node"})
+                return True
         # cross-node: bind keeping the RESERVATION's node (the reference
         # passes unboundReservationsToNodes[name], resourcereservations.go
         # :326-335 — the reservation stays on its node and, since the pod
@@ -272,6 +279,8 @@ class ResourceReservationManager:
             pod, reservation_name, unbound[reservation_name]
         )
         self._soft_reservations.remove_executor_reservation(app_id, pod.name)
+        self._metrics.counter(mnames.SOFT_RESERVATION_COMPACTIONS, {"result": "cross-node"})
+        return True
 
     def _drain_da_compaction_apps(self) -> Dict[str, str]:
         with self._da_compaction_lock:
@@ -310,9 +319,6 @@ class ResourceReservationManager:
         # time-to-first-bind metric + slow log, only on the reservation's
         # first binding (resourcereservations.go:364-387)
         if first_bind and rr.meta.creation_timestamp:
-
-            from ..metrics import names as mnames
-
             duration = timesource.now() - rr.meta.creation_timestamp
             self._metrics.histogram(mnames.TIME_TO_FIRST_BIND, duration)
             snap = self._metrics.get_histogram(mnames.TIME_TO_FIRST_BIND)
@@ -339,6 +345,7 @@ class ResourceReservationManager:
         self._soft_reservations.add_reservation_for_pod(
             driver.labels.get(L.SPARK_APP_ID_LABEL, ""), executor.name, reservation
         )
+        self._metrics.counter(mnames.SOFT_RESERVATION_BINDS)
 
     def _get_unbound_reservations(self, app_id: str, namespace: str) -> Dict[str, str]:
         """reservationName → node for reservations that are unbound, bound

@@ -116,6 +116,35 @@ EXPECTED_SINGLE_AZ = {
 }
 
 
+# an executor's tree under ``predicate`` (``_select_executor_node``): the
+# reservation look-ups are one aggregate child, a phase per look-up; an
+# executor beyond its application's min is placed from the tensor mirror
+# (``executor.fast_reschedule``) and recorded (``executor.soft_bind``);
+# where the mirror's lane declines, or there is none, the Quantity path
+# answers under a name of its own
+LOOKUP = ("executor.reservation_lookup", [])
+FAST = ("executor.fast_reschedule", [("executor.snapshot", []), ("executor.order", [])])
+EXPECTED_EXECUTOR = {
+    "reserved": [
+        ("executor.select", [LOOKUP, ("state.writeback.enqueue", [])]),
+        ("provenance.finish", []),
+    ],
+    "extra": [
+        ("executor.select", [LOOKUP, FAST, ("executor.soft_bind", [])]),
+        ("provenance.finish", []),
+    ],
+    "refused": [("executor.select", [LOOKUP]), ("provenance.finish", [])],
+    "declined": [
+        ("executor.select", [LOOKUP, FAST, ("executor.quantity_reschedule", []), ("executor.soft_bind", [])]),
+        ("provenance.finish", []),
+    ],
+    "demoted": [
+        ("executor.select", [LOOKUP, ("executor.quantity_reschedule", []), ("executor.soft_bind", [])]),
+        ("provenance.finish", []),
+    ],
+}
+
+
 def shape(span):
     return [(c.name, shape(c)) for c in span.children]
 
@@ -225,6 +254,119 @@ def test_the_decode_says_what_it_built_in_tags_and_the_tree_stays(binpack_algo):
         hosts = decode.tags["hostNodes"]
         assert hosts in (1, 2)  # the granted driver asked for two executors
         assert decode.tags == {"hostNodes": hosts, "objects": hosts}
+    finally:
+        h.close()
+
+
+def dynamic_allocation_roots(h, min_count=1, max_count=3, app_id="app-da"):
+    """A dynamic-allocation application's driver and all its executors,
+    and one executor more than its max: {pod name: its ``predicate`` root}."""
+    h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
+    roots = roots_of(h)
+    pods = h.dynamic_allocation_spark_pods(app_id, min_count, max_count)
+    one_more = h.dynamic_allocation_spark_pods(app_id, min_count, max_count + 1)[-1]
+    for pod in [*pods, one_more]:
+        h.schedule(pod, NODES)
+    return {r.tags["pod"]: r for r in roots if r.name == "predicate"}
+
+
+@pytest.mark.parametrize("allocation", ["static", "dynamic"])
+def test_a_driver_filter_has_the_tree_it_had_before_executors_were_named(allocation):
+    """The executor spans (``executor.*``, ``da.compact``) are children of
+    ``predicate`` on executor requests only: a driver's tree is the
+    documented one, letter for letter, whether or not its application
+    allocates dynamically, so ``lock_unnamed_ms`` (``predicate``'s self
+    time on driver requests) reads what it read."""
+    h = served_harness("native")
+    try:
+        if allocation == "static":
+            root = granted_driver_root(h)
+        else:
+            root = dynamic_allocation_roots(h)["app-da-driver"]
+        assert shape(root) == EXPECTED["native"]
+        assert not [n for n in names(root) if n.startswith("executor.") or n == "da.compact"]
+    finally:
+        h.close()
+
+
+def test_an_executor_filter_has_exactly_the_documented_children():
+    h = served_harness("native")
+    try:
+        by_pod = dynamic_allocation_roots(h)
+        reserved, extra, last_extra, refused = (by_pod[f"app-da-exec-{i}"] for i in (1, 2, 3, 4))
+        assert shape(reserved) == EXPECTED_EXECUTOR["reserved"]
+        assert find(reserved, "executor.reservation_lookup").tags == {"count": 2}  # already bound? unbound?
+        assert shape(extra) == shape(last_extra) == EXPECTED_EXECUTOR["extra"]
+        lookup = find(extra, "executor.reservation_lookup")
+        assert type(lookup) is tracing.AggregateSpan and lookup.tags == {"count": 3}  # and the remaining count
+        assert find(extra, "executor.fast_reschedule").tags == {"candidates": len(NODES), "hit": True}
+        assert extra.tags["outcome"] == "success-scheduled-extra-executor"
+        # max - min soft reservations are held: the next executor is refused before any placement
+        assert shape(refused) == EXPECTED_EXECUTOR["refused"]
+        assert refused.tags["outcome"] == "failure-unbound"
+        metrics = h.server.metrics
+        assert metrics.get_counter(mnames.SOFT_RESERVATION_BINDS) == 2
+        assert metrics.get_counter(mnames.TPU_FASTPATH, {"path": "executor", "lane": "fast"}) == 2
+        assert metrics.get_counter(mnames.TPU_FASTPATH, {"path": "executor", "lane": "slow"}) == 0
+    finally:
+        h.close()
+
+
+class _RescheduleLaneDemoted:
+    """A lane-health table in which the mirror's executor lane is demoted."""
+
+    def allow(self, lane):
+        return lane != "tensor_reschedule"
+
+    def __getattr__(self, name):
+        return lambda *args, **kwargs: None
+
+
+@pytest.mark.parametrize("why", ["declined", "demoted"])
+def test_an_extra_executor_the_mirror_did_not_place_is_named_for_the_quantity_path(why):
+    """The slow lane is no fallback (nothing raised, nothing is counted
+    by ``host_fallbacks``): its name on the request is how a reader tells
+    it from a mirror-served one."""
+    h = served_harness("native")
+    try:
+        if why == "declined":
+            h.extender._tensor_snapshot._exact = False  # as after a quantity the mirror cannot hold exactly
+        else:
+            h.extender._lane_health = _RescheduleLaneDemoted()
+        extra = dynamic_allocation_roots(h)["app-da-exec-2"]
+        assert shape(extra) == EXPECTED_EXECUTOR[why]
+        assert find(extra, "executor.quantity_reschedule").tags == {"candidates": len(NODES)}
+        assert extra.tags["outcome"] == "success-scheduled-extra-executor"
+        if why == "declined":
+            assert "hit" not in find(extra, "executor.fast_reschedule").tags
+        assert h.extender.host_fallbacks() == 0
+        assert h.server.metrics.get_counter(mnames.TPU_FASTPATH, {"path": "executor", "lane": "slow"}) == 2
+    finally:
+        h.close()
+
+
+def test_compaction_is_a_span_of_the_filter_that_found_something_to_compact():
+    h = served_harness("native")
+    try:
+        pods = h.dynamic_allocation_spark_pods("app-da", 1, 3)
+        for pod in pods:
+            h.assert_success(h.schedule(pod, NODES))
+        roots = roots_of(h)
+        h.delete_pod(pods[1])  # the executor that held the hard slot
+        replacement = h.dynamic_allocation_spark_pods("app-da", 1, 4)[4]
+        h.assert_success(h.schedule(replacement, NODES))
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-after", 1)[0], NODES))
+        first, after = [r for r in roots if r.name == "predicate"]
+        assert [c.name for c in first.children] == ["da.compact", "executor.select", "provenance.finish"]
+        compact = find(first, "da.compact")
+        assert compact.tags["apps"] == 1 and 1 <= compact.tags["moved"] <= 2
+        assert shape(compact) == [("state.writeback.enqueue", [])] * compact.tags["moved"]  # a slot's new binding each
+        moved = sum(
+            h.server.metrics.get_counter(mnames.SOFT_RESERVATION_COMPACTIONS, {"result": result})
+            for result in ("same-node", "cross-node")
+        )
+        assert moved == compact.tags["moved"]
+        assert find(after, "da.compact") is None  # nothing queued: no span
     finally:
         h.close()
 
