@@ -152,6 +152,10 @@ QUEUE_VIEW_READS = "foundry.spark.scheduler.fifo.queue.view.reads"
 # (ops/fast_path.py, keyed by structure revision, affinity signature and
 # candidate list): result=hit|miss|uncacheable
 PREP_CACHE_READS = "foundry.spark.scheduler.tpu.fastpath.prepcache.reads"
+# executor reschedules from the mirror by how their candidate rows were
+# come by (ops/fast_path.py, keyed by structure revision, candidate list
+# and executor label priority): result=hit|miss|uncacheable
+EXECUTOR_ROWS_READS = "foundry.spark.scheduler.tpu.fastpath.executorrows.reads"
 # queue apps of single-AZ driver Filters by who chose their zone
 # (result=certified|resolved|host-queue), ops/fifo_solver.py
 FIFO_ZONE_CHOICE = "foundry.spark.scheduler.fifo.zone.choice"

@@ -17,12 +17,18 @@ derives from Quantity metadata:
 - the required-node-affinity filter over snapshot label dicts
   (resource.go:292-295).
 
+An executor's reschedule (resource.go:594-663) needs only the head of
+that order among the nodes that fit: `first_in_executor_order` selects
+it as a lexicographic minimum over candidate rows kept between requests
+(`executor_rows_keyed`), sorting nothing.
+
 Only usable when the snapshot is exact; callers fall back to the
 Quantity path otherwise.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -30,6 +36,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..metrics.names import EXECUTOR_ROWS_READS, PREP_CACHE_READS
 from ..state.tensor_snapshot import TensorSnapshot
 from .nodesort import LabelPriorityOrder
 from .tensorize import INT32_SAFE, ClusterTensor
@@ -75,59 +82,102 @@ def _base_priority_order(
     )
 
 
-def executor_reschedule_order(
-    snap: TensorSnapshot,
-    candidate_names: List[str],
-    executor_label_priority: Optional[LabelPriorityOrder] = None,
-    zone: Optional[str] = None,
-) -> Optional[Tuple[List[str], np.ndarray, np.ndarray, np.ndarray]]:
-    """Executor priority order + exact availability for the executor
-    reschedule path (resource.go:594-663): metadata restricted to the
-    kube-scheduler candidate list (optionally one zone for single-AZ
-    dynamic allocation), AZ-aware sort keyed on
-    avail = allocatable − usage − overhead, executor candidates
-    ready ∧ ¬unschedulable, then the label-priority stable re-sort.
+@dataclass
+class _ExecutorRows:
+    """What an executor's reschedule needs of the node TABLE and the
+    request's candidate list (resource.go:594-663's metadata restricted to
+    kube-scheduler's names), in the snapshot's row space: nothing here
+    depends on usage or overhead, so it is kept between requests, keyed
+    like `_BuildPrep`."""
 
-    Returns (names_in_order, avail_rows [M,3] int64, overhead_rows
-    [M,3] int64, reservation_entry_mask [M] bool) or None when the
-    snapshot is inexact.  Zone totals for the AZ sort are computed over
-    ALL candidate nodes (including not-ready ones), exactly like the
-    slow path's metadata."""
-    if not snap.exact:
-        return None
+    # [N] bool: named by the request (a name twice counts once, like the
+    # slow path's metadata dict; an unknown name is no row) ∧ ready ∧
+    # ¬unschedulable
+    exec_ok: np.ndarray
+    # the candidate rows, not-ready ones included, one zone after another,
+    # so that the zone totals are one reduceat
+    by_zone: np.ndarray
+    zone_starts: np.ndarray     # where each zone that has a candidate starts in by_zone
+    zone_ids: np.ndarray        # those zones' ids, in by_zone's order
+    zone_name_rank: np.ndarray  # and the rank of each one's name among them
+    label_rank: Optional[np.ndarray]  # [N], executor label priority
+    name_index: Dict[str, int]  # node name → row
+
+
+def _compute_executor_rows(snap, candidate_names, elp) -> _ExecutorRows:
     nidx = snap.name_index
     rows = np.fromiter(
-        (nidx.get(nm, -1) for nm in candidate_names),
+        map(nidx.get, candidate_names, itertools.repeat(-1)),
         dtype=np.int64,
         count=len(candidate_names),
     )
-    idx = np.unique(rows[rows >= 0])  # dedupe like the slow path's metadata dict
-    if zone is not None:
-        try:
-            zi = snap.zone_names.index(zone)
-        except ValueError:
-            idx = idx[:0]
-        else:
-            idx = idx[snap.zone_id[idx] == zi]
-    if len(idx) == 0:
-        return [], np.zeros((0, 3), np.int64), np.zeros((0, 3), np.int64), np.zeros(0, bool)
-
-    avail = snap.avail[idx]
-    order = _base_priority_order(snap, idx, avail)
-
-    exec_ok = snap.ready[idx] & ~snap.unschedulable[idx]
-    order = order[exec_ok[order]]
-    if executor_label_priority is not None:
-        keys = _label_ranks([snap.labels[i] for i in idx], executor_label_priority)
-        order = order[np.argsort(keys[order], kind="stable")]
-
-    sel = idx[order]
-    return (
-        [snap.names[i] for i in sel],
-        avail[order],  # == snap.avail[sel] without re-materializing the property
-        snap.overhead[sel],
-        snap.res_entries[sel],
+    is_cand = np.zeros(len(snap.names), dtype=bool)
+    is_cand[rows[rows >= 0]] = True
+    idx = np.flatnonzero(is_cand)
+    by_zone = idx[np.argsort(snap.zone_id[idx], kind="stable")]
+    zone_ids, zone_starts = np.unique(snap.zone_id[by_zone], return_index=True)
+    zone_names = np.array([snap.zone_names[z] for z in zone_ids], dtype=object)
+    return _ExecutorRows(
+        exec_ok=is_cand & snap.ready & ~snap.unschedulable,
+        by_zone=by_zone,
+        zone_starts=zone_starts,
+        zone_ids=zone_ids,
+        zone_name_rank=np.argsort(np.argsort(zone_names)),
+        label_rank=_label_ranks(snap.labels, elp) if elp is not None else None,
+        name_index=nidx,
     )
+
+
+def rows_fitting(avail: np.ndarray, row: np.ndarray) -> np.ndarray:
+    """[N] bool: avail ≥ row in every dimension (column by column: a
+    reduction along the short axis of [N, 3] costs several times as much)."""
+    fits = avail[:, 0] >= row[0]
+    for dim in range(1, avail.shape[1]):
+        fits &= avail[:, dim] >= row[dim]
+    return fits
+
+
+def first_in_executor_order(
+    snap: TensorSnapshot,
+    rows: _ExecutorRows,
+    avail: np.ndarray,
+    mask: np.ndarray,
+    lead_keys=(),
+) -> int:
+    """The head of the executor priority order (nodesorting.go:95-122 and
+    161-180) among the candidates that `mask` admits, as a selection: the
+    row that is the lexicographic minimum of (lead_keys..., label rank
+    where configured, zone priority, memory, cpu, name), which is where
+    the stable sorts put it, with no sort over the rows and no name built.
+
+    `avail`, `mask` and the keys are in the snapshot's row space.  The
+    zones are ranked by their exact int64 totals of `avail` over every
+    candidate, not-ready nodes included, as the slow path's metadata has
+    them.  Returns -1 where the mask admits no executor candidate."""
+    cand = np.flatnonzero(mask & rows.exec_ok)
+    if not len(cand):
+        return -1
+    mem, cpu = avail[:, 1], avail[:, 0]
+    zone_mem = np.add.reduceat(mem[rows.by_zone], rows.zone_starts)
+    zone_cpu = np.add.reduceat(cpu[rows.by_zone], rows.zone_starts)
+    zone_priority = np.zeros(len(snap.zone_names), dtype=np.int64)
+    zone_priority[
+        rows.zone_ids[np.lexsort((rows.zone_name_rank, zone_cpu, zone_mem))]
+    ] = np.arange(len(rows.zone_ids))
+    for key in (
+        *lead_keys,
+        rows.label_rank,
+        zone_priority[snap.zone_id],
+        mem,
+        cpu,
+        snap.name_rank,
+    ):
+        if len(cand) == 1:
+            break
+        if key is not None:
+            of_cand = key[cand]
+            cand = cand[of_cand == of_cand.min()]
+    return int(cand[0])
 
 
 @dataclass
@@ -150,7 +200,8 @@ class _BuildPrep:
 
 
 _PREP_CACHE: OrderedDict = OrderedDict()
-_PREP_CACHE_MAX = 32
+_EXECUTOR_ROWS_CACHE: OrderedDict = OrderedDict()
+_PREP_CACHE_MAX = 32  # of each
 _prep_lock = threading.Lock()
 
 
@@ -217,16 +268,33 @@ def _compute_prep(snap, driver_pod, candidate_names, dlp, elp) -> _BuildPrep:
     )
 
 
-def _note_prep(result: str) -> None:
-    """How the prework was come by (hit, miss, uncacheable): a tag on the
-    active span and a count in the server's registry, which the kernel
-    profiler is bound to."""
-    from ..metrics.names import PREP_CACHE_READS
+def _kept(cache: OrderedDict, key, tag: str, counter: str, compute):
+    """`compute()`, or what an earlier request computed under the same
+    exact key (None = uncacheable).  How it was come by (hit, miss,
+    uncacheable) is a tag on the active span and a count in the server's
+    registry, which the kernel profiler is bound to."""
     from ..tracing import add_tag
     from ..tracing.profiling import default_profiler
 
-    add_tag("prepCache", result)
-    default_profiler.metrics.counter(PREP_CACHE_READS, {"result": result})
+    value = None
+    if key is not None:
+        with _prep_lock:
+            value = cache.get(key)
+            if value is not None:
+                cache.move_to_end(key)
+    result = "uncacheable" if key is None else "miss" if value is None else "hit"
+    # a prep miss at 10k nodes is ~20ms of the request — worth seeing on
+    # the span when hunting a latency outlier
+    add_tag(tag, result)
+    default_profiler.metrics.counter(counter, {"result": result})
+    if value is None:
+        value = compute()
+        if key is not None:
+            with _prep_lock:
+                cache[key] = value
+                while len(cache) > _PREP_CACHE_MAX:
+                    cache.popitem(last=False)
+    return value
 
 
 def build_prep_keyed(snap, driver_pod, candidate_names, dlp, elp):
@@ -248,22 +316,26 @@ def build_prep_keyed(snap, driver_pod, candidate_names, dlp, elp):
             _lp_sig(dlp),
             _lp_sig(elp),
         )
-        with _prep_lock:
-            hit = _PREP_CACHE.get(key)
-            if hit is not None:
-                _PREP_CACHE.move_to_end(key)
-                _note_prep("hit")
-                return hit, key
-    # a miss at 10k nodes is ~20ms of the request — worth seeing on the
-    # span when hunting a latency outlier
-    _note_prep("miss" if key is not None else "uncacheable")
-    prep = _compute_prep(snap, driver_pod, candidate_names, dlp, elp)
-    if key is not None:
-        with _prep_lock:
-            _PREP_CACHE[key] = prep
-            while len(_PREP_CACHE) > _PREP_CACHE_MAX:
-                _PREP_CACHE.popitem(last=False)
+    prep = _kept(
+        _PREP_CACHE, key, "prepCache", PREP_CACHE_READS,
+        lambda: _compute_prep(snap, driver_pod, candidate_names, dlp, elp),
+    )
     return prep, key
+
+
+def executor_rows_keyed(snap, candidate_names, elp) -> _ExecutorRows:
+    """The candidate rows of an executor's reschedule, kept per
+    (structure revision, candidate tuple, executor label priority): the
+    node table changes with node events only and kube-scheduler sends the
+    same list with every pod (the interned tuple on the HTTP path), so on
+    a hit no name is looked up."""
+    key = None
+    if snap.structure_key[0] >= 0:
+        key = (snap.structure_key, tuple(candidate_names), _lp_sig(elp))
+    return _kept(
+        _EXECUTOR_ROWS_CACHE, key, "rowsCache", EXECUTOR_ROWS_READS,
+        lambda: _compute_executor_rows(snap, candidate_names, elp),
+    )
 
 
 def _build_prep(snap, driver_pod, candidate_names, dlp, elp) -> _BuildPrep:

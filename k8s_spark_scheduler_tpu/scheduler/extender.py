@@ -1215,15 +1215,17 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         zone: Optional[str],
     ):
         """Executor reschedule served entirely from the tensor mirror:
-        AZ-aware executor order (including label priority) and the fit
-        check in vectorized integer math.  Returns (hit, node_name) or
-        None to use the Quantity path.  Decision parity: availability
-        rows equal the slow path's alloc − reserved − overhead exactly
-        (tests/test_tensor_snapshot.py); the double-overhead reschedule
-        quirk applies to reservation-entry nodes under strict parity
-        (compat.py #1).  The single-az-minimal-fragmentation policy's
-        app-attraction variant (resource.go:675-703) is served as a
-        vectorized lexicographic min instead of first-fit."""
+        the first node that fits, in AZ-aware executor order (including
+        label priority), as one selection over the mirror's rows in
+        integer math (ops/fast_path.py:first_in_executor_order).  Returns
+        (hit, node_name) or None to use the Quantity path.  Decision
+        parity: availability rows equal the slow path's alloc − reserved
+        − overhead exactly (tests/test_tensor_snapshot.py); the
+        double-overhead reschedule quirk applies to reservation-entry
+        nodes under strict parity (compat.py #1).  The
+        single-az-minimal-fragmentation policy's app-attraction variant
+        (resource.go:675-703) is the same selection behind two leading
+        keys instead of first-fit."""
         self.last_reschedule_path = "slow"
         if self._tensor_snapshot is None or not self._fast_path_ok:
             return None
@@ -1256,7 +1258,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
     def _try_fast_reschedule_traced(
         self, executor, node_names, executor_resources, zone, span
     ):
-        from ..ops.fast_path import executor_reschedule_order
+        from ..ops.fast_path import executor_rows_keyed, first_in_executor_order, rows_fitting
         from ..ops.tensorize import _resources_to_base
 
         span.tag("candidates", len(node_names))
@@ -1265,75 +1267,64 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         exec_row, exact = _resources_to_base(executor_resources)
         if not exact:
             return None
+        # the question asked is which node comes first, in executor
+        # priority order, among those that fit: a selection over the
+        # candidate rows, which are kept between requests
         with self._tracer.span("executor.order"):
-            built = executor_reschedule_order(
-                snap,
-                list(node_names),
-                self._node_sorter.executor_label_priority,
-                zone,
+            if not snap.exact:
+                return None
+            rows = executor_rows_keyed(
+                snap, node_names, self._node_sorter.executor_label_priority
             )
-        if built is None:
-            return None
-        names, avail, overhead, res_entry = built
-        row = np.array(exec_row, dtype=np.int64)
-        if self._is_single_az_min_frag():
-            hit_name = self._fast_min_frag_reschedule(
-                executor, names, avail, overhead, row
-            )
-            self.last_reschedule_path = "fast"
-            span.tag("hit", hit_name is not None)
-            if hit_name is not None:
-                return True, hit_name
-            return False, None
-        fit_avail = avail
-        if self._strict_reference_parity and len(names):
-            # QUIRK #1 (resource.go:638-643): nodes with a usage
-            # entry see overhead subtracted twice on this path
-            fit_avail = avail.copy()
-            fit_avail[res_entry] -= overhead[res_entry]
-        fits = (fit_avail >= row[None, :]).all(axis=1)
-        hit = np.flatnonzero(fits)
+            row = np.array(exec_row, dtype=np.int64)
+            avail = snap.avail
+            if self._is_single_az_min_frag():
+                mask, lead_keys = self._min_frag_keys(executor, snap, rows, avail, row)
+            else:
+                fit_avail = avail
+                if self._strict_reference_parity:
+                    # QUIRK #1 (resource.go:638-643): nodes with a usage
+                    # entry see overhead subtracted twice on this path
+                    fit_avail = avail - snap.overhead * snap.res_entries[:, None]
+                mask, lead_keys = rows_fitting(fit_avail, row), ()
+            if zone is not None:
+                # single-AZ dynamic allocation: the application's zone only
+                zone_id = snap.zone_names.index(zone) if zone in snap.zone_names else -1
+                mask &= snap.zone_id == zone_id
+            at = first_in_executor_order(snap, rows, avail, mask, lead_keys)
         self.last_reschedule_path = "fast"
-        span.tag("hit", bool(len(hit)))
-        if len(hit):
-            return True, names[int(hit[0])]
+        span.tag("hit", at >= 0)
+        if at >= 0:
+            return True, snap.names[at]
         return False, None
 
-    def _fast_min_frag_reschedule(self, executor, names, avail, overhead, row):
-        """resource.go:675-703 from the mirror: capacity per node with
-        overhead passed as the reserved map (the reference's
-        GetNodeCapacities call — net DOUBLE overhead on top of the
-        availability rows, which already subtract it once; unconditional
-        in the reference, unlike the first-fit branch's flagged quirk),
-        then the best node = lexicographic min of (not-hosting-this-app,
-        capacity, priority position) among capacity ≥ 1 — identical to
-        the sequential strict-improvement loop."""
-        if not len(names):
-            return None
+    def _min_frag_keys(self, executor, snap, rows, avail, row):
+        """resource.go:675-703 from the mirror, as (mask, leading keys) of
+        the selection: capacity per node with overhead passed as the
+        reserved map (the reference's GetNodeCapacities call — net DOUBLE
+        overhead on top of the availability rows, which already subtract
+        it once; unconditional in the reference, unlike the first-fit
+        branch's flagged quirk), then the best node = lexicographic min of
+        (not-hosting-this-app, capacity, priority position) among
+        capacity ≥ 1 — identical to the sequential strict-improvement
+        loop."""
         # capacity_against_single_dimension per dim: reserved > available
         # → 0; zero requirement → unbounded; else exact floor division
-        diff = avail - overhead
+        overhead = snap.overhead
         per_dim = np.where(
             overhead > avail,
             np.int64(0),
             np.where(
                 row[None, :] == 0,
                 np.int64(2**62),
-                np.floor_divide(diff, np.maximum(row[None, :], 1)),
+                np.floor_divide(avail - overhead, np.maximum(row[None, :], 1)),
             ),
         )
-        cap = per_dim.min(axis=1)
-        candidates = np.flatnonzero(cap >= 1)
-        if not len(candidates):
-            return None
+        capacity = per_dim.min(axis=1)
+        not_hosting = np.ones(len(capacity), dtype=bool)
         app_nodes = self._get_nodes_with_executors_belonging_to_same_app(executor)
-        not_in_app = np.fromiter(
-            (names[i] not in app_nodes for i in candidates),
-            dtype=bool,
-            count=len(candidates),
-        )
-        order = np.lexsort((candidates, cap[candidates], not_in_app))
-        return names[int(candidates[order[0]])]
+        not_hosting[[rows.name_index[nm] for nm in app_nodes if nm in rows.name_index]] = False
+        return capacity >= 1, (not_hosting, capacity)
 
     def _reschedule_executor_with_minimal_fragmentation(
         self,

@@ -19,6 +19,7 @@ from test_span_contract import EXPECTED_EXECUTOR, find, shape
 
 from k8s_spark_scheduler_tpu.metrics import names as mnames
 from k8s_spark_scheduler_tpu.testing.harness import Harness
+from k8s_spark_scheduler_tpu.types.resources import ZONE_LABEL
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
 POLICIES = {"tpu-batch": "tightly-pack", "tpu-batch-minimal-fragmentation": "minimal-fragmentation"}
@@ -144,6 +145,84 @@ def test_an_application_never_holds_more_than_max_less_min_soft_reservations():
         # an executor that asks again is given the node it holds, and no second reservation
         assert h.assert_success(h.schedule(pods[3], names)) == held.reservations["app-da-exec-3"].node
         assert h.server.metrics.get_counter(mnames.SOFT_RESERVATION_BINDS) == 2
+    finally:
+        h.close()
+
+
+def _add_a_smaller_node(h):
+    h.new_node("n9", cpu="2", memory="4Gi", gpu="0")
+
+
+def _change_node(name, **fields):
+    def change(h):
+        node = h.api.get("Node", "default", name)
+        for field, value in fields.items():
+            setattr(node, field, value)
+        h.api.update(node)
+    return change
+
+
+def _move_to_a_zone_of_its_own(h):
+    node = h.api.get("Node", "default", "n2")
+    node.meta.labels = {**node.meta.labels, ZONE_LABEL: "zone2"}
+    h.api.update(node)
+
+
+# what happens between the third and the fourth executor: (the event, the
+# list the fourth is asked with, how its candidate rows are come by, its node)
+FOUR = ["n0", "n1", "n2", "n3"]
+BETWEEN_TWO_EXTRAS = {
+    "nothing": (lambda h: None, FOUR, "hit", "n1"),
+    "node-added": (_add_a_smaller_node, FOUR + ["n9"], "miss", "n9"),
+    # ... and left out of the list that is asked with, which is the kept one: the table changed all the same
+    "node-added-unnamed": (_add_a_smaller_node, FOUR, "miss", "n1"),
+    "node-deleted": (lambda h: h.api.delete("Node", "default", "n1"), FOUR, "miss", "n2"),
+    "node-relabelled": (_move_to_a_zone_of_its_own, FOUR, "miss", "n2"),  # the zone with least free comes first
+    "node-cordoned": (_change_node("n1", unschedulable=True), FOUR, "miss", "n2"),
+    "node-not-ready": (_change_node("n1", ready=False), FOUR, "miss", "n2"),
+    "another-list": (lambda h: None, ["n3", "n0", "n2", "n7"], "miss", "n2"),  # as long, one name unknown
+}
+
+
+@pytest.mark.parametrize("between", sorted(BETWEEN_TWO_EXTRAS))
+def test_the_kept_candidate_rows_are_dropped_when_the_node_table_or_the_list_changes(between):
+    """`executor.order` says how it came by the candidate rows (tag
+    `rowsCache`, counted in `...fastpath.executorrows.reads`): computed for
+    the first extra executor, kept for the next, and computed anew after
+    any node event (a new `structure_key`) or for another list, so that
+    the node chosen is the one the table as it stands gives."""
+    event, asked_with, how, node = BETWEEN_TWO_EXTRAS[between]
+    h = Harness(binpack_algo="tpu-batch")
+    try:
+        for name in FOUR:
+            h.new_node(name, cpu="4", memory="8Gi", gpu="0")
+        pods = h.dynamic_allocation_spark_pods("app-da", 1, 5)
+        roots = []
+        h.server.tracer.add_observer(roots.append)
+
+        def reads():
+            return {
+                result: h.server.metrics.get_counter(mnames.EXECUTOR_ROWS_READS, {"result": result})
+                for result in ("hit", "miss", "uncacheable")
+            }
+
+        before = reads()
+        for pod in pods[:4]:  # the driver, the reserved executor and two extras fill n0
+            assert h.assert_success(h.schedule(pod, FOUR)) == "n0"
+        event(h)
+        assert h.assert_success(h.schedule(pods[4], asked_with)) == node
+        assert h.assert_success(h.schedule(pods[5], asked_with)) == node
+        by_pod = {r.tags["pod"]: r for r in roots if r.name == "predicate"}
+        tags = [find(by_pod[f"app-da-exec-{i}"], "executor.order").tags for i in (2, 3, 4, 5)]
+        assert tags == [{"rowsCache": "miss"}, {"rowsCache": "hit"}, {"rowsCache": how}, {"rowsCache": "hit"}]
+        counted = {result: n - before[result] for result, n in reads().items()}
+        assert counted == {"hit": 3 if how == "hit" else 2, "miss": 1 if how == "hit" else 2, "uncacheable": 0}
+        # the tags the span contract pins are the reschedule's own, as they were
+        fast = find(by_pod["app-da-exec-4"], "executor.fast_reschedule")
+        assert fast.tags == {"candidates": len(asked_with), "hit": True}
+        assert [c.name for c in fast.children] == ["executor.snapshot", "executor.order"]
+        assert h.extender.host_fallbacks() == 0
+        assert h.server.metrics.get_counter(mnames.TPU_FASTPATH, {"path": "executor", "lane": "slow"}) == 0
     finally:
         h.close()
 

@@ -207,8 +207,8 @@ def test_fast_reschedule_lane_engages_and_matches_slow_lane():
 
     # variants: (binpack algo, single-az DA flag, executor label priority)
     # — "labels" exercises the lane's label-priority re-sort, "zone" its
-    # single-AZ zone restriction (executor_reschedule_order's two
-    # branches beyond the plain first-fit)
+    # single-AZ zone restriction (a key and a mask of the lane's
+    # selection, ops/fast_path.py:first_in_executor_order)
     variants = {
         "plain": ("tightly-pack", False, None),
         "labels": (
@@ -291,6 +291,143 @@ def test_fast_reschedule_lane_engages_and_matches_slow_lane():
                 if any(results["fast"]):
                     assert lanes["fast"] == "fast", tag
                     assert lanes["slow"] == "slow", tag
+
+
+# what each case of the selection's parity test builds: the cluster, the
+# candidate list the extra executors are probed with, the install
+SELECTION_CASES = {
+    # sizes, zones, pools, overhead and usage all drawn
+    "random": {},
+    # identical nodes in one zone, two pods wide: every other executor
+    # opens a node, the first by NAME, and names are drawn so that their
+    # order is not the rows'
+    "name-tie": {"zones": ["az-0"], "per_zone": 6, "cpus": [2], "mems": [16], "overhead": 0, "want": "first-name"},
+    # the app placed on nodes that the probe's list leaves out, and the
+    # same fresh nodes in every zone: equal zone totals, so the zone's
+    # NAME decides, and zone ids are handed out in the other order
+    "zone-tie": {"zones": ["az-z", "az-m", "az-a"], "fresh_per_zone": 3, "overhead": 0, "want": "first-zone"},
+    # nodes that take no executor and still count in their zone's total
+    "not-ready": {"sidelined": True},
+    "duplicate-and-unknown-names": {"candidates": "noisy"},
+    "strict-subset": {"candidates": "subset"},
+    "label-priority": {"label_priority": True},
+    "zone-restriction": {"algo": "single-az-tightly-pack", "single_az": True, "zone": True},
+    "minimal-fragmentation": {"algo": "single-az-minimal-fragmentation"},  # over every zone
+    "minimal-fragmentation-in-zone": {
+        "algo": "tpu-batch-single-az-minimal-fragmentation", "single_az": True, "zone": True,
+    },
+    # every node filled by other pods after the min executors are placed
+    "no-node-fits": {"fill": True, "want": "miss"},
+}
+
+
+@pytest.mark.parametrize("strict", [True, False], ids=["strict-parity", "overhead-once"])
+@pytest.mark.parametrize("case", sorted(SELECTION_CASES))
+def test_the_mirrors_selection_is_the_quantity_paths_first_fit(case, strict):
+    """The extra executor's node as the mirror selects it (the minimum of
+    (label rank, zone priority, memory, cpu, name) over the rows that fit,
+    ops/fast_path.py:first_in_executor_order) is the node the Quantity
+    path finds (`_node_sorter.potential_nodes`, then first fit or the
+    min-frag loop), on the same state, executor after executor."""
+    import random
+
+    from k8s_spark_scheduler_tpu.config import Install
+    from k8s_spark_scheduler_tpu.ops.nodesort import LabelPriorityOrder
+    from k8s_spark_scheduler_tpu.scheduler.extender import SchedulingFailure
+    from k8s_spark_scheduler_tpu.scheduler.sparkpods import spark_resources
+    from k8s_spark_scheduler_tpu.types.objects import Container, ObjectMeta, Pod, PodPhase
+    from k8s_spark_scheduler_tpu.types.resources import Resources
+
+    spec = SELECTION_CASES[case]
+    compared = 0
+    for seed in range(4):
+        rng = random.Random(f"{case}-{seed}")
+        zones = spec.get("zones", ["az-1", "az-0", "az-2"][: rng.randint(2, 3)])
+        h = Harness(
+            extra_install=Install(
+                fifo=False,
+                binpack_algo=spec.get("algo", "tightly-pack"),
+                should_schedule_dynamically_allocated_executors_in_same_az=spec.get("single_az", False),
+                executor_prioritized_node_label=(
+                    LabelPriorityOrder("pool", ["reserved", "spot"]) if spec.get("label_priority") else None
+                ),
+                strict_reference_parity=strict,
+            ),
+        )
+        try:
+            zone_of = {}
+
+            def node(zone, cpu, mem, **flags):
+                name = f"n{rng.randrange(10**6):06d}"
+                zone_of[name] = zone
+                labels = {"pool": rng.choice(["reserved", "spot", "other"])}
+                h.new_node(name, cpu=str(cpu), memory=f"{mem}Gi", zone=zone, labels=labels, **flags)
+                return name
+
+            def other_pod(name, on, cpu, mem):
+                h.create_pod(Pod(
+                    meta=ObjectMeta(name=name, namespace="kube-system"),
+                    node_name=on,
+                    phase=PodPhase.RUNNING,
+                    containers=[Container(requests=Resources.of(str(cpu), f"{mem}Gi"))],
+                ))
+
+            cpus, mems = spec.get("cpus", [3, 4, 6, 8, 10]), spec.get("mems", [8, 12, 16, 24])
+            names = [node(zone, rng.choice(cpus), rng.choice(mems)) for zone in zones for _ in range(spec.get("per_zone", rng.randint(2, 4)))]
+            if spec.get("sidelined"):
+                for zone in zones:  # from nothing to more than the rest of the zone: they move it among the zones
+                    names.append(node(zone, 8, rng.choice([1, 100, 300]), ready=False))
+                    names.append(node(zone, 8, rng.choice([1, 100, 300]), unschedulable=True))
+            for i in range(spec.get("overhead", rng.randint(0, 4))):
+                other_pod(f"sys-{i}", rng.choice(names), rng.randint(0, 2), 1)
+
+            most = rng.randint(4, 6)
+            pods = h.dynamic_allocation_spark_pods("app-da", 2, most)
+            placed = [h.assert_success(h.schedule(pod, names)) for pod in pods[:3]]  # the driver, the min executors
+            probe = names
+            if "fresh_per_zone" in spec:
+                shapes = [(rng.choice(cpus), rng.choice(mems)) for _ in range(spec["fresh_per_zone"])]
+                probe = [node(zone, cpu, mem) for zone in zones for cpu, mem in shapes]
+            elif spec.get("candidates") == "subset":
+                probe = rng.sample(names, len(names) - rng.randint(1, 2))
+            elif spec.get("candidates") == "noisy":
+                again = rng.choice(zones)  # one zone's nodes four times: counted once in its total
+                probe = names + [n for n in names if zone_of[n] == again] * 3 + ["no-such-node", "nor-this-one"]
+                rng.shuffle(probe)
+            if spec.get("fill"):
+                for i, name in enumerate(names):
+                    other_pod(f"fill-{i}", name, 10, 24)
+            zone = zone_of[placed[0]] if spec.get("zone") else None
+
+            resources = spark_resources(pods[0]).executor_resources
+            for executor in pods[3:]:
+                h.create_pod(executor)
+                fast = h.extender._try_fast_reschedule(executor, probe, resources, zone)
+                try:
+                    slow, _ = h.extender._quantity_reschedule(
+                        executor, probe, resources, zone is not None, zone or "", "outcome"
+                    )
+                except SchedulingFailure:
+                    slow = None
+                tag = f"{case} strict={strict} seed={seed} {executor.name}"
+                assert fast == (slow is not None, slow), tag
+                assert h.extender.last_reschedule_path == "fast", tag
+                compared += 1
+                answer = h.schedule(executor, probe)  # and as served, which moves the state on
+                assert (answer.node_names or [None]) == [slow], tag
+                if spec.get("want") == "miss":
+                    assert slow is None, tag
+                    assert h.wait_for_api(lambda: h.api.list("Demand")), tag
+                elif spec.get("want") == "first-name" and slow is not None:
+                    untouched = sorted(set(probe) - set(placed))
+                    assert slow in placed or slow == untouched[0], tag
+                elif spec.get("want") == "first-zone" and executor is pods[3]:
+                    assert zone_of[slow] == "az-a", tag
+                if slow is not None:
+                    placed.append(slow)
+        finally:
+            h.close()
+    assert compared >= 8
 
 
 def test_fastpath_lane_counters(harness):
