@@ -33,6 +33,7 @@ from .. import timesource
 from ..analysis import racecheck
 from ..analysis.guarded import guarded_by
 from ..metrics import names as mnames
+from ..tracing import spans as tracing
 from . import in_predicate_lock
 from .probe import (
     DEFAULT_K_MAX,
@@ -252,7 +253,8 @@ class CapacitySampler:
                     time.sleep(self.debounce_seconds)
                 self._wake.clear()
             try:
-                self.maybe_sample(trigger="feed" if fired else "interval")
+                with tracing.background("capacity.sample"):
+                    self.maybe_sample(trigger="feed" if fired else "interval")
             except Exception:
                 logger.exception("capacity sample failed (diagnostic only)")
 

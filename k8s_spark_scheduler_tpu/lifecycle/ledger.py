@@ -38,6 +38,7 @@ from .. import timesource
 from ..analysis import racecheck
 from ..analysis.guarded import guarded_by
 from ..capacity import in_predicate_lock
+from ..tracing import spans as tracing
 from ..tracing.spans import REQUEST_ROOTS
 
 logger = logging.getLogger("k8s_spark_scheduler_tpu.lifecycle")
@@ -230,7 +231,8 @@ class LifecycleLedger:
                     time.sleep(self.debounce_seconds)
                 self._wake.clear()
             try:
-                self.maybe_drain(trigger="feed" if fired else "interval")
+                with tracing.background("lifecycle.drain"):
+                    self.maybe_drain(trigger="feed" if fired else "interval")
             except Exception:
                 logger.exception("lifecycle drain failed (diagnostic only)")
 

@@ -76,6 +76,9 @@ KERNEL_JIT_CACHE_SIZE = "foundry.spark.scheduler.tpu.kernel.jit.cache.size"
 KERNEL_COMPILES = "foundry.spark.scheduler.tpu.kernel.compile.count"
 # per-span duration distributions (tracing/spans.py), tagged span=
 TRACE_SPAN_TIME = "foundry.spark.scheduler.trace.span.time"
+# the collector's pauses in this process (tracing/spans.py's gc hook),
+# seconds, tagged generation=0|1|2; drained at the reporters' tick
+RUNTIME_GC_PAUSE_TIME = "foundry.spark.scheduler.runtime.gc.pause.time"
 # unschedulable-pod marker (scheduler/unschedulable.py): seconds per
 # scan of the aged pending backlog, and empty-cluster feasibility
 # solves run (verdict-cache misses), tagged lane=tensor|host
@@ -366,6 +369,7 @@ TAG_OBJECTIVE = "objective"
 TAG_WINDOW = "window"
 TAG_CAUSE = "cause"
 TAG_CELL = "cell"
+TAG_GENERATION = "generation"
 
 TICK_INTERVAL_SECONDS = 30.0
 SLOW_LOG_THRESHOLD_SECONDS = 45.0

@@ -21,6 +21,7 @@ from typing import List, Optional
 
 from .. import timesource
 from ..scheduler import labels as L
+from ..tracing import spans as tracing
 from ..types.resources import Resources
 from . import names
 from .registry import MetricsRegistry
@@ -73,7 +74,8 @@ class ReporterSet:
 
     def _loop(self) -> None:
         while not self._stop.wait(self._tick):
-            self.report_once()
+            with tracing.background("reporters"):
+                self.report_once()
 
     def report_once(self) -> None:
         waste = getattr(self._server, "waste_reporter", None)
@@ -93,6 +95,7 @@ class ReporterSet:
             self.report_jit_cache_sizes,
             self.report_resilience,
             self.report_contention,
+            self.report_gc_pauses,
             self.report_registry_series,
         ):
             try:
@@ -278,6 +281,12 @@ class ReporterSet:
 
         if locktime.active():
             locktime.publish(self.metrics)
+
+    def report_gc_pauses(self) -> None:
+        """Drain the collector's pauses into their histogram: the hook
+        runs wherever an allocation trips a collection, the registry's
+        own lock included, so it never publishes itself."""
+        tracing.publish_gc_pauses(self.metrics)
 
     # -- resilience ----------------------------------------------------------
 

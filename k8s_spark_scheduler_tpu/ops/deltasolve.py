@@ -408,6 +408,7 @@ class DeltaSolveEngine:
         with tracing.child_span(
             "fifo_gate",
             {"lane": "native-session", "earlierApps": n_earlier},
+            cpu=True,
         ) as gate_span:
             with default_profiler.profile(
                 "fifo_queue", lane="native-session", jit=False

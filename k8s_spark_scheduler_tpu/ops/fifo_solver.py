@@ -578,7 +578,7 @@ class TpuFifoSolver:
         minfrag = self.assignment_policy == "minimal-fragmentation"
         # the fifo_gate span is the request's "earlier drivers fit?" phase
         with tracing.child_span(
-            "fifo_gate", _gate_tags(cluster, problem, n_earlier)
+            "fifo_gate", _gate_tags(cluster, problem, n_earlier), cpu=True
         ) as gate_span:
             feasible, didx_all, avail_after = np.zeros(0, dtype=bool), None, problem.avail
             if n_earlier > 0:
@@ -638,7 +638,7 @@ class TpuFifoSolver:
         self.last_queue_lane = lane
         nb = problem.avail.shape[0]
         with tracing.child_span(
-            "fifo_gate", {**_gate_tags(cluster, problem, n_earlier), "lane": lane}
+            "fifo_gate", {**_gate_tags(cluster, problem, n_earlier), "lane": lane}, cpu=True
         ) as gate_span:
             nodes_dev, apps_dev = _upload(*_filter_blocks(problem, n_earlier))
             with default_profiler.profile("fifo_queue", lane=lane, fn=solve_filter) as rec:
@@ -1339,7 +1339,7 @@ class TpuSingleAzFifoSolver:
         avail, probe = problem.avail, None
         if n_earlier > 0:
             with tracing.child_span(
-                "fifo_gate", _gate_tags(cluster, problem, n_earlier)
+                "fifo_gate", _gate_tags(cluster, problem, n_earlier), cpu=True
             ) as gate_span:
                 feasible, avail, probe = self._queue_pass(zones, n_earlier, gate_span)
                 gate_span.tag("lane", self.last_queue_lane)
@@ -1351,7 +1351,7 @@ class TpuSingleAzFifoSolver:
                     return FifoOutcome(supported=True, earlier_ok=False)
         else:
             with tracing.child_span(
-                "fifo_gate", {**_gate_tags(cluster, problem, 0), "earlierOk": True}
+                "fifo_gate", {**_gate_tags(cluster, problem, 0), "earlierOk": True}, cpu=True
             ):
                 pass
 

@@ -913,7 +913,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         """resource.go:224-262: binpack every earlier driver and subtract
         its usage before considering this one."""
         with self._tracer.span(
-            "fifo_gate", {"lane": "host", "earlierApps": len(drivers)}
+            "fifo_gate", {"lane": "host", "earlierApps": len(drivers)}, cpu=True
         ) as sp:
             for driver in drivers:
                 try:

@@ -79,7 +79,8 @@ class UnschedulablePodMarker:
     def _loop(self) -> None:
         while not self._stop.wait(self._interval):
             try:
-                self.scan_for_unschedulable_pods()
+                with tracing.background("unschedulable.scan"):
+                    self.scan_for_unschedulable_pods()
             except Exception:
                 logger.exception("unschedulable pod scan failed")
 

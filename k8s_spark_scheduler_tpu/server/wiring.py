@@ -34,7 +34,7 @@ from ..state.typed_caches import (
     ResourceReservationCache,
     SafeDemandCache,
 )
-from ..tracing import Tracer
+from ..tracing import Tracer, install_gc_hook
 from ..tracing import profiling as kernel_profiling
 from ..types.objects import Node, Pod, ResourceReservation
 
@@ -273,6 +273,7 @@ def init_server_with_clients(
     # rebinding it here points kernel metrics/spans at THIS server —
     # correct for the one-server-per-process production shape.
     tracer = Tracer(capacity=256, metrics=metrics)
+    install_gc_hook()
     kernel_profiling.default_profiler.configure(metrics=metrics, tracer=tracer)
     # critical-path extraction rides trace completion: every finished
     # request tree decomposes into gate-queue / lock-wait / serde /

@@ -16,6 +16,7 @@ from ..analysis.guarded import guarded_by
 from ..kube.apiserver import APIServer
 from ..kube.crd import DEMAND_CRD_NAME
 from ..kube.informer import Informer, InformerFactory
+from ..tracing import spans as tracing
 from ..types.objects import Demand, ResourceReservation
 from .cache import AsyncClient, TypedClient, WriteBackCache
 from .store import ObjectStore, ShardedUniqueQueue
@@ -274,9 +275,10 @@ class LazyDemandInformer:
 
     def _poll(self) -> None:
         while not self._ready.is_set():
-            if self._check_crd():
-                self._become_ready()
-                return
+            with tracing.background("demand.poll"):
+                if self._check_crd():
+                    self._become_ready()
+                    return
             time.sleep(self._poll_interval)
 
     def _check_crd(self) -> bool:

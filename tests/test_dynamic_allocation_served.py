@@ -15,7 +15,7 @@ import time
 
 import pytest
 from test_instance_groups import client_of
-from test_span_contract import EXPECTED_EXECUTOR, find, shape
+from test_span_contract import EXPECTED_EXECUTOR, find, own, shape
 
 from k8s_spark_scheduler_tpu.metrics import names as mnames
 from k8s_spark_scheduler_tpu.testing.harness import Harness
@@ -113,7 +113,7 @@ def test_the_served_stack_answers_as_the_plain_reference_does(bench, seed, binpa
     assert shape(by_pod[f"{some.app_id}-exec-1"]) == EXPECTED_EXECUTOR["reserved"]
     extra = by_pod[f"{some.app_id}-exec-{some.executors}"]
     assert shape(extra) == EXPECTED_EXECUTOR["extra"]
-    assert find(extra, "executor.fast_reschedule").tags == {"candidates": len(cluster.names), "hit": True}
+    assert own(find(extra, "executor.fast_reschedule").tags) == {"candidates": len(cluster.names), "hit": True}
     replacement = by_pod[f"{some.app_id}-exec-{some.executors + 1}"]
     assert [c.name for c in replacement.children] == ["da.compact", "executor.select", "provenance.finish"]
 
@@ -213,13 +213,13 @@ def test_the_kept_candidate_rows_are_dropped_when_the_node_table_or_the_list_cha
         assert h.assert_success(h.schedule(pods[4], asked_with)) == node
         assert h.assert_success(h.schedule(pods[5], asked_with)) == node
         by_pod = {r.tags["pod"]: r for r in roots if r.name == "predicate"}
-        tags = [find(by_pod[f"app-da-exec-{i}"], "executor.order").tags for i in (2, 3, 4, 5)]
+        tags = [own(find(by_pod[f"app-da-exec-{i}"], "executor.order").tags) for i in (2, 3, 4, 5)]
         assert tags == [{"rowsCache": "miss"}, {"rowsCache": "hit"}, {"rowsCache": how}, {"rowsCache": "hit"}]
         counted = {result: n - before[result] for result, n in reads().items()}
         assert counted == {"hit": 3 if how == "hit" else 2, "miss": 1 if how == "hit" else 2, "uncacheable": 0}
         # the tags the span contract pins are the reschedule's own, as they were
         fast = find(by_pod["app-da-exec-4"], "executor.fast_reschedule")
-        assert fast.tags == {"candidates": len(asked_with), "hit": True}
+        assert own(fast.tags) == {"candidates": len(asked_with), "hit": True}
         assert [c.name for c in fast.children] == ["executor.snapshot", "executor.order"]
         assert h.extender.host_fallbacks() == 0
         assert h.server.metrics.get_counter(mnames.TPU_FASTPATH, {"path": "executor", "lane": "slow"}) == 0

@@ -915,7 +915,8 @@ def test_feasible_batch_larger_than_one_app_block(policy):
     assert verdicts == native.feasible_batch(cluster, apps)
     assert verdicts[-5:] == [has_capacity(binpacker, a, d_order, e_order, metadata) for a in apps[-5:]]
     nb = 64
-    assert phase.tags == {
+    runtime = ("cpuMs", "gcMs", "gcRuns", "bg")  # what a span may say of the runtime at its exit
+    assert {k: v for k, v in phase.tags.items() if k not in runtime} == {
         "count": 2,
         "arrays": (1 + 2 * 2) + (1 + 2),
         "bytes": (nb * 6 * 4 + 2 * VERDICT_ROWS * 9 * 4) + (nb * 6 * 4 + VERDICT_ROWS * 9 * 4),

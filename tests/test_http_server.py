@@ -555,7 +555,10 @@ def test_traces_cover_fifo_binpack_and_writeback(served_fifo):
     assert status == 200
     text = raw.decode()
     assert "foundry_spark_scheduler_tpu_kernel_execute_time" in text
-    assert "foundry_spark_scheduler_tpu_kernel_cache_miss_count" in text
+    # a miss, or a hit where an earlier test file of this process compiled the shape
+    assert any(
+        f"foundry_spark_scheduler_tpu_kernel_cache_{result}_count" in text for result in ("miss", "hit")
+    )
     assert "foundry_spark_scheduler_trace_span_time" in text
 
     # the application_scheduled event carries the same trace id

@@ -4,6 +4,7 @@ contract"): which children a granted driver Filter leaves under
 marker's one trace per scan, the profiler bridge, and the critical-path
 decomposition over the new tree.  No wall-clock budgets (ROADMAP D10)."""
 
+import contextlib
 import glob
 import json
 import os
@@ -145,6 +146,15 @@ EXPECTED_EXECUTOR = {
 }
 
 
+# what a span may say of the runtime at its exit, beside what its call site tags
+RUNTIME_TAGS = ("cpuMs", "gcMs", "gcRuns", "bg")
+
+
+def own(tags):
+    """A span's tags less the runtime's."""
+    return {k: v for k, v in tags.items() if k not in RUNTIME_TAGS}
+
+
 def shape(span):
     return [(c.name, shape(c)) for c in span.children]
 
@@ -228,7 +238,7 @@ def test_granted_driver_filter_crosses_the_device_boundary_once_each_way(binpack
         # the crossings of a request are these two tags: 2 arrays up, 1 down
         upload, readback = find(gate, "device.upload"), find(gate, "device.readback")
         assert upload.tags["arrays"] == 2 and upload.tags["bytes"] == 4 * (64 * 5 + 16 * 8)
-        assert readback.tags == {"arrays": 1, "bytes": 4 * (4 * 64 + 16 + 2)}
+        assert own(readback.tags) == {"arrays": 1, "bytes": 4 * (4 * 64 + 16 + 2)}
         assert find(root, "binpack").tags["feasible"] is True
         device = [n for n in names(root) if n.startswith("device.")]
         assert sorted(device) == [
@@ -253,7 +263,7 @@ def test_the_decode_says_what_it_built_in_tags_and_the_tree_stays(binpack_algo):
         decode = find(root, "fast_path.decode")
         hosts = decode.tags["hostNodes"]
         assert hosts in (1, 2)  # the granted driver asked for two executors
-        assert decode.tags == {"hostNodes": hosts, "objects": hosts}
+        assert own(decode.tags) == {"hostNodes": hosts, "objects": hosts}
     finally:
         h.close()
 
@@ -295,11 +305,11 @@ def test_an_executor_filter_has_exactly_the_documented_children():
         by_pod = dynamic_allocation_roots(h)
         reserved, extra, last_extra, refused = (by_pod[f"app-da-exec-{i}"] for i in (1, 2, 3, 4))
         assert shape(reserved) == EXPECTED_EXECUTOR["reserved"]
-        assert find(reserved, "executor.reservation_lookup").tags == {"count": 2}  # already bound? unbound?
+        assert own(find(reserved, "executor.reservation_lookup").tags) == {"count": 2}  # already bound? unbound?
         assert shape(extra) == shape(last_extra) == EXPECTED_EXECUTOR["extra"]
         lookup = find(extra, "executor.reservation_lookup")
-        assert type(lookup) is tracing.AggregateSpan and lookup.tags == {"count": 3}  # and the remaining count
-        assert find(extra, "executor.fast_reschedule").tags == {"candidates": len(NODES), "hit": True}
+        assert type(lookup) is tracing.AggregateSpan and own(lookup.tags) == {"count": 3}  # and the remaining count
+        assert own(find(extra, "executor.fast_reschedule").tags) == {"candidates": len(NODES), "hit": True}
         assert extra.tags["outcome"] == "success-scheduled-extra-executor"
         # max - min soft reservations are held: the next executor is refused before any placement
         assert shape(refused) == EXPECTED_EXECUTOR["refused"]
@@ -335,7 +345,7 @@ def test_an_extra_executor_the_mirror_did_not_place_is_named_for_the_quantity_pa
             h.extender._lane_health = _RescheduleLaneDemoted()
         extra = dynamic_allocation_roots(h)["app-da-exec-2"]
         assert shape(extra) == EXPECTED_EXECUTOR[why]
-        assert find(extra, "executor.quantity_reschedule").tags == {"candidates": len(NODES)}
+        assert own(find(extra, "executor.quantity_reschedule").tags) == {"candidates": len(NODES)}
         assert extra.tags["outcome"] == "success-scheduled-extra-executor"
         if why == "declined":
             assert "hit" not in find(extra, "executor.fast_reschedule").tags
@@ -453,7 +463,7 @@ def test_aggregate_child_sums_its_phases_and_keeps_the_parents_self_time(monkeyp
     assert root.duration == pytest.approx(4.25)
     assert root.duration - sum(c.duration for c in root.children) == pytest.approx(3.0)
     as_dict = tracer.traces()[0]["root"]
-    assert [(c["name"], c["durationMs"], c["tags"]) for c in as_dict["children"]] == [
+    assert [(c["name"], c["durationMs"], own(c["tags"])) for c in as_dict["children"]] == [
         ("scan.solve", 750.0, {"count": 3}),
         ("scan.mark", 500.0, {"count": 1}),
     ]
@@ -497,14 +507,14 @@ def test_one_scan_is_one_trace_with_three_aggregate_children_and_two_metrics(lan
         h.unschedulable_marker.scan_for_unschedulable_pods()
         (root,) = roots
         assert root.name == "unschedulable.scan" and root.parent is None
-        assert root.tags == {
+        assert own(root.tags) == {
             "pods": 4, "verdictMisses": 4, "verdictBatches": 1, "signatures": 1, "conditionWrites": 4,
         }
         children = {c.name: c for c in root.children}
         assert {name: c.tags["count"] for name, c in children.items()} == {
             "scan.metadata": 1, "scan.solve": 1, "scan.mark": 4,
         }
-        crossings = {k: v for k, v in children["scan.solve"].tags.items() if k != "count"}
+        crossings = {k: v for k, v in own(children["scan.solve"].tags).items() if k != "count"}
         # the node block [64, 6], the app block [1024, 8] up, [1024] down, int32
         assert crossings == ({"arrays": 3, "bytes": 4 * (64 * 6 + 1024 * 9)} if lane == "xla" else {})
         assert all(type(c) is tracing.AggregateSpan and not c.children for c in root.children)
@@ -533,10 +543,10 @@ def test_a_scan_under_a_host_policy_asks_no_batch():
         roots = roots_of(h)
         h.unschedulable_marker.scan_for_unschedulable_pods()
         (root,) = roots
-        assert root.tags == {
+        assert own(root.tags) == {
             "pods": 4, "verdictMisses": 4, "verdictBatches": 0, "signatures": 1, "conditionWrites": 4,
         }
-        assert {c.name: c.tags for c in root.children}["scan.solve"] == {"count": 1}
+        assert {c.name: own(c.tags) for c in root.children}["scan.solve"] == {"count": 1}
         assert h.server.metrics.get_counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": "host"}) == 4
     finally:
         h.close()
@@ -705,3 +715,394 @@ def test_decompose_on_the_new_tree_sums_to_the_root_and_leaves_nothing_new_in_ot
         name in criticalpath.SPAN_SEGMENTS or name.startswith("kernel:") or name == "device.dispatch"
         for name in names(find(root, "predicate"))
     )
+
+
+# -- (f) what a span says of the runtime: cpuMs, gcMs / gcRuns, bg -------------------
+
+
+def spans_of(span):
+    yield span
+    for child in span.children:
+        yield from spans_of(child)
+
+
+def dict_spans(span):
+    yield span
+    for child in span.get("children", ()):
+        yield from dict_spans(child)
+
+
+CLOCK_TOLERANCE_MS = 0.05  # two clocks; the CPU's is read inside the wall's interval
+
+
+@pytest.mark.parametrize("kind", ["driver-xla", "driver-native", "executor"])
+def test_the_gate_alone_reads_its_threads_cpu_clock_in_a_real_trace(kind):
+    """``cpuMs`` stands where a call site asked for it (``cpu=True``):
+    on ``fifo_gate``, whatever the lane, and on no other span of a
+    request; an executor's trace has no gate and reads the clock never."""
+    h = served_harness("native" if kind == "driver-native" else "xla")
+    try:
+        if kind == "executor":
+            root = dynamic_allocation_roots(h)["app-da-exec-2"]
+        else:
+            root = granted_driver_root(h)
+        spans = list(spans_of(root))
+        assert len(spans) >= 8
+        assert [s.name for s in spans if "cpuMs" in s.tags] == ([] if kind == "executor" else ["fifo_gate"])
+        for span in spans:
+            if "cpuMs" in span.tags:
+                assert 0.0 <= span.tags["cpuMs"] <= span.duration * 1e3 + CLOCK_TOLERANCE_MS
+    finally:
+        h.close()
+
+
+def test_no_span_carries_cpu_time_or_the_collectors_under_a_virtual_clock():
+    import gc
+
+    from k8s_spark_scheduler_tpu import timesource
+
+    tracing.install_gc_hook()
+    h = served_harness("native")
+    try:
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
+        roots = roots_of(h)
+        started = time.time()
+        timesource.set_source(lambda: started)
+        timesource.set_perf_source(lambda: 5.0)
+        try:
+            h.assert_success(h.schedule(h.static_allocation_spark_pods("app-virtual", 2)[0], NODES))
+            with h.server.tracer.span("predicate"):
+                with tracing.aggregate_span("phases"):
+                    gc.collect()
+        finally:
+            timesource.reset()
+        for root in roots:
+            for span in spans_of(root):
+                assert not set(span.tags) & {"cpuMs", "gcMs", "gcRuns"}, (span.name, span.tags)
+                assert span.duration == 0.0
+        assert len(roots) == 2 and len(list(spans_of(roots[0]))) >= 8
+    finally:
+        h.close()
+
+
+def test_a_span_that_sleeps_reads_wall_far_above_cpu_and_one_that_spins_reads_its_cpu():
+    tracer = Tracer(capacity=4)
+    with tracer.span("predicate", cpu=True) as root:
+        with tracer.span("sleeps", cpu=True) as sleeps:
+            time.sleep(0.05)
+        with tracing.child_span("spins", cpu=True) as spins:
+            until = time.thread_time() + 0.03
+            while time.thread_time() < until:
+                pass
+    assert sleeps.duration * 1e3 >= 50.0 and sleeps.tags["cpuMs"] < 10.0
+    # every millisecond the thread burnt is the span's, and no more than the wall's
+    assert 30.0 <= spins.tags["cpuMs"] <= spins.duration * 1e3 + CLOCK_TOLERANCE_MS
+    assert root.tags["cpuMs"] >= sleeps.tags["cpuMs"] + spins.tags["cpuMs"]
+    assert root.duration * 1e3 - root.tags["cpuMs"] >= 40.0  # what the thread did not run
+    as_dict = tracer.traces()[0]["root"]
+    assert [c["tags"]["cpuMs"] for c in as_dict["children"]] == [sleeps.tags["cpuMs"], spins.tags["cpuMs"]]
+
+
+def test_the_thread_clock_is_read_only_where_a_call_site_asks_and_never_by_an_aggregate_phase(monkeypatch):
+    """The clock can be a system call (20 µs a read on the benchmark's
+    host): a span that did not ask makes none, and the marker's thousand
+    phases make none."""
+    reads = []
+    cpu_ns = [0]
+
+    def thread_ns():
+        reads.append(tracing.current_span().name)
+        return cpu_ns[0]
+
+    monkeypatch.setattr(tracing.spans, "_thread_ns", thread_ns)
+    tracer = Tracer(capacity=4)
+    with tracer.span("unschedulable.scan") as root:
+        for _ in range(3):
+            with root.aggregate("scan.solve"):
+                cpu_ns[0] += 250_000
+        with tracing.aggregate_span("scan.mark"):
+            pass
+        with tracer.span("plain"):
+            with tracing.child_span("asks", {"lane": "xla"}, cpu=True) as asks:
+                cpu_ns[0] += 1_500_000
+            with tracing.child_span("plain-too"):
+                pass
+    assert reads == ["asks", "asks"]
+    assert asks.tags == {"lane": "xla", "cpuMs": 1.5}
+    assert [s.name for s in spans_of(root) if "cpuMs" in s.tags] == ["asks"]
+    assert {c.name: c.tags for c in root.children[:2]} == {"scan.solve": {"count": 3}, "scan.mark": {"count": 1}}
+
+
+def test_a_collection_is_booked_to_the_span_it_ran_in_and_to_its_ancestors_and_to_no_other_thread():
+    import gc
+    import threading
+
+    from k8s_spark_scheduler_tpu.metrics.registry import MetricsRegistry
+
+    tracing.install_gc_hook()
+    tracing.install_gc_hook()  # once, however often a process wires a server
+    assert gc.callbacks.count(tracing.spans._on_gc) == 1
+    tracer = Tracer(capacity=8)
+    inside, release = threading.Event(), threading.Event()
+
+    def other_thread():
+        with tracer.span("http.request"):
+            inside.set()
+            release.wait(10.0)
+
+    other = threading.Thread(target=other_thread)
+    gc.disable()  # only the collections the test asks for
+    try:
+        other.start()
+        assert inside.wait(10.0)
+        with tracer.span("http.request") as root:
+            with tracer.span("predicate") as predicate:
+                with tracer.span("before"):
+                    pass
+                with tracer.span("collects") as collects:
+                    gc.collect()
+                    gc.collect(0)
+                with predicate.aggregate("phases") as phases:
+                    gc.collect()
+                with predicate.aggregate("phases"):
+                    pass
+        release.set()
+        other.join(10.0)
+        assert not other.is_alive()
+    finally:
+        release.set()
+        gc.enable()
+    assert collects.tags["gcRuns"] == 2 and 0.0 < collects.tags["gcMs"] <= collects.duration * 1e3
+    assert phases.tags["gcRuns"] == 1 and phases.tags["count"] == 2
+    for ancestor in (predicate, root):
+        assert ancestor.tags["gcRuns"] == 3
+        assert ancestor.tags["gcMs"] == pytest.approx(collects.tags["gcMs"] + phases.tags["gcMs"], abs=1e-3)
+    assert "gcMs" not in find(root, "before").tags and "gcRuns" not in find(root, "before").tags
+    theirs, ours = (t["root"] for t in tracer.traces())  # newest first: theirs closed at the release
+    assert ours["tags"]["gcRuns"] == 3 and not {"gcMs", "gcRuns"} & set(theirs["tags"])
+    # the same hook feeds the operator's histogram, drained where the reporters tick
+    metrics = MetricsRegistry()
+    tracing.publish_gc_pauses(metrics)
+    pauses = {
+        tags: h for (name, tags), h in metrics._histograms.items() if name == mnames.RUNTIME_GC_PAUSE_TIME
+    }
+    assert {dict(tags)[mnames.TAG_GENERATION] for tags in pauses} >= {"0", "2"}
+    assert sum(h.count for h in pauses.values()) >= 3
+    tracing.publish_gc_pauses(metrics)  # drained: nothing is counted twice
+    assert sum(h.count for (name, _), h in metrics._histograms.items() if name == mnames.RUNTIME_GC_PAUSE_TIME) == sum(
+        h.count for h in pauses.values()
+    )
+
+
+class _Held:
+    """Background work (``work()`` gives its context manager, on the
+    thread that does it) held open on another thread until released."""
+
+    def __init__(self, work):
+        import threading
+
+        self._inside, self._release = threading.Event(), threading.Event()
+        self._thread = threading.Thread(target=self._run, args=(work,))
+
+    def _run(self, work):
+        with work():
+            self._inside.set()
+            self._release.wait(10.0)
+
+    def __enter__(self):
+        self._thread.start()
+        assert self._inside.wait(10.0)
+        return self
+
+    def __exit__(self, *exc):
+        self._release.set()
+        self._thread.join(10.0)
+        assert not self._thread.is_alive()
+
+
+@contextlib.contextmanager
+def _scan(tracer):
+    """One unit of the marker's loop: the marker, and the scan's trace inside it."""
+    with tracing.background("unschedulable.scan"):
+        with tracer.span("unschedulable.scan") as root:
+            with root.aggregate("scan.mark"):
+                pass
+            yield root
+
+
+def test_a_span_names_the_background_work_it_overlapped_and_a_marker_leaves_no_trace():
+    tracer = Tracer(capacity=8)
+    with tracer.span("http.request") as quiet:
+        with tracer.span("predicate"):
+            pass
+    assert all("bg" not in s.tags for s in spans_of(quiet))
+
+    # the marker's loop: a marker and, inside it, a root that is no request's,
+    # on another thread across the whole request
+    with _Held(lambda: _scan(tracer)):
+        with tracer.span("http.request") as beside_scan:
+            with tracer.span("predicate"):
+                with tracer.span("fifo_gate"):
+                    pass
+    assert [s.tags["bg"] for s in spans_of(beside_scan)] == ["unschedulable.scan"] * 3
+    scan = tracer.traces()[0]["root"]
+    # marked work's own spans name nothing: not themselves, and not what ran beside them
+    assert scan["name"] == "unschedulable.scan"
+    assert [s["tags"] for s in dict_spans(scan)] == [{}, {"count": 1}]
+    # a root that is no request's is background work only where its loop marks it
+    with _Held(lambda: tracer.span("unschedulable.scan")):
+        with tracer.span("http.request") as beside_unmarked:
+            pass
+    assert "bg" not in beside_unmarked.tags
+
+    # a marker: under way at the enter of one span, begun and ended inside another, sorted with a root's
+    before = len(tracer)
+    with tracer.span("http.request") as root:
+        with _Held(lambda: tracing.background("writeback")):
+            with tracer.span("predicate") as predicate:
+                with tracer.span("early"):
+                    pass
+        with tracer.span("late") as late:
+            pass
+        with tracer.span("holds-one") as holds_one:
+            with tracing.background("reporters"):
+                pass
+        with _Held(lambda: _scan(tracer)):
+            with _Held(lambda: tracing.background("capacity.sample")):
+                with tracer.span("both") as both:
+                    pass
+    assert predicate.tags["bg"] == find(root, "early").tags["bg"] == "writeback"
+    assert "bg" not in late.tags  # it left before this one began
+    assert holds_one.tags["bg"] == "reporters"
+    assert both.tags["bg"] == "capacity.sample,unschedulable.scan"
+    assert root.tags["bg"] == "capacity.sample,reporters,unschedulable.scan,writeback"
+    assert len(tracer) == before + 2  # the request and the scan's root: a marker puts nothing into the ring
+    with tracer.span("http.request") as after:
+        pass
+    assert "bg" not in after.tags
+
+
+def test_a_disabled_tracer_still_returns_the_shared_noop_and_touches_no_table():
+    mark = tracing.spans._BACKGROUND.mark
+    tracer = Tracer(enabled=False)
+    span = tracer.span("unschedulable.scan")
+    assert span is tracing.NOOP_SPAN
+    with span:
+        assert tracing.current_span() is None
+    assert tracing.spans._BACKGROUND.mark == mark and len(tracer) == 0
+
+
+def test_the_background_table_loses_no_enter_and_no_leave_under_contention():
+    """More workers than cores, a switch after every few bytecodes, and
+    the repo's race detector watching the table it instrumented."""
+    import sys
+    import threading
+
+    from k8s_spark_scheduler_tpu.analysis import racecheck
+
+    workers, rounds = 16, 300
+    idle_while_working = []
+
+    def work(table, i):
+        for _ in range(rounds):
+            table.enter(f"loop-{i % 4}")
+            if table.mark <= 0:
+                idle_while_working.append(i)  # work is under way: the mark has to say so
+            table.leave(f"loop-{i % 4}")
+
+    interval = sys.getswitchinterval()
+    detector = racecheck.enable(racecheck.RaceDetector())
+    sys.setswitchinterval(1e-6)
+    try:
+        table = tracing.spans.BackgroundTable()  # instrumented as it is made
+        threads = [threading.Thread(target=work, args=(table, i)) for i in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+        racecheck.disable()
+    assert detector.clean(), (detector.races, detector.hb_races, detector.lock_order_violations)
+    assert not idle_while_working
+    assert table._active == {} and table._ticks == 2 * workers * rounds and table.mark == -table._ticks
+    assert table.since(0) == {f"loop-{i}" for i in range(4)} and table.since(table.mark) == set()
+
+
+def test_traces_over_the_wire_carry_the_gates_cpu_time_and_name_the_scan_a_request_ran_beside():
+    h = served_harness("xla")
+    http = ExtenderHTTPServer(h.server, port=0)
+    http.start()
+    try:
+        post_driver(h, http.port, "app-first")
+        with _Held(lambda: _scan(h.server.tracer)):
+            post_driver(h, http.port, "app-beside-the-scan")
+        post_driver(h, http.port, "app-after")
+        with urllib.request.urlopen(f"http://127.0.0.1:{http.port}/traces", timeout=30) as resp:
+            traces = json.loads(resp.read())["traces"]
+    finally:
+        http.stop()
+        h.close()
+    by_pod = {
+        next(s for s in dict_spans(t["root"]) if s["name"] == "predicate")["tags"]["pod"]: t["root"]
+        for t in traces if t["root"]["name"] == "http.request"
+    }
+    beside, after = by_pod["app-beside-the-scan-driver"], by_pod["app-after-driver"]
+    for root in (beside, after):
+        spans = list(dict_spans(root))
+        assert len(spans) >= 24 and [s["name"] for s in spans if "cpuMs" in s["tags"]] == ["fifo_gate"]
+    gate = next(s for s in dict_spans(beside) if s["name"] == "fifo_gate")
+    assert "unschedulable.scan" in beside["tags"]["bg"].split(",")
+    assert "unschedulable.scan" in gate["tags"]["bg"].split(",")
+    assert all("unschedulable.scan" not in s["tags"].get("bg", "") for s in dict_spans(after))
+
+
+def test_each_loop_the_server_starts_marks_one_unit_of_its_work_under_its_name():
+    """``writeback``, ``capacity.sample`` and ``lifecycle.drain`` follow a
+    granted driver on their own threads; the reporters tick, the lazy
+    demand informer polls and the marker scans on theirs.  Each unit of work runs with its
+    name in the table, and none leaves a trace."""
+    from k8s_spark_scheduler_tpu.metrics.reporters import ReporterSet
+    from k8s_spark_scheduler_tpu.state.typed_caches import LazyDemandInformer
+
+    table = tracing.spans._BACKGROUND
+    seen = {}
+
+    def watching(name, unit):
+        def watched(*args, **kwargs):
+            seen.setdefault(name, []).append(set(table._active))
+            return unit(*args, **kwargs)
+        return watched
+
+    h = served_harness("native")
+    try:
+        server = h.server
+        writer = server.resource_reservation_cache._async
+        writer._do_create = watching("writeback", writer._do_create)
+        server.capacity.maybe_sample = watching("capacity.sample", server.capacity.maybe_sample)
+        server.lifecycle.maybe_drain = watching("lifecycle.drain", server.lifecycle.maybe_drain)
+        reporters = ReporterSet(server, tick_seconds=0.01)
+        reporters.report_once = watching("reporters", lambda: reporters.stop())
+        polls = LazyDemandInformer(h.api, server.informer_factory, poll_interval=0.01)
+        answers = iter([False, False])  # the CRD is not there yet: start() and one poll; then it is
+        polls._check_crd = watching("demand.poll", lambda: next(answers, True))
+        polls._become_ready = polls._ready.set
+        marker = h.unschedulable_marker  # its scan is a trace of its own: stood in for, so that the ring's count stays
+        marker._interval = 0.01
+        marker.scan_for_unschedulable_pods = watching("unschedulable.scan", marker.stop)
+        before = len(server.tracer)
+        reporters.start()
+        polls.start()
+        marker.start()
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-marked", 1)[0], NODES))
+        names = {"writeback", "capacity.sample", "lifecycle.drain", "reporters", "demand.poll", "unschedulable.scan"}
+        assert h.wait_for_api(lambda: names <= set(seen) and polls.ready(), timeout=20.0), sorted(seen)
+        for name in names - {"demand.poll"}:
+            assert all(name in active for active in seen[name]), (name, seen[name])
+        # start()'s own look, on the caller's thread, is no poll; the two polls after it are
+        assert ["demand.poll" in active for active in seen["demand.poll"]] == [False, True, True]
+        assert h.wait_for_api(lambda: not set(table._active) & names, timeout=20.0)
+        assert len(server.tracer) == before + 1  # the request's trace and no marker's
+    finally:
+        h.close()
