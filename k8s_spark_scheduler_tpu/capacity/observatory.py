@@ -135,6 +135,52 @@ _DEFAULT_SHAPE = (
 )
 
 
+@guarded_by("_lock", "_key", "_ids", "_names")
+class GroupIndex:
+    """Each node's instance group as an int64 id into the sorted group
+    names, kept per node-table revision (``snap.structure_key``: labels
+    change only with a structure revision, and a node added or removed
+    is one too).  Only a sample reads or rebuilds it, under the
+    sampler's ``_sample_mutex``, so its own lock is never contended: it
+    is there for the race checker.  A snapshot without a revision
+    (``structure_key[0] < 0``) is rebuilt for and not kept."""
+
+    def __init__(self, label: str, metrics=None):
+        self._label = label
+        self._metrics = metrics
+        self._lock = threading.Lock()
+        self._key: Optional[Tuple] = None
+        self._ids = np.zeros(0, dtype=np.int64)
+        self._names: List[str] = []
+
+    def read(self, snap) -> Tuple[np.ndarray, List[str], str]:
+        """(group id per node, sorted group names, ``hit`` | ``rebuild``)."""
+        key = tuple(snap.structure_key)
+        with self._lock:
+            racecheck.note_access(self, "_key", write=False)
+            hit = key[0] >= 0 and key == self._key
+            if hit:
+                ids, names = self._ids, self._names
+        if not hit:
+            label = self._label
+            groups = [labels.get(label, "") for labels in snap.labels]
+            names = sorted(set(groups))
+            number = {name: i for i, name in enumerate(names)}
+            ids = np.fromiter(
+                (number[g] for g in groups), dtype=np.int64, count=len(groups)
+            )
+            if key[0] >= 0:
+                with self._lock:
+                    racecheck.note_access(self, "_key")
+                    self._key, self._ids, self._names = key, ids, names
+        result = "hit" if hit else "rebuild"
+        if self._metrics is not None:
+            self._metrics.counter(
+                mnames.CAPACITY_GROUP_INDEX_READS, {"result": result}
+            )
+        return ids, names, result
+
+
 @guarded_by(
     "_lock",
     "_ring",
@@ -164,12 +210,16 @@ class CapacitySampler:
         max_group_zones: int = 16,
         max_queue: int = 64,
         k_max: int = DEFAULT_K_MAX,
+        tracer=None,
     ):
         self._cache = snapshot_cache
         self._pod_lister = pod_lister
         self._waste = waste_reporter
         self._metrics = metrics
-        self._group_label = instance_group_label
+        # the server's tracer: each sample of the background loop is one
+        # root span ``capacity.sample``; other callers sample untraced
+        self._tracer = tracer
+        self._groups = GroupIndex(instance_group_label, metrics)
         self.debounce_seconds = float(debounce_seconds)
         self.interval_seconds = float(interval_seconds)
         self.max_shapes = int(max_shapes)
@@ -254,7 +304,10 @@ class CapacitySampler:
                 self._wake.clear()
             try:
                 with tracing.background("capacity.sample"):
-                    self.maybe_sample(trigger="feed" if fired else "interval")
+                    self.maybe_sample(
+                        trigger="feed" if fired else "interval",
+                        tracer=self._tracer,
+                    )
             except Exception:
                 logger.exception("capacity sample failed (diagnostic only)")
 
@@ -272,7 +325,9 @@ class CapacitySampler:
         except Exception:
             return -1
 
-    def maybe_sample(self, trigger: str = "feed") -> Optional[CapacitySample]:
+    def maybe_sample(
+        self, trigger: str = "feed", tracer=None
+    ) -> Optional[CapacitySample]:
         """Sample iff the ChangeFeed moved OR the driver queue changed
         since the last sample — O(1) when nothing changed."""
         seq = self._cache.feed.seq
@@ -282,11 +337,17 @@ class CapacitySampler:
             if seq == self._last_seq and rev == self._last_queue_rev:
                 self._stats["skipped_unchanged"] += 1
                 return None
-        return self.sample_now(trigger=trigger)
+        return self.sample_now(trigger=trigger, tracer=tracer)
 
-    def sample_now(self, trigger: str = "manual") -> Optional[CapacitySample]:
+    def sample_now(
+        self, trigger: str = "manual", tracer=None
+    ) -> Optional[CapacitySample]:
         """Probe the current snapshot unconditionally (modulo the
-        extender-lock refusal) and append to the timeline."""
+        extender-lock refusal) and append to the timeline.  With a
+        ``tracer`` the sample is one root span ``capacity.sample``,
+        tagged ``pending`` (pending drivers), ``groupIndex`` (``hit`` or
+        ``rebuild``) and ``stashedRows`` (gangs whose rows came from
+        their demand's stash); a refused sample opens none."""
         if in_predicate_lock():
             # NEVER probe while holding the extender lock: refuse,
             # count, and let the next off-lock trigger pick it up
@@ -294,11 +355,16 @@ class CapacitySampler:
                 racecheck.note_access(self, "_stats")
                 self._stats["lock_violations"] += 1
             return None
-        with self._sample_mutex:
+        span = (
+            tracer.span("capacity.sample")
+            if tracer is not None
+            else tracing.NOOP_SPAN
+        )
+        with self._sample_mutex, span:
             t0 = time.perf_counter()
             queue_rev = self._queue_rev()
             snap = self._cache.snapshot()
-            sample = self._build_sample(snap, trigger)
+            sample = self._build_sample(snap, trigger, span)
             sample.sample_ms = (time.perf_counter() - t0) * 1000.0
             with self._lock:
                 racecheck.note_access(self, "_ring")
@@ -407,27 +473,37 @@ class CapacitySampler:
         pending.sort(key=lambda p: (p.creation_timestamp, p.name))
         return pending
 
-    def _gang_rows(self, pod):
-        """(driver_row, executor_row, count) in base units, or None when
-        the pod's annotations don't parse / aren't exact."""
-        try:
-            from ..ops.tensorize import _resources_to_base
-            from ..scheduler.sparkpods import spark_app_demand_cached
+    def _gang_rows(self, pending) -> Tuple[List, int]:
+        """Per pending driver (driver_row, executor_row, count) in base
+        units, or None where the pod's annotations don't parse or aren't
+        exact; and how many gangs found their rows stashed on their
+        demand (``tensorize._app_base_rows``: the FIFO pass converts a
+        pod version's demand once, and the sample reads that)."""
+        from ..ops.tensorize import _app_base_rows
+        from ..scheduler.sparkpods import spark_app_demand_cached
 
-            _, demand = spark_app_demand_cached(pod)
-            drow, de = _resources_to_base(demand.driver_resources)
-            erow, ee = _resources_to_base(demand.executor_resources)
-            if not (de and ee):
-                return None
-            return (
-                tuple(int(x) for x in drow),
-                tuple(int(x) for x in erow),
-                int(demand.min_executor_count),
-            )
-        except Exception:
-            return None
+        out = []
+        stashed = 0
+        for pod in pending:
+            try:
+                _, demand = spark_app_demand_cached(pod)
+                stashed += getattr(demand, "_base_rows", None) is not None
+                drow, erow, exact = _app_base_rows(demand)
+                rows = (
+                    (
+                        tuple(int(x) for x in drow),
+                        tuple(int(x) for x in erow),
+                        int(demand.min_executor_count),
+                    )
+                    if exact
+                    else None
+                )
+            except Exception:
+                rows = None
+            out.append(rows)
+        return out, stashed
 
-    def _build_sample(self, snap, trigger: str) -> CapacitySample:
+    def _build_sample(self, snap, trigger: str, span) -> CapacitySample:
         now = timesource.now()
         sample = CapacitySample(
             seq=int(snap.content_key[1]),
@@ -459,12 +535,11 @@ class CapacitySampler:
         # per-pod cached — the FIFO path pays it anyway) so the
         # pressure gauge counts every known-not-fitting gang; only the
         # per-driver forecast ENTRIES are capped at max_queue
-        gangs = []  # (pod, rows or None)
+        all_rows, stashed = self._gang_rows(pending)
+        gangs = list(zip(pending, all_rows))  # (pod, rows or None)
         shapes: Dict[str, Tuple] = {}
         dropped_shapes: set = set()
-        for pod in pending:
-            rows = self._gang_rows(pod)
-            gangs.append((pod, rows))
+        for rows in all_rows:
             if rows is None:
                 continue
             key = shape_key(rows[0], rows[1])
@@ -482,6 +557,11 @@ class CapacitySampler:
             [list(d) + list(e) for _, (d, e) in shape_list], dtype=np.int64
         )
 
+        group_ids, group_names, index_read = self._groups.read(snap)
+        span.tag("pending", len(pending))
+        span.tag("groupIndex", index_read)
+        span.tag("stashedRows", stashed)
+
         if n > 0 and sample.ready_nodes > 0:
             rank = np.where(eligible, np.int64(0), np.int64(2**31 - 1))
             headroom, usable, probes, lane = probe_headroom(
@@ -496,7 +576,8 @@ class CapacitySampler:
                     "probes": int(probes[i]),
                 }
             self._per_group(
-                snap, avail, eligible, shape_list, shape_rows, sample
+                snap, group_ids, group_names, avail, eligible, shape_list,
+                shape_rows, sample,
             )
         else:
             sample.probe_lane = "empty"
@@ -509,7 +590,7 @@ class CapacitySampler:
 
         if n > 0:
             self._class_lane(snap, avail, eligible, shape_list, sample)
-        self._tenants(snap, sample)
+        self._tenants(snap, group_ids, group_names, sample)
         self._forecast(gangs, pending, sample, now)
         return sample
 
@@ -576,31 +657,38 @@ class CapacitySampler:
             logger.exception("class analytics lane failed (diagnostic only)")
 
     def _per_group(
-        self, snap, avail, eligible, shape_list, shape_rows, sample
+        self, snap, group_ids, group_names, avail, eligible, shape_list,
+        shape_rows, sample,
     ) -> None:
         """Per-(instance-group, zone) fragmentation + headroom, bounded
-        at max_group_zones combos (sorted — determinism over truncation
-        luck)."""
-        combos: Dict[Tuple[str, str], List[int]] = {}
-        for i in range(len(snap.names)):
-            group = snap.labels[i].get(self._group_label, "")
-            zone = (
-                snap.zone_names[snap.zone_id[i]]
-                if 0 <= snap.zone_id[i] < len(snap.zone_names)
-                else ""
-            )
-            combos.setdefault((group, zone), []).append(i)
-        ordered = sorted(combos.items())
-        if len(ordered) > self.max_group_zones:
-            sample.groups_dropped = len(ordered) - self.max_group_zones
-            ordered = ordered[: self.max_group_zones]
-        for (group, zone), rows in ordered:
-            idx = np.array(rows, dtype=np.int64)
+        at max_group_zones combos (sorted by (group, zone) — determinism
+        over truncation luck), each combo's rows in node order."""
+        # a zone id out of range is the zone ""; zones ranked by name
+        zone_list = list(snap.zone_names) + [""]
+        zone_order = sorted(set(zone_list))
+        zone_rank = np.array(
+            [zone_order.index(z) for z in zone_list], dtype=np.int64
+        )
+        zone_id = np.asarray(snap.zone_id, dtype=np.int64)
+        in_range = (zone_id >= 0) & (zone_id < len(snap.zone_names))
+        zones = zone_rank[np.where(in_range, zone_id, len(snap.zone_names))]
+        combo_keys, combo_of = np.unique(
+            group_ids * len(zone_order) + zones, return_inverse=True
+        )
+        order = np.argsort(combo_of, kind="stable")
+        bounds = np.searchsorted(
+            combo_of[order], np.arange(len(combo_keys) + 1)
+        )
+        if len(combo_keys) > self.max_group_zones:
+            sample.groups_dropped = len(combo_keys) - self.max_group_zones
+        for c in range(min(len(combo_keys), self.max_group_zones)):
+            group, zone = divmod(int(combo_keys[c]), len(zone_order))
+            idx = order[bounds[c]:bounds[c + 1]]
             sub_avail = avail[idx]
             sub_elig = eligible[idx]
             total, largest, _, _, frag = frag_report(sub_avail, sub_elig)
             entry = {
-                "nodes": len(rows),
+                "nodes": len(idx),
                 "readyNodes": int(sub_elig.sum()),
                 "free": [int(x) for x in total],
                 "largestChunk": [int(x) for x in largest],
@@ -615,28 +703,28 @@ class CapacitySampler:
                 sample.probe_solves += int(probes.sum())
                 for i, (key, _) in enumerate(shape_list):
                     entry["headroom"][key] = int(headroom[i])
-            sample.groups["|".join((group, zone))] = entry
+            sample.groups[
+                "|".join((group_names[group], zone_order[zone]))
+            ] = entry
 
-    def _tenants(self, snap, sample) -> None:
+    def _tenants(self, snap, group_ids, group_names, sample) -> None:
         """Per-instance-group utilization attribution: who holds the
-        reserved capacity (usage rows are hard + soft reservations)."""
-        groups: Dict[str, Dict] = {}
+        reserved capacity (usage rows are hard + soft reservations).
+        One int64 group-sum of used and allocatable (``np.add.at``:
+        exact past 2**53, where a float64 bincount would round)."""
         usage = snap.usage
         alloc = snap.allocatable
         cluster_used = np.maximum(usage, 0).sum(axis=0)
-        for i in range(len(snap.names)):
-            group = snap.labels[i].get(self._group_label, "")
-            g = groups.get(group)
-            if g is None:
-                g = groups[group] = {
-                    "used": np.zeros(3, dtype=np.int64),
-                    "allocatable": np.zeros(3, dtype=np.int64),
-                }
-            g["used"] += np.maximum(usage[i], 0)
-            g["allocatable"] += np.maximum(alloc[i], 0)
-        for group in sorted(groups):
-            g = groups[group]
-            used, allocatable = g["used"], g["allocatable"]
+        sums = np.zeros((len(group_names), 6), dtype=np.int64)
+        np.add.at(
+            sums,
+            group_ids,
+            np.hstack([np.maximum(usage, 0), np.maximum(alloc, 0)]).astype(
+                np.int64
+            ),
+        )
+        for g, group in enumerate(group_names):
+            used, allocatable = sums[g, :3], sums[g, 3:]
             with np.errstate(divide="ignore", invalid="ignore"):
                 util = float(
                     np.max(

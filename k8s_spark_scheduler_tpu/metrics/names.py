@@ -197,6 +197,10 @@ CAPACITY_TIME_TO_ADMIT = "foundry.spark.scheduler.tpu.capacity.time.to.admit"
 CAPACITY_SAMPLE_COUNT = "foundry.spark.scheduler.tpu.capacity.sample.count"
 CAPACITY_SAMPLE_TIME = "foundry.spark.scheduler.tpu.capacity.sample.time"
 CAPACITY_PROBE_SOLVES = "foundry.spark.scheduler.tpu.capacity.probe.solves"
+# samples by how each node's instance group was come by (capacity/
+# observatory.py:GroupIndex, kept per node-table revision):
+# result=hit|rebuild
+CAPACITY_GROUP_INDEX_READS = "foundry.spark.scheduler.tpu.capacity.groupindex.reads"
 
 # contention observatory (contention/): lock wait/hold telemetry and
 # per-request critical-path decomposition

@@ -403,6 +403,7 @@ def init_server_with_clients(
             max_shapes=install.capacity.max_shapes,
             max_group_zones=install.capacity.max_group_zones,
             max_queue=install.capacity.max_queue,
+            tracer=tracer,
         )
 
     # scheduling-policy engine (policy/): priority ordering, backfill,

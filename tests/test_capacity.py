@@ -606,3 +606,261 @@ def test_sim_summary_carries_capacity_and_waste_columns():
     seqs = [s["seq"] for s in result.capacity_timeline]
     assert seqs == sorted(seqs)
     assert "waste_phases" in result.summary
+
+
+# -- one sample in arrays, against the plain loops it replaced (PR 39) ---------
+
+
+class _PlainLoops(CapacitySampler):
+    """The sampler with PR 38's ``_gang_rows``, ``_per_group`` and
+    ``_tenants`` bodies: a loop over the pending gangs converting each
+    demand twice, and two loops over the nodes.  The oracle the arrays
+    are held to, field for field."""
+
+    @property
+    def _group_label(self):  # the sampler keeps its label in the group index
+        return self._groups._label
+
+    def _gang_rows(self, pending):
+        return [self._gang_rows_of(pod) for pod in pending], 0
+
+    def _gang_rows_of(self, pod):
+        try:
+            from k8s_spark_scheduler_tpu.ops.tensorize import _resources_to_base
+            from k8s_spark_scheduler_tpu.scheduler.sparkpods import spark_app_demand_cached
+
+            _, demand = spark_app_demand_cached(pod)
+            drow, de = _resources_to_base(demand.driver_resources)
+            erow, ee = _resources_to_base(demand.executor_resources)
+            if not (de and ee):
+                return None
+            return (
+                tuple(int(x) for x in drow),
+                tuple(int(x) for x in erow),
+                int(demand.min_executor_count),
+            )
+        except Exception:
+            return None
+
+    def _per_group(self, snap, group_ids, group_names, avail, eligible, shape_list, shape_rows, sample):
+        combos = {}
+        for i in range(len(snap.names)):
+            group = snap.labels[i].get(self._group_label, "")
+            zone = (
+                snap.zone_names[snap.zone_id[i]]
+                if 0 <= snap.zone_id[i] < len(snap.zone_names)
+                else ""
+            )
+            combos.setdefault((group, zone), []).append(i)
+        ordered = sorted(combos.items())
+        if len(ordered) > self.max_group_zones:
+            sample.groups_dropped = len(ordered) - self.max_group_zones
+            ordered = ordered[: self.max_group_zones]
+        for (group, zone), rows in ordered:
+            idx = np.array(rows, dtype=np.int64)
+            sub_avail = avail[idx]
+            sub_elig = eligible[idx]
+            total, largest, _, _, frag = frag_report(sub_avail, sub_elig)
+            entry = {
+                "nodes": len(rows),
+                "readyNodes": int(sub_elig.sum()),
+                "free": [int(x) for x in total],
+                "largestChunk": [int(x) for x in largest],
+                "fragIndex": [round(float(x), 6) for x in frag],
+                "headroom": {},
+            }
+            if sub_elig.any():
+                rank = np.where(sub_elig, np.int64(0), np.int64(2**31 - 1))
+                headroom, _, probes, _ = probe_headroom(sub_avail, rank, sub_elig, shape_rows, self.k_max)
+                sample.probe_solves += int(probes.sum())
+                for i, (key, _) in enumerate(shape_list):
+                    entry["headroom"][key] = int(headroom[i])
+            sample.groups["|".join((group, zone))] = entry
+
+    def _tenants(self, snap, group_ids, group_names, sample):
+        groups = {}
+        usage = snap.usage
+        alloc = snap.allocatable
+        cluster_used = np.maximum(usage, 0).sum(axis=0)
+        for i in range(len(snap.names)):
+            group = snap.labels[i].get(self._group_label, "")
+            g = groups.get(group)
+            if g is None:
+                g = groups[group] = {
+                    "used": np.zeros(3, dtype=np.int64),
+                    "allocatable": np.zeros(3, dtype=np.int64),
+                }
+            g["used"] += np.maximum(usage[i], 0)
+            g["allocatable"] += np.maximum(alloc[i], 0)
+        for group in sorted(groups):
+            g = groups[group]
+            used, allocatable = g["used"], g["allocatable"]
+            with np.errstate(divide="ignore", invalid="ignore"):
+                util = float(np.max(np.where(allocatable > 0, used / np.maximum(allocatable, 1), 0.0)))
+                share = np.where(cluster_used > 0, used / np.maximum(cluster_used, 1), 0.0)
+            sample.tenants[group] = {
+                "used": [int(x) for x in used],
+                "allocatable": [int(x) for x in allocatable],
+                "utilization": round(util, 6),
+                "share": [round(float(x), 6) for x in share],
+            }
+
+
+def _without_the_clock(sample):
+    """``to_dict()`` less the fields that read the wall clock."""
+    d = sample.to_dict()
+    for key in ("t", "sampleMs"):
+        d.pop(key)
+    d["classes"].pop("expandMs", None)
+    for entry in d["queue"]:
+        entry.pop("ageSeconds")
+    return d
+
+
+GROUP_LABEL = "resource_channel"
+
+
+class _RandomCluster:
+    """A snapshot source holding one random cluster: nodes without the
+    group label or with an empty one, zone ids below and past the zone
+    list (a zone name repeated, and ""), overdrawn nodes and negative
+    usage rows, memory past 2**53 bytes, a group of nodes that have no
+    allocatable and a group whose nodes are all down."""
+
+    def __init__(self, seed):
+        from k8s_spark_scheduler_tpu.state.tensor_snapshot import TensorSnapshot
+
+        rng = np.random.RandomState(seed)
+        n = int(rng.randint(60, 240))
+        zone_names = ["us-b", "us-a", "", "us-c", "us-a"][: int(rng.randint(1, 6))]
+        zone_id = rng.randint(-2, len(zone_names) + 2, size=n).astype(np.int32)
+        group_of = rng.choice(["ig-b", "ig-a", "ig-c", "", None, "ig-empty", "ig-down"], size=n)
+        labels = [{} if g is None else {GROUP_LABEL: str(g), "other": "x"} for g in group_of]
+        alloc = np.stack(
+            [
+                rng.randint(0, 96_000, size=n),
+                # up to 2**56 bytes, odd ones among them: a float64 sum would round
+                (rng.randint(0, 1 << 20, size=n).astype(np.int64) << 36) + rng.randint(0, 1 << 30, size=n),
+                rng.randint(0, 8_000, size=n),
+            ],
+            axis=1,
+        ).astype(np.int64)
+        alloc[group_of == "ig-empty"] = 0
+        usage = (alloc * rng.uniform(-0.3, 1.3, size=(n, 3))).astype(np.int64)  # < 0 and > allocatable
+        ready = rng.rand(n) > 0.1
+        ready[group_of == "ig-down"] = False
+        self.snap = TensorSnapshot(
+            names=[f"node-{i:04d}" for i in range(n)],
+            allocatable=alloc,
+            usage=usage,
+            overhead=rng.randint(0, 500, size=(n, 3)).astype(np.int64),
+            zone_names=zone_names,
+            zone_id=zone_id,
+            ready=ready,
+            unschedulable=rng.rand(n) < 0.1,
+            labels=labels,
+            exact=True,
+            res_entries=np.zeros(n, dtype=bool),
+            name_rank=np.arange(n, dtype=np.int64),
+            structure_key=(seed, 0),
+            content_key=(seed, 1),
+        )
+
+    def snapshot(self):
+        return self.snap
+
+
+@pytest.fixture(scope="module")
+def queued_gangs():
+    """A pending queue of repeated shapes, one gang whose annotations
+    do not parse and one whose demand is not exact in base units."""
+    h = Harness(binpack_algo="tpu-batch", is_fifo=True)
+    h.server.capacity.stop()
+    for i in range(12):
+        h.create_pod(
+            h.static_allocation_spark_pods(
+                f"app-g{i}", 1 + i % 4, executor_cpu=str(1 + i % 3), creation_timestamp=1000.0 + i
+            )[0]
+        )
+    bad = h.static_allocation_spark_pods("app-unparseable", 2, creation_timestamp=1100.0)[0]
+    bad.meta.annotations = {**bad.meta.annotations, "spark-executor-count": "many"}
+    h.create_pod(bad)
+    h.create_pod(h.static_allocation_spark_pods("app-inexact", 2, driver_cpu="1500u", creation_timestamp=1101.0)[0])
+    yield h
+    h.close()
+
+
+@pytest.mark.parametrize("seed", [3, 2718281828, 20261015, 4, 5, 6])
+def test_a_sample_in_arrays_is_the_plain_loops_sample_field_for_field(seed, queued_gangs):
+    cluster = _RandomCluster(seed)
+
+    def sampler(cls):
+        return cls(
+            cluster,
+            pod_lister=queued_gangs.server.pod_lister,
+            instance_group_label=GROUP_LABEL,
+            max_group_zones=5,
+            k_max=64,
+        )
+
+    got = sampler(CapacitySampler).sample_now(trigger="t")
+    want = sampler(_PlainLoops).sample_now(trigger="t")
+    assert _without_the_clock(got) == _without_the_clock(want)
+    # the cases the cluster is there for
+    assert got.groups_dropped > 0
+    assert set(got.tenants) >= {"", "ig-empty", "ig-down"}
+    assert got.tenants["ig-empty"]["allocatable"] == [0, 0, 0]
+    assert max(t["allocatable"][1] for t in got.tenants.values()) > 2**53
+    states = [e["state"] for e in got.queue]
+    assert states.count("unparseable") == 2 and got.queued_gangs == 14
+
+
+def test_group_index_is_kept_per_node_table_revision_and_counted():
+    """A hit while the node table stands (reservations come and go); a
+    rebuild after a label change, a node added and a node removed.  The
+    registry counts each read, and every sample equals the loops'."""
+    h = Harness(binpack_algo="tpu-batch", is_fifo=True)
+    try:
+        h.server.capacity.stop()
+        metrics = MetricsRegistry()
+
+        def sampler(cls, metrics=None):
+            return cls(
+                h.server.tensor_snapshot,
+                pod_lister=h.server.pod_lister,
+                metrics=metrics,
+                instance_group_label=h.server.install.instance_group_label,
+            )
+
+        arrays, loops = sampler(CapacitySampler, metrics), sampler(_PlainLoops)
+        nodes = ["n1", "n2", "n3"]
+        for i, name in enumerate(nodes):
+            h.new_node(name, zone=f"z{i % 2}", instance_group=f"ig-{i % 2}")
+
+        def read(want):
+            sample = arrays.sample_now(trigger="t")
+            assert _without_the_clock(sample) == _without_the_clock(loops.sample_now(trigger="t"))
+            counts = {
+                r: metrics.get_counter(mnames.CAPACITY_GROUP_INDEX_READS, {"result": r}) or 0
+                for r in ("hit", "rebuild")
+            }
+            assert counts == want
+            return sample
+
+        assert set(read({"hit": 0, "rebuild": 1}).tenants) == {"ig-0", "ig-1"}
+        read({"hit": 1, "rebuild": 1})
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-1", 1, instance_group="ig-0")[0], nodes))
+        used = read({"hit": 2, "rebuild": 1})
+        assert sum(t["used"][0] for t in used.tenants.values()) > 0
+
+        node = h.api.get("Node", "default", "n1")
+        node.meta.labels = {**node.meta.labels, "resource_channel": "ig-2"}
+        h.api.update(node)
+        assert set(read({"hit": 2, "rebuild": 2}).tenants) == {"ig-0", "ig-1", "ig-2"}
+        h.new_node("n4", instance_group="ig-3")
+        assert read({"hit": 2, "rebuild": 3}).nodes == 4
+        h.api.delete("Node", "default", "n4")
+        assert set(read({"hit": 2, "rebuild": 4}).tenants) == {"ig-0", "ig-1", "ig-2"}
+        read({"hit": 3, "rebuild": 4})
+    finally:
+        h.close()

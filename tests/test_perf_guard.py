@@ -429,10 +429,10 @@ def test_capacity_sampler_overhead_within_budget():
         sampled = []  # (thread, thread name, inside the extender lock)
         build = sampler._build_sample
 
-        def recording(snap, trigger):
+        def recording(snap, trigger, span):
             t = threading.current_thread()
             sampled.append((t.ident, t.name, in_predicate_lock()))
-            return build(snap, trigger)
+            return build(snap, trigger, span)
 
         sampler._build_sample = recording
         before = sampler.stats()["samples"]

@@ -194,8 +194,11 @@ def served_harness(lane, binpack_algo="tpu-batch"):
 
 
 def roots_of(h):
+    """The roots the server's tracer finishes from here on, less the
+    capacity sampler's: its thread samples after any node or
+    reservation change, whenever the debounce lets it."""
     roots = []
-    h.server.tracer.add_observer(roots.append)
+    h.server.tracer.add_observer(lambda root: root.name == "capacity.sample" or roots.append(root))
     return roots
 
 
@@ -1091,7 +1094,10 @@ def test_each_loop_the_server_starts_marks_one_unit_of_its_work_under_its_name()
         marker = h.unschedulable_marker  # its scan is a trace of its own: stood in for, so that the ring's count stays
         marker._interval = 0.01
         marker.scan_for_unschedulable_pods = watching("unschedulable.scan", marker.stop)
-        before = len(server.tracer)
+        def traces_but_samples():
+            return [t for t in server.tracer.traces() if t["root"]["name"] != "capacity.sample"]
+
+        before = len(traces_but_samples())
         reporters.start()
         polls.start()
         marker.start()
@@ -1103,6 +1109,90 @@ def test_each_loop_the_server_starts_marks_one_unit_of_its_work_under_its_name()
         # start()'s own look, on the caller's thread, is no poll; the two polls after it are
         assert ["demand.poll" in active for active in seen["demand.poll"]] == [False, True, True]
         assert h.wait_for_api(lambda: not set(table._active) & names, timeout=20.0)
-        assert len(server.tracer) == before + 1  # the request's trace and no marker's
+        # the request's trace and no marker's; each sample is a trace of its own
+        assert len(traces_but_samples()) == before + 1
+    finally:
+        h.close()
+
+
+def test_each_background_sample_is_one_capacity_sample_root_with_its_tags():
+    """The sampler's loop opens one root ``capacity.sample`` per sample,
+    from the server's tracer: ``pending`` pending drivers, each of whose
+    rows came from its demand's stash (``stashedRows``), and the group
+    index ``rebuild`` after a node event, ``hit`` after a reservation.
+    Neither the root nor its spans carry ``bg``: it is the marked work."""
+    h = Harness(binpack_algo="tpu-batch")
+    try:
+        sampler, tracer = h.server.capacity, h.server.tracer
+
+        def sample_roots():  # oldest first
+            return [t["root"] for t in reversed(tracer.traces()) if t["root"]["name"] == "capacity.sample"]
+
+        def settled(count):
+            """``count`` samples or more, each a root, the last of the node table as it stands."""
+            return lambda: (
+                len(sample_roots()) == sampler.stats()["samples"] >= count
+                and sampler.latest().structure_key == h.server.tensor_snapshot.snapshot().structure_key
+            )
+
+        for name in NODES[:3]:
+            h.new_node(name)
+        for i in range(2):
+            # behind the granted driver in the queue: created after it
+            h.create_pod(h.static_allocation_spark_pods(f"app-waiting-{i}", 1, creation_timestamp=time.time() + 600)[0])
+        assert h.wait_for_api(settled(1), timeout=20.0), sampler.stats()
+        assert sample_roots()[-1]["tags"]["groupIndex"] == "rebuild"
+        samples = len(sample_roots())
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-granted", 1)[0], NODES[:3]))
+        assert h.wait_for_api(settled(samples + 1), timeout=20.0), sampler.stats()
+        last = sample_roots()[-1]
+        assert own(last["tags"]) == {"pending": 2, "groupIndex": "hit", "stashedRows": 2}
+        for root in sample_roots():
+            assert root["parentId"] is None
+            assert all("bg" not in s["tags"] for s in dict_spans(root))
+    finally:
+        h.close()
+
+
+def test_a_sample_refused_under_the_predicate_lock_opens_no_span():
+    from k8s_spark_scheduler_tpu import capacity
+
+    h = Harness(binpack_algo="tpu-batch", is_fifo=True)
+    try:
+        h.new_node("n0")
+        sampler = h.server.capacity
+        sampler.stop()
+        tracer = Tracer(capacity=4)
+        capacity.enter_predicate_lock()
+        try:
+            assert sampler.sample_now(trigger="in-lock", tracer=tracer) is None
+        finally:
+            capacity.exit_predicate_lock()
+        assert len(tracer) == 0
+        assert sampler.sample_now(trigger="untraced") is not None and len(tracer) == 0
+        assert sampler.sample_now(trigger="traced", tracer=tracer) is not None
+        (trace,) = tracer.traces()
+        assert trace["root"]["name"] == "capacity.sample"
+        assert set(trace["root"]["tags"]) >= {"pending", "groupIndex", "stashedRows"}
+    finally:
+        h.close()
+
+
+def test_a_samples_own_spans_name_no_background_work_beside_them():
+    """Under its loop's marker the sample's root takes no ``bg``, though a
+    write-back runs beside it; the same sample unmarked would name it."""
+    h = Harness(binpack_algo="tpu-batch", is_fifo=True)
+    try:
+        h.new_node("n0")
+        sampler = h.server.capacity
+        sampler.stop()
+        tracer = Tracer(capacity=4)
+        with _Held(lambda: tracing.background("writeback")):
+            with tracing.background("capacity.sample"):
+                sampler.sample_now(trigger="marked", tracer=tracer)
+            sampler.sample_now(trigger="unmarked", tracer=tracer)
+        unmarked, marked = (t["root"] for t in tracer.traces())
+        assert "bg" not in marked["tags"]
+        assert unmarked["tags"]["bg"] == "writeback"
     finally:
         h.close()
