@@ -160,7 +160,10 @@ PREP_CACHE_READS = "foundry.spark.scheduler.tpu.fastpath.prepcache.reads"
 # and executor label priority): result=hit|miss|uncacheable
 EXECUTOR_ROWS_READS = "foundry.spark.scheduler.tpu.fastpath.executorrows.reads"
 # queue apps of single-AZ driver Filters by who chose their zone
-# (result=certified|resolved|host-queue), ops/fifo_solver.py
+# (result=certified|resolved|unmemoised|host-queue, a partition),
+# ops/fifo_solver.py; unmemoised: decided on the host where the policy's
+# choice keeps no evidence for the memo (single-AZ min-frag), resolved:
+# decided on the host otherwise
 FIFO_ZONE_CHOICE = "foundry.spark.scheduler.fifo.zone.choice"
 PACKING_EFFICIENCY_MAX = "foundry.spark.scheduler.packing.efficiency.max"
 DRIVER_EXECUTOR_COLLOCATION = "foundry.spark.scheduler.driver.executor.collocation"

@@ -95,14 +95,21 @@ def min_frag_unclamped_caps(
     under a zero requirement — the reserved>available short-circuit)."""
     avail = avail.astype(np.int64).copy()
     avail[driver_idx] -= driver_row.astype(np.int64)
-    exec_row = exec_row.astype(np.int64)
+    return np.where(exec_ok, unclamped_caps(avail, exec_row), 0)
+
+
+def unclamped_caps(avail: np.ndarray, exec_row: np.ndarray) -> np.ndarray:
+    """``min_frag_unclamped_caps`` of rows that already show every
+    subtraction, for every row; ``exec_row`` one executor [3] or one per
+    row [n, 3]."""
+    avail = avail.astype(np.int64)
+    exec_row = np.broadcast_to(exec_row.astype(np.int64), avail.shape)
     per_dim = np.where(
-        exec_row[None, :] == 0,
+        exec_row == 0,
         np.where(avail >= 0, np.int64(2**62), np.int64(0)),
-        np.floor_divide(avail, np.maximum(exec_row[None, :], 1)),
+        np.floor_divide(avail, np.maximum(exec_row, 1)),
     )
-    cap = np.clip(per_dim.min(axis=1), 0, None)
-    return np.where(exec_ok, cap, 0)
+    return np.clip(per_dim.min(axis=1), 0, None)
 
 
 def minimal_fragmentation_rows(cap: np.ndarray, k: int) -> Optional[np.ndarray]:
@@ -176,6 +183,21 @@ def _drain_sorted(
     else:
         return None
     return np.repeat(np.concatenate(hosts), on_each)
+
+
+def minimal_fragmentation_order(
+    cap: np.ndarray, rows: np.ndarray, groups: np.ndarray
+) -> np.ndarray:
+    """The positions of ``rows``, the hosts of min-frag placements already
+    made (``cap`` their capacities, the driver subtracted on its node;
+    ``groups`` tells disjoint placements apart), in the order
+    ``minimal_fragmentation_rows`` emits them, read from the hosts alone
+    with no sort of the other nodes.  The drain takes capacity classes
+    from the largest down, each in priority order, and the node that
+    takes what is left comes last: its capacity is below every drained
+    class's, or it follows them in their own class.  So each placement's
+    hosts come by descending capacity, then row."""
+    return np.lexsort((rows, -cap, groups))
 
 
 def tightly_rows(counts: np.ndarray) -> np.ndarray:

@@ -569,11 +569,49 @@ HINT_BASE = 64
 DRIVER_BIT = 30
 
 
-def snapshot_slots(n_nodes: int) -> int:
+def snapshot_slots(n_nodes: int, compacted: bool = False) -> int:
     """Snapshot slots of a single-AZ pass over ``n_nodes``: as many as 6
     MiB hold (four int32 rows of the node axis each; the pallas kernel
-    keeps them in its fast memory), 32 at the most."""
-    return max(1, min(32, (6 << 20) // (16 * max(n_nodes, 1))))
+    keeps them in its fast memory), 32 at the most.  Where the host
+    reads the slots ``compacted`` (``compact_snapshots``: min-frag's
+    valve), what it reads no longer grows with the node axis, and the
+    slots may take 40 MiB, 256 at the most: a launch per 256 flagged
+    apps instead of per 32."""
+    budget, most = ((40 << 20), 256) if compacted else ((6 << 20), 32)
+    return max(1, min(most, budget // (16 * max(n_nodes, 1))))
+
+
+# nodes a compacted snapshot keeps per slot: every zone's placement of a
+# gang of up to 41 executors in three zones; a slot that occupies more is
+# read whole (the caller checks the count)
+COMPACT_NODES = 128
+
+
+def compact_snapshots(snapshots: jnp.ndarray, width: int = COMPACT_NODES) -> jnp.ndarray:
+    """The snapshots [S, 4, N] as the min-frag valve reads them, [S, 1 +
+    5 * width] int32: per slot the number of nodes its packing plane
+    occupies, the first ``width`` of them in node order (N past the
+    count), then the four planes at those nodes, plane by plane.  A slot
+    the pass never filled reads as garbage, as it does whole."""
+    s, _, n = snapshots.shape
+    width = min(width, n)
+    occupied = snapshots[:, 3] != 0
+    count = occupied.sum(axis=1, dtype=jnp.int32)
+    # the largest keys are the occupied nodes, the lowest node first
+    key = jnp.where(occupied, -jnp.arange(n, dtype=jnp.int32)[None, :], jnp.int32(-n - 1))
+    _, first = lax.top_k(key, width)
+    nodes = jnp.where(jnp.arange(width, dtype=jnp.int32)[None, :] < count[:, None], first, n)
+    values = jnp.take_along_axis(snapshots, jnp.minimum(nodes, n - 1)[:, None, :], axis=2)
+    return jnp.concatenate(
+        [count[:, None], nodes.astype(jnp.int32), values.reshape(s, 4 * width)], axis=1
+    )
+
+
+@jax.jit
+def compacted_with_probe(snapshots: jnp.ndarray, probe_slot: jnp.ndarray):
+    """(``compact_snapshots``, the probe's slot whole [4, N]) of a pass's
+    snapshots, for a lane whose pass returns them apart."""
+    return compact_snapshots(snapshots), snapshots[jnp.maximum(probe_slot, 0)]
 
 
 def _zone_score(

@@ -26,9 +26,11 @@ from .batch_adapter import (
     evenly_rows,
     min_frag_unclamped_caps,
     min_frag_zone_decode,
+    minimal_fragmentation_order,
     minimal_fragmentation_rows,
     names_of_rows,
     tightly_rows,
+    unclamped_caps,
 )
 from .efficiency import compute_packing_efficiencies
 from .packers import PackingResult, empty_packing_result
@@ -960,6 +962,21 @@ def _host_gang_solve(avail, rank, exec_ok, driver, executor, k):
     return d, counts
 
 
+def _occupied_rows(keep: np.ndarray, view=None, snapshots=None):
+    """(slot, node, the four planes there [L, 4]) of every node the
+    packing plane of a kept slot occupies, slot by slot in node order,
+    from a compacted view (``batch_solver.compact_snapshots``) or from the
+    snapshots [S, 4, N] whole."""
+    if view is not None:
+        width = (view.shape[1] - 1) // 5
+        inside = (np.arange(width)[None, :] < view[:, :1]) & keep[:, None]
+        slot, at = np.nonzero(inside)
+        planes = view[:, 1 + width :].reshape(len(view), 4, width)
+        return slot, view[slot, 1 + at], planes[slot, :, at]
+    slot, nodes = np.nonzero((snapshots[:, 3] != 0) & keep[:, None])
+    return slot, nodes, snapshots[slot, :, nodes]
+
+
 def _same_evidence(a, b) -> bool:
     return a is not None and b is not None and a.shape == b.shape and bool((a == b).all())
 
@@ -1071,6 +1088,103 @@ class _ZoneProblem:
         """The same decision from a snapshot of the device pass ([4, n]:
         the carry's three planes and every zone's packing in one row),
         with no solve on the host: (the pick or None, the carry [n, 3])."""
+        if self.minfrag:
+            return self._pick_placed_min_frag(snapshot, app_idx), snapshot[:3].T
+        avail, packings = self.snapshot_packings(snapshot)
+        return self._choose(avail, app_idx, packings), avail
+
+    def zone_from_snapshot(self, snapshot: np.ndarray, app_idx: int) -> int:
+        """The candidate zone of ``pick_from_snapshot``'s pick, which is all
+        the valve asks of it (min-frag's asks ``placed_min_frag_zones``)."""
+        return self.candidate(self.pick_from_snapshot(snapshot, app_idx)[0])
+
+    def placed_min_frag_zones(self, apps: np.ndarray, view=None, snapshots=None) -> np.ndarray:
+        """The zone the reference chooses for the app of each slot of a
+        pass (``apps`` [S]; -1 = a slot not asked about, which reads -1),
+        from the slots as they stand: ``view`` compacted
+        (``batch_solver.compact_snapshots``) or ``snapshots`` [S, 4, N]
+        whole.  Every slot in one pass of ``_placed_min_frag``."""
+        rows = _occupied_rows(apps >= 0, view=view, snapshots=snapshots)
+        return self._placed_min_frag(apps, *rows)[0]
+
+    def _placed_min_frag(self, apps, slot, nodes, values):
+        """``_choose`` over min-frag placements as they stand, with no
+        decode, for many snapshots at once (``apps`` [S]: the app each was
+        left for; ``slot``, ``nodes``, ``values`` [L, 4]: the nodes their
+        packing planes occupy, slot by slot in node order, and the four
+        planes there): every zone of every snapshot scored in one pass
+        (the zones are disjoint), the hosts' emission order read from
+        their own capacities (``minimal_fragmentation_order``), and each
+        zone's sum taken term by term in float64, the driver's node
+        first, as ``_average`` takes it; the earlier zone on a tie.  (each
+        snapshot's zone or -1 [S]; per node: its zone, executor count,
+        driver flag; the nodes' emission order)."""
+        from .batch_solver import DRIVER_BIT
+
+        problem = self.problem
+        app = apps[slot]
+        driver = problem.driver[app].astype(np.int64)
+        executor = problem.executor[app].astype(np.int64)
+        zone = self.zone_vec[nodes]
+        counts = (values[:, 3] & ((1 << DRIVER_BIT) - 1)).astype(np.int64)
+        is_driver = (values[:, 3] >> DRIVER_BIT) != 0
+        here = values[:, :3].astype(np.int64)
+        # the score sees the driver alone under strict parity (no write-back)
+        seen = np.zeros_like(counts) if self.strict else counts
+        reserved = seen[:, None] * executor + is_driver[:, None] * driver
+        s = self.cluster.sched[nodes]
+        cpu, mem, gpu = _efficiency_columns(s, s - (here - reserved) * self.scale)
+        terms = np.maximum(np.maximum(cpu, mem), gpu)
+        cap = unclamped_caps(here - is_driver[:, None] * driver, executor)
+        n_zones = max(self.n_zones, 1)
+        group = slot * n_zones + zone
+        order = minimal_fragmentation_order(cap, nodes, group)
+        # a zone packed where its driver is: its sum starts at that node,
+        # then one term per executor in emission order, each zone's terms
+        # a row of ``ledger`` added up column by column (zeros pad the end)
+        heads = np.flatnonzero(is_driver)
+        on_each = counts[order]
+        g = np.concatenate([group[heads], np.repeat(group[order], on_each)])
+        t = np.concatenate([terms[heads], np.repeat(terms[order], on_each)])
+        by_group = np.argsort(g, kind="stable")
+        g, t = g[by_group], t[by_group]
+        at = np.arange(len(g)) - np.searchsorted(g, g)
+        n_groups = len(apps) * n_zones
+        ledger = np.zeros((n_groups, int(at.max(initial=0)) + 1))
+        ledger[g, at] = t
+        total = ledger[:, 0].copy()
+        for column in ledger.T[1:]:
+            total += column
+        placed = np.bincount(group, weights=counts, minlength=n_groups)
+        packed = np.zeros(n_groups, bool)
+        packed[group[heads]] = True
+        avg = np.where(packed, total / (placed + 1.0), -np.inf).reshape(len(apps), n_zones)
+        avg[np.isnan(avg)] = -np.inf
+        best = np.argmax(avg, axis=1)
+        best = np.where(avg[np.arange(len(apps)), best] > 0.0, best, -1)
+        return best, zone, counts, is_driver, order
+
+    def _pick_placed_min_frag(self, snapshot: np.ndarray, app_idx: int) -> Optional[_ZonePick]:
+        """The pick of ``_placed_min_frag``'s zone for one snapshot."""
+        slot, nodes, values = _occupied_rows(np.ones(1, bool), snapshots=snapshot[None])
+        best, zone, counts, is_driver, order = self._placed_min_frag(
+            np.array([app_idx]), slot, nodes, values
+        )
+        best = int(best[0])
+        if best < 0:
+            return None
+        inside = zone == best
+        mine = order[zone[order] == best]
+        pick_counts = np.zeros(self.n, np.int64)
+        pick_counts[nodes[inside]] = counts[inside]
+        rows = np.repeat(nodes[mine], counts[mine])
+        return _ZonePick(
+            best, int(nodes[inside & is_driver][0]), pick_counts,
+            names_of_rows(self.names, rows)[0],
+        )
+
+    def snapshot_packings(self, snapshot: np.ndarray):
+        """(the carry [n, 3], each zone's packing or None) of a snapshot."""
         from .batch_solver import DRIVER_BIT
 
         avail = snapshot[:3].T
@@ -1088,14 +1202,13 @@ class _ZoneProblem:
                 continue
             hosts = inside & (counts > 0)
             packings.append((int(drivers[0]), occupied[hosts], counts[hosts]))
-        return self._choose(avail, app_idx, packings), avail
+        return avail, packings
 
     def evidence(self, snapshot: np.ndarray, app_idx: int):
         """Everything ``pick_from_snapshot`` reads to choose the app's
         zone: the packings, and the carry, schedulable totals and zones
         of the nodes they occupy, the app's demand and the scale.  None
-        where the choice reads more (the min-frag decode reads every
-        node's capacity)."""
+        under min-frag, whose choice is not memoised."""
         if self.minfrag:
             return None
         problem = self.problem
@@ -1120,7 +1233,7 @@ class _ZoneProblem:
             if packing is None:
                 continue
             d_idx, hosts, on_each = packing
-            nodes = None
+            rows = None
             if self.minfrag:
                 # placements and their order are the drain's, from the
                 # exact host bisect on the same capacities
@@ -1131,7 +1244,6 @@ class _ZoneProblem:
                 if decoded is None:  # unreachable: the zone is feasible
                     continue
                 rows, counts, reserved_counts = decoded
-                nodes = names_of_rows(self.names, rows)[0]
                 hosts = np.flatnonzero(counts)
                 on_each = counts[hosts]
                 avg = self._average(
@@ -1141,9 +1253,12 @@ class _ZoneProblem:
             else:
                 avg = self._average(avail, app_idx, d_idx, hosts, on_each, on_each)
             if best_avg < avg:
-                best, best_avg = (zi, d_idx, hosts, on_each, nodes), avg
+                best, best_avg = (zi, d_idx, hosts, on_each, rows), avg
+        nodes = None
         if best is not None:
-            zi, d_idx, hosts, on_each, nodes = best
+            zi, d_idx, hosts, on_each, rows = best
+            if rows is not None:
+                nodes = names_of_rows(self.names, rows)[0]
         elif self.az_aware:
             # az_aware_pack_tightly.go:34-37: plain tightly-pack across zones
             solved = _host_gang_solve(avail[:n], self.rank, self.exec_ok, driver, executor, k)
@@ -1203,7 +1318,10 @@ class TpuSingleAzFifoSolver:
     device pass, resolved apps included), "native" or "host";
     ``last_queue_lane`` on what: "pallas" / "xla" / "native" / "host";
     None = no queue pass ran.  ``last_zone_choices`` counts the last
-    request's queue apps by who chose their zone."""
+    request's queue apps by who chose their zone, each app under one key;
+    a device pass's host decisions are ``resolved`` where a memo could
+    answer them and ``unmemoised`` where the policy's choice keeps no
+    evidence (min-frag's)."""
 
     def __init__(
         self,
@@ -1444,8 +1562,9 @@ class TpuSingleAzFifoSolver:
         zone its score cannot certify, goes on with the score's choice
         and leaves a snapshot; each flagged app is decided here in
         float64 from its snapshot, and only where that differs from the
-        score's choice (or the pass ran out of slots and halted) is the
-        pass launched again, from that app, with its zone forced.  The
+        score's choice is the pass launched again, from that app, with
+        its zone forced; a pass that ran out of slots and halted is
+        launched again from the app it halted at, undecided.  The
         request's own app rides along as a probe: its snapshot is what
         ``binpack`` chooses from."""
         from .batch_solver import FORCE_NONE, HINT_BASE
@@ -1470,72 +1589,112 @@ class TpuSingleAzFifoSolver:
                     forced[u] = HINT_BASE + known[0]
         memo = {}
         feasible = np.zeros(n_earlier, bool)
-        carry, start, launches, resolved = None, 0, 0, 0
+        # resolved: flagged apps decided on the host; unmemoised: those among
+        # them whose policy's choice keeps no evidence, so no memo could answer
+        carry, start, launches, resolved, unmemoised = None, 0, 0, 0, 0
         while True:
-            columns, avail_dev, snapshots_dev = launch(carry, forced, start)
+            columns, avail_dev, left = launch(carry, forced, start)
             launches += 1
             flagged = start + np.flatnonzero(columns[start : n_earlier + 1, 3])
             slots = columns[flagged, 4]
-            snapshots = _readback(snapshots_dev) if (slots >= 0).any() else None
-            redo = probe = avail = None
+            snapshots = view = probe = None
+            if (slots >= 0).any():
+                if zones.minfrag:
+                    # min-frag's valve reads the slots compacted, and the
+                    # probe's whole where this launch packed it
+                    left, view_dev, probe_dev = left
+                    view = _readback(view_dev)
+                    if slots[-1] >= 0 and flagged[-1] == n_earlier:
+                        probe = _readback(probe_dev)
+                else:
+                    snapshots = _readback(left)
+            redo = avail = None
             with tracing.aggregate_span("fifo_gate.zone_resolve"):
+                decided = None
+                if view is not None:
+                    decided = self._min_frag_decisions(zones, view, left, flagged, slots, n_earlier)
                 for u, slot in zip(flagged.tolist(), slots.tolist()):
                     if slot < 0:  # out of slots: the pass halted here
                         redo = u
                         break
                     if u == n_earlier:
-                        probe = snapshots[slot]
+                        if snapshots is not None:
+                            probe = snapshots[slot]
                         break
-                    # the decision is a function of what the snapshot shows of
-                    # the nodes its packings occupy: the same evidence as last
-                    # time is the same decision, with no arithmetic
-                    evidence = zones.evidence(snapshots[slot], u)
-                    known = self._zone_memo.get(app_keys[u])
-                    if known is not None and _same_evidence(known[1], evidence):
-                        zone = known[0]
+                    if decided is not None:
+                        # min-frag's choice keeps no evidence, so no memo answers it
+                        evidence, zone = None, int(decided[slot])
+                        unmemoised += 1
                     else:
-                        zone = zones.candidate(zones.pick_from_snapshot(snapshots[slot], u)[0])
+                        # the decision is a function of what the snapshot shows of
+                        # the nodes its packings occupy: the same evidence as last
+                        # time is the same decision, with no arithmetic
+                        evidence = zones.evidence(snapshots[slot], u)
+                        known = self._zone_memo.get(app_keys[u])
+                        if known is not None and _same_evidence(known[1], evidence):
+                            zone = known[0]
+                        else:
+                            zone = zones.zone_from_snapshot(snapshots[slot], u)
                     memo[app_keys[u]] = (zone, evidence)
                     resolved += 1
                     if zone != zones.candidate(int(columns[u, 2])):
                         forced[u] = zone
-                        redo, avail = u, snapshots[slot][:3].T
+                        whole = snapshots if snapshots is not None else _readback(left)
+                        redo, avail = u, whole[slot][:3].T
                         break
                 stop = n_earlier if redo is None else redo
                 feasible[start:stop] = columns[start:stop, 0] != 0
             if redo is None:
                 break
             if avail is None:
-                avail = _readback(avail_dev)
                 if redo == n_earlier:
+                    avail = _readback(avail_dev)
                     break  # the probe found no slot: binpack packs on the host
-                with tracing.aggregate_span("fifo_gate.zone_resolve"):
-                    forced[redo] = zones.candidate(zones.pick(avail, redo))
-                resolved += 1
+                # out of slots: the next launch starts at the halted app,
+                # which it flags into its first slot
                 carry = avail_dev
             else:
                 carry = _upload(np.ascontiguousarray(avail, np.int32))[0]
             start = redo
         gate_span.tag("zoneResolved", resolved).tag("launches", launches)
+        gate_span.tag("zoneUnmemoised", unmemoised)
         self._zone_memo = memo
         self.last_launches = launches
-        self.last_zone_choices = {"certified": n_earlier - resolved, "resolved": resolved}
+        self.last_zone_choices = {
+            "certified": n_earlier - resolved, "resolved": resolved - unmemoised,
+            "unmemoised": unmemoised,
+        }
         if probe is not None:
             avail = np.ascontiguousarray(probe[:3].T)
         elif avail is None:
             avail = _readback(avail_dev)
         return feasible, avail, probe
 
+    @staticmethod
+    def _min_frag_decisions(zones, view, snapshots_dev, flagged, slots, n_earlier):
+        """The zone of every queue app a launch packed into a slot, by
+        slot, from the compacted view; a slot that occupies more nodes
+        than the view keeps has the snapshots read whole."""
+        apps = np.full(len(view), -1, np.int64)
+        asked = (slots >= 0) & (flagged < n_earlier)
+        apps[slots[asked]] = flagged[asked]
+        width = (view.shape[1] - 1) // 5
+        if (view[slots[asked], 0] > width).any():
+            return zones.placed_min_frag_zones(apps, snapshots=_readback(snapshots_dev))
+        return zones.placed_min_frag_zones(apps, view=view)
+
     def _launcher(self, zones, valid, score_inputs, pallas):
         """launch(carry | None, forced, start) -> (host verdict columns
         [A, 5]: placed, driver node, zone, flagged, snapshot slot; device
-        availability afterwards; device snapshots).  What does not
-        change between the launches of one request is uploaded once."""
+        availability afterwards; device snapshots, under min-frag with
+        their compacted view and the probe's slot beside them).  What
+        does not change between the launches of one request is uploaded
+        once."""
         from .batch_solver import snapshot_slots
 
         problem = zones.problem
         s_cpu, s_gpu, inv_m, th_m, scale_c, scale_g = score_inputs
-        n_slots = snapshot_slots(problem.avail.shape[0])
+        n_slots = snapshot_slots(problem.avail.shape[0], compacted=zones.minfrag)
         if pallas:
             from .pallas_queue import pallas_solve_queue_single_az_packed as kernel
 
@@ -1557,19 +1716,20 @@ class TpuSingleAzFifoSolver:
                 with default_profiler.profile(
                     "fifo_queue_single_az", lane="pallas", fn=kernel
                 ) as rec:
-                    columns, avail_after, snapshots = kernel(
+                    columns, avail_after, *left = kernel(
                         avail0 if carry is None else carry,
                         nodes_dev, apps_dev, scalars,
                         n_zones=zones.n_zones, az_aware=self.az_aware,
                         interpret=self.interpret, minfrag=zones.minfrag,
                         strict=self.strict_reference_parity, n_slots=n_slots,
+                        compact=zones.minfrag,
                     )
                     rec.sync(avail_after)
-                return _readback(columns), avail_after, snapshots
+                return _readback(columns), avail_after, left[0] if len(left) == 1 else left
 
             return launch
 
-        from .batch_solver import solve_queue_single_az
+        from .batch_solver import compacted_with_probe, solve_queue_single_az
 
         zone_masks = zones.zone_vec[None, :] == np.arange(max(zones.n_zones, 1))[:, None]
         fixed = _upload(
@@ -1595,7 +1755,12 @@ class TpuSingleAzFifoSolver:
                  (out.feasible, out.driver_idx, out.zone_idx, out.uncertain, out.slot)],
                 axis=1,
             ).astype(np.int32)
-            return columns, out.avail_after, out.snapshots
+            if not zones.minfrag:
+                return columns, out.avail_after, out.snapshots
+            probe_slot = np.int32(columns[valid == 2, 4].max(initial=0))
+            return columns, out.avail_after, (
+                out.snapshots, *compacted_with_probe(out.snapshots, probe_slot)
+            )
 
         return launch
 

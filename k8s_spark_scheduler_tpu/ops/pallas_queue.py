@@ -30,7 +30,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .batch_solver import DRIVER_BIT, EFF_SHIFT, FORCE_NONE, HINT_BASE, MF_SENT
+from .batch_solver import DRIVER_BIT, EFF_SHIFT, FORCE_NONE, HINT_BASE, MF_SENT, compact_snapshots
 
 LANES = 128
 BIG = 2**31 - 1  # plain int: a module-level jnp scalar would be a captured const in the kernel
@@ -727,7 +727,10 @@ def pallas_solve_queue_single_az(
     return feasible, feas[:, 2], driver_idx, feas[:, 3] != 0, avail_after
 
 
-@functools.partial(jax.jit, static_argnames=_SINGLE_AZ_STATIC)
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_zones", "az_aware", "interpret", "minfrag", "strict", "n_slots", "compact"),
+)
 def pallas_solve_queue_single_az_packed(
     avail: jnp.ndarray,      # [N, 3] int32 — the carry this launch starts from
     node_cols: jnp.ndarray,  # [N, 7] int32: driver rank, executor ok, zone, schedulable
@@ -740,13 +743,16 @@ def pallas_solve_queue_single_az_packed(
     minfrag: bool = False,
     strict: bool = True,
     n_slots: int = 0,
+    compact: bool = False,
 ):
     """``pallas_solve_queue_single_az`` as the served path launches it:
     the inputs in four arrays (an upload costs the host the same
     whatever its size; ``valid`` 2 marks the probe) and the per-app
     results in one, [A, 5] int32 (placed, driver node, zone, flagged,
     snapshot slot), then avail_after [N, 3] and the snapshots
-    [n_slots, 4, N]."""
+    [n_slots, 4, N].  ``compact``: the snapshots also as
+    ``batch_solver.compact_snapshots`` has them and the probe's slot
+    whole [4, N], which is what the min-frag valve reads back."""
     feas, avail_after, snaps = _single_az_call(
         avail, node_cols[:, 0], node_cols[:, 1], node_cols[:, 2],
         app_cols[:, 0:3], app_cols[:, 3:6], app_cols[:, 6], app_cols[:, 7],
@@ -757,7 +763,11 @@ def pallas_solve_queue_single_az_packed(
         strict=strict, n_slots=n_slots,
     )
     node = jnp.where(feas[:, 0] != 0, feas[:, 1], jnp.int32(avail.shape[0]))
-    return feas[:, 0:5].at[:, 1].set(node), avail_after, snaps
+    columns = feas[:, 0:5].at[:, 1].set(node)
+    if not compact:
+        return columns, avail_after, snaps
+    probe_slot = jnp.max(jnp.where(app_cols[:, 7] == 2, feas[:, 4], 0))
+    return columns, avail_after, snaps, compact_snapshots(snaps), snaps[probe_slot]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -1081,7 +1081,11 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         should_schedule_into_single_az = False
         single_az_zone = ""
         if self.binpacker.is_single_az and self._single_az_da:
-            zone, all_in_same_az = self._get_common_zone_for_executors_application(executor)
+            with self._tracer.span("executor.common_zone") as span:
+                zone, all_in_same_az, pods, zones = (
+                    self._get_common_zone_for_executors_application(executor)
+                )
+                span.tag("pods", pods).tag("zones", zones)
             if all_in_same_az:
                 single_az_zone = zone
                 should_schedule_into_single_az = True
@@ -1262,6 +1266,8 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         from ..ops.tensorize import _resources_to_base
 
         span.tag("candidates", len(node_names))
+        if zone is not None:
+            span.tag("zone", zone)
         with self._tracer.span("executor.snapshot"):
             snap = self._tensor_snapshot.snapshot()
         exec_row, exact = _resources_to_base(executor_resources)
@@ -1279,7 +1285,12 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             row = np.array(exec_row, dtype=np.int64)
             avail = snap.avail
             if self._is_single_az_min_frag():
-                mask, lead_keys = self._min_frag_keys(executor, snap, rows, avail, row)
+                with self._tracer.span("executor.app_attraction") as keys:
+                    mask, lead_keys, app_nodes = self._min_frag_keys(
+                        executor, snap, rows, avail, row
+                    )
+                    if keys is not tracing.NOOP_SPAN:
+                        keys.tag("appNodes", app_nodes).tag("fitting", int(mask.sum()))
             else:
                 fit_avail = avail
                 if self._strict_reference_parity:
@@ -1307,7 +1318,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         branch's flagged quirk), then the best node = lexicographic min of
         (not-hosting-this-app, capacity, priority position) among
         capacity ≥ 1 — identical to the sequential strict-improvement
-        loop."""
+        loop.  Also the number of candidate rows that host the app."""
         # capacity_against_single_dimension per dim: reserved > available
         # → 0; zero requirement → unbounded; else exact floor division
         overhead = snap.overhead
@@ -1323,8 +1334,9 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         capacity = per_dim.min(axis=1)
         not_hosting = np.ones(len(capacity), dtype=bool)
         app_nodes = self._get_nodes_with_executors_belonging_to_same_app(executor)
-        not_hosting[[rows.name_index[nm] for nm in app_nodes if nm in rows.name_index]] = False
-        return capacity >= 1, (not_hosting, capacity)
+        hosting = [rows.name_index[nm] for nm in app_nodes if nm in rows.name_index]
+        not_hosting[hosting] = False
+        return capacity >= 1, (not_hosting, capacity), len(hosting)
 
     def _reschedule_executor_with_minimal_fragmentation(
         self,
@@ -1372,8 +1384,11 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
 
     # -- single-AZ helpers ---------------------------------------------------
 
-    def _get_common_zone_for_executors_application(self, executor: Pod) -> Tuple[str, bool]:
-        """resource.go:493-515."""
+    def _get_common_zone_for_executors_application(
+        self, executor: Pod
+    ) -> Tuple[str, bool, int, int]:
+        """resource.go:493-515; also the running pods walked and the
+        zones they are in, counted."""
         app_id = executor.labels.get(L.SPARK_APP_ID_LABEL)
         if app_id is None:
             raise SchedulingFailure(FAILURE_INTERNAL, "executor has no spark app id label")
@@ -1395,13 +1410,13 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
                 )
             zones.add(zone)
         if len(zones) > 1:
-            return "", False
+            return "", False, len(running), len(zones)
         if len(zones) == 0:
             raise SchedulingFailure(
                 FAILURE_INTERNAL,
                 "application has no scheduled pods, can't make scheduling decisions based on AZ",
             )
-        return next(iter(zones)), True
+        return next(iter(zones)), True, len(running), 1
 
     def _filter_nodes_to_zone(self, nodes: List[Node], zone: str) -> List[Node]:
         """resource.go:463-478."""

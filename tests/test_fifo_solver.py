@@ -4,6 +4,7 @@ end-to-end extender behavior under binpack: tpu-batch with FIFO."""
 import random
 import time
 
+import numpy as np
 import pytest
 
 from k8s_spark_scheduler_tpu.ops import packers
@@ -306,13 +307,44 @@ def test_single_az_fused_symmetric_tie_keeps_first_zone():
     solver = TpuSingleAzFifoSolver(az_aware=False, backend="xla")
     outcome = solver.solve(metadata, order, order, earlier, [False], current)
     assert solver.last_path == "fused"
-    assert solver.last_zone_choices == {"certified": 0, "resolved": 1}
+    assert solver.last_zone_choices == {"certified": 0, "resolved": 1, "unmemoised": 0}
     expected_ok, expected = host_single_az_fifo_oracle(
         metadata, order, order, earlier, [False], current, az_aware=False
     )
     assert outcome.supported and outcome.earlier_ok == expected_ok
     assert outcome.result.driver_node == expected.driver_node
     assert outcome.result.executor_nodes == expected.executor_nodes
+
+
+@pytest.mark.parametrize("inner_policy", ["tightly-pack", "minimal-fragmentation"])
+@pytest.mark.parametrize("lane", ["xla", "pallas"])
+def test_single_az_gate_counts_the_resolved_apps_no_memo_could_answer(lane, inner_policy):
+    """The tie of identical zones is decided on the host in every request.
+    Under tightly-pack the second request finds the first one's decision
+    with the same evidence; the min-frag choice reads every node's
+    capacity and keeps no evidence, so each such app is ``unmemoised``:
+    ``fifo_gate``'s ``zoneUnmemoised`` tag (a part of ``zoneResolved``),
+    and in ``last_zone_choices`` (the registry's labels, a partition of
+    the queue) under ``unmemoised`` instead of ``resolved``."""
+    from k8s_spark_scheduler_tpu.ops.fifo_solver import TpuSingleAzFifoSolver
+    from k8s_spark_scheduler_tpu.tracing import Tracer
+
+    metadata = _two_zone_cluster(600000, 600000)
+    order = ["a0", "a1"]
+    solver = TpuSingleAzFifoSolver(backend=lane, interpret=True, inner_policy=inner_policy)
+    tracer = Tracer(capacity=4)
+    tags, choices = [], []
+    for _ in range(2):
+        with tracer.span("predicate") as root:
+            solver.solve(metadata, order, order, [_byte_app()], [False], _byte_app())
+        (gate,) = [c for c in root.children if c.name == "fifo_gate"]
+        tags.append((gate.tags["zoneResolved"], gate.tags["zoneUnmemoised"]))
+        choices.append(dict(solver.last_zone_choices))
+    unmemoised = 1 if inner_policy == "minimal-fragmentation" else 0
+    assert tags == [(1, unmemoised)] * 2
+    assert choices == [
+        {"certified": 0, "resolved": 1 - unmemoised, "unmemoised": unmemoised}
+    ] * 2
 
 
 def test_single_az_fused_near_tie_is_resolved_for_that_app_alone():
@@ -331,7 +363,7 @@ def test_single_az_fused_near_tie_is_resolved_for_that_app_alone():
     solver = TpuSingleAzFifoSolver(az_aware=False, backend="xla")
     outcome = solver.solve(metadata, order, order, earlier, [False], current)
     assert solver.last_path == "fused" and solver.last_queue_lane == "xla"
-    assert solver.last_zone_choices == {"certified": 0, "resolved": 1}
+    assert solver.last_zone_choices == {"certified": 0, "resolved": 1, "unmemoised": 0}
     expected_ok, expected = host_single_az_fifo_oracle(
         metadata, order, order, earlier, [False], current, az_aware=False
     )
@@ -720,6 +752,65 @@ def test_single_az_min_frag_fifo_solver_parity(strict):
     # the one-dispatch lane must actually serve these queues — decisions
     # matching via a silent host-lane fallback would not pin the kernel
     assert fused_served >= 30, fused_served
+
+
+@pytest.mark.parametrize("strict", [True, False])
+def test_single_az_min_frag_snapshot_choice_equals_the_zone_decode(strict, monkeypatch):
+    """The valve and the current driver's zone choice read a snapshot's
+    min-frag placements as they stand, every zone in one pass, each
+    zone's hosts ordered from their own capacities; the valve decides
+    every slot of a launch at once from their compacted view.  Every
+    such choice equals the one that decodes every zone's drain again
+    from the carry: zone, driver, counts and placement list; and the
+    compacted view answers as the snapshots whole do."""
+    from k8s_spark_scheduler_tpu.ops import fifo_solver as fs
+
+    checked = []
+    real_pick = fs._ZoneProblem.pick_from_snapshot
+    real_decisions = fs.TpuSingleAzFifoSolver._min_frag_decisions
+
+    def decoded(self, snapshot, app_idx):
+        avail, packings = self.snapshot_packings(snapshot)
+        return self._choose(avail, app_idx, packings)
+
+    def pick(self, snapshot, app_idx):
+        got, avail = real_pick(self, snapshot, app_idx)
+        want = decoded(self, snapshot, app_idx)
+        assert (got is None) == (want is None)
+        if got is not None:
+            assert (got.zone, got.driver_idx) == (want.zone, want.driver_idx)
+            assert (got.counts == want.counts).all()
+            assert got.executor_nodes == want.executor_nodes
+        checked.append(app_idx)
+        return got, avail
+
+    def decisions(zones, view, snapshots_dev, flagged, slots, n_earlier):
+        got = real_decisions(zones, view, snapshots_dev, flagged, slots, n_earlier)
+        whole = np.asarray(snapshots_dev)
+        apps = np.full(len(view), -1)
+        for u, slot in zip(flagged.tolist(), slots.tolist()):
+            if slot >= 0 and u < n_earlier:
+                assert got[slot] == zones.candidate(decoded(zones, whole[slot], u))
+                apps[slot] = u
+                checked.append(u)
+        assert (zones.placed_min_frag_zones(apps, snapshots=whole) == got).all()
+        return got
+
+    monkeypatch.setattr(fs._ZoneProblem, "pick_from_snapshot", pick)
+    monkeypatch.setattr(fs.TpuSingleAzFifoSolver, "_min_frag_decisions", staticmethod(decisions))
+    rng = random.Random(3303 + strict)
+    solver = fs.TpuSingleAzFifoSolver(
+        backend="xla", inner_policy="minimal-fragmentation", strict_reference_parity=strict
+    )
+    for _ in range(60):
+        metadata = random_cluster(rng, rng.randint(2, 16))
+        driver_order, executor_order = orders_for(metadata, rng)
+        earlier = [random_app(rng) for _ in range(rng.randint(1, 6))]
+        solver.solve(
+            metadata, driver_order, executor_order, earlier,
+            [False] * len(earlier), random_app(rng),
+        )
+    assert len(checked) >= 20, len(checked)
 
 
 def test_extender_tpu_batch_single_az_min_frag_matches_host():

@@ -16,8 +16,10 @@ from k8s_spark_scheduler_tpu.ops.batch_adapter import (
     counts_to_evenly_list,
     counts_to_tightly_list,
     min_frag_zone_decode,
+    minimal_fragmentation_order,
     minimal_fragmentation_rows,
     names_of_rows,
+    unclamped_caps,
 )
 from k8s_spark_scheduler_tpu.ops.fifo_solver import TpuFifoSolver
 from k8s_spark_scheduler_tpu.ops.packers import minimal_fragmentation_from_capacities
@@ -151,6 +153,70 @@ def test_zone_decode_counts_are_a_count_of_its_own_rows(seed, strict):
         assert counts.shape == (n,) and (counts == expected).all()
         assert (eff_counts == (0 if strict else expected)).all()
     assert decoded_any > 0
+
+
+def order_of_placements(avail, executor, driver, placements):
+    """``minimal_fragmentation_order`` given only what disjoint placements
+    show, ``placements`` as (driver row, rows emitted): their hosts, the
+    executors on each, the hosts' capacities with each driver subtracted
+    on its node; the rows in the order it reads, placement by placement."""
+    hosts, on_each, groups, here = [], [], [], []
+    for g, (d_idx, rows) in enumerate(placements):
+        h, c = np.unique(np.asarray(rows, dtype=np.int64), return_counts=True)
+        a = avail[h].astype(np.int64)
+        a[h == d_idx] -= driver
+        hosts.append(h)
+        on_each.append(c)
+        groups.append(np.full(len(h), g))
+        here.append(a)
+    hosts, on_each = np.concatenate(hosts), np.concatenate(on_each)
+    cap = unclamped_caps(np.concatenate(here), executor)
+    order = minimal_fragmentation_order(cap, hosts, np.concatenate(groups))
+    return np.repeat(hosts[order], on_each[order]).tolist()
+
+
+@pytest.mark.parametrize("edge", sorted(e for e in EDGES if EDGES[e][2]))
+def test_emission_order_from_the_hosts_alone_on_the_named_edges(edge):
+    cap, k, expected = EDGES[edge]
+    # one dimension carries the capacities; the others ask for nothing
+    avail = np.array([[c, 0, 0] for c in cap], dtype=np.int64)
+    executor, driver = np.array([1, 0, 0]), np.zeros(3, dtype=np.int64)
+    assert order_of_placements(avail, executor, driver, [(0, expected)]) == expected
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_emission_order_from_the_hosts_alone_equals_the_decode(seed):
+    """What the single-AZ valve reads of a device pass's min-frag
+    placements, one per zone: each drain's emission order, from its hosts'
+    capacities (the driver subtracted on its node), zone after zone,
+    equal to the whole decodes'."""
+    rng = np.random.default_rng(4100 + seed)
+    compared = 0
+    for _ in range(40):
+        n = int(rng.choice([3, 12, 60, 600]))
+        top = int(rng.choice([3, 8, 40]))
+        avail = rng.integers(-2, top, size=(n, 3)).astype(np.int64)
+        executor = rng.integers(0, 4, size=3).astype(np.int64)
+        driver = rng.integers(0, 3, size=3).astype(np.int64)
+        zone = rng.integers(0, 3, size=n)
+        exec_ok = rng.random(n) < 0.7
+        placements = []
+        for z in range(3):
+            members = np.flatnonzero(zone == z)
+            if members.size == 0:
+                continue
+            d_idx, k = int(rng.choice(members)), int(rng.integers(1, 33))
+            decoded = min_frag_zone_decode(
+                avail, executor, exec_ok & (zone == z), d_idx, driver, k, True
+            )
+            if decoded is not None:
+                placements.append((d_idx, decoded[0].tolist()))
+        if not placements:
+            continue
+        expected = [row for _, rows in placements for row in rows]
+        assert order_of_placements(avail, executor, driver, placements) == expected
+        compared += 1
+    assert compared > 10
 
 
 # -- the served decode ----------------------------------------------------------

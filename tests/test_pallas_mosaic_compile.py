@@ -67,6 +67,13 @@ def _served_slots(n):
     return snapshot_slots(n)
 
 
+def _compacted_slots(n):
+    """The slots of the min-frag valve, which reads them compacted."""
+    from k8s_spark_scheduler_tpu.ops.batch_solver import snapshot_slots
+
+    return snapshot_slots(n, compacted=True)
+
+
 # (registry policy, jitted entry point, arg builder, static kwargs)
 VARIANTS = [
     ("tpu-batch", pq.pallas_solve_queue, _queue_args, dict(evenly=False)),
@@ -88,7 +95,7 @@ VARIANTS = [
      _single_az_packed_args, dict(n_zones=3, az_aware=True, n_slots=_served_slots)),
     ("tpu-batch-single-az-minimal-fragmentation (served)",
      pq.pallas_solve_queue_single_az_packed, _single_az_packed_args,
-     dict(n_zones=3, minfrag=True, strict=True, n_slots=_served_slots)),
+     dict(n_zones=3, minfrag=True, strict=True, n_slots=_compacted_slots, compact=True)),
 ]
 
 

@@ -195,7 +195,7 @@ def test_a_gap_below_one_quantisation_step_is_decided_in_float64():
         # the earlier app is the near-tie; the request's own app lands where
         # the oracle puts it only if the earlier one went to a1
         outcome = solver.solve(metadata, order, order, [app], [False], app)
-        assert solver.last_zone_choices == {"certified": 0, "resolved": 1}, lane
+        assert solver.last_zone_choices == {"certified": 0, "resolved": 1, "unmemoised": 0}, lane
         assert solver.last_path == "fused" and solver.last_launches == 2, lane
         assert outcome.earlier_ok and outcome.result.has_capacity
         assert outcome.result.driver_node == second.driver_node, lane

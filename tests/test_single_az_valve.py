@@ -13,9 +13,14 @@ import pytest
 from k8s_spark_scheduler_tpu.ops.batch_solver import (
     DRIVER_BIT,
     FORCE_NONE,
+    compact_snapshots,
     solve_queue_single_az,
 )
-from k8s_spark_scheduler_tpu.ops.fifo_solver import _fused_efficiency_inputs, _ZoneProblem
+from k8s_spark_scheduler_tpu.ops.fifo_solver import (
+    TpuSingleAzFifoSolver,
+    _fused_efficiency_inputs,
+    _ZoneProblem,
+)
 from k8s_spark_scheduler_tpu.ops.pallas_queue import (
     pallas_solve_queue_single_az,
     pallas_solve_queue_single_az_packed,
@@ -25,7 +30,7 @@ from k8s_spark_scheduler_tpu.ops.tensorize import scale_problem, tensorize_apps,
 from k8s_spark_scheduler_tpu.types.resources import NodeSchedulingMetadata, Resources
 
 
-def tying_problem(seed, nodes=90, apps=20, zones=3, az_aware=False):
+def tying_problem(seed, nodes=90, apps=20, zones=3, az_aware=False, inner="tightly-pack"):
     """A cluster whose zones repeat the same few node sizes, as the
     benchmark's stratified multisets do, so that zone scores tie or
     nearly tie for several apps of the queue."""
@@ -51,7 +56,7 @@ def tying_problem(seed, nodes=90, apps=20, zones=3, az_aware=False):
     cluster = tensorize_cluster(metadata, order, order)
     problem = scale_problem(cluster, tensorize_apps(queue))
     assert problem.ok
-    zones_of = _ZoneProblem(cluster, problem, az_aware, "tightly-pack", True)
+    zones_of = _ZoneProblem(cluster, problem, az_aware, inner, True)
     score = _fused_efficiency_inputs(cluster, problem)
     assert score is not None
     return problem, zones_of, score
@@ -169,6 +174,56 @@ def test_kernel_and_twin_leave_the_same_flags_slots_and_snapshots(seed, n_slots)
         assert (pick.zone, pick.driver_idx, pick.executor_nodes) == (
             host.zone, host.driver_idx, host.executor_nodes
         )
+
+
+@pytest.mark.parametrize("seed", [3, 17])
+def test_min_frag_valve_reads_the_compacted_slots_as_it_reads_them_whole(seed):
+    """Under min-frag the launch also hands back every slot compacted
+    (the nodes its packing plane occupies, in node order, and the four
+    planes there) and the probe's slot whole; the valve decides every
+    slot of the launch at once from that view, as it does from the slots
+    whole and as the drain's decode does, and a view too narrow for a
+    slot has the slots read whole."""
+    problem, zones_of, score = tying_problem(seed, inner="minimal-fragmentation")
+    s_cpu, s_gpu, inv_m, th_m, scale_c, scale_g = score
+    valid = problem.app_valid.astype(np.int32)
+    probe = int(valid.sum()) - 1
+    valid[probe] = 2
+    node_cols = np.stack(
+        [problem.driver_rank, problem.exec_ok.astype(np.int32), zones_of.zone_vec,
+         s_cpu, s_gpu, th_m, inv_m.view(np.int32)], axis=1,
+    )
+    app_cols = np.concatenate(
+        [problem.driver, problem.executor, problem.count[:, None], valid[:, None],
+         np.full((valid.shape[0], 1), FORCE_NONE, np.int32)], axis=1,
+    )
+    columns, _, snapshots_dev, view, probe_slot = pallas_solve_queue_single_az_packed(
+        jnp.asarray(problem.avail), jnp.asarray(node_cols), jnp.asarray(app_cols),
+        jnp.asarray(np.array([scale_c, scale_g, 0], np.int32)),
+        n_zones=zones_of.n_zones, interpret=True, minfrag=True, n_slots=24, compact=True,
+    )
+    columns, whole, view = np.asarray(columns), np.asarray(snapshots_dev), np.asarray(view)
+    flagged = np.flatnonzero(columns[:, 3])
+    slots = columns[flagged, 4]
+    assert (slots >= 0).all() and flagged[-1] == probe and flagged.size >= 3
+    assert (np.asarray(probe_slot) == whole[slots[-1]]).all()
+    width = (view.shape[1] - 1) // 5
+    for slot in slots.tolist():
+        nodes = np.flatnonzero(whole[slot, 3])
+        assert view[slot, 0] == nodes.size <= width
+        assert (view[slot, 1 : 1 + nodes.size] == nodes).all()
+        planes = view[slot, 1 + width :].reshape(4, width)[:, : nodes.size]
+        assert (planes == whole[slot][:, nodes]).all()
+    apps = np.full(len(view), -1)
+    apps[slots[:-1]] = flagged[:-1]
+    got = zones_of.placed_min_frag_zones(apps, view=view)
+    assert (got == zones_of.placed_min_frag_zones(apps, snapshots=whole)).all()
+    for u, slot in zip(flagged[:-1].tolist(), slots[:-1].tolist()):
+        avail, packings = zones_of.snapshot_packings(whole[slot])
+        assert got[slot] == zones_of.candidate(zones_of._choose(avail, u, packings))
+    narrow = np.asarray(compact_snapshots(jnp.asarray(whole), width=2))
+    decide = TpuSingleAzFifoSolver._min_frag_decisions
+    assert (decide(zones_of, narrow, snapshots_dev, flagged, slots, probe) == got).all()
 
 
 # -- the marker's verdict ------------------------------------------------------

@@ -20,6 +20,7 @@ from k8s_spark_scheduler_tpu.server.http import ExtenderHTTPServer
 from k8s_spark_scheduler_tpu.testing.harness import Harness
 from k8s_spark_scheduler_tpu.tracing import Tracer
 from k8s_spark_scheduler_tpu.types import serde
+from k8s_spark_scheduler_tpu.types.resources import ZONE_LABEL
 
 NODES = [f"n{i}" for i in range(6)]
 
@@ -134,6 +135,21 @@ EXPECTED_EXECUTOR = {
         ("executor.select", [LOOKUP, FAST, ("executor.soft_bind", [])]),
         ("provenance.finish", []),
     ],
+    # single-AZ min-frag with single-AZ dynamic allocation on: the zone of the
+    # application's running pods first, and the min-frag choice's keys (what
+    # the application holds, each node's room) inside the order
+    "extra-single-az-minfrag": [
+        ("executor.select", [
+            LOOKUP,
+            ("executor.common_zone", []),
+            ("executor.fast_reschedule", [
+                ("executor.snapshot", []),
+                ("executor.order", [("executor.app_attraction", [])]),
+            ]),
+            ("executor.soft_bind", []),
+        ]),
+        ("provenance.finish", []),
+    ],
     "refused": [("executor.select", [LOOKUP]), ("provenance.finish", [])],
     "declined": [
         ("executor.select", [LOOKUP, FAST, ("executor.quantity_reschedule", []), ("executor.soft_bind", [])]),
@@ -176,11 +192,11 @@ def find(span, name):
     return None
 
 
-def served_harness(lane, binpack_algo="tpu-batch"):
+def served_harness(lane, binpack_algo="tpu-batch", **harness_options):
     """The full wiring at a small size with a short pending queue, the
     warm delta-solve lane off so that ``solve_tensor`` serves, on the
     queue lane asked for."""
-    h = Harness(binpack_algo=binpack_algo)
+    h = Harness(binpack_algo=binpack_algo, **harness_options)
     for i, name in enumerate(NODES):
         h.new_node(name, zone=f"zone{1 + i % 2}" if "single-az" in binpack_algo else "zone1")
     h.extender.delta_engine = None
@@ -325,6 +341,36 @@ def test_an_executor_filter_has_exactly_the_documented_children():
         h.close()
 
 
+@pytest.mark.parametrize(
+    "binpack_algo,same_az,expected",
+    [
+        ("tpu-batch", False, "extra"),  # fifo10k-dynalloc's install
+        ("tpu-batch-single-az-minimal-fragmentation", True, "extra-single-az-minfrag"),
+    ],
+)
+def test_an_extra_executor_names_its_zone_and_its_applications_nodes_where_the_install_asks(
+    binpack_algo, same_az, expected
+):
+    h = served_harness("native", binpack_algo, dynamic_allocation_single_az=same_az)
+    try:
+        extra = dynamic_allocation_roots(h)["app-da-exec-2"]
+        assert shape(extra) == EXPECTED_EXECUTOR[expected]
+        fast = find(extra, "executor.fast_reschedule")
+        if not same_az:
+            assert not {"executor.common_zone", "executor.app_attraction"} & set(names(extra))
+            assert own(fast.tags) == {"candidates": len(NODES), "hit": True}
+            return
+        # the driver and the executor at min run, in one zone: the extra is held to it
+        assert own(find(extra, "executor.common_zone").tags) == {"pods": 2, "zones": 1}
+        driver_node = h.get_resource_reservation("app-da").spec.reservations["driver"].node
+        zone = h.api.get("Node", "default", driver_node).labels[ZONE_LABEL]
+        assert own(fast.tags) == {"candidates": len(NODES), "hit": True, "zone": zone}
+        keys = own(find(extra, "executor.app_attraction").tags)
+        assert keys["appNodes"] == 1 and 1 <= keys["fitting"] <= len(NODES)
+    finally:
+        h.close()
+
+
 class _RescheduleLaneDemoted:
     """A lane-health table in which the mirror's executor lane is demoted."""
 
@@ -401,6 +447,7 @@ def test_single_az_driver_filter_has_exactly_the_documented_children(lane):
             assert "launches" not in gate.tags
         else:
             assert gate.tags["launches"] == 1 and gate.tags["zoneResolved"] >= 0
+            assert gate.tags["zoneUnmemoised"] == 0  # the tightly-pack choice keeps its evidence
             resolve = find(gate, "fifo_gate.zone_resolve")
             assert type(resolve) is tracing.AggregateSpan and resolve.tags["count"] == 1
             assert h.server.metrics.get_counter(
