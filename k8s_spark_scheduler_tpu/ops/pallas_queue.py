@@ -206,23 +206,43 @@ def _mf_caps(cpu, mem, gpu, ex, exec_ok):
     return jnp.where(exec_ok, cap, 0)
 
 
+def _mf_stop_class(dd, dc, k):
+    """The drain's stop class v* = max{v : Σ_{dd ≥ v} min(dd, k) ≥ k}
+    (1 when no class qualifies), searched only where it can lie.  With
+    m = max(dd): if m ≥ k the node of capacity m alone contributes k and
+    no class lies above it, so v* = m with no probe; otherwise v* ≤ m and
+    the binary search over [1, m] takes at most ⌈log₂ m⌉ probes.  The
+    same answer as batch_solver.min_frag_counts' 31 probes over the whole
+    int32 domain whenever k > 0 (k = 0 zeroes the placement either way).
+    Returns (vstar, probes)."""
+    m = jnp.max(dd)
+    hi = jnp.maximum(m, 1)
+    lo = jnp.where(m >= k, hi, 1)
+
+    def cond(c):
+        lo, hi, _ = c
+        return lo < hi
+
+    def body(c):
+        lo, hi, probes = c
+        mid = lo + (hi - lo + 1) // 2
+        good = jnp.sum(jnp.where(dd >= mid, dc, 0)) >= k
+        return jnp.where(good, mid, lo), jnp.where(good, hi, mid - 1), probes + 1
+
+    vstar, _, probes = lax.while_loop(cond, body, (lo, hi, jnp.int32(0)))
+    return vstar, probes
+
+
 def _mf_run(d, sub, k, node_ids):
     """One _internal_minimal_fragmentation pass over eligibility mask
     `sub` (batch_solver.min_frag_counts.run on [R,128] planes): the
-    drain-stop value class via 31 masked-sum probes, then the drained
-    mask and the final partial placement.  Returns (ok, drained,
+    drain-stop value class (_mf_stop_class), then the drained mask and
+    the final partial placement.  Returns (ok, drained,
     partial_flat_idx, kstar)."""
     dd = jnp.where(sub, d, 0)
     dc = jnp.minimum(dd, k)
     ok = (jnp.sum(dc) >= k) & (k > 0)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = lo + (hi - lo + 1) // 2
-        good = jnp.sum(jnp.where(dd >= mid, dc, 0)) >= k
-        return (jnp.where(good, mid, lo), jnp.where(good, hi, mid - 1))
-
-    vstar, _ = lax.fori_loop(0, 31, body, (jnp.int32(1), jnp.int32(MF_SENT)))
+    vstar, _ = _mf_stop_class(dd, dc, k)
     s = jnp.sum(jnp.where(dd > vstar, dd, 0))  # drained classes, < k
     r = k - s
     tstar = jnp.maximum(r - 1, 0) // vstar
