@@ -32,8 +32,10 @@ class Informer:
     """A shared informer for one kind."""
 
     # bound on remembered last-seen resourceVersions for departed objects
-    # (guards against a late stale MODIFIED resurrecting a deleted object)
-    _TOMBSTONE_LIMIT = 16384
+    # (guards against a late stale MODIFIED resurrecting a deleted object),
+    # beside the one kept for each object in the store: a store of 100,000
+    # bound pods prunes once per this many deletions, not on every event
+    TOMBSTONES_KEPT = 16384
     # bound on per-(label, value) selector revision stamps (unbounded-
     # value labels like spark-app-id would otherwise leak one entry per
     # application for the life of the process)
@@ -96,7 +98,7 @@ class Informer:
                 return
             self._last_rv[key] = rv
             self.revision += 1
-            if len(self._last_rv) > self._TOMBSTONE_LIMIT:
+            if len(self._last_rv) > len(self._store) + self.TOMBSTONES_KEPT:
                 # prune entries for objects we no longer mirror
                 self._last_rv = {
                     k: v for k, v in self._last_rv.items() if k in self._store

@@ -68,7 +68,7 @@ from ..analysis.guarded import guarded_by
 from ..metrics import names as mnames
 from ..tracing import spans as tracing
 from ..tracing.profiling import default_profiler
-from .fifo_solver import FifoOutcome
+from .fifo_solver import FifoOutcome, gate_overhead_rows
 from .tensorize import INT32_SAFE, ScaledProblem
 
 logger = logging.getLogger(__name__)
@@ -407,7 +407,8 @@ class DeltaSolveEngine:
         solver.last_queue_lane = "native-session"
         with tracing.child_span(
             "fifo_gate",
-            {"lane": "native-session", "earlierApps": n_earlier},
+            {"lane": "native-session", "earlierApps": n_earlier,
+             "overheadRows": gate_overhead_rows()},
             cpu=True,
         ) as gate_span:
             with default_profiler.profile(

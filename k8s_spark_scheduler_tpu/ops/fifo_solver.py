@@ -17,6 +17,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from .. import compat
+from ..state.tensor_snapshot import OVERHEAD_SPAN
 from ..tracing import spans as tracing
 from ..tracing.profiling import PHASE_REQUEST, default_profiler
 from ..types.resources import NodeGroupSchedulingMetadata
@@ -327,15 +328,24 @@ def _gate_tags(cluster, problem, n_earlier: int) -> dict:
     """What a ``fifo_gate`` span says of its request's shape: the nodes
     the driver's affinity admitted and the drivers ahead of it in its
     instance group's queue, the bucket each was padded to (the compiled
-    program's shape), and the programs request threads had compiled
-    before it (0 on a server whose warm-up covered its groups)."""
+    program's shape), the programs request threads had compiled
+    before it (0 on a server whose warm-up covered its groups), and the
+    pod rows the request's overhead refreshes walked (0 where its
+    snapshot found the overhead current)."""
     return {
         "earlierApps": n_earlier,
         "eligibleNodes": cluster.n_nodes,
         "nodeBucket": problem.avail.shape[0],
         "appBucket": problem.count.shape[0],
         "requestCompiles": default_profiler.compiles(PHASE_REQUEST),
+        "overheadRows": gate_overhead_rows(),
     }
+
+
+def gate_overhead_rows() -> int:
+    """``fifo_gate``'s ``overheadRows``: the active pod rows walked by the
+    ``mirror.overhead`` refreshes of the request so far."""
+    return tracing.trace_tag_total(OVERHEAD_SPAN, "rows")
 
 
 def _earlier_ok(gate_span, feasible, earlier_skip_allowed) -> bool:

@@ -32,6 +32,7 @@ from ..metrics import names as mnames
 from ..metrics.registry import MetricsRegistry, default_registry
 from ..ops import capacity as cap
 from ..ops.efficiency import compute_avg_packing_efficiency
+from ..ops.fifo_solver import gate_overhead_rows
 from ..ops.nodesort import NodeSorter
 from ..ops.registry import SINGLE_AZ_MINIMAL_FRAGMENTATION, Binpacker, check_kernel_fault
 from ..resilience import deadline as req_deadline
@@ -913,7 +914,9 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         """resource.go:224-262: binpack every earlier driver and subtract
         its usage before considering this one."""
         with self._tracer.span(
-            "fifo_gate", {"lane": "host", "earlierApps": len(drivers)}, cpu=True
+            "fifo_gate",
+            {"lane": "host", "earlierApps": len(drivers), "overheadRows": gate_overhead_rows()},
+            cpu=True,
         ) as sp:
             for driver in drivers:
                 try:

@@ -103,8 +103,9 @@ class UnschedulablePodMarker:
         One root span ``unschedulable.scan`` per scan; its verdict
         batches, metadata builds and condition writes are three
         aggregate children (``scan.solve``, one phase per signature;
-        ``scan.metadata``; ``scan.mark``), the walk and the yields
-        between pods stay in the root's self time."""
+        ``scan.metadata``, which holds ``scan.overhead``, the walk over
+        the bound pods; ``scan.mark``), the walk and the yields between
+        pods stay in the root's self time."""
         span = (
             self._tracer.span("unschedulable.scan")
             if self._tracer is not None
@@ -191,13 +192,17 @@ class UnschedulablePodMarker:
         """(node names, zero-usage metadata, its ClusterTensor or None,
         the tensor solver or None) of the nodes ``driver``'s affinity
         signature admits."""
-        with tracing.aggregate_span("scan.metadata"):
+        with tracing.aggregate_span("scan.metadata") as metadata_phase:
             nodes = self._node_informer.list_with_predicate(
                 lambda n: driver.matches_node(n)
             )
             node_names = [n.name for n in nodes]
             zero_usage = {n.name: Resources.zero() for n in nodes}
-            overhead = self._overhead.get_non_schedulable_overhead(nodes)
+            # the walk over every bound pod of the signature's nodes: an
+            # aggregate child of the metadata's own, so the scan's other
+            # readings keep their times
+            with metadata_phase.aggregate("scan.overhead"):
+                overhead = self._overhead.get_non_schedulable_overhead(nodes)
             # chunked: one unbroken 10k-node Quantity build holds the
             # GIL for ~0.5-1s and was the single biggest tail spike
             # live Filters saw from this janitor

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from .. import tracing
 from ..analysis import racecheck
 from ..ops.tensorize import _resources_to_base
 from ..scheduler import labels as L
@@ -56,6 +57,10 @@ from .store import (
 )
 
 _GROW = 256
+
+# the span of one overhead refresh, tagged ``rows``: the active pod rows
+# it walked; a ``fifo_gate`` carries its request's sum as ``overheadRows``
+OVERHEAD_SPAN = "mirror.overhead"
 
 
 @dataclass
@@ -451,44 +456,49 @@ class TensorSnapshotCache:
     # -- snapshot ------------------------------------------------------------
 
     def _recompute_overhead(self) -> None:
-        n_nodes = len(self._node_names)
-        overhead = np.zeros((n_nodes, 3), dtype=np.int64)
+        """Every active pod row walked again: one ``mirror.overhead``
+        span under whatever took the snapshot (a Filter's
+        ``fast_path.snapshot`` or ``executor.snapshot``, the capacity
+        sampler's ``capacity.sample``, the reconcile)."""
         active = np.flatnonzero(self._pod_active)
-        if len(active):
-            # reserved pods don't count (overhead.go:139-141; soft
-            # reservations match by bare pod name like the reference)
-            mask = np.fromiter(
-                (
-                    (key := self._pod_key_of_slot.get(int(slot), ("", ""))) not in self._reserved_pods
-                    and key[1] not in self._soft_reserved_names
-                    for slot in active
-                ),
-                dtype=bool,
-                count=len(active),
-            )
-            counted = active[mask]
-            node_idx = np.fromiter(
-                (
-                    self._node_slot.get(self._pod_node_name[int(slot)], -1)
-                    for slot in counted
-                ),
-                dtype=np.int64,
-                count=len(counted),
-            )
-            ok = node_idx >= 0
-            np.add.at(overhead, node_idx[ok], self._pod_requests[counted][ok])
-        old = self._node_overhead
-        if len(old) < n_nodes:
-            pad = np.zeros((n_nodes - len(old), 3), np.int64)
-            old = np.vstack([old, pad]) if len(old) else pad
-        changed = np.flatnonzero((old[:n_nodes] != overhead).any(axis=1))
-        self._node_overhead = overhead
-        self._pods_dirty = False
-        # overhead shifted under some nodes: bring their class-index rows
-        # up to date (class KEY never depends on overhead, so this only
-        # refreshes content hashes — class_rev is untouched)
-        for slot in changed:
-            self._note_class(int(slot))
+        with tracing.child_span(OVERHEAD_SPAN, {"rows": len(active)}):
+            n_nodes = len(self._node_names)
+            overhead = np.zeros((n_nodes, 3), dtype=np.int64)
+            if len(active):
+                # reserved pods don't count (overhead.go:139-141; soft
+                # reservations match by bare pod name like the reference)
+                mask = np.fromiter(
+                    (
+                        (key := self._pod_key_of_slot.get(int(slot), ("", ""))) not in self._reserved_pods
+                        and key[1] not in self._soft_reserved_names
+                        for slot in active
+                    ),
+                    dtype=bool,
+                    count=len(active),
+                )
+                counted = active[mask]
+                node_idx = np.fromiter(
+                    (
+                        self._node_slot.get(self._pod_node_name[int(slot)], -1)
+                        for slot in counted
+                    ),
+                    dtype=np.int64,
+                    count=len(counted),
+                )
+                ok = node_idx >= 0
+                np.add.at(overhead, node_idx[ok], self._pod_requests[counted][ok])
+            old = self._node_overhead
+            if len(old) < n_nodes:
+                pad = np.zeros((n_nodes - len(old), 3), np.int64)
+                old = np.vstack([old, pad]) if len(old) else pad
+            changed = np.flatnonzero((old[:n_nodes] != overhead).any(axis=1))
+            self._node_overhead = overhead
+            self._pods_dirty = False
+            # overhead shifted under some nodes: bring their class-index rows
+            # up to date (class KEY never depends on overhead, so this only
+            # refreshes content hashes — class_rev is untouched)
+            for slot in changed:
+                self._note_class(int(slot))
 
     def _recompute_name_ranks(self) -> None:
         live = [i for i, name in enumerate(self._node_names) if name is not None]

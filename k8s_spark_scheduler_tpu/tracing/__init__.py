@@ -43,4 +43,5 @@ from .spans import (  # noqa: F401
     default_tracer,
     install_gc_hook,
     publish_gc_pauses,
+    trace_tag_total,
 )

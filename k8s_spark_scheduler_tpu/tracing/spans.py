@@ -275,6 +275,26 @@ def aggregate_span(name: str):
     return parent.aggregate(name)
 
 
+def trace_tag_total(name: str, tag: str):
+    """The sum of the numeric tag ``tag`` over the spans named ``name``
+    in the active trace so far, 0 outside a trace: what a request's
+    earlier phases did, for a later span of the same request to say
+    (``fifo_gate``'s ``overheadRows``: the pod rows the request's
+    ``mirror.overhead`` refreshes walked)."""
+    span = _CURRENT.get()
+    if span is None:
+        return 0
+    while span.parent is not None:
+        span = span.parent
+    total, todo = 0, [span]
+    while todo:
+        span = todo.pop()
+        if span.name == name:
+            total += span.tags.get(tag, 0)
+        todo.extend(span.children)
+    return total
+
+
 class Span:
     """One timed phase.  Children attach at creation; duration lands at
     context-manager exit.  Not a dataclass: __slots__ + plain attribute
@@ -465,6 +485,9 @@ class _NoopSpan:
     tags: Dict[str, Any] = {}
 
     def tag(self, key: str, value: Any) -> "_NoopSpan":
+        return self
+
+    def aggregate(self, name: str) -> "_NoopSpan":
         return self
 
     def __enter__(self) -> "_NoopSpan":

@@ -171,8 +171,15 @@ def own(tags):
     return {k: v for k, v in tags.items() if k not in RUNTIME_TAGS}
 
 
+# the overhead refresh lies under whichever snapshot first follows a
+# change to the pod table or the reservations: a request's, or the
+# capacity sampler's on its own thread; the trees are compared without
+# it, and ``test_an_overhead_refresh_*`` pins where it lies
+OVERHEAD_SPAN = "mirror.overhead"
+
+
 def shape(span):
-    return [(c.name, shape(c)) for c in span.children]
+    return [(c.name, shape(c)) for c in span.children if c.name != OVERHEAD_SPAN]
 
 
 def names(span):
@@ -522,6 +529,36 @@ def test_aggregate_child_sums_its_phases_and_keeps_the_parents_self_time(monkeyp
 
 def test_aggregate_phase_outside_any_trace_is_the_noop():
     assert tracing.aggregate_span("scan.solve") is tracing.NOOP_SPAN
+    # and so is a phase's own aggregate child (``scan.overhead``)
+    with tracing.aggregate_span("scan.metadata") as phase:
+        assert phase.aggregate("scan.overhead") is tracing.NOOP_SPAN
+
+
+def test_an_aggregate_child_of_a_phase_sums_its_own_phases_inside_the_parents(monkeypatch):
+    """``scan.overhead`` inside ``scan.metadata``: the inner aggregate's
+    time is part of the outer's, the root's self time and the outer's
+    count are what they were without it."""
+    from k8s_spark_scheduler_tpu import timesource
+
+    clock = [0.0]
+    monkeypatch.setattr(timesource, "perf", lambda: clock[0])
+    tracer = Tracer(capacity=4)
+    with tracer.span("unschedulable.scan") as root:
+        for _ in range(2):
+            clock[0] += 1.0
+            with tracing.aggregate_span("scan.metadata") as phase:
+                clock[0] += 0.5
+                with phase.aggregate("scan.overhead"):
+                    assert tracing.current_span() is None
+                    clock[0] += 0.25
+    assert shape(root) == [("scan.metadata", [("scan.overhead", [])])]
+    (metadata,) = root.children
+    (walk,) = metadata.children
+    assert metadata.duration == pytest.approx(1.5) and metadata.tags["count"] == 2
+    assert walk.duration == pytest.approx(0.5) and walk.tags["count"] == 2
+    assert root.duration - metadata.duration == pytest.approx(2.0)
+    as_dict = tracer.traces()[0]["root"]["children"][0]["children"][0]
+    assert (as_dict["name"], as_dict["durationMs"], own(as_dict["tags"])) == ("scan.overhead", 500.0, {"count": 2})
 
 
 # -- (c) the marker's scan -----------------------------------------------------
@@ -567,7 +604,14 @@ def test_one_scan_is_one_trace_with_three_aggregate_children_and_two_metrics(lan
         crossings = {k: v for k, v in own(children["scan.solve"].tags).items() if k != "count"}
         # the node block [64, 6], the app block [1024, 8] up, [1024] down, int32
         assert crossings == ({"arrays": 3, "bytes": 4 * (64 * 6 + 1024 * 9)} if lane == "xla" else {})
-        assert all(type(c) is tracing.AggregateSpan and not c.children for c in root.children)
+        assert all(type(c) is tracing.AggregateSpan for c in root.children)
+        # the walk over the bound pods is the metadata's own aggregate child
+        assert shape(root) == [
+            ("scan.metadata", [("scan.overhead", [])]), ("scan.solve", []), ("scan.mark", []),
+        ]
+        walk = children["scan.metadata"].children[0]
+        assert type(walk) is tracing.AggregateSpan and own(walk.tags) == {"count": 1}
+        assert walk.duration <= children["scan.metadata"].duration
         assert sum(c.duration for c in root.children) <= root.duration
         metrics = h.server.metrics
         assert metrics.get_counter(mnames.UNSCHEDULABLE_SOLVE_COUNT, {"lane": "tensor"}) == 4
@@ -761,10 +805,109 @@ def test_decompose_on_the_new_tree_sums_to_the_root_and_leaves_nothing_new_in_ot
     assert record["segments"]["other"] == pytest.approx(max(root_self - waits, 0.0), abs=0.01)
     assert set(record["segments"]) == set(criticalpath.SEGMENT_NAMES)
     assert all(c.name in criticalpath.SPAN_SEGMENTS for c in root.children)
+    # the overhead refresh is its snapshot's: it inherits ``assemble``
     assert all(
-        name in criticalpath.SPAN_SEGMENTS or name.startswith("kernel:") or name == "device.dispatch"
+        name in criticalpath.SPAN_SEGMENTS or name.startswith("kernel:")
+        or name in ("device.dispatch", OVERHEAD_SPAN)
         for name in names(find(root, "predicate"))
     )
+
+
+# -- (g) the mirror's overhead refresh and the gate's ``overheadRows`` --------------
+
+
+def parents_of(span, name, parent=None):
+    """The names of the spans whose child ``name`` is, one per occurrence."""
+    found = [parent.name] if span.name == name and parent is not None else []
+    for child in span.children:
+        found.extend(parents_of(child, name, span))
+    return found
+
+
+def quiet_driver_root(h, stale):
+    """A granted driver's ``predicate`` root, asked once the cluster is
+    quiet (write-backs drained, the capacity sampler stopped, the mirror
+    snapshotted): ``stale``, a bound pod no reservation holds appears
+    just before the request, so the request's snapshot finds the
+    overhead stale; otherwise nothing changes and it finds it current.
+    Returns (root, the mirror's active pod rows at the request)."""
+    from k8s_spark_scheduler_tpu.types.objects import Container, ObjectMeta, Pod, PodPhase
+    from k8s_spark_scheduler_tpu.types.resources import Resources
+
+    h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
+    h.server.capacity.stop()
+    assert h.wait_quiesced()
+    mirror = h.server.tensor_snapshot
+    mirror.snapshot()
+    if stale:
+        h.create_pod(Pod(
+            meta=ObjectMeta(name="daemon-n0", namespace="kube-system"),
+            scheduler_name="default-scheduler", node_name=NODES[0],
+            containers=[Container("agent", Resources.of("100m", "128Mi"))], phase=PodPhase.RUNNING,
+        ))
+    rows = int(mirror._pod_active.sum())
+    roots = roots_of(h)
+    h.assert_success(h.schedule(h.static_allocation_spark_pods("app-new", 2)[0], NODES))
+    (root,) = [r for r in roots if r.name == "predicate"]
+    return root, rows
+
+
+@pytest.mark.parametrize("lane", ["native", "xla"])
+def test_an_overhead_refresh_is_a_child_of_the_snapshot_that_ran_it_and_the_gate_counts_its_rows(lane):
+    h = served_harness(lane)
+    try:
+        root, rows = quiet_driver_root(h, stale=True)
+        assert parents_of(root, OVERHEAD_SPAN) == ["fast_path.snapshot"]
+        refresh = find(root, OVERHEAD_SPAN)
+        assert own(refresh.tags) == {"rows": rows} and rows >= 2  # the granted driver's pod and the daemon's
+        assert find(root, "fifo_gate").tags["overheadRows"] == rows
+        assert shape(root) == EXPECTED["native" if lane == "native" else "device"]
+    finally:
+        h.close()
+
+
+@pytest.mark.parametrize("lane", ["native", "xla"])
+def test_a_snapshot_that_finds_the_overhead_current_opens_no_refresh_and_the_gate_reads_zero(lane):
+    h = served_harness(lane)
+    try:
+        root, _ = quiet_driver_root(h, stale=False)
+        assert OVERHEAD_SPAN not in names(root)
+        assert find(root, "fifo_gate").tags["overheadRows"] == 0
+    finally:
+        h.close()
+
+
+def test_an_extra_executors_refresh_lies_under_its_own_snapshot_and_nowhere_else():
+    """A soft reservation makes the overhead stale: the next extra
+    executor's ``executor.snapshot`` (or the sampler's) refreshes it;
+    no refresh is a child of ``predicate``, whose self time a metric reads."""
+    h = served_harness("native")
+    try:
+        by_pod = dynamic_allocation_roots(h)
+        for pod, root in by_pod.items():
+            assert set(parents_of(root, OVERHEAD_SPAN)) <= {"fast_path.snapshot", "executor.snapshot"}, pod
+            for refresh in (s for s in [find(root, OVERHEAD_SPAN)] if s is not None):
+                assert set(own(refresh.tags)) == {"rows"}
+    finally:
+        h.close()
+
+
+def test_the_capacity_samplers_refresh_is_a_child_of_its_sample():
+    h = served_harness("native")
+    try:
+        h.assert_success(h.schedule(h.static_allocation_spark_pods("app-first", 1)[0], NODES))
+        h.server.capacity.stop()
+        assert h.wait_quiesced()
+        samples = []
+        h.server.tracer.add_observer(lambda root: root.name == "capacity.sample" and samples.append(root))
+        h.delete_pod(h.static_allocation_spark_pods("app-first", 1)[0])  # the reservation goes with it
+        assert h.wait_for_api(lambda: h.get_resource_reservation("app-first") is None)
+        h.server.capacity.sample_now(tracer=h.server.tracer)
+        (sample,) = samples
+        assert [c.name for c in sample.children] == [OVERHEAD_SPAN]
+        assert own(sample.children[0].tags)["rows"] == int(h.server.tensor_snapshot._pod_active.sum())
+    finally:
+        h.close()
 
 
 # -- (f) what a span says of the runtime: cpuMs, gcMs / gcRuns, bg -------------------

@@ -357,3 +357,31 @@ def test_informer_label_index():
     }
     # combined selectors still filter correctly through the index
     assert inf.list(label_selector={"spark-app-id": "app-1", "other": "x"}) == []
+
+
+def test_informer_remembers_departed_objects_beside_its_store_and_prunes_only_past_the_bound():
+    """A store of more live objects than ``TOMBSTONES_KEPT`` (a busy
+    cluster's bound pods) keeps every live object's resourceVersion and
+    prunes only once the departed ones pass the bound: an event costs
+    O(1), not a walk over the store; a stale event of a departed object
+    is still dropped."""
+    from k8s_spark_scheduler_tpu.kube.informer import MODIFIED, Informer
+    from k8s_spark_scheduler_tpu.types.objects import ObjectMeta, Pod
+
+    api = APIServer()
+    inf = Informer(api, "Pod")
+    inf.TOMBSTONES_KEPT = 4
+    inf.start()
+    for i in range(20):
+        api.create(Pod(meta=ObjectMeta(name=f"p{i}")))
+    assert len(inf._last_rv) == 20 and len(inf.list()) == 20
+    stale = api.get("Pod", "default", "p0")
+    for i in range(4):
+        api.delete("Pod", "default", f"p{i}")
+    assert len(inf._last_rv) == 20  # 16 live, 4 departed: within the bound
+    inf._on_event(MODIFIED, stale)  # a late update of a deleted pod
+    assert inf.get("default", "p0") is None
+    for i in range(4, 10):
+        api.delete("Pod", "default", f"p{i}")
+    assert len(inf.list()) == 10
+    assert len(inf._last_rv) <= 10 + inf.TOMBSTONES_KEPT + 1
