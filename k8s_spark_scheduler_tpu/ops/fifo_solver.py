@@ -330,8 +330,8 @@ def _gate_tags(cluster, problem, n_earlier: int) -> dict:
     instance group's queue, the bucket each was padded to (the compiled
     program's shape), the programs request threads had compiled
     before it (0 on a server whose warm-up covered its groups), and the
-    pod rows the request's overhead refreshes walked (0 where its
-    snapshot found the overhead current)."""
+    pod slots the request's overhead folds took (0 where its snapshot
+    found no slot marked)."""
     return {
         "earlierApps": n_earlier,
         "eligibleNodes": cluster.n_nodes,
@@ -343,7 +343,7 @@ def _gate_tags(cluster, problem, n_earlier: int) -> dict:
 
 
 def gate_overhead_rows() -> int:
-    """``fifo_gate``'s ``overheadRows``: the active pod rows walked by the
+    """``fifo_gate``'s ``overheadRows``: the pod slots folded by the
     ``mirror.overhead`` refreshes of the request so far."""
     return tracing.trace_tag_total(OVERHEAD_SPAN, "rows")
 

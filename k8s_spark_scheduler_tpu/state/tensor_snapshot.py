@@ -18,8 +18,9 @@ This cache keeps the same state as O(delta)-updated int64 arrays:
   writer of both, so the mirror is exact);
 - overhead: a pod table (requests, node, scheduler flag) from pod
   informer events plus a reserved-pod-name set maintained from the
-  same reservation observers; per-request overhead is one vectorized
-  segment-sum.
+  same reservation observers; each event marks the pod slots whose
+  counted state it may change, and a snapshot folds only those slots
+  into the per-node sums.
 
 Exactness: every quantity is converted to base units once, at event
 time; anything not exactly representable poisons the affected row and
@@ -58,8 +59,8 @@ from .store import (
 
 _GROW = 256
 
-# the span of one overhead refresh, tagged ``rows``: the active pod rows
-# it walked; a ``fifo_gate`` carries its request's sum as ``overheadRows``
+# the span of one overhead fold, tagged ``rows``: the pod slots it
+# folded; a ``fifo_gate`` carries its request's sum as ``overheadRows``
 OVERHEAD_SPAN = "mirror.overhead"
 
 
@@ -179,12 +180,23 @@ class TensorSnapshotCache:
         # pod table (for overhead)
         self._pod_slot: Dict[Tuple[str, str], int] = {}
         self._pod_requests = np.zeros((0, 3), dtype=np.int64)
-        # node NAME per pod slot (resolved to a node slot at recompute
-        # time: slots are reused on node churn and pods can be observed
-        # before their node, so a stored slot index would go stale)
+        # node NAME per pod slot (resolved to a node slot at fold time:
+        # slots are reused on node churn and pods can be observed before
+        # their node, so a stored slot index would go stale)
         self._pod_node_name: List[str] = []
         self._pod_active = np.zeros(0, dtype=bool)
         self._free_pods: List[int] = []
+        # what each pod slot adds to _node_overhead now: the node slot
+        # (-1: nothing) and the row; a node delete drops the records on
+        # its slot at once, so a record never outlives its node
+        self._pod_counted_on = np.zeros(0, dtype=np.int64)
+        self._pod_counted_row = np.zeros((0, 3), dtype=np.int64)
+        # pod slots whose counted state may have changed since the last
+        # fold, and the indexes that find them: node name → bound slots
+        # (a node add), bare pod name → slots (a soft reservation)
+        self._dirty_pods: Set[int] = set()
+        self._pods_on_node: Dict[str, Set[int]] = {}
+        self._pods_named: Dict[str, Set[int]] = {}
         # pods currently holding a reservation: (ns, name) from RR
         # status.pods; soft reservations track bare pod names (the
         # reference's soft lookup ignores namespace,
@@ -192,7 +204,6 @@ class TensorSnapshotCache:
         self._reserved_pods: Set[Tuple[str, str]] = set()
         self._soft_reserved_names: Dict[str, int] = {}
         self._pod_key_of_slot: Dict[int, Tuple[str, str]] = {}
-        self._pods_dirty = False
 
         node_informer.add_event_handler(
             on_add=self._on_node, on_update=lambda o, n: self._on_node(n),
@@ -259,6 +270,7 @@ class TensorSnapshotCache:
                 pending = self._orphan_usage.pop(node.name, None)
                 self._usage[slot] = pending if pending is not None else 0
                 self._res_count[slot] = self._orphan_res_count.pop(node.name, 0)
+                self._dirty_pods.update(self._pods_on_node.get(node.name, ()))
             row, exact = _resources_to_base(node.allocatable)
             if not exact:
                 self._exact = False
@@ -287,19 +299,25 @@ class TensorSnapshotCache:
             self._alloc[slot] = 0
             self._usage[slot] = 0
             self._res_count[slot] = 0
+            # the slot may go to another node before the next fold: the
+            # records on it leave with its overhead, and each such pod
+            # now counts nowhere, as its node is unknown (a pod that
+            # moved off it since its last fold is dirty already)
             self._node_overhead[slot] = 0
+            dropped = np.flatnonzero(self._pod_counted_on == slot)
+            self._pod_counted_on[dropped] = -1
+            self._pod_counted_row[dropped] = 0
             self._ready[slot] = False
             self._labels[slot] = {}
             self._free_nodes.append(slot)
-            self._pods_dirty = True
             self.classes.drop_node(slot)
 
     def _note_class(self, slot: int,
                     labels: Optional[Dict[str, str]] = None) -> None:
         """Mirror one slot's full row into the equivalence-class index
-        (O(1); callers hold ``self._lock``).  Overhead is recomputed
-        lazily at snapshot() — until then the index sees the previous
-        overhead row, and _recompute_overhead re-notes whatever changed,
+        (O(1); callers hold ``self._lock``).  Overhead is folded lazily
+        at snapshot() — until then the index sees the previous overhead
+        row, and _fold_overhead re-notes whatever changed,
         so by the time snapshot() stamps class_digest the index is
         consistent with the rows it hands out."""
         name = self._node_names[slot]
@@ -365,6 +383,7 @@ class TensorSnapshotCache:
                     self._apply_usage(node, row, -1)
                 for pod_name in old.status.pods.values():
                     self._reserved_pods.discard((old.namespace, pod_name))
+                    self._mark_pod((old.namespace, pod_name))
             if new is not None:
                 for reservation in new.spec.reservations.values():
                     _, e = _resources_to_base(reservation.resources_value())
@@ -374,7 +393,7 @@ class TensorSnapshotCache:
                     self._apply_usage(node, row, +1)
                 for pod_name in new.status.pods.values():
                     self._reserved_pods.add((new.namespace, pod_name))
-            self._pods_dirty = True
+                    self._mark_pod((new.namespace, pod_name))
             ref = new if new is not None else old
             self.feed.publish(
                 DELTA_RESERVATION, ref.name if ref is not None else None
@@ -391,7 +410,7 @@ class TensorSnapshotCache:
                 self._soft_reserved_names.pop(pod_name, None)
             else:
                 self._soft_reserved_names[pod_name] = count
-            self._pods_dirty = True
+            self._dirty_pods.update(self._pods_named.get(pod_name, ()))
             self.feed.publish(DELTA_SOFT_RESERVATION, pod_name)
 
     # -- pod table (overhead) ------------------------------------------------
@@ -402,8 +421,33 @@ class TensorSnapshotCache:
         self._pod_requests = np.vstack([self._pod_requests, np.zeros((extra, 3), np.int64)])
         self._pod_node_name.extend([""] * extra)
         self._pod_active = np.concatenate([self._pod_active, np.zeros(extra, bool)])
+        self._pod_counted_on = np.concatenate(
+            [self._pod_counted_on, np.full(extra, -1, np.int64)]
+        )
+        self._pod_counted_row = np.vstack(
+            [self._pod_counted_row, np.zeros((extra, 3), np.int64)]
+        )
         self._free_pods.extend(range(n + extra - 1, n - 1, -1))
         return self._free_pods.pop()
+
+    def _mark_pod(self, key: Tuple[str, str]) -> None:
+        slot = self._pod_slot.get(key)
+        if slot is not None:
+            self._dirty_pods.add(slot)
+
+    def _bind_pod(self, slot: int, node_name: str) -> None:
+        """Point one pod slot at a node name, keeping _pods_on_node."""
+        old = self._pod_node_name[slot]
+        if old == node_name:
+            return
+        if old:
+            on_old = self._pods_on_node[old]
+            on_old.discard(slot)
+            if not on_old:
+                del self._pods_on_node[old]
+        if node_name:
+            self._pods_on_node.setdefault(node_name, set()).add(slot)
+        self._pod_node_name[slot] = node_name
 
     def _on_pod(self, pod: Pod) -> None:
         with self._lock:
@@ -413,7 +457,7 @@ class TensorSnapshotCache:
             if pod.node_name == "":
                 if slot is not None:
                     self._pod_active[slot] = False
-                    self._pods_dirty = True
+                    self._dirty_pods.add(slot)
                     self.feed.publish(DELTA_POD, pod.name)
                 # a nodeless pod the mirror never tracked changes no
                 # state: queued-driver heartbeats must not churn the
@@ -423,11 +467,12 @@ class TensorSnapshotCache:
                 slot = self._free_pods.pop() if self._free_pods else self._grow_pods()
                 self._pod_slot[key] = slot
                 self._pod_key_of_slot[slot] = key
+                self._pods_named.setdefault(pod.name, set()).add(slot)
             row, exact = _resources_to_base(pod_to_resources(pod))
             if not exact:
                 self._exact = False
             self._pod_requests[slot] = row
-            self._pod_node_name[slot] = pod.node_name
+            self._bind_pod(slot, pod.node_name)
             self._pod_active[slot] = True
             self.feed.publish(DELTA_POD, pod.name)
             if pod.labels.get(L.SPARK_ROLE_LABEL) == L.EXECUTOR and pod.is_terminated():
@@ -436,7 +481,7 @@ class TensorSnapshotCache:
                 # entry exists — parity is with overhead.go which relies on
                 # delete events, so keep the pod until deletion
                 pass
-            self._pods_dirty = True
+            self._dirty_pods.add(slot)
 
     def _on_pod_delete(self, pod: Pod) -> None:
         with self._lock:
@@ -444,10 +489,16 @@ class TensorSnapshotCache:
             slot = self._pod_slot.pop((pod.namespace, pod.name), None)
             if slot is not None:
                 self._pod_active[slot] = False
-                self._pod_node_name[slot] = ""
+                self._bind_pod(slot, "")
                 self._pod_key_of_slot.pop(slot, None)
+                named = self._pods_named[pod.name]
+                named.discard(slot)
+                if not named:
+                    del self._pods_named[pod.name]
                 self._free_pods.append(slot)
-                self._pods_dirty = True
+                # its record leaves at the next fold, before any reuse
+                # of the slot can count a new pod there
+                self._dirty_pods.add(slot)
             was_reserved = (pod.namespace, pod.name) in self._reserved_pods
             self._reserved_pods.discard((pod.namespace, pod.name))
             if slot is not None or was_reserved:
@@ -455,45 +506,44 @@ class TensorSnapshotCache:
 
     # -- snapshot ------------------------------------------------------------
 
-    def _recompute_overhead(self) -> None:
-        """Every active pod row walked again: one ``mirror.overhead``
-        span under whatever took the snapshot (a Filter's
-        ``fast_path.snapshot`` or ``executor.snapshot``, the capacity
-        sampler's ``capacity.sample``, the reconcile)."""
-        active = np.flatnonzero(self._pod_active)
-        with tracing.child_span(OVERHEAD_SPAN, {"rows": len(active)}):
-            n_nodes = len(self._node_names)
-            overhead = np.zeros((n_nodes, 3), dtype=np.int64)
-            if len(active):
-                # reserved pods don't count (overhead.go:139-141; soft
-                # reservations match by bare pod name like the reference)
-                mask = np.fromiter(
-                    (
-                        (key := self._pod_key_of_slot.get(int(slot), ("", ""))) not in self._reserved_pods
-                        and key[1] not in self._soft_reserved_names
-                        for slot in active
-                    ),
-                    dtype=bool,
-                    count=len(active),
-                )
-                counted = active[mask]
-                node_idx = np.fromiter(
-                    (
-                        self._node_slot.get(self._pod_node_name[int(slot)], -1)
-                        for slot in counted
-                    ),
-                    dtype=np.int64,
-                    count=len(counted),
-                )
-                ok = node_idx >= 0
-                np.add.at(overhead, node_idx[ok], self._pod_requests[counted][ok])
-            old = self._node_overhead
-            if len(old) < n_nodes:
-                pad = np.zeros((n_nodes - len(old), 3), np.int64)
-                old = np.vstack([old, pad]) if len(old) else pad
-            changed = np.flatnonzero((old[:n_nodes] != overhead).any(axis=1))
-            self._node_overhead = overhead
-            self._pods_dirty = False
+    def _counted_on(self, slot: int) -> int:
+        """The node slot one pod slot counts on, or -1: an active pod no
+        reservation holds (overhead.go:139-141; soft reservations match
+        by bare pod name like the reference) on a node the mirror knows."""
+        if not self._pod_active[slot]:
+            return -1
+        key = self._pod_key_of_slot[slot]
+        if key in self._reserved_pods or key[1] in self._soft_reserved_names:
+            return -1
+        return self._node_slot.get(self._pod_node_name[slot], -1)
+
+    def _fold_overhead(self) -> None:
+        """Each dirty pod slot's recorded row taken off its node and its
+        current one added: one ``mirror.overhead`` span under whatever
+        took the snapshot (a Filter's ``fast_path.snapshot`` or
+        ``executor.snapshot``, the capacity sampler's ``capacity.sample``,
+        the reconcile)."""
+        dirty = np.fromiter(self._dirty_pods, np.int64, len(self._dirty_pods))
+        self._dirty_pods = set()
+        with tracing.child_span(OVERHEAD_SPAN, {"rows": len(dirty)}):
+            was_on = self._pod_counted_on[dirty]
+            now_on = np.fromiter(
+                (self._counted_on(int(slot)) for slot in dirty),
+                dtype=np.int64, count=len(dirty),
+            )
+            touched = np.unique(np.concatenate([was_on, now_on]))
+            touched = touched[touched >= 0]
+            before = self._node_overhead[touched]
+            was = was_on >= 0
+            np.subtract.at(
+                self._node_overhead, was_on[was], self._pod_counted_row[dirty[was]]
+            )
+            now = now_on >= 0
+            rows = np.where(now[:, None], self._pod_requests[dirty], 0)
+            np.add.at(self._node_overhead, now_on[now], rows[now])
+            self._pod_counted_on[dirty] = now_on
+            self._pod_counted_row[dirty] = rows
+            changed = touched[(self._node_overhead[touched] != before).any(axis=1)]
             # overhead shifted under some nodes: bring their class-index rows
             # up to date (class KEY never depends on overhead, so this only
             # refreshes content hashes — class_rev is untouched)
@@ -509,8 +559,8 @@ class TensorSnapshotCache:
 
     def snapshot(self) -> TensorSnapshot:
         with self._lock:
-            if self._pods_dirty:
-                self._recompute_overhead()
+            if self._dirty_pods:
+                self._fold_overhead()
             if self._names_dirty:
                 self._recompute_name_ranks()
             # structure-derived parts (the Python-loop costs: live-slot

@@ -279,8 +279,8 @@ def trace_tag_total(name: str, tag: str):
     """The sum of the numeric tag ``tag`` over the spans named ``name``
     in the active trace so far, 0 outside a trace: what a request's
     earlier phases did, for a later span of the same request to say
-    (``fifo_gate``'s ``overheadRows``: the pod rows the request's
-    ``mirror.overhead`` refreshes walked)."""
+    (``fifo_gate``'s ``overheadRows``: the pod slots the request's
+    ``mirror.overhead`` refreshes folded)."""
     span = _CURRENT.get()
     if span is None:
         return 0

@@ -171,8 +171,8 @@ def own(tags):
     return {k: v for k, v in tags.items() if k not in RUNTIME_TAGS}
 
 
-# the overhead refresh lies under whichever snapshot first follows a
-# change to the pod table or the reservations: a request's, or the
+# the overhead fold lies under whichever snapshot first follows an
+# event that marked a pod slot: a request's, or the
 # capacity sampler's on its own thread; the trees are compared without
 # it, and ``test_an_overhead_refresh_*`` pins where it lies
 OVERHEAD_SPAN = "mirror.overhead"
@@ -830,7 +830,9 @@ def quiet_driver_root(h, stale):
     snapshotted): ``stale``, a bound pod no reservation holds appears
     just before the request, so the request's snapshot finds the
     overhead stale; otherwise nothing changes and it finds it current.
-    Returns (root, the mirror's active pod rows at the request)."""
+    Returns (root, the pod slots the mirror has to fold at the request:
+    the daemon's alone, as the quiet snapshot folded the granted
+    driver's)."""
     from k8s_spark_scheduler_tpu.types.objects import Container, ObjectMeta, Pod, PodPhase
     from k8s_spark_scheduler_tpu.types.resources import Resources
 
@@ -845,7 +847,9 @@ def quiet_driver_root(h, stale):
             scheduler_name="default-scheduler", node_name=NODES[0],
             containers=[Container("agent", Resources.of("100m", "128Mi"))], phase=PodPhase.RUNNING,
         ))
-    rows = int(mirror._pod_active.sum())
+    daemon = {mirror._pod_slot[("kube-system", "daemon-n0")]} if stale else set()
+    assert mirror._dirty_pods == daemon
+    rows = len(daemon)
     roots = roots_of(h)
     h.assert_success(h.schedule(h.static_allocation_spark_pods("app-new", 2)[0], NODES))
     (root,) = [r for r in roots if r.name == "predicate"]
@@ -859,7 +863,7 @@ def test_an_overhead_refresh_is_a_child_of_the_snapshot_that_ran_it_and_the_gate
         root, rows = quiet_driver_root(h, stale=True)
         assert parents_of(root, OVERHEAD_SPAN) == ["fast_path.snapshot"]
         refresh = find(root, OVERHEAD_SPAN)
-        assert own(refresh.tags) == {"rows": rows} and rows >= 2  # the granted driver's pod and the daemon's
+        assert own(refresh.tags) == {"rows": rows} and rows == 1  # the daemon's pod, not every active row
         assert find(root, "fifo_gate").tags["overheadRows"] == rows
         assert shape(root) == EXPECTED["native" if lane == "native" else "device"]
     finally:
@@ -900,12 +904,15 @@ def test_the_capacity_samplers_refresh_is_a_child_of_its_sample():
         assert h.wait_quiesced()
         samples = []
         h.server.tracer.add_observer(lambda root: root.name == "capacity.sample" and samples.append(root))
+        mirror = h.server.tensor_snapshot
+        driver = mirror._pod_slot[("default", h.static_allocation_spark_pods("app-first", 1)[0].name)]
         h.delete_pod(h.static_allocation_spark_pods("app-first", 1)[0])  # the reservation goes with it
         assert h.wait_for_api(lambda: h.get_resource_reservation("app-first") is None)
+        assert mirror._dirty_pods == {driver}  # the reservation named only the deleted pod
         h.server.capacity.sample_now(tracer=h.server.tracer)
         (sample,) = samples
         assert [c.name for c in sample.children] == [OVERHEAD_SPAN]
-        assert own(sample.children[0].tags)["rows"] == int(h.server.tensor_snapshot._pod_active.sum())
+        assert own(sample.children[0].tags)["rows"] == 1  # the driver's slot, not every active row
     finally:
         h.close()
 

@@ -456,3 +456,238 @@ def test_the_zones_are_ranked_by_exact_integer_totals():
     assert float(memory[[0, 2]].sum()) == float(memory[[1, 3]].sum())  # what a float sum would see
     rows = fast_path.executor_rows_keyed(snap, names, None)
     assert names[fast_path.first_in_executor_order(snap, rows, snap.avail, np.ones(4, bool))] == "b0"
+
+
+# -- the overhead fold against the full walk ----------------------------------
+
+
+class _NoFeed:
+    """Stands for the informers and the reservation stores: the tests
+    call the mirror's handlers themselves."""
+
+    def add_event_handler(self, **_):
+        pass
+
+    def add_change_observer(self, _):
+        pass
+
+
+def _bare_mirror():
+    from k8s_spark_scheduler_tpu.state.tensor_snapshot import TensorSnapshotCache
+
+    return TensorSnapshotCache(_NoFeed(), _NoFeed(), _NoFeed(), _NoFeed())
+
+
+def _full_walk(mirror):
+    """The overhead the whole pod table gives: each active slot whose
+    (namespace, name) no reservation's status.pods holds and whose bare
+    name no soft reservation holds, added on its node if the mirror
+    knows the node."""
+    overhead = np.zeros((len(mirror._node_names), 3), np.int64)
+    for slot in map(int, np.flatnonzero(mirror._pod_active)):
+        key = mirror._pod_key_of_slot[slot]
+        node = mirror._node_slot.get(mirror._pod_node_name[slot])
+        if key not in mirror._reserved_pods and key[1] not in mirror._soft_reserved_names and node is not None:
+            overhead[node] += mirror._pod_requests[slot]
+    return overhead
+
+
+def _folded(mirror):
+    """The pod slots one snapshot folds, read off its ``mirror.overhead`` span."""
+    from k8s_spark_scheduler_tpu import tracing
+    from k8s_spark_scheduler_tpu.state.tensor_snapshot import OVERHEAD_SPAN
+
+    with tracing.Tracer().span("test") as root:
+        mirror.snapshot()
+    return sum(c.tags["rows"] for c in root.children if c.name == OVERHEAD_SPAN)
+
+
+def _pod(namespace, name, node, requests):
+    from k8s_spark_scheduler_tpu.types.objects import Container, ObjectMeta, Pod, PodPhase
+
+    return Pod(
+        meta=ObjectMeta(name=name, namespace=namespace), node_name=node,
+        containers=[Container("main", requests)], phase=PodPhase.RUNNING,
+    )
+
+
+def _reservation(namespace, name, nodes, pods, requests):
+    from k8s_spark_scheduler_tpu.types.objects import (
+        ObjectMeta, Reservation, ResourceReservation, ResourceReservationSpec, ResourceReservationStatus,
+    )
+
+    return ResourceReservation(
+        meta=ObjectMeta(name=name, namespace=namespace),
+        spec=ResourceReservationSpec(
+            reservations={f"r{i}": Reservation.for_resources(node, requests) for i, node in enumerate(nodes)}
+        ),
+        status=ResourceReservationStatus(pods={f"r{i}": pod for i, pod in enumerate(pods)}),
+    )
+
+
+_FOLD_COVERAGE = {
+    "pod before its node", "node slot reused by another name before a fold", "pod moved", "pod unbound",
+    "reservation moved its pods", "pod in two reservations", "soft name in two namespaces",
+    "soft count down from two", "reserved pod deleted", "inexact quantity",
+}
+
+
+class _Churn:
+    """Seeded random events into a bare mirror: the log that replays
+    them into a fresh one, and the situations the sequence covered."""
+
+    NAMESPACES = ("ns-a", "ns-b")
+    NODES = [f"n{i}" for i in range(5)]
+    PODS = [f"p{i}" for i in range(8)]
+
+    def __init__(self, seed):
+        from k8s_spark_scheduler_tpu.types.resources import Resources
+
+        self.rng = random.Random(seed)
+        self.mirror = _bare_mirror()
+        self.log = []
+        self.nodes = set()
+        self.pods = {}         # (namespace, name) -> node name, "" unbound
+        self.reservations = {}  # name -> ResourceReservation
+        self.soft = {}         # bare pod name -> [(node, requests)] held
+        self.freed = {}        # node slot -> the name deleted from it since the last snapshot
+        self.covered = set()
+        self.requests = [Resources.of("100m", "128Mi"), Resources.of("1", "1Gi"), Resources.of("250m", "512Mi", "1")]
+        self.inexact = Resources.of("100m", "1500m")  # memory not a whole byte
+
+    def apply(self, handler, *args):
+        self.log.append((handler, args))
+        getattr(self.mirror, handler)(*args)
+
+    def step(self):
+        from k8s_spark_scheduler_tpu.types.objects import Node, ObjectMeta
+        from k8s_spark_scheduler_tpu.types.resources import Resources
+
+        rng, action = self.rng, self.rng.random()
+        if action < 0.12:
+            name = rng.choice(self.NODES)
+            if name not in self.nodes and name in self.pods.values():
+                self.covered.add("pod before its node")
+            node = Node(meta=ObjectMeta(name=name, labels={"pool": rng.choice("ab")}),
+                        allocatable=Resources.of("16", "64Gi"))
+            self.apply("_on_node", node)
+            if self.freed.get(self.mirror._node_slot[name], name) != name:
+                self.covered.add("node slot reused by another name before a fold")
+            self.nodes.add(name)
+        elif action < 0.2 and self.nodes:
+            name = rng.choice(sorted(self.nodes))
+            self.freed[self.mirror._node_slot[name]] = name
+            self.apply("_on_node_delete", Node(meta=ObjectMeta(name=name)))
+            self.nodes.discard(name)
+        elif action < 0.5:
+            key = (rng.choice(self.NAMESPACES), rng.choice(self.PODS))
+            node = "" if rng.random() < 0.15 else rng.choice(self.NODES)
+            was = self.pods.get(key, "")
+            if was and node and node != was:
+                self.covered.add("pod moved")
+            if was and not node:
+                self.covered.add("pod unbound")
+            requests = rng.choice(self.requests)
+            if rng.random() < 0.03:
+                requests = self.inexact
+                self.covered.add("inexact quantity")
+            self.apply("_on_pod", _pod(*key, node, requests))
+            if node or key in self.pods:
+                self.pods[key] = node
+        elif action < 0.6 and self.pods:
+            key = rng.choice(sorted(self.pods))
+            if key in self.mirror._reserved_pods:
+                self.covered.add("reserved pod deleted")
+            self.apply("_on_pod_delete", _pod(*key, self.pods.pop(key), self.requests[0]))
+        elif action < 0.85:
+            name = rng.choice(["rr0", "rr1", "rr2", "rr3"])
+            old = self.reservations.get(name)
+            namespace = old.namespace if old is not None else rng.choice(self.NAMESPACES)
+            new = None
+            if old is None or rng.random() < 0.7:
+                pods = rng.sample(self.PODS, rng.randint(1, 3))
+                nodes = [rng.choice(self.NODES) for _ in pods]
+                new = _reservation(namespace, name, nodes, pods, rng.choice(self.requests))
+            before = set(old.status.pods.values()) if old is not None else set()
+            after = set(new.status.pods.values()) if new is not None else set()
+            if old is not None and before != after:
+                self.covered.add("reservation moved its pods")
+            others = {
+                (rr.namespace, pod) for other, rr in self.reservations.items() if other != name
+                for pod in rr.status.pods.values()
+            }
+            if {(namespace, pod) for pod in before | after} & others:
+                self.covered.add("pod in two reservations")
+            self.apply("_on_rr_change", old, new)
+            if new is None:
+                del self.reservations[name]
+            else:
+                self.reservations[name] = new
+        else:
+            name = rng.choice(self.PODS)
+            held = self.soft.setdefault(name, [])
+            if held and rng.random() < 0.5:
+                if len(held) == 2:
+                    self.covered.add("soft count down from two")
+                node, requests = held.pop(rng.randrange(len(held)))
+                sign = -1
+            else:
+                node, requests = rng.choice(self.NODES), rng.choice(self.requests)
+                held.append((node, requests))
+                sign = +1
+            if all((namespace, name) in self.pods for namespace in self.NAMESPACES):
+                self.covered.add("soft name in two namespaces")
+            self.apply("_on_soft_change", node, requests, sign, name)
+
+    def replayed(self):
+        fresh = _bare_mirror()
+        for handler, args in self.log:
+            getattr(fresh, handler)(*args)
+        return fresh
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_overhead_fold_equals_the_full_walk_after_every_snapshot(seed):
+    """Every snapshot's overhead is the whole table's walk, bit for bit,
+    and the class digest is what a mirror that saw the same events and
+    folded them once, at its first snapshot, stamps."""
+    churn = _Churn(seed)
+    snapshots = 0
+    for _ in range(400):
+        churn.step()
+        if churn.rng.random() < 0.25:
+            mirror = churn.mirror
+            snap = mirror.snapshot()
+            assert not mirror._dirty_pods
+            assert np.array_equal(mirror._node_overhead, _full_walk(mirror))
+            live = [mirror._node_slot[name] for name in snap.names]
+            assert np.array_equal(snap.overhead, mirror._node_overhead[live])
+            fresh = churn.replayed()
+            assert snap.class_digest[1] == fresh.snapshot().class_digest[1]
+            assert snap.exact == fresh._exact == ("inexact quantity" not in churn.covered)
+            churn.freed.clear()
+            snapshots += 1
+    assert snapshots > 50
+    assert churn.covered == _FOLD_COVERAGE
+
+
+def test_one_event_folds_its_own_slots_on_a_mirror_of_fifty_thousand_bound_pods():
+    """The fold follows the delta, not the table: a guard on the count of
+    slots folded, which a return to the whole walk breaks at any speed."""
+    from k8s_spark_scheduler_tpu.types.objects import Node, ObjectMeta
+    from k8s_spark_scheduler_tpu.types.resources import Resources
+
+    mirror = _bare_mirror()
+    for i in range(100):
+        mirror._on_node(Node(meta=ObjectMeta(name=f"n{i}"), allocatable=Resources.of("64", "256Gi")))
+    requests = Resources.of("100m", "128Mi")
+    for i in range(50_000):
+        mirror._on_pod(_pod("ns", f"p{i}", f"n{i % 100}", requests))
+    assert _folded(mirror) == 50_000  # start-up: the whole table, once
+    assert _folded(mirror) == 0
+    mirror._on_pod(_pod("ns", "p7", "n8", requests))  # a bound pod moves
+    assert _folded(mirror) == 1
+    pods = ["p1", "p2", "p3"]
+    mirror._on_rr_change(None, _reservation("ns", "app", ["n1", "n2", "n3"], pods, requests))
+    assert _folded(mirror) == len(pods)
+    assert np.array_equal(mirror._node_overhead, _full_walk(mirror))
