@@ -155,6 +155,10 @@ QUEUE_VIEW_READS = "foundry.spark.scheduler.fifo.queue.view.reads"
 # (ops/fast_path.py, keyed by structure revision, affinity signature and
 # candidate list): result=hit|miss|uncacheable
 PREP_CACHE_READS = "foundry.spark.scheduler.tpu.fastpath.prepcache.reads"
+# driver tensor builds by how their node priority order was come by
+# (ops/fast_path.py, kept with the prep entry): result=kept (no selected
+# row changed since the last sort under the key) or rebuilt (sorted whole)
+NODE_ORDER_READS = "foundry.spark.scheduler.tpu.fastpath.nodeorder.reads"
 # executor reschedules from the mirror by how their candidate rows were
 # come by (ops/fast_path.py, keyed by structure revision, candidate list
 # and executor label priority): result=hit|miss|uncacheable

@@ -17,6 +17,12 @@ derives from Quantity metadata:
 - the required-node-affinity filter over snapshot label dicts
   (resource.go:292-295).
 
+A driver's tensor build keeps that order, and the arrays that follow
+from it, with the request's prep entry (`_NodeOrder`): the next request
+under the same key whose selected rows still hold the allocatable, usage
+and overhead the order was sorted from is handed the same read-only
+arrays; any change to them sorts the rows whole again.
+
 An executor's reschedule (resource.go:594-663) needs only the head of
 that order among the nodes that fit: `first_in_executor_order` selects
 it as a lexicographic minimum over candidate rows kept between requests
@@ -36,7 +42,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..metrics.names import EXECUTOR_ROWS_READS, PREP_CACHE_READS
+from ..metrics.names import EXECUTOR_ROWS_READS, NODE_ORDER_READS, PREP_CACHE_READS
 from ..state.tensor_snapshot import TensorSnapshot
 from .nodesort import LabelPriorityOrder
 from .tensorize import INT32_SAFE, ClusterTensor
@@ -197,6 +203,9 @@ class _BuildPrep:
     d_keys: Optional[np.ndarray]
     e_keys: Optional[np.ndarray]
     zones: Dict[str, str]      # eligible node → zone name
+    # the node order the last request under this entry's key sorted
+    # (build_cluster_tensor)
+    node_order: Optional["_NodeOrder"] = None
 
 
 _PREP_CACHE: OrderedDict = OrderedDict()
@@ -342,44 +351,33 @@ def _build_prep(snap, driver_pod, candidate_names, dlp, elp) -> _BuildPrep:
     return build_prep_keyed(snap, driver_pod, candidate_names, dlp, elp)[0]
 
 
-def build_cluster_tensor(
-    snap: TensorSnapshot,
-    driver_pod,
-    candidate_names: List[str],
-    driver_label_priority: Optional[LabelPriorityOrder] = None,
-    executor_label_priority: Optional[LabelPriorityOrder] = None,
-) -> Optional[Tuple[ClusterTensor, Dict[str, str]]]:
-    """(cluster tensor, node→zone map) or None when the fast path can't
-    represent the snapshot exactly."""
-    if not snap.exact:
-        return None
-    n = len(snap.names)
-    if n == 0:
-        # no eligible nodes: an empty tensor is still valid input
-        empty = ClusterTensor(
-            node_names=[],
-            avail=np.zeros((0, 3), np.int64),
-            sched=np.zeros((0, 3), np.int64),
-            driver_rank=np.zeros(0, np.int32),
-            exec_ok=np.zeros(0, bool),
-            zone_id=np.zeros(0, np.int32),
-            zone_names=[],
-            valid=np.zeros(0, bool),
-            exact=True,
-        )
-        return empty, {}
+@dataclass(frozen=True)
+class _NodeOrder:
+    """A driver tensor's node order and the ClusterTensor arrays that
+    follow from it, with the selected rows' allocatable, usage and
+    overhead it was sorted from.  Never written once built: its arrays
+    are read-only and handed to every request that finds the same rows."""
 
-    prep = _build_prep(
-        snap, driver_pod, candidate_names, driver_label_priority,
-        executor_label_priority,
-    )
-    idx = prep.idx
-    avail = snap.avail[idx]
-    sched = snap.schedulable[idx]
-    zone_id = snap.zone_id[idx]
+    basis: Tuple[np.ndarray, np.ndarray, np.ndarray]  # [len(idx), 3] each
+    names: Tuple[str, ...]
+    avail: np.ndarray
+    sched: np.ndarray
+    driver_rank: np.ndarray
+    exec_ok: np.ndarray
+    zone_id: np.ndarray
+    valid: np.ndarray
 
-    # AZ-aware base priority (shared with the executor lane)
-    order = _base_priority_order(snap, idx, avail)
+
+def _sorted_whole(snap: TensorSnapshot, prep: _BuildPrep, basis) -> _NodeOrder:
+    """The node order of the selected rows, sorted whole, and the arrays
+    that follow from it."""
+    allocatable, usage, overhead = basis
+    avail = allocatable - usage - overhead
+    sched = allocatable - overhead
+    zone_id = snap.zone_id[prep.idx]
+
+    # AZ-aware base priority
+    order = _base_priority_order(snap, prep.idx, avail)
 
     # per-role label-priority re-sort on top of the base order
     # (nodesorting.go:161-180).  The array order is the EXECUTOR priority
@@ -404,17 +402,85 @@ def build_cluster_tensor(
     driver_rank[pos_in_array[cand_base_positions]] = np.arange(
         len(cand_base_positions)
     )
-    ordered_names = list(prep.names_arr[perm])
+
+    arrays = (
+        np.take(avail, perm, axis=0),
+        np.take(sched, perm, axis=0),
+        driver_rank.astype(np.int32),
+        prep.exec_ok_base[perm],
+        zone_id[perm].astype(np.int32),
+        np.ones(len(perm), dtype=bool),
+    )
+    for array in arrays:
+        array.setflags(write=False)
+    return _NodeOrder(basis, tuple(prep.names_arr[perm]), *arrays)
+
+
+def build_cluster_tensor(
+    snap: TensorSnapshot,
+    driver_pod,
+    candidate_names: List[str],
+    driver_label_priority: Optional[LabelPriorityOrder] = None,
+    executor_label_priority: Optional[LabelPriorityOrder] = None,
+) -> Optional[Tuple[ClusterTensor, Dict[str, str]]]:
+    """(cluster tensor, node→zone map) or None when the fast path can't
+    represent the snapshot exactly.
+
+    The node order is kept with the prep entry: a request whose selected
+    rows read as the last sort under the same key left them takes it as
+    it is (`nodeOrder=kept` on the span, `orderRows` 0); the first
+    request, a node event (a new key), an uncacheable affinity shape or
+    any changed row sorts them all (`rebuilt`, `orderRows` the rows
+    sorted).  The tensor's arrays are read-only: later requests are
+    handed the same ones until a row changes."""
+    from ..tracing import add_tag
+    from ..tracing.profiling import default_profiler
+
+    if not snap.exact:
+        return None
+    n = len(snap.names)
+    if n == 0:
+        # no eligible nodes: an empty tensor is still valid input
+        empty = ClusterTensor(
+            node_names=[],
+            avail=np.zeros((0, 3), np.int64),
+            sched=np.zeros((0, 3), np.int64),
+            driver_rank=np.zeros(0, np.int32),
+            exec_ok=np.zeros(0, bool),
+            zone_id=np.zeros(0, np.int32),
+            zone_names=[],
+            valid=np.zeros(0, bool),
+            exact=True,
+        )
+        return empty, {}
+
+    prep = _build_prep(
+        snap, driver_pod, candidate_names, driver_label_priority,
+        executor_label_priority,
+    )
+    idx = prep.idx
+    # np.take copies whole rows several times faster than fancy indexing
+    basis = tuple(np.take(a, idx, axis=0) for a in (snap.allocatable, snap.usage, snap.overhead))
+    kept = prep.node_order
+    if kept is not None and all(map(np.array_equal, basis, kept.basis)):
+        read = "kept"
+    else:
+        # an uncacheable affinity shape's prep entry is its request's alone
+        read = "rebuilt"
+        kept = prep.node_order = _sorted_whole(snap, prep, basis)
+    add_tag("nodeOrder", read)
+    add_tag("orderRows", 0 if read == "kept" else len(idx))
+    default_profiler.metrics.counter(NODE_ORDER_READS, {"result": read})
 
     cluster = ClusterTensor(
-        node_names=ordered_names,
-        avail=avail[perm],
-        sched=sched[perm],
-        driver_rank=driver_rank.astype(np.int32),
-        exec_ok=prep.exec_ok_base[perm],
-        zone_id=zone_id[perm].astype(np.int32),
+        node_names=list(kept.names),
+        avail=kept.avail,
+        sched=kept.sched,
+        driver_rank=kept.driver_rank,
+        exec_ok=kept.exec_ok,
+        zone_id=kept.zone_id,
         zone_names=list(snap.zone_names),
-        valid=np.ones(len(ordered_names), dtype=bool),
+        valid=kept.valid,
         exact=True,
     )
     return cluster, prep.zones
