@@ -188,7 +188,7 @@ class Informer:
             snapshot = list(self._store.values()) if on_add else []
         # client-go semantics: a late-registered handler receives synthetic
         # ADD events for everything already in the store, so components
-        # wired after the informer started (overhead computer, stores) see
+        # wired after the informer started (the tensor mirror, stores) see
         # pre-existing objects
         for obj in snapshot:
             wrap_add(obj)

@@ -36,6 +36,7 @@ from ..ops.fifo_solver import gate_overhead_rows
 from ..ops.nodesort import NodeSorter
 from ..ops.registry import SINGLE_AZ_MINIMAL_FRAGMENTATION, Binpacker, check_kernel_fault
 from ..resilience import deadline as req_deadline
+from ..state.tensor_snapshot import TensorSnapshotCache
 from ..types.extenderapi import ExtenderArgs, ExtenderFilterResult
 from ..types.objects import Node, Pod
 from ..types.resources import (
@@ -45,7 +46,6 @@ from ..types.resources import (
     subtract_usage_if_exists,
 )
 from . import labels as L
-from .overhead import OverheadComputer
 from .reservations_manager import DRIVER_RESERVATION_NAME, ResourceReservationManager
 from .sparkpods import (
     VIEW_PER_POD,
@@ -113,13 +113,12 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         fifo_config: FifoConfig,
         binpacker: Binpacker,
         should_schedule_dynamically_allocated_executors_in_same_az: bool,
-        overhead_computer: OverheadComputer,
+        tensor_snapshot_cache: TensorSnapshotCache,
         instance_group_label: str,
         node_sorter: NodeSorter,
         metrics: MetricsRegistry | None = None,
         event_log: Optional[ev.EventLog] = None,
         waste_reporter=None,
-        tensor_snapshot_cache=None,
         strict_reference_parity: bool = compat.DEFAULT_STRICT,
         tracer: Optional[tracing.Tracer] = None,
         resilience=None,
@@ -137,16 +136,16 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         self._fifo_config = fifo_config
         self.binpacker = binpacker
         self._single_az_da = should_schedule_dynamically_allocated_executors_in_same_az
-        self._overhead = overhead_computer
         self._instance_group_label = instance_group_label
         self._node_sorter = node_sorter
         self._metrics = metrics or default_registry
         self._event_log = event_log
         self._tracer = tracer if tracer is not None else tracing.default_tracer
         self._waste_reporter = waste_reporter
-        # event-driven integer snapshot for the driver fast path; the
+        # event-driven integer snapshot for the driver fast path (the
         # fast lexsort replicates the NodeSorter ordering including any
-        # configured per-role label-priority re-sort
+        # configured per-role label-priority re-sort) and the keeper of
+        # the overhead the Quantity paths read
         self._tensor_snapshot = tensor_snapshot_cache
         # kube-scheduler serializes Filter calls per scheduler instance
         # (SURVEY §2.10); the reference's state (lastRequest, the
@@ -159,15 +158,14 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         self._predicate_lock = TimedLock(
             threading.Lock(), "extender.predicate", sample_every=1, tag_waits=True
         )
-        self._fast_path_ok = tensor_snapshot_cache is not None
+        self._fast_path_ok = True
         # incremental delta-solve engine (ops/deltasolve.py): persistent
         # native solver sessions + prefix-feasibility reuse for the
-        # earlier-drivers pass.  None when disabled or when there is no
-        # tensor mirror to key invalidation on; the engine itself
+        # earlier-drivers pass.  None when disabled; the engine itself
         # declines (returns None) per request when it can't serve
         # exactly, so construction is cheap and unconditional otherwise.
         self.delta_engine = None
-        if delta_solve and tensor_snapshot_cache is not None:
+        if delta_solve:
             from ..ops.deltasolve import DeltaSolveEngine
 
             self.delta_engine = DeltaSolveEngine(metrics=self._metrics)
@@ -617,7 +615,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         )
 
         usage = self._rrm.get_reserved_resources()
-        overhead = self._overhead.get_overhead(available_nodes)
+        overhead = self._tensor_snapshot.overhead(n.name for n in available_nodes)
         metadata = node_scheduling_metadata_for_nodes(available_nodes, usage, overhead)
         driver_node_names, executor_node_names = self._node_sorter.potential_nodes(
             metadata, node_names
@@ -1151,7 +1149,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
             node_names = [n.name for n in available_nodes]
 
         usage = self._rrm.get_reserved_resources()
-        overhead = self._overhead.get_overhead(available_nodes)
+        overhead = self._tensor_snapshot.overhead(n.name for n in available_nodes)
         metadata = node_scheduling_metadata_for_nodes(available_nodes, usage, overhead)
 
         # QUIRK (switchable, install key strict-reference-parity;
@@ -1234,7 +1232,7 @@ class SparkSchedulerExtender:  # schedlint: disable=LK004 -- _predicate_lock ser
         (resource.go:675-703) is the same selection behind two leading
         keys instead of first-fit."""
         self.last_reschedule_path = "slow"
-        if self._tensor_snapshot is None or not self._fast_path_ok:
+        if not self._fast_path_ok:
             return None
         if self._lane_health is not None and not self._lane_health.allow(
             "tensor_reschedule"

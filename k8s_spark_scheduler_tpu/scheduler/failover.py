@@ -50,7 +50,7 @@ def sync_resource_reservations_and_demands(extender) -> None:
     if fast is not None:
         available, ordered_nodes = fast
     else:
-        overhead = extender._overhead.get_overhead(nodes)
+        overhead = extender._tensor_snapshot.overhead(n.name for n in nodes)
         soft_overhead = (
             extender._soft_reservation_store.used_soft_reservation_resources()
         )
@@ -176,12 +176,11 @@ def _available_resources_fast(extender, nodes: List[Node]):
     by tests/test_tensor_snapshot.py), with per-node Resources built
     only when the reconciler actually reads them.  Returns None when the
     mirror fast paths are disabled (_fast_path_ok, the same kill switch
-    the extender's other mirror lanes honor), the mirror is absent or
-    inexact, or it is out of step with the informer."""
-    cache = getattr(extender, "_tensor_snapshot", None)
-    if cache is None or not getattr(extender, "_fast_path_ok", False):
+    the extender's other mirror lanes honor), the mirror is inexact, or
+    it is out of step with the informer."""
+    if not extender._fast_path_ok:
         return None
-    snap = cache.snapshot()
+    snap = extender._tensor_snapshot.snapshot()
     if not snap.exact:
         return None
     index = snap.name_index

@@ -11,8 +11,9 @@ every Predicate the scheduler's state must satisfy:
       creation in the local cache);
   I4  per-node hard+soft reserved resources never exceed the node's
       allocatable (capacity safety — gang admission must not overbook);
-  I5  the tensor mirror (when present) matches the Quantity-path
-      availability exactly.
+  I5  the tensor mirror's availability matches allocatable less the
+      reservations and the overhead read from scratch
+      (scheduler/overhead.py) exactly.
 
 Violations raise InvariantViolation (tests) or log CRITICAL (prod).
 """
@@ -83,10 +84,13 @@ def check(server, raise_on_violation: bool = True) -> list:
 
         from ..ops.tensorize import _resources_to_base
         from ..types.resources import node_scheduling_metadata_for_nodes
+        from .overhead import node_overhead
 
         snap = snapshot_cache.snapshot()
         if snap.exact:
-            overhead = server.overhead_computer.get_overhead(list(nodes.values()))
+            overhead, _ = node_overhead(
+                server.pod_informer.list(), server.resource_reservation_manager, nodes
+            )
             usage2 = server.resource_reservation_manager.get_reserved_resources()
             metadata = node_scheduling_metadata_for_nodes(
                 nodes.values(), usage2, overhead

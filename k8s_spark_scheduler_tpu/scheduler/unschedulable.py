@@ -27,10 +27,10 @@ from ..kube.apiserver import APIServer
 from ..kube.informer import Informer
 from ..metrics import names as mnames
 from ..ops.registry import Binpacker
+from ..state.tensor_snapshot import TensorSnapshotCache
 from ..types.objects import Pod, PodCondition
 from ..types.resources import Resources, node_scheduling_metadata_for_nodes
 from . import labels as L
-from .overhead import OverheadComputer
 from .sparkpods import AnnotationError, spark_app_demand_cached
 
 logger = logging.getLogger(__name__)
@@ -46,7 +46,7 @@ class UnschedulablePodMarker:
         api: APIServer,
         node_informer: Informer,
         pod_informer: Informer,
-        overhead_computer: OverheadComputer,
+        tensor_snapshot: TensorSnapshotCache,
         binpacker: Binpacker,
         timeout_seconds: float = DEFAULT_TIMEOUT_SECONDS,
         polling_interval_seconds: float = UNSCHEDULABLE_POLLING_INTERVAL_SECONDS,
@@ -58,7 +58,7 @@ class UnschedulablePodMarker:
         self._api = api
         self._node_informer = node_informer
         self._pod_informer = pod_informer
-        self._overhead = overhead_computer
+        self._mirror = tensor_snapshot
         self._binpacker = binpacker
         self._timeout = timeout_seconds
         self._interval = polling_interval_seconds
@@ -103,9 +103,9 @@ class UnschedulablePodMarker:
         One root span ``unschedulable.scan`` per scan; its verdict
         batches, metadata builds and condition writes are three
         aggregate children (``scan.solve``, one phase per signature;
-        ``scan.metadata``, which holds ``scan.overhead``, the walk over
-        the bound pods; ``scan.mark``), the walk and the yields between
-        pods stay in the root's self time."""
+        ``scan.metadata``, which holds ``scan.overhead``, the mirror's
+        non-schedulable overhead; ``scan.mark``), the walk and the
+        yields between pods stay in the root's self time."""
         span = (
             self._tracer.span("unschedulable.scan")
             if self._tracer is not None
@@ -198,11 +198,11 @@ class UnschedulablePodMarker:
             )
             node_names = [n.name for n in nodes]
             zero_usage = {n.name: Resources.zero() for n in nodes}
-            # the walk over every bound pod of the signature's nodes: an
-            # aggregate child of the metadata's own, so the scan's other
-            # readings keep their times
+            # the mirror's non-schedulable overhead of the signature's
+            # nodes: an aggregate child of the metadata's own, so the
+            # scan's other readings keep their times
             with metadata_phase.aggregate("scan.overhead"):
-                overhead = self._overhead.get_non_schedulable_overhead(nodes)
+                overhead = self._mirror.non_schedulable_overhead(node_names)
             # chunked: one unbroken 10k-node Quantity build holds the
             # GIL for ~0.5-1s and was the single biggest tail spike
             # live Filters saw from this janitor

@@ -23,7 +23,6 @@ from ..ops.registry import select_binpacker
 from ..resilience import ResilienceKit, build_kit
 from ..scheduler.demand_gc import start_demand_gc
 from ..scheduler.extender import SparkSchedulerExtender
-from ..scheduler.overhead import OverheadComputer
 from ..scheduler.reservations_manager import ResourceReservationManager
 from ..scheduler.sparkpods import SparkPodLister
 from ..scheduler.unschedulable import UnschedulablePodMarker
@@ -60,7 +59,6 @@ class Server:
     soft_reservation_store: SoftReservationStore
     pod_lister: SparkPodLister
     resource_reservation_manager: ResourceReservationManager
-    overhead_computer: OverheadComputer
     extender: SparkSchedulerExtender
     tensor_snapshot: TensorSnapshotCache
     unschedulable_marker: UnschedulablePodMarker
@@ -352,9 +350,8 @@ def init_server_with_clients(
     rrm = ResourceReservationManager(
         rr_cache, soft_store, pod_lister, pod_informer, metrics=metrics, tracer=tracer
     )
-    overhead = OverheadComputer(pod_informer, rrm)
-
-    # event-driven integer snapshot for the tpu-batch fast path
+    # event-driven integer snapshot for the tpu-batch fast path, and
+    # the one keeper of node overhead every path reads
     tensor_snapshot = TensorSnapshotCache(node_informer, pod_informer, rr_cache, soft_store)
 
     # waste reporter (cmd/server.go:171-191 NewWasteMetricsReporter)
@@ -478,13 +475,12 @@ def init_server_with_clients(
         should_schedule_dynamically_allocated_executors_in_same_az=(
             install.should_schedule_dynamically_allocated_executors_in_same_az
         ),
-        overhead_computer=overhead,
+        tensor_snapshot_cache=tensor_snapshot,
         instance_group_label=install.instance_group_label,
         node_sorter=node_sorter,
         metrics=metrics,
         event_log=event_log,
         waste_reporter=waste_reporter,
-        tensor_snapshot_cache=tensor_snapshot,
         strict_reference_parity=install.strict_reference_parity,
         tracer=tracer,
         resilience=resilience_kit,
@@ -521,7 +517,7 @@ def init_server_with_clients(
         api,
         node_informer,
         pod_informer,
-        overhead,
+        tensor_snapshot,
         binpacker,
         timeout_seconds=install.unschedulable_pod_timeout_seconds,
         polling_interval_seconds=unschedulable_polling_interval,
@@ -543,7 +539,6 @@ def init_server_with_clients(
         soft_reservation_store=soft_store,
         pod_lister=pod_lister,
         resource_reservation_manager=rrm,
-        overhead_computer=overhead,
         extender=extender,
         tensor_snapshot=tensor_snapshot,
         unschedulable_marker=marker,

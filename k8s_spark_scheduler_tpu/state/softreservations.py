@@ -38,7 +38,7 @@ class SoftReservationStore:
     def __init__(self, pod_informer: Optional[Informer] = None):
         self._lock = threading.RLock()
         self._store: Dict[str, SoftReservation] = {}
-        # (node, Resources, +1/-1) observers for incremental usage mirrors
+        # (app id, node, Resources, +1/-1, pod name) observers for usage mirrors
         self._observers = []
         if pod_informer is not None:
             pod_informer.add_event_handler(
@@ -75,7 +75,7 @@ class SoftReservationStore:
                 return
             sr.reservations[pod_name] = reservation
             sr.status[pod_name] = True
-            self._notify(reservation.node, reservation.resources_value(), +1, pod_name)
+            self._notify(app_id, reservation.node, reservation.resources_value(), +1, pod_name)
 
     def executor_has_soft_reservation(self, executor: Pod) -> bool:
         return self.get_executor_soft_reservation(executor) is not None
@@ -115,7 +115,7 @@ class SoftReservationStore:
             removed = sr.reservations.pop(executor_name, None)
             sr.status[executor_name] = False
             if removed is not None:
-                self._notify(removed.node, removed.resources_value(), -1, executor_name)
+                self._notify(app_id, removed.node, removed.resources_value(), -1, executor_name)
 
     def remove_driver_reservation(self, app_id: str) -> None:
         with self._lock:
@@ -123,7 +123,9 @@ class SoftReservationStore:
             sr = self._store.pop(app_id, None)
             if sr is not None:
                 for pod_name, reservation in sr.reservations.items():
-                    self._notify(reservation.node, reservation.resources_value(), -1, pod_name)
+                    self._notify(
+                        app_id, reservation.node, reservation.resources_value(), -1, pod_name
+                    )
 
     def _on_pod_deletion(self, pod: Pod) -> None:
         app_id = pod.labels.get(SPARK_APP_ID_LABEL, "")
@@ -134,18 +136,20 @@ class SoftReservationStore:
             self.remove_executor_reservation(app_id, pod.name)
 
     def add_change_observer(self, fn) -> None:
-        """fn(node, resources, sign, pod_name): called under the store lock
-        on every reservation add (+1) / removal (-1)."""
+        """fn(app_id, node, resources, sign, pod_name): called under the
+        store lock on every reservation add (+1) / removal (-1)."""
         # under the lock: registration must not race a concurrent
         # _notify iteration over the same list
         with self._lock:
             racecheck.note_access(self, "_observers")
             self._observers.append(fn)
 
-    def _notify(self, node: str, resources: Resources, sign: int, pod_name: str) -> None:
+    def _notify(
+        self, app_id: str, node: str, resources: Resources, sign: int, pod_name: str
+    ) -> None:
         for fn in self._observers:
             try:
-                fn(node, resources, sign, pod_name)
+                fn(app_id, node, resources, sign, pod_name)
             except Exception:
                 import logging
 

@@ -17,10 +17,13 @@ This cache keeps the same state as O(delta)-updated int64 arrays:
   SoftReservationStore change observers (this process is the sole
   writer of both, so the mirror is exact);
 - overhead: a pod table (requests, node, scheduler flag) from pod
-  informer events plus a reserved-pod-name set maintained from the
-  same reservation observers; each event marks the pod slots whose
-  counted state it may change, and a snapshot folds only those slots
-  into the per-node sums.
+  informer events plus the reserved-pod keys maintained from the same
+  reservation observers; each event marks the pod slots whose counted
+  state it may change, and a snapshot folds only those slots into the
+  per-node sums.  This is the one keeper of the overhead rule
+  (overhead.go): the Quantity paths ask it through ``overhead()`` and
+  ``non_schedulable_overhead()``, which sum each counted pod's exact
+  requests.
 
 Exactness: every quantity is converted to base units once, at event
 time; anything not exactly representable poisons the affected row and
@@ -35,7 +38,7 @@ from __future__ import annotations
 import itertools
 import threading
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -43,9 +46,9 @@ from .. import tracing
 from ..analysis import racecheck
 from ..ops.tensorize import _resources_to_base
 from ..scheduler import labels as L
-from ..scheduler.overhead import pod_to_resources
 from ..types.objects import Node, Pod
-from ..types.resources import ZONE_LABEL, ZONE_LABEL_PLACEHOLDER
+from ..types.resources import ZONE_LABEL, ZONE_LABEL_PLACEHOLDER, NodeGroupResources, Resources
+from ..types.resources import pod_to_resources
 from ..analysis.guarded import guarded_by
 from .classindex import ClassIndex
 from .store import (
@@ -62,6 +65,24 @@ _GROW = 256
 # the span of one overhead fold, tagged ``rows``: the pod slots it
 # folded; a ``fifo_gate`` carries its request's sum as ``overheadRows``
 OVERHEAD_SPAN = "mirror.overhead"
+
+# nodes per lock hold of a Quantity answer: the gather for a 10,000-node
+# ask of a busy cluster (~98,000 pods) is tens of milliseconds of host
+# Python, which a Filter's snapshot would otherwise wait out whole
+_ANSWER_CHUNK = 256
+
+
+def _holds(pod: Pod) -> Tuple[Optional[tuple], Optional[tuple]]:
+    """The keys under which a reservation holds ``pod``: its own
+    application's ResourceReservation listing its name in status.pods
+    (namespace, app id, name), and for an executor its application's
+    soft reservation (app id, name) (resourcereservations.go:115-132,
+    softreservations.go:83-93).  A pod without an app id has neither."""
+    app_id = pod.labels.get(L.SPARK_APP_ID_LABEL)
+    if app_id is None:
+        return None, None
+    soft = (app_id, pod.name) if pod.labels.get(L.SPARK_ROLE_LABEL) == L.EXECUTOR else None
+    return (pod.namespace, app_id, pod.name), soft
 
 
 @dataclass
@@ -135,7 +156,7 @@ class TensorSnapshot:
 _INSTANCE_SEQ = itertools.count()
 
 
-@guarded_by("_lock", "_node_slot", "_pod_slot")
+@guarded_by("_lock", "_node_slot", "_pod_slot", "_pod_resources", "_pod_foreign", "_pod_holds")
 class TensorSnapshotCache:
     def __init__(self, node_informer, pod_informer, rr_cache, soft_store):
         self._lock = threading.RLock()
@@ -185,6 +206,12 @@ class TensorSnapshotCache:
         # their node, so a stored slot index would go stale)
         self._pod_node_name: List[str] = []
         self._pod_active = np.zeros(0, dtype=bool)
+        # per pod slot: its exact requests (the Quantity answers sum
+        # these, never the int rows), whether another scheduler placed
+        # it, and its _holds keys
+        self._pod_resources: List[Optional[Resources]] = []
+        self._pod_foreign = np.zeros(0, dtype=bool)
+        self._pod_holds: List[Tuple[Optional[tuple], Optional[tuple]]] = []
         self._free_pods: List[int] = []
         # what each pod slot adds to _node_overhead now: the node slot
         # (-1: nothing) and the row; a node delete drops the records on
@@ -193,17 +220,16 @@ class TensorSnapshotCache:
         self._pod_counted_row = np.zeros((0, 3), dtype=np.int64)
         # pod slots whose counted state may have changed since the last
         # fold, and the indexes that find them: node name → bound slots
-        # (a node add), bare pod name → slots (a soft reservation)
+        # (a node add; the Quantity answers read it too), bare pod name →
+        # slots (a soft reservation)
         self._dirty_pods: Set[int] = set()
         self._pods_on_node: Dict[str, Set[int]] = {}
         self._pods_named: Dict[str, Set[int]] = {}
-        # pods currently holding a reservation: (ns, name) from RR
-        # status.pods; soft reservations track bare pod names (the
-        # reference's soft lookup ignores namespace,
-        # softreservations.go:133-151)
-        self._reserved_pods: Set[Tuple[str, str]] = set()
-        self._soft_reserved_names: Dict[str, int] = {}
-        self._pod_key_of_slot: Dict[int, Tuple[str, str]] = {}
+        # what the reservations hold, in _holds' keys: (namespace, app
+        # id, pod name) from each ResourceReservation's status.pods, and
+        # a count per (app id, pod name) of soft reservations
+        self._reserved_pods: Set[Tuple[str, str, str]] = set()
+        self._soft_reserved: Dict[Tuple[str, str], int] = {}
 
         node_informer.add_event_handler(
             on_add=self._on_node, on_update=lambda o, n: self._on_node(n),
@@ -323,17 +349,12 @@ class TensorSnapshotCache:
         name = self._node_names[slot]
         if name is None:
             return
-        overhead = (
-            self._node_overhead[slot]
-            if slot < len(self._node_overhead)
-            else np.zeros(3, np.int64)
-        )
         self.classes.note_node(
             slot,
             name,
             self._alloc[slot],
             self._usage[slot],
-            overhead,
+            self._node_overhead[slot],
             int(self._zone_id[slot]),
             bool(self._ready[slot]),
             bool(self._unsched[slot]),
@@ -382,7 +403,7 @@ class TensorSnapshotCache:
                 for node, row in self._rr_rows(old).items():
                     self._apply_usage(node, row, -1)
                 for pod_name in old.status.pods.values():
-                    self._reserved_pods.discard((old.namespace, pod_name))
+                    self._reserved_pods.discard((old.namespace, old.name, pod_name))
                     self._mark_pod((old.namespace, pod_name))
             if new is not None:
                 for reservation in new.spec.reservations.values():
@@ -392,43 +413,48 @@ class TensorSnapshotCache:
                 for node, row in self._rr_rows(new).items():
                     self._apply_usage(node, row, +1)
                 for pod_name in new.status.pods.values():
-                    self._reserved_pods.add((new.namespace, pod_name))
+                    self._reserved_pods.add((new.namespace, new.name, pod_name))
                     self._mark_pod((new.namespace, pod_name))
             ref = new if new is not None else old
             self.feed.publish(
                 DELTA_RESERVATION, ref.name if ref is not None else None
             )
 
-    def _on_soft_change(self, node: str, resources, sign: int, pod_name: str) -> None:
+    def _on_soft_change(self, app_id: str, node: str, resources, sign: int, pod_name: str) -> None:
         with self._lock:
             row, exact = _resources_to_base(resources)
             if not exact:
                 self._exact = False
             self._apply_usage(node, np.array(row, np.int64), sign)
-            count = self._soft_reserved_names.get(pod_name, 0) + sign
+            key = (app_id, pod_name)
+            count = self._soft_reserved.get(key, 0) + sign
             if count <= 0:
-                self._soft_reserved_names.pop(pod_name, None)
+                self._soft_reserved.pop(key, None)
             else:
-                self._soft_reserved_names[pod_name] = count
+                self._soft_reserved[key] = count
             self._dirty_pods.update(self._pods_named.get(pod_name, ()))
             self.feed.publish(DELTA_SOFT_RESERVATION, pod_name)
 
     # -- pod table (overhead) ------------------------------------------------
 
     def _grow_pods(self) -> int:
-        n = len(self._pod_active)
-        extra = _GROW
-        self._pod_requests = np.vstack([self._pod_requests, np.zeros((extra, 3), np.int64)])
-        self._pod_node_name.extend([""] * extra)
-        self._pod_active = np.concatenate([self._pod_active, np.zeros(extra, bool)])
-        self._pod_counted_on = np.concatenate(
-            [self._pod_counted_on, np.full(extra, -1, np.int64)]
-        )
-        self._pod_counted_row = np.vstack(
-            [self._pod_counted_row, np.zeros((extra, 3), np.int64)]
-        )
-        self._free_pods.extend(range(n + extra - 1, n - 1, -1))
-        return self._free_pods.pop()
+        with self._lock:  # reentrant: _on_pod holds it already
+            n = len(self._pod_active)
+            extra = _GROW
+            self._pod_requests = np.vstack([self._pod_requests, np.zeros((extra, 3), np.int64)])
+            self._pod_node_name.extend([""] * extra)
+            self._pod_active = np.concatenate([self._pod_active, np.zeros(extra, bool)])
+            self._pod_resources.extend([None] * extra)
+            self._pod_foreign = np.concatenate([self._pod_foreign, np.zeros(extra, bool)])
+            self._pod_holds.extend([(None, None)] * extra)
+            self._pod_counted_on = np.concatenate(
+                [self._pod_counted_on, np.full(extra, -1, np.int64)]
+            )
+            self._pod_counted_row = np.vstack(
+                [self._pod_counted_row, np.zeros((extra, 3), np.int64)]
+            )
+            self._free_pods.extend(range(n + extra - 1, n - 1, -1))
+            return self._free_pods.pop()
 
     def _mark_pod(self, key: Tuple[str, str]) -> None:
         slot = self._pod_slot.get(key)
@@ -466,54 +492,49 @@ class TensorSnapshotCache:
             if slot is None:
                 slot = self._free_pods.pop() if self._free_pods else self._grow_pods()
                 self._pod_slot[key] = slot
-                self._pod_key_of_slot[slot] = key
                 self._pods_named.setdefault(pod.name, set()).add(slot)
-            row, exact = _resources_to_base(pod_to_resources(pod))
+            requests = pod_to_resources(pod)
+            row, exact = _resources_to_base(requests)
             if not exact:
                 self._exact = False
             self._pod_requests[slot] = row
+            self._pod_resources[slot] = requests
+            self._pod_foreign[slot] = pod.scheduler_name != L.SPARK_SCHEDULER_NAME
+            self._pod_holds[slot] = _holds(pod)
             self._bind_pod(slot, pod.node_name)
             self._pod_active[slot] = True
             self.feed.publish(DELTA_POD, pod.name)
-            if pod.labels.get(L.SPARK_ROLE_LABEL) == L.EXECUTOR and pod.is_terminated():
-                # terminated pods keep informer entries but the reference
-                # counts them via the lister; overhead counts any pod whose
-                # entry exists — parity is with overhead.go which relies on
-                # delete events, so keep the pod until deletion
-                pass
             self._dirty_pods.add(slot)
 
     def _on_pod_delete(self, pod: Pod) -> None:
         with self._lock:
             racecheck.note_access(self, "_pod_slot")
             slot = self._pod_slot.pop((pod.namespace, pod.name), None)
-            if slot is not None:
-                self._pod_active[slot] = False
-                self._bind_pod(slot, "")
-                self._pod_key_of_slot.pop(slot, None)
-                named = self._pods_named[pod.name]
-                named.discard(slot)
-                if not named:
-                    del self._pods_named[pod.name]
-                self._free_pods.append(slot)
-                # its record leaves at the next fold, before any reuse
-                # of the slot can count a new pod there
-                self._dirty_pods.add(slot)
-            was_reserved = (pod.namespace, pod.name) in self._reserved_pods
-            self._reserved_pods.discard((pod.namespace, pod.name))
-            if slot is not None or was_reserved:
-                self.feed.publish(DELTA_POD, pod.name)
+            if slot is None:
+                return
+            self._pod_active[slot] = False
+            self._pod_resources[slot] = None
+            self._bind_pod(slot, "")
+            named = self._pods_named[pod.name]
+            named.discard(slot)
+            if not named:
+                del self._pods_named[pod.name]
+            self._free_pods.append(slot)
+            # its record leaves at the next fold, before any reuse of
+            # the slot can count a new pod there
+            self._dirty_pods.add(slot)
+            self.feed.publish(DELTA_POD, pod.name)
 
     # -- snapshot ------------------------------------------------------------
 
     def _counted_on(self, slot: int) -> int:
         """The node slot one pod slot counts on, or -1: an active pod no
-        reservation holds (overhead.go:139-141; soft reservations match
-        by bare pod name like the reference) on a node the mirror knows."""
+        reservation of its own application holds (overhead.go:139-141,
+        ``_holds``) on a node the mirror knows."""
         if not self._pod_active[slot]:
             return -1
-        key = self._pod_key_of_slot[slot]
-        if key in self._reserved_pods or key[1] in self._soft_reserved_names:
+        hard, soft = self._pod_holds[slot]
+        if hard in self._reserved_pods or soft in self._soft_reserved:
             return -1
         return self._node_slot.get(self._pod_node_name[slot], -1)
 
@@ -522,7 +543,7 @@ class TensorSnapshotCache:
         current one added: one ``mirror.overhead`` span under whatever
         took the snapshot (a Filter's ``fast_path.snapshot`` or
         ``executor.snapshot``, the capacity sampler's ``capacity.sample``,
-        the reconcile)."""
+        the reconcile) or asked a Quantity answer."""
         dirty = np.fromiter(self._dirty_pods, np.int64, len(self._dirty_pods))
         self._dirty_pods = set()
         with tracing.child_span(OVERHEAD_SPAN, {"rows": len(dirty)}):
@@ -549,6 +570,43 @@ class TensorSnapshotCache:
             # refreshes content hashes — class_rev is untouched)
             for slot in changed:
                 self._note_class(int(slot))
+
+    # -- Quantity answers ----------------------------------------------------
+
+    def overhead(self, names: Iterable[str]) -> NodeGroupResources:
+        """Each named node's overhead in exact Quantities: the requests
+        of the pods counted on it (overhead.go:91-96)."""
+        return self._answer(names, foreign_only=False)
+
+    def non_schedulable_overhead(self, names: Iterable[str]) -> NodeGroupResources:
+        """The part of ``overhead`` that pods of other schedulers
+        (daemonsets and the like) take (overhead.go:98-103, read by the
+        unschedulable-pod marker, unschedulablepods.go:149-151)."""
+        return self._answer(names, foreign_only=True)
+
+    def _answer(self, names: Iterable[str], foreign_only: bool) -> NodeGroupResources:
+        """Folds, then gathers under the lock only which counted slots
+        lie on which asked node and their Resources; the exact sums run
+        outside it."""
+        names = list(names)
+        out: NodeGroupResources = {}
+        for start in range(0, len(names), _ANSWER_CHUNK):
+            chunk = names[start : start + _ANSWER_CHUNK]
+            with self._lock:
+                if self._dirty_pods:
+                    self._fold_overhead()
+                on = [self._pods_on_node.get(name, ()) for name in chunk]
+                slots = np.fromiter(itertools.chain.from_iterable(on), np.int64)
+                owner = np.repeat(np.arange(len(chunk)), [len(s) for s in on])
+                kept = self._pod_counted_on[slots] >= 0
+                if foreign_only:
+                    kept &= self._pod_foreign[slots]
+                requests = [self._pod_resources[s] for s in slots[kept].tolist()]
+            totals = [Resources.zero()] * len(chunk)
+            for i, r in zip(owner[kept].tolist(), requests):
+                totals[i] = totals[i].add(r)
+            out.update(zip(chunk, totals))
+        return out
 
     def _recompute_name_ranks(self) -> None:
         live = [i for i, name in enumerate(self._node_names) if name is not None]
@@ -598,9 +656,7 @@ class TensorSnapshotCache:
                 names=names,
                 allocatable=self._alloc[idx].copy(),
                 usage=self._usage[idx].copy(),
-                overhead=self._node_overhead[idx].copy()
-                if len(self._node_overhead) >= len(self._node_names)
-                else np.zeros((len(names), 3), np.int64),
+                overhead=self._node_overhead[idx].copy(),
                 zone_names=zone_names,
                 zone_id=zone_id,
                 ready=ready,

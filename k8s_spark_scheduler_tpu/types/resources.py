@@ -111,6 +111,17 @@ class Resources:
         )
 
 
+def pod_to_resources(pod) -> Resources:
+    """A pod's requests: max(sum of containers, each init container) per
+    dimension (overhead.go:195-209)."""
+    total = Resources.zero()
+    for c in pod.containers:
+        total = total.add(c.requests)
+    for c in pod.init_containers:
+        total = total.set_max(c.requests)
+    return total
+
+
 # NodeGroupResources — map[node]Resources (resources.go:103).  Plain dict,
 # with the reference's in-place Add/Sub helpers as functions.
 NodeGroupResources = Dict[str, Resources]

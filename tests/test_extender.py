@@ -561,6 +561,7 @@ def per_pod_reference_scan(h, packer, timeout=600.0):
     """The scan as unschedulablepods.go:93-129 runs it, pod by pod on the
     host oracle: the condition writes it would make, in order."""
     from k8s_spark_scheduler_tpu.scheduler import labels as L
+    from k8s_spark_scheduler_tpu.scheduler.overhead import node_overhead
     from k8s_spark_scheduler_tpu.scheduler.sparkpods import AnnotationError, spark_resources
     from k8s_spark_scheduler_tpu.types.resources import (
         Resources,
@@ -584,10 +585,11 @@ def per_pod_reference_scan(h, packer, timeout=600.0):
             break
         nodes = server.node_informer.list_with_predicate(pod.matches_node)
         names = [n.name for n in nodes]
+        _, non_schedulable = node_overhead(
+            server.pod_informer.list(), server.resource_reservation_manager, names
+        )
         metadata = node_scheduling_metadata_for_nodes(
-            nodes,
-            {n.name: Resources.zero() for n in nodes},
-            server.overhead_computer.get_non_schedulable_overhead(nodes),
+            nodes, {n.name: Resources.zero() for n in nodes}, non_schedulable
         )
         fits = packer(
             app.driver_resources, app.executor_resources, app.min_executor_count,

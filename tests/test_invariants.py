@@ -49,3 +49,34 @@ def test_invariants_catch_corruption():
         assert any(v.startswith("I1") for v in violations)
     finally:
         h.close()
+
+
+def test_invariant_i5_catches_a_mirror_blind_to_daemonset_pods(monkeypatch):
+    """I5 holds the mirror to the overhead read from scratch: a mirror
+    that never sees a pod another scheduler bound drifts on that node."""
+    from k8s_spark_scheduler_tpu.scheduler import labels as L
+    from k8s_spark_scheduler_tpu.state.tensor_snapshot import TensorSnapshotCache
+    from k8s_spark_scheduler_tpu.types.objects import Container, ObjectMeta, Pod, PodPhase
+    from k8s_spark_scheduler_tpu.types.resources import Resources
+
+    real = TensorSnapshotCache._on_pod
+
+    def blind(self, pod):
+        if pod.scheduler_name == L.SPARK_SCHEDULER_NAME:
+            real(self, pod)
+
+    monkeypatch.setattr(TensorSnapshotCache, "_on_pod", blind)
+    h = Harness(binpack_algo="tpu-batch")
+    try:
+        h.new_node("n1")
+        h.new_node("n2")
+        assert invariants.check(h.server) == []
+        h.create_pod(Pod(
+            meta=ObjectMeta(name="daemon-n1", namespace="kube-system"),
+            scheduler_name="default-scheduler", node_name="n1",
+            containers=[Container("agent", Resources.of("100m", "128Mi"))], phase=PodPhase.RUNNING,
+        ))
+        violations = invariants.check(h.server, raise_on_violation=False)
+        assert [v.split(":")[0] for v in violations] == ["I5"] and "n1" in violations[0]
+    finally:
+        h.close()

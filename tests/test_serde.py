@@ -79,7 +79,7 @@ def test_serde_roundtrip_properties():
 def test_pod_init_containers_round_trip_and_requests():
     """initContainers parse + serialize; pod requests = max(sum of
     containers, each init container) per dimension (overhead.go:195-209)."""
-    from k8s_spark_scheduler_tpu.scheduler.overhead import pod_to_resources
+    from k8s_spark_scheduler_tpu.types.resources import pod_to_resources
     from k8s_spark_scheduler_tpu.types import serde
     from k8s_spark_scheduler_tpu.types.resources import Resources
 

@@ -9,16 +9,27 @@ import numpy as np
 import pytest
 
 from k8s_spark_scheduler_tpu.ops.tensorize import _resources_to_base
+from k8s_spark_scheduler_tpu.scheduler import labels as L
+from k8s_spark_scheduler_tpu.scheduler.overhead import node_overhead
 from k8s_spark_scheduler_tpu.testing.harness import Harness
 from k8s_spark_scheduler_tpu.types.resources import (
     node_scheduling_metadata_for_nodes,
+    pod_to_resources,
 )
+
+
+def _reference_overhead(harness, nodes):
+    """(overhead, non-schedulable overhead) of ``nodes`` read from scratch."""
+    server = harness.server
+    return node_overhead(
+        server.pod_informer.list(), server.resource_reservation_manager, [n.name for n in nodes]
+    )
 
 
 def _slowpath_rows(harness, nodes):
     """The Quantity path's availability, as base-unit int rows."""
     usage = harness.server.resource_reservation_manager.get_reserved_resources()
-    overhead = harness.server.overhead_computer.get_overhead(nodes)
+    overhead, _ = _reference_overhead(harness, nodes)
     metadata = node_scheduling_metadata_for_nodes(nodes, usage, overhead)
     rows = {}
     for name, md in metadata.items():
@@ -211,7 +222,7 @@ def test_fast_path_label_priority_order_matches_nodesorter(dlp, elp):
 
         nodes = h.server.node_informer.list()
         usage = h.server.resource_reservation_manager.get_reserved_resources()
-        overhead = h.server.overhead_computer.get_overhead(nodes)
+        overhead, _ = _reference_overhead(h, nodes)
         metadata = node_scheduling_metadata_for_nodes(nodes, usage, overhead)
         sorter = NodeSorter(dlp, elp)
         expect_driver, expect_executor = sorter.potential_nodes(metadata, candidates)
@@ -478,18 +489,45 @@ def _bare_mirror():
     return TensorSnapshotCache(_NoFeed(), _NoFeed(), _NoFeed(), _NoFeed())
 
 
-def _full_walk(mirror):
-    """The overhead the whole pod table gives: each active slot whose
-    (namespace, name) no reservation's status.pods holds and whose bare
-    name no soft reservation holds, added on its node if the mirror
-    knows the node."""
+class _Reservations:
+    """What the reservation manager reads, as plain dicts fed the same
+    events as the mirror: the ResourceReservation of each (namespace,
+    app id) and a count of soft reservations per (app id, pod name)."""
+
+    def __init__(self):
+        self.hard = {}
+        self.soft = {}
+
+    def get(self, namespace, name):
+        return self.hard.get((namespace, name))
+
+    def executor_has_soft_reservation(self, pod):
+        return self.soft.get((pod.labels.get(L.SPARK_APP_ID_LABEL), pod.name), 0) > 0
+
+    def manager(self):
+        """The reservation manager itself over these dicts: its
+        ``pod_has_reservation`` is the rule under test."""
+        from k8s_spark_scheduler_tpu.scheduler.reservations_manager import ResourceReservationManager
+
+        return ResourceReservationManager(self, self, None, _NoFeed())
+
+
+def _full_walk(mirror, pods, held):
+    """The overhead rows a walk over every pod gives (overhead.go:120-153),
+    read with the reservation manager's rule: each pod it does not hold
+    adds its requests on its node, if the mirror knows the node."""
+    rrm = held.manager()
     overhead = np.zeros((len(mirror._node_names), 3), np.int64)
-    for slot in map(int, np.flatnonzero(mirror._pod_active)):
-        key = mirror._pod_key_of_slot[slot]
-        node = mirror._node_slot.get(mirror._pod_node_name[slot])
-        if key not in mirror._reserved_pods and key[1] not in mirror._soft_reserved_names and node is not None:
-            overhead[node] += mirror._pod_requests[slot]
+    for pod in pods:
+        node = mirror._node_slot.get(pod.node_name)
+        if node is not None and not rrm.pod_has_reservation(pod):
+            overhead[node] += _resources_to_base(pod_to_resources(pod))[0]
     return overhead
+
+
+def _same(got, expected):
+    """Two NodeGroupResources equal node for node, in exact Quantities."""
+    return got.keys() == expected.keys() and all(got[n].eq(expected[n]) for n in expected)
 
 
 def _folded(mirror):
@@ -502,12 +540,12 @@ def _folded(mirror):
     return sum(c.tags["rows"] for c in root.children if c.name == OVERHEAD_SPAN)
 
 
-def _pod(namespace, name, node, requests):
+def _pod(namespace, name, node, requests, labels=None, scheduler_name=""):
     from k8s_spark_scheduler_tpu.types.objects import Container, ObjectMeta, Pod, PodPhase
 
     return Pod(
-        meta=ObjectMeta(name=name, namespace=namespace), node_name=node,
-        containers=[Container("main", requests)], phase=PodPhase.RUNNING,
+        meta=ObjectMeta(name=name, namespace=namespace, labels=labels or {}), node_name=node,
+        scheduler_name=scheduler_name, containers=[Container("main", requests)], phase=PodPhase.RUNNING,
     )
 
 
@@ -527,30 +565,36 @@ def _reservation(namespace, name, nodes, pods, requests):
 
 _FOLD_COVERAGE = {
     "pod before its node", "node slot reused by another name before a fold", "pod moved", "pod unbound",
-    "reservation moved its pods", "pod in two reservations", "soft name in two namespaces",
-    "soft count down from two", "reserved pod deleted", "inexact quantity",
+    "reservation moved its pods", "pod in two reservations", "pod listed by another application's reservation",
+    "unlabelled pod listed by a reservation", "soft name of a driver", "soft name of another application's pod",
+    "soft name in two namespaces", "soft count down from two", "reserved pod deleted",
+    "deleted reserved pod bound again", "inexact quantity",
 }
 
 
 class _Churn:
-    """Seeded random events into a bare mirror: the log that replays
-    them into a fresh one, and the situations the sequence covered."""
+    """Seeded random events into a bare mirror and the same events into
+    plain dicts of what the reservations hold: the log that replays them
+    into a fresh mirror, and the situations the sequence covered."""
 
     NAMESPACES = ("ns-a", "ns-b")
     NODES = [f"n{i}" for i in range(5)]
     PODS = [f"p{i}" for i in range(8)]
+    APPS = ["rr0", "rr1", "rr2", "rr3"]  # a reservation is named for its application
 
     def __init__(self, seed):
         from k8s_spark_scheduler_tpu.types.resources import Resources
 
         self.rng = random.Random(seed)
         self.mirror = _bare_mirror()
+        self.held = _Reservations()
+        self.rrm = self.held.manager()
         self.log = []
         self.nodes = set()
-        self.pods = {}         # (namespace, name) -> node name, "" unbound
-        self.reservations = {}  # name -> ResourceReservation
-        self.soft = {}         # bare pod name -> [(node, requests)] held
-        self.freed = {}        # node slot -> the name deleted from it since the last snapshot
+        self.pods = {}          # (namespace, name) -> the pod as last seen, node_name "" unbound
+        self.soft = {}          # (app id, pod name) -> [(node, requests)] held
+        self.freed = {}         # node slot -> the name deleted from it since the last snapshot
+        self.deleted_reserved = {}  # (namespace, name) -> the labels of a pod deleted while reserved
         self.covered = set()
         self.requests = [Resources.of("100m", "128Mi"), Resources.of("1", "1Gi"), Resources.of("250m", "512Mi", "1")]
         self.inexact = Resources.of("100m", "1500m")  # memory not a whole byte
@@ -559,6 +603,9 @@ class _Churn:
         self.log.append((handler, args))
         getattr(self.mirror, handler)(*args)
 
+    def named(self, name):
+        return [pod for (_, pod_name), pod in self.pods.items() if pod_name == name]
+
     def step(self):
         from k8s_spark_scheduler_tpu.types.objects import Node, ObjectMeta
         from k8s_spark_scheduler_tpu.types.resources import Resources
@@ -566,7 +613,7 @@ class _Churn:
         rng, action = self.rng, self.rng.random()
         if action < 0.12:
             name = rng.choice(self.NODES)
-            if name not in self.nodes and name in self.pods.values():
+            if name not in self.nodes and name in {pod.node_name for pod in self.pods.values()}:
                 self.covered.add("pod before its node")
             node = Node(meta=ObjectMeta(name=name, labels={"pool": rng.choice("ab")}),
                         allocatable=Resources.of("16", "64Gi"))
@@ -582,7 +629,7 @@ class _Churn:
         elif action < 0.5:
             key = (rng.choice(self.NAMESPACES), rng.choice(self.PODS))
             node = "" if rng.random() < 0.15 else rng.choice(self.NODES)
-            was = self.pods.get(key, "")
+            was = self.pods[key].node_name if key in self.pods else ""
             if was and node and node != was:
                 self.covered.add("pod moved")
             if was and not node:
@@ -590,18 +637,31 @@ class _Churn:
             requests = rng.choice(self.requests)
             if rng.random() < 0.03:
                 requests = self.inexact
-                self.covered.add("inexact quantity")
-            self.apply("_on_pod", _pod(*key, node, requests))
+                if node:  # the mirror tables bound pods alone
+                    self.covered.add("inexact quantity")
+            app = rng.choice(self.APPS + [None])
+            labels = {} if app is None else {
+                L.SPARK_APP_ID_LABEL: app, L.SPARK_ROLE_LABEL: rng.choice([L.DRIVER, L.EXECUTOR, L.EXECUTOR]),
+            }
+            if key in self.deleted_reserved and rng.random() < 0.5:
+                labels = self.deleted_reserved[key]  # the same pod again, by name and application
+            scheduler = L.SPARK_SCHEDULER_NAME if rng.random() < 0.7 else "default-scheduler"
+            pod = _pod(*key, node, requests, labels, scheduler)
+            if node and key in self.deleted_reserved and self.rrm.pod_has_reservation(pod):
+                self.covered.add("deleted reserved pod bound again")
+            self.apply("_on_pod", pod)
             if node or key in self.pods:
-                self.pods[key] = node
+                self.pods[key] = pod
         elif action < 0.6 and self.pods:
             key = rng.choice(sorted(self.pods))
-            if key in self.mirror._reserved_pods:
+            pod = self.pods.pop(key)
+            if self.rrm.pod_has_reservation(pod):
                 self.covered.add("reserved pod deleted")
-            self.apply("_on_pod_delete", _pod(*key, self.pods.pop(key), self.requests[0]))
+                self.deleted_reserved[key] = pod.labels
+            self.apply("_on_pod_delete", pod)
         elif action < 0.85:
-            name = rng.choice(["rr0", "rr1", "rr2", "rr3"])
-            old = self.reservations.get(name)
+            name = rng.choice(self.APPS)
+            old = next((rr for (_, app), rr in self.held.hard.items() if app == name), None)
             namespace = old.namespace if old is not None else rng.choice(self.NAMESPACES)
             new = None
             if old is None or rng.random() < 0.7:
@@ -613,19 +673,25 @@ class _Churn:
             if old is not None and before != after:
                 self.covered.add("reservation moved its pods")
             others = {
-                (rr.namespace, pod) for other, rr in self.reservations.items() if other != name
+                (rr.namespace, pod) for (_, app), rr in self.held.hard.items() if app != name
                 for pod in rr.status.pods.values()
             }
             if {(namespace, pod) for pod in before | after} & others:
                 self.covered.add("pod in two reservations")
+            for pod_name in after:
+                listed = self.pods.get((namespace, pod_name))
+                if listed is not None and listed.labels.get(L.SPARK_APP_ID_LABEL) is None:
+                    self.covered.add("unlabelled pod listed by a reservation")
+                elif listed is not None and listed.labels[L.SPARK_APP_ID_LABEL] != name:
+                    self.covered.add("pod listed by another application's reservation")
             self.apply("_on_rr_change", old, new)
             if new is None:
-                del self.reservations[name]
+                del self.held.hard[(namespace, name)]
             else:
-                self.reservations[name] = new
+                self.held.hard[(namespace, name)] = new
         else:
-            name = rng.choice(self.PODS)
-            held = self.soft.setdefault(name, [])
+            app, name = rng.choice(self.APPS), rng.choice(self.PODS)
+            held = self.soft.setdefault((app, name), [])
             if held and rng.random() < 0.5:
                 if len(held) == 2:
                     self.covered.add("soft count down from two")
@@ -635,9 +701,15 @@ class _Churn:
                 node, requests = rng.choice(self.NODES), rng.choice(self.requests)
                 held.append((node, requests))
                 sign = +1
+            for pod in self.named(name):
+                if pod.labels.get(L.SPARK_APP_ID_LABEL) not in (None, app):
+                    self.covered.add("soft name of another application's pod")
+                elif pod.labels.get(L.SPARK_ROLE_LABEL) == L.DRIVER:
+                    self.covered.add("soft name of a driver")
             if all((namespace, name) in self.pods for namespace in self.NAMESPACES):
                 self.covered.add("soft name in two namespaces")
-            self.apply("_on_soft_change", node, requests, sign, name)
+            self.held.soft[(app, name)] = len(held)
+            self.apply("_on_soft_change", app, node, requests, sign, name)
 
     def replayed(self):
         fresh = _bare_mirror()
@@ -646,11 +718,16 @@ class _Churn:
         return fresh
 
 
-@pytest.mark.parametrize("seed", range(6))
-def test_the_overhead_fold_equals_the_full_walk_after_every_snapshot(seed):
-    """Every snapshot's overhead is the whole table's walk, bit for bit,
-    and the class digest is what a mirror that saw the same events and
-    folded them once, at its first snapshot, stamps."""
+@pytest.mark.parametrize("seed,answer", [
+    *(pytest.param(seed, "rows", id=str(seed)) for seed in range(6)),
+    *(pytest.param(seed, "non-schedulable", id=f"non-schedulable-{seed}") for seed in range(6)),
+])
+def test_the_overhead_fold_equals_the_full_walk_after_every_snapshot(seed, answer):
+    """Every snapshot's overhead is a from-scratch walk with the
+    reservation manager's rule, bit for bit, and the class digest is what
+    a mirror that saw the same events and folded them once, at its first
+    snapshot, stamps; or, in exact Quantities, the mirror's overhead and
+    non-schedulable answers are what ``node_overhead`` reads."""
     churn = _Churn(seed)
     snapshots = 0
     for _ in range(400):
@@ -659,16 +736,148 @@ def test_the_overhead_fold_equals_the_full_walk_after_every_snapshot(seed):
             mirror = churn.mirror
             snap = mirror.snapshot()
             assert not mirror._dirty_pods
-            assert np.array_equal(mirror._node_overhead, _full_walk(mirror))
-            live = [mirror._node_slot[name] for name in snap.names]
-            assert np.array_equal(snap.overhead, mirror._node_overhead[live])
-            fresh = churn.replayed()
-            assert snap.class_digest[1] == fresh.snapshot().class_digest[1]
-            assert snap.exact == fresh._exact == ("inexact quantity" not in churn.covered)
+            if answer == "rows":
+                assert np.array_equal(mirror._node_overhead, _full_walk(mirror, churn.pods.values(), churn.held))
+                live = [mirror._node_slot[name] for name in snap.names]
+                assert np.array_equal(snap.overhead, mirror._node_overhead[live])
+                fresh = churn.replayed()
+                assert snap.class_digest[1] == fresh.snapshot().class_digest[1]
+                assert snap.exact == fresh._exact == ("inexact quantity" not in churn.covered)
+            else:
+                names = sorted(churn.nodes)
+                overhead, non_schedulable = node_overhead(churn.pods.values(), churn.rrm, names)
+                assert _same(mirror.overhead(names), overhead)
+                assert _same(mirror.non_schedulable_overhead(names), non_schedulable)
             churn.freed.clear()
             snapshots += 1
     assert snapshots > 50
     assert churn.covered == _FOLD_COVERAGE
+
+
+# (pod labels, the application whose reservation lists the pod, the
+# application whose soft reservation holds its name): none holds it
+_NOT_HELD = {
+    "listed by another application's reservation": ({L.SPARK_APP_ID_LABEL: "app-a", L.SPARK_ROLE_LABEL: L.EXECUTOR}, "app-b", None),
+    "listed by a reservation without an app id": ({}, "app-a", None),
+    "a driver with a soft-reserved executor's name": ({L.SPARK_APP_ID_LABEL: "app-a", L.SPARK_ROLE_LABEL: L.DRIVER}, None, "app-a"),
+    "another application's executor with a soft-reserved name": (
+        {L.SPARK_APP_ID_LABEL: "app-b", L.SPARK_ROLE_LABEL: L.EXECUTOR}, None, "app-a",
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_NOT_HELD))
+def test_a_reservation_holds_only_its_own_applications_pods(case):
+    """A pod counts as overhead unless its own application's reservation
+    lists it, or, for an executor, its own application's soft reservation
+    holds it (resourcereservations.go:115-132): the mirror's rows and
+    answers count each of these pods, as the reference does."""
+    from k8s_spark_scheduler_tpu.types.objects import Node, ObjectMeta
+    from k8s_spark_scheduler_tpu.types.resources import Resources
+
+    labels, listed_by, soft_for = _NOT_HELD[case]
+    mirror, held = _bare_mirror(), _Reservations()
+    mirror._on_node(Node(meta=ObjectMeta(name="n0"), allocatable=Resources.of("16", "64Gi")))
+    pod = _pod("ns", "p", "n0", Resources.of("1", "1Gi"), labels, L.SPARK_SCHEDULER_NAME)
+    mirror._on_pod(pod)
+    if listed_by is not None:
+        held.hard[("ns", listed_by)] = _reservation("ns", listed_by, ["n0"], ["p"], Resources.of("2", "2Gi"))
+        mirror._on_rr_change(None, held.hard[("ns", listed_by)])
+    if soft_for is not None:
+        held.soft[(soft_for, "p")] = 1
+        mirror._on_soft_change(soft_for, "n0", Resources.of("1", "1Gi"), +1, "p")
+    overhead, non_schedulable = node_overhead([pod], held.manager(), ["n0"])
+    assert overhead["n0"].eq(Resources.of("1", "1Gi")) and non_schedulable["n0"].eq(Resources.zero())
+    assert mirror.snapshot().overhead.tolist() == [_resources_to_base(overhead["n0"])[0]]
+    assert _same(mirror.overhead(["n0"]), overhead)
+    assert _same(mirror.non_schedulable_overhead(["n0"]), non_schedulable)
+
+
+def test_the_quantity_answers_equal_the_reference_under_inexact_quantities():
+    """Requests no int row holds exactly (a micro-cpu, a milli-byte): the
+    mirror is inexact, and its Quantity answers are still the reference's
+    to the last fraction, as they sum each pod's own Resources."""
+    from k8s_spark_scheduler_tpu.types.resources import Resources
+
+    h = Harness(binpack_algo="tpu-batch")
+    try:
+        for i in range(3):
+            h.new_node(f"n{i}")
+        for name, node, cpu, memory, scheduler in [
+            ("d0", "n0", "100u", "500m", "default-scheduler"),
+            ("d1", "n0", "1", "1500m", "default-scheduler"),
+            ("d2", "n1", "333u", "1Gi", "default-scheduler"),
+            ("stray", "n1", "100u", "500m", L.SPARK_SCHEDULER_NAME),  # ours, yet no reservation holds it
+        ]:
+            h.create_pod(_pod("kube-system", name, node, Resources.of(cpu, memory), scheduler_name=scheduler))
+        mirror = h.server.tensor_snapshot
+        assert not mirror.snapshot().exact
+        nodes = h.server.node_informer.list()
+        names = [n.name for n in nodes]
+        overhead, non_schedulable = _reference_overhead(h, nodes)
+        assert _same(mirror.overhead(names), overhead)
+        assert _same(mirror.non_schedulable_overhead(names), non_schedulable)
+        assert overhead["n0"].eq(Resources.of("1000100u", "2"))  # 500m + 1500m bytes: two whole bytes
+        assert not overhead["n1"].eq(non_schedulable["n1"])
+        assert overhead["n2"].eq(Resources.zero())
+    finally:
+        h.close()
+
+
+def test_quantity_answers_asked_beside_pod_events_end_equal_to_the_reference():
+    """Readers ask ``non_schedulable_overhead`` while writers bind, move
+    and delete pods, with the interpreter switching threads every 10 µs:
+    no reader fails, and once the writers stop the answer is the
+    reference's (a lost or torn update would leave it off)."""
+    import sys
+    import threading
+
+    from k8s_spark_scheduler_tpu.types.objects import Node, ObjectMeta
+    from k8s_spark_scheduler_tpu.types.resources import Resources
+
+    mirror, held = _bare_mirror(), _Reservations()
+    names = [f"n{i}" for i in range(8)]
+    for name in names:
+        mirror._on_node(Node(meta=ObjectMeta(name=name), allocatable=Resources.of("64", "256Gi")))
+    pods, errors, stop = {}, [], threading.Event()
+
+    def write(w):
+        rng = random.Random(w)
+        for i in range(400):
+            key = ("kube-system", f"w{w}-p{rng.randrange(40)}")
+            if rng.random() < 0.25:
+                mirror._on_pod_delete(_pod(*key, "", Resources.zero()))
+                pods.pop(key, None)
+            else:
+                pod = _pod(*key, rng.choice(names), Resources.of(f"{rng.randint(1, 9)}00m", "1Gi"),
+                           scheduler_name="default-scheduler")
+                mirror._on_pod(pod)
+                pods[key] = pod
+
+    def read():
+        try:
+            while not stop.is_set():
+                mirror.non_schedulable_overhead(names)
+        except Exception as err:  # a reader must never fail
+            errors.append(err)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        readers = [threading.Thread(target=read) for _ in range(2)]
+        writers = [threading.Thread(target=write, args=(w,)) for w in range(6)]
+        for t in readers + writers:
+            t.start()
+        for t in writers:
+            t.join(timeout=60)
+        stop.set()
+        for t in readers:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in readers + writers) and errors == []
+    _, non_schedulable = node_overhead(pods.values(), held.manager(), names)
+    assert _same(mirror.non_schedulable_overhead(names), non_schedulable)
 
 
 def test_one_event_folds_its_own_slots_on_a_mirror_of_fifty_thousand_bound_pods():
@@ -681,13 +890,18 @@ def test_one_event_folds_its_own_slots_on_a_mirror_of_fifty_thousand_bound_pods(
     for i in range(100):
         mirror._on_node(Node(meta=ObjectMeta(name=f"n{i}"), allocatable=Resources.of("64", "256Gi")))
     requests = Resources.of("100m", "128Mi")
-    for i in range(50_000):
-        mirror._on_pod(_pod("ns", f"p{i}", f"n{i % 100}", requests))
+    labels = {L.SPARK_APP_ID_LABEL: "app", L.SPARK_ROLE_LABEL: L.EXECUTOR}
+    pods = {f"p{i}": _pod("ns", f"p{i}", f"n{i % 100}", requests, labels) for i in range(50_000)}
+    for pod in pods.values():
+        mirror._on_pod(pod)
     assert _folded(mirror) == 50_000  # start-up: the whole table, once
     assert _folded(mirror) == 0
-    mirror._on_pod(_pod("ns", "p7", "n8", requests))  # a bound pod moves
+    pods["p7"] = _pod("ns", "p7", "n8", requests, labels)  # a bound pod moves
+    mirror._on_pod(pods["p7"])
     assert _folded(mirror) == 1
-    pods = ["p1", "p2", "p3"]
-    mirror._on_rr_change(None, _reservation("ns", "app", ["n1", "n2", "n3"], pods, requests))
-    assert _folded(mirror) == len(pods)
-    assert np.array_equal(mirror._node_overhead, _full_walk(mirror))
+    held = _Reservations()
+    held.hard[("ns", "app")] = _reservation("ns", "app", ["n1", "n2", "n3"], ["p1", "p2", "p3"], requests)
+    mirror._on_rr_change(None, held.hard[("ns", "app")])
+    assert _folded(mirror) == 3
+    assert np.array_equal(mirror._node_overhead, _full_walk(mirror, pods.values(), held))
+
